@@ -448,7 +448,6 @@ def timer(name, logger=None):
         logging.getLogger('timer').info(msg)
 
 
-from . import _jax_compat  # noqa: E402,F401  (backfills jax.shard_map on old jax)
 from .parallel.runtime import CurrentMesh, use_mesh, cpu_mesh, tpu_mesh  # noqa: E402,F401
 
 

@@ -1,86 +1,32 @@
-"""Compatibility shims across the jax versions the graft toolchain
-ships.
-
-The framework is written against the current jax surface
-(``jax.shard_map``, the ``jax_num_cpu_devices`` config); some images
-bake an older jax (0.4.x) where ``shard_map`` still lives in
-``jax.experimental`` and virtual CPU devices are only reachable through
-``XLA_FLAGS``.  :func:`apply` runs once at package import (idempotent)
-and backfills the modern names, so the rest of the codebase — and the
-test suite — uses one spelling everywhere.
-"""
+"""Process-level jax configuration, for the one installation there is
+(jax 0.9): how many virtual CPU devices a CPU run gets, and where the
+persistent compile cache lives.  Both must be called before the first
+backend initializes (the first ``jax.devices()``)."""
 
 import os
 
 import jax
 
 
-def apply():
-    """Backfill modern jax API names onto an older jax. Idempotent."""
-    if not hasattr(jax, 'shard_map'):
-        import functools
-
-        from jax.experimental.shard_map import shard_map as _shard_map
-
-        @functools.wraps(_shard_map)
-        def shard_map(f, *args, **kwargs):
-            # the old experimental shard_map has no replication rule
-            # for while_loop (used by the sort paint kernel and the
-            # distributed sample sort); modern jax handles it with the
-            # check enabled, so disabling the check here is the
-            # behavior-preserving translation, not a semantics change
-            kwargs.setdefault('check_rep', False)
-            return _shard_map(f, *args, **kwargs)
-
-        jax.shard_map = shard_map
-
-        # modern jax defaults to partitionable threefry; the framework's
-        # RNG contract (draws are a function of (seed, global index),
-        # device-count invariant — rng.py) depends on it for sharded
-        # draws, so restore that default on old jax as well
-        try:
-            if not jax.config.jax_threefry_partitionable:
-                jax.config.update('jax_threefry_partitionable', True)
-        except AttributeError:  # pragma: no cover - very old jax
-            pass
-
-    # the varying-manual-axes (vma) type system does not exist on old
-    # jax, so its casts are identities there — and with check_rep=False
-    # the shard_map type checker never asks for them
-    if not hasattr(jax.lax, 'pvary'):
-        jax.lax.pvary = lambda x, axis_name=None: x
-    if not hasattr(jax.lax, 'pcast'):
-        jax.lax.pcast = lambda x, axis_name=None, to=None: x
-    if not hasattr(jax, 'typeof'):
-        def _typeof(x):
-            from jax import core
-            return core.get_aval(x)
-
-        jax.typeof = _typeof
-
-
 def set_cpu_devices(n):
-    """Request ``n`` virtual CPU devices, version-robustly.
-
-    Newer jax exposes the ``jax_num_cpu_devices`` config; older ones
-    only honor ``--xla_force_host_platform_device_count`` via
-    ``XLA_FLAGS``, which still takes effect when set before the first
-    backend initialization (i.e. before the first ``jax.devices()``
-    call).  Returns True when the config path worked, False when the
-    env-flag fallback was used.
-    """
-    n = int(n)
-    try:
-        jax.config.update('jax_num_cpu_devices', n)
-        return True
-    except AttributeError:
-        pass
-    flags = os.environ.get('XLA_FLAGS', '')
-    if 'xla_force_host_platform_device_count' not in flags:
-        os.environ['XLA_FLAGS'] = (
-            flags + ' --xla_force_host_platform_device_count=%d' % n
-        ).strip()
-    return False
+    """Request ``n`` virtual CPU devices (the multi-device rehearsal
+    mesh of the tests and the ``--devices`` CLIs)."""
+    jax.config.update('jax_num_cpu_devices', int(n))
 
 
-apply()
+def enable_compile_cache():
+    """Turn on jax's persistent compile cache; returns its directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, jax itself reads it
+    and no path is set in code; otherwise the cache is
+    ``<checkout>/.jax_cache`` (a fixed path: the path is part of the
+    cache's key).  Every entry point — chip_smoke.py, bench.py, the
+    serve and tune CLIs, tests/conftest.py — calls this one helper."""
+    cache = os.environ.get('JAX_COMPILATION_CACHE_DIR')
+    if not cache:
+        cache = os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), '.jax_cache')
+        jax.config.update('jax_compilation_cache_dir', cache)
+    jax.config.update('jax_persistent_cache_min_compile_time_secs', 0.0)
+    jax.config.update('jax_persistent_cache_min_entry_size_bytes', -1)
+    return cache

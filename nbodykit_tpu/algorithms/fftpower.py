@@ -172,7 +172,7 @@ def project_to_basis(y3d, edges, los=[0, 0, 1], poles=[]):
     # loops over its own rows and psums the small histograms) — the
     # per-device memory hazard is worst exactly in the multi-chip
     # configuration (round-2 VERDICT weak #4).
-    from ..parallel.runtime import mesh_size, AXIS
+    from ..parallel.runtime import mesh_size, vary_like, AXIS
     S0, S1, S2 = (int(s) for s in value.shape)
     try:
         nproc = mesh_size(getattr(pm, 'comm', None))
@@ -253,7 +253,7 @@ def project_to_basis(y3d, edges, los=[0, 0, 1], poles=[]):
 
     nstreams = 3 + Nell * (2 if is_cplx else 1)
 
-    def _block_hists(v_loc, base, varying=False):
+    def _block_hists(v_loc, base):
         """Histograms of one device's (S0_local, S1, S2) block starting
         at global row ``base``, chunk-looped so only ``rows`` rows of
         temporaries are live. Cross-chunk sums are Kahan-compensated:
@@ -278,16 +278,10 @@ def project_to_basis(y3d, edges, los=[0, 0, 1], poles=[]):
                   for _ in range(nstreams)]
         init_c = [jnp.zeros((Nx + 2, Nmu + 2), hist_dtype)
                   for _ in range(nstreams)]
-        if varying:
-            # inside shard_map the body outputs are device-varying;
-            # the carry init must carry the same vma type
-            def _vary(a):
-                pcast = getattr(jax.lax, 'pcast', None)
-                if pcast is not None:
-                    return pcast(a, AXIS, to='varying')
-                return jax.lax.pvary(a, AXIS)
-            init_a = [_vary(a) for a in init_a]
-            init_c = [_vary(a) for a in init_c]
+        # inside shard_map the body folds device-local rows into the
+        # carry, so it must start with the block's varying type
+        init_a = [vary_like(a, v_loc) for a in init_a]
+        init_c = [vary_like(a, v_loc) for a in init_c]
         acc, _ = jax.lax.fori_loop(0, nch, body, (init_a, init_c))
         return acc
 
@@ -299,7 +293,7 @@ def project_to_basis(y3d, edges, los=[0, 0, 1], poles=[]):
 
         def _local(v_loc):
             base = jax.lax.axis_index(AXIS) * S0_local
-            hs = _block_hists(v_loc, base, varying=True)
+            hs = _block_hists(v_loc, base)
             return tuple(jax.lax.psum(h, AXIS) for h in hs)
 
         _bin = instrumented_jit(jax.shard_map(

@@ -20,10 +20,10 @@ them all.
 
     python -m nbodykit_tpu.diagnostics --regress [ROOT]
         Build BENCH_HISTORY.json from the BENCH_r*.json /
-        BASELINE*.json / BENCH_TPU_CACHE.json family under ROOT
-        (default .) and print the verdicts.  Exits nonzero on a
-        malformed bench record (the smoke-gate contract); stale cache
-        replays and regressions warn loudly but do not block.
+        BASELINE*.json family under ROOT (default .) and print the
+        verdicts.  Exits nonzero on a malformed bench record (the
+        smoke-gate contract); regressions warn loudly but do not
+        block.
 
     python -m nbodykit_tpu.diagnostics --chrome PATH
         Export PATH to chrome_trace.json for ui.perfetto.dev.
@@ -151,14 +151,12 @@ def run_analyze(path, out=None):
     return 0
 
 
-def run_regress(root, out=None, threshold=0.25,
-                stale_hours=24.0, write=True):
+def run_regress(root, out=None, threshold=0.25, write=True):
     """--regress: build + print the bench history; the exit code is
     the CI gate (nonzero only on malformed records)."""
     from .regress import build_history, gate_rc, render_regress
     out = out if out is not None else sys.stdout
-    history = build_history(root, threshold=threshold,
-                            stale_hours=stale_hours, write=write)
+    history = build_history(root, threshold=threshold, write=write)
     out.write(render_regress(history))
     return gate_rc(history)
 
@@ -284,14 +282,14 @@ def _resilience_counts(trace):
 
 
 def run_doctor(trace=None, root='.', self_check_only=False,
-               out=None, threshold=0.25, stale_hours=24.0):
+               out=None, threshold=0.25):
     """Self-check + analyze + regress + lint, one verdict block.
 
     Returns 0 (OK/WARN) or 1 (FAIL).  FAIL means the diagnostics stack
     itself is broken, a trace shows a hung collective or silent
     process, a committed bench record is malformed, the lint gate
     has non-baselined findings, or TUNE_CACHE.json is malformed.
-    WARN covers stale replays, regressions, compile-cache misses
+    WARN covers regressions, compile-cache misses
     whose jit label carries an open NBK2xx finding (the
     static/runtime cross-link), device live-byte watermarks past half
     a v5e's HBM while open NBK5xx (donation/peak) findings exist (the
@@ -358,8 +356,7 @@ def run_doctor(trace=None, root='.', self_check_only=False,
     if root is not None:
         from .regress import build_history, render_regress
         try:
-            history = build_history(root, threshold=threshold,
-                                    stale_hours=stale_hours)
+            history = build_history(root, threshold=threshold)
         except Exception as e:
             history = None
             fail.append('regress')
@@ -375,11 +372,10 @@ def run_doctor(trace=None, root='.', self_check_only=False,
                 fail.append('regress')
                 lines.append('regress      FAIL: %s — malformed bench '
                              'record(s)' % desc)
-            elif s.get('stale') or s.get('regression'):
+            elif s.get('regression'):
                 warn.append('regress')
-                lines.append('regress      WARN: %s — stale replays / '
-                             'regressions are evidence to refresh, '
-                             'not results (see %s)'
+                lines.append('regress      WARN: %s — regressions '
+                             '(see %s)'
                              % (desc, history.get('path',
                                                   'BENCH_HISTORY.json')))
             else:
@@ -1021,9 +1017,6 @@ def main(argv=None):
     ap.add_argument('--threshold', type=float, default=0.25,
                     help='relative regression threshold for --regress '
                          '/ --doctor (default 0.25)')
-    ap.add_argument('--stale-hours', type=float, default=24.0,
-                    help='cache-replay age beyond which a bench '
-                         'headline is verdicted stale (default 24)')
     ap.add_argument('--chrome', metavar='TRACE',
                     help='export a trace to chrome_trace.json')
     ap.add_argument('--lint', metavar='ROOT', nargs='?', const='.',
@@ -1057,8 +1050,7 @@ def main(argv=None):
             else os.environ.get('NBKIT_DIAGNOSTICS') or None
         return run_doctor(trace=trace, root=args.root,
                           self_check_only=args.self_check_only,
-                          threshold=args.threshold,
-                          stale_hours=args.stale_hours)
+                          threshold=args.threshold)
     if args.self_check:
         return self_check(args.path)
     if args.report:
@@ -1071,8 +1063,7 @@ def main(argv=None):
     if args.analyze:
         return run_analyze(args.analyze)
     if args.regress is not None:
-        return run_regress(args.regress, threshold=args.threshold,
-                           stale_hours=args.stale_hours)
+        return run_regress(args.regress, threshold=args.threshold)
     if args.lint is not None:
         return run_lint_cmd(args.lint)
     if args.chrome:

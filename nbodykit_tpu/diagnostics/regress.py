@@ -2,14 +2,11 @@
 
 Every round commits one ``BENCH_rNN.json`` (the driver's record of
 ``python bench.py``: rc, output tail, the parsed headline JSON line),
-plus the committed measurement stores ``BASELINE_CPU.json`` /
-``BENCH_TPU_CACHE.json``.  Until now that history was interpreted by
-hand — and round 5 silently headlined a 4-day-old cache replay as if
-it were a fresh TPU measurement.  This module makes the trajectory
-machine-checked:
+plus the committed CPU measurement store ``BASELINE_CPU.json``.  This
+module makes the trajectory machine-checked:
 
 - :func:`load_rounds` ingests the family and normalizes each round to
-  one entry (metric, value, platform, note, replay provenance);
+  one entry (metric, value, platform, note);
 - :func:`classify` assigns each entry a verdict —
 
   ``malformed``    unreadable JSON, or a "successful" round whose
@@ -17,10 +14,6 @@ machine-checked:
                    scripts/smoke.sh runs ``--regress`` so a broken
                    bench record cannot land),
   ``no-result``    the round produced no number and said so (rc != 0);
-  ``stale``        the record is a cache replay whose underlying
-                   measurement is older than ``stale_hours`` — the
-                   round-5 failure mode, now loud,
-  ``replay``       a cache replay of unknown age,
   ``regression``   value worse than the previous round's same-metric
                    value by more than ``threshold`` (relative),
   ``improved`` / ``ok`` otherwise;
@@ -28,10 +21,6 @@ machine-checked:
 - :func:`build_history` writes the whole thing to ``BENCH_HISTORY.json``
   atomically (same tmp+rename discipline as report.py) so the next
   round — and the doctor — reads one file, not eight.
-
-Stale evidence is judged against *now* by default: the question the
-doctor answers is "is this number fresh enough to act on today", not
-"was it fresh when committed".  Pass ``now`` for reproducible tests.
 """
 
 import calendar
@@ -46,10 +35,7 @@ from .trace import atomic_write
 HISTORY_NAME = 'BENCH_HISTORY.json'
 PRECISION_NAME = 'PRECISION.json'
 ROUND_GLOBS = ('BENCH_r*.json', 'MULTICHIP_r*.json')
-CACHE_FILES = ('BENCH_TPU_CACHE.json', 'BASELINE_CPU.json')
-# note text that marks a headline as replayed from the TPU cache
-# rather than measured live this round (bench.py main())
-_REPLAY_MARKERS = ('BENCH_TPU_CACHE', 'most recent real-TPU')
+CACHE_FILES = ('BASELINE_CPU.json',)
 _TS_RE = re.compile(r'(\d{4}-\d{2}-\d{2}T\d{2}:\d{2}:\d{2})Z?')
 
 
@@ -101,8 +87,7 @@ def load_rounds(root):
             rec = data.get('parsed')
             if isinstance(rec, dict):
                 for k in ('metric', 'value', 'unit', 'platform',
-                          'vs_baseline', 'note', 'measured_at',
-                          'cache_age_hours'):
+                          'vs_baseline', 'note', 'measured_at'):
                     if rec.get(k) is not None:
                         entry[k] = rec[k]
                 if rec.get('error') is not None:
@@ -111,35 +96,10 @@ def load_rounds(root):
     return entries
 
 
-def _is_replay(entry):
-    if entry.get('cache_age_hours') is not None:
-        return True
-    note = str(entry.get('note', ''))
-    return any(m in note for m in _REPLAY_MARKERS)
-
-
-def _age_hours(entry, now):
-    """Age of the underlying measurement, preferring the explicit
-    ``cache_age_hours`` stamp (bench.py), else the ``measured_at`` /
-    'taken at ...Z' timestamp embedded in the record or its note."""
-    age = entry.get('cache_age_hours')
-    if age is not None:
-        try:
-            return float(age)
-        except (TypeError, ValueError):
-            pass
-    ts = parse_utc(entry.get('measured_at')) \
-        or parse_utc(entry.get('note'))
-    if ts is None:
-        return None
-    return (now - ts) / 3600.0
-
-
-def classify(entries, threshold=0.25, stale_hours=24.0, now=None):
+def classify(entries, threshold=0.25):
     """Assign each entry a ``verdict`` (+ ``why``) in place and return
     the entries.  Regressions compare consecutive rounds of the SAME
     metric (a 256-cubed timing vs a 1024-cubed one is not a trend)."""
-    now = time.time() if now is None else now
     last_by_metric = {}
     for entry in entries:
         if entry.get('load_error'):
@@ -169,11 +129,6 @@ def classify(entries, threshold=0.25, stale_hours=24.0, now=None):
                 entry['why'] = ('rc=0 but the record is missing '
                                 'metric/value/unit')
             continue
-        replay = _is_replay(entry)
-        age = _age_hours(entry, now)
-        entry['replay'] = replay
-        if age is not None:
-            entry['age_hours'] = round(age, 1)
         prev = last_by_metric.get(entry['metric'])
         verdict, why = 'ok', ''
         if prev is not None and prev > 0:
@@ -187,28 +142,16 @@ def classify(entries, threshold=0.25, stale_hours=24.0, now=None):
                 verdict = 'improved'
                 why = '%.4g s vs %.4g s previous (%.0f%%)' \
                     % (value, prev, 100 * rel)
-        if replay:
-            if age is not None and age > stale_hours:
-                verdict = 'stale'
-                why = ('cache replay of a measurement %.0f h old '
-                       '(stale after %.0f h) — NOT a fresh number'
-                       % (age, stale_hours))
-            elif verdict in ('ok', 'improved'):
-                verdict = 'replay'
-                why = 'cache replay, not a live measurement'
         entry['verdict'] = verdict
         if why:
             entry['why'] = why
-        # replays do not advance the comparison baseline: the next live
-        # measurement should be judged against the last LIVE one
-        if not replay:
-            last_by_metric[entry['metric']] = value
+        last_by_metric[entry['metric']] = value
     return entries
 
 
-def load_caches(root, stale_hours=24.0, now=None):
+def load_caches(root, now=None):
     """Summarize the committed measurement stores: per metric, value +
-    measurement age, staleness-flagged."""
+    measurement age."""
     now = time.time() if now is None else now
     out = {}
     for fname in CACHE_FILES:
@@ -230,7 +173,6 @@ def load_caches(root, stale_hours=24.0, now=None):
                 'platform': rec.get('platform'),
                 'measured_at': rec.get('measured_at'),
                 'age_hours': age,
-                'stale': None if age is None else age > stale_hours,
             }
         out[fname] = summary
     return out
@@ -848,19 +790,17 @@ def precision_summary(root, now=None):
         return {'error': str(e)}
 
 
-def build_history(root='.', out=None, threshold=0.25, stale_hours=24.0,
-                  now=None, write=True):
+def build_history(root='.', out=None, threshold=0.25, now=None,
+                  write=True):
     """Assemble + (atomically) write ``BENCH_HISTORY.json``; returns
     the history dict.  ``write=False`` analyzes without touching disk.
     """
-    entries = classify(load_rounds(root), threshold=threshold,
-                       stale_hours=stale_hours, now=now)
+    entries = classify(load_rounds(root), threshold=threshold)
     history = {
         'generated_at': time.strftime('%Y-%m-%dT%H:%M:%SZ',
                                       time.gmtime(now)),
         'root': os.path.abspath(root),
         'threshold': threshold,
-        'stale_hours': stale_hours,
         'rounds': entries,
         'lint': lint_summary(root),
         'tune': tune_summary(root, now=now),
@@ -874,11 +814,11 @@ def build_history(root='.', out=None, threshold=0.25, stale_hours=24.0,
         'integrity': integrity_summary(root),
         'slo': slo_summary(root),
         'precision': precision_summary(root, now=now),
-        'caches': load_caches(root, stale_hours=stale_hours, now=now),
+        'caches': load_caches(root, now=now),
         'summary': {v: sum(1 for e in entries
                            if e.get('verdict') == v)
-                    for v in ('ok', 'improved', 'replay', 'stale',
-                              'regression', 'no-result', 'malformed')},
+                    for v in ('ok', 'improved', 'regression',
+                              'no-result', 'malformed')},
     }
     if write:
         path = out or os.path.join(root, HISTORY_NAME)
@@ -892,9 +832,9 @@ def render_regress(history):
     out = []
     w = out.append
     w('== nbodykit_tpu bench regression report ==')
-    w('root: %s   rounds: %d   threshold: %.0f%%   stale after: %.0f h'
+    w('root: %s   rounds: %d   threshold: %.0f%%'
       % (history['root'], len(history['rounds']),
-         100 * history['threshold'], history['stale_hours']))
+         100 * history['threshold']))
     rounds = history['rounds']
     if rounds:
         fw = max(len(e['file']) for e in rounds)
@@ -913,12 +853,7 @@ def render_regress(history):
         if 'error' in summary:
             w('  %s: MALFORMED (%s)' % (fname, summary['error']))
             continue
-        stale = [m for m, st in summary.items() if st.get('stale')]
-        w('  %s: %d metrics%s'
-          % (fname, len(summary),
-             ', %d older than the stale bar (fine for a cache; loud '
-             'only when replayed as a headline)' % len(stale)
-             if stale else ''))
+        w('  %s: %d metrics' % (fname, len(summary)))
     res = history.get('resilience')
     if res is not None:
         bits = []
@@ -1190,20 +1125,17 @@ def render_regress(history):
     w('verdicts: %s' % '  '.join('%s=%d' % (k, n)
                                  for k, n in s.items() if n))
     bad = s.get('malformed', 0)
-    warn = s.get('stale', 0) + s.get('regression', 0)
+    warn = s.get('regression', 0)
     if bad:
         w('RESULT: FAIL — %d malformed bench record(s)' % bad)
     elif warn:
-        w('RESULT: WARN — %d stale replay / regression verdict(s); '
-          'treat the affected numbers as evidence to refresh, not '
-          'results' % warn)
+        w('RESULT: WARN — %d regression verdict(s)' % warn)
     else:
         w('RESULT: OK')
     return '\n'.join(out) + '\n'
 
 
 def gate_rc(history):
-    """Exit code for CI gates: malformed records fail; stale replays
-    and regressions warn loudly but do not block (the committed round-5
-    replay must not wedge every future smoke run)."""
+    """Exit code for CI gates: malformed records fail; regressions
+    warn loudly but do not block."""
     return 1 if history['summary'].get('malformed') else 0

@@ -139,11 +139,8 @@ class DeviceGridHash(object):
         """
         if self.axis_name is None:
             return x
-        x = jnp.asarray(x)
-        vma = getattr(jax.typeof(x), 'vma', ())
-        if self.axis_name in vma:
-            return x
-        return jax.lax.pcast(x, (self.axis_name,), to='varying')
+        from ..parallel.runtime import vary_like
+        return vary_like(x, self.pos_s)
 
     def fold(self, p, ci, body, carry):
         """Accumulate ``carry = body(carry, j, valid, d, r2)`` over all

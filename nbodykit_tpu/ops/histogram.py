@@ -17,10 +17,8 @@ All weight streams share one dot per chunk (their B-columns are
 concatenated), one-hots are exact in bfloat16, each weight is split
 into bf16 hi+lo parts (w = hi + lo), the MXU accumulates in f32 and
 chunk results are summed in f64. Accuracy (~2e-7 max relative error
-vs exact f64 bincount) is asserted by tests/test_histogram.py; TPU
-timings for the containing FFTPower pipeline are recorded per-config
-in BENCH_TPU_CACHE.json (phases.binning_s), the single artifact perf
-claims should be read from.
+vs exact f64 bincount) is asserted by tests/test_histogram.py; its
+time on the chip is not measured (root PERF.md).
 
 ``hist2d_weighted`` picks the MXU path on TPU and plain bincount
 elsewhere (CPU bincount is exact f64 and faster than emulated matmuls).
@@ -84,8 +82,12 @@ def hist2d_mxu(abin, bbin, weights, NA, NB, chunk=131072,
                                 preferred_element_type=jnp.float32)
         return acc + H.astype(acc_dtype)
 
-    H = jax.lax.fori_loop(0, nch, body,
-                          jnp.zeros((NA, ncols), acc_dtype))
+    # inside shard_map (the multi-device binning) the chunks are
+    # device-local, so the accumulator starts with their varying type
+    from ..parallel.runtime import vary_like
+    H = jax.lax.fori_loop(
+        0, nch, body,
+        vary_like(jnp.zeros((NA, ncols), acc_dtype), abin, bbin, *ws))
     out = []
     for iw in range(nw):
         hi = H[:, (2 * iw) * NB:(2 * iw + 1) * NB]
@@ -117,6 +119,39 @@ def lattice_shell_index(isq, nbins):
     # nbkl: disable=NBK704
     r = r - (r * r > isq) + ((r + 1) * (r + 1) <= isq)
     return jnp.minimum(r, nbins - 1)
+
+
+def shell_sums(shell, value, nbins, weight=None):
+    """Per-shell sum of ``value * weight`` and of ``weight``, both
+    f32, for a 3-d ``value`` whose cells carry the shell index
+    ``shell`` (broadcastable to it) and an integer ``weight``
+    (broadcastable; default 1).
+
+    One partial histogram per leading row, the rows summed at the end:
+    a single f32 accumulator per shell stalls once it outgrows its
+    addends.  At 512^3 the last shell, which takes every cell past the
+    Nyquist sphere, counted 2^25 of its 6.4e7 weight-2 modes and then
+    stopped, so the served P(k) there read 74% high, on the CPU as on
+    the chip.  The counts are integers throughout and rounded to f32
+    once, at the end; they are summed over the rows as two 16-bit
+    halves, so that a mesh of more than 2^31 cells (2048^3) does not
+    overflow int32 either.
+    """
+    rows = int(value.shape[0])
+    row = jnp.arange(rows, dtype=jnp.int32).reshape(-1, 1, 1) * nbins
+    flat = jnp.broadcast_to(row + shell, value.shape).reshape(-1)
+    if weight is None:
+        weight = jnp.ones((), jnp.int32)
+    weight = jnp.broadcast_to(weight, value.shape)
+    S = jnp.zeros(rows * nbins, jnp.float32).at[flat].add(
+        (value.astype(jnp.float32) * weight.astype(jnp.float32))
+        .reshape(-1))
+    N = jnp.zeros(rows * nbins, jnp.int32).at[flat].add(
+        weight.astype(jnp.int32).reshape(-1))
+    N = N.reshape(rows, nbins)
+    hi = (N >> 16).sum(axis=0, dtype=jnp.int32).astype(jnp.float32)
+    lo = (N & 0xFFFF).sum(axis=0, dtype=jnp.int32).astype(jnp.float32)
+    return S.reshape(rows, nbins).sum(axis=0), hi * 65536.0 + lo
 
 
 def lattice_shell_edges(xedges, unit):

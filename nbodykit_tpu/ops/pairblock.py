@@ -39,6 +39,8 @@ import jax.numpy as jnp
 
 from functools import partial
 
+from ..parallel.runtime import vary_like
+
 
 def _pad_rows(x, n, fill=0):
     """Pad the leading axis of ``x`` up to ``n`` rows with ``fill``."""
@@ -80,14 +82,14 @@ def _pairblock_tiles(pos, w, kvecs, tile_p, tile_k):
             im = im + wt @ jnp.sin(ph)
             return re, im
 
-        zero = jnp.zeros((tile_k,), acc_dtype)
+        zero = vary_like(jnp.zeros((tile_k,), acc_dtype), pos, w)
         re_t, im_t = jax.lax.fori_loop(0, npt, pbody, (zero, zero))
         return (jax.lax.dynamic_update_slice(re_acc, re_t,
                                              (ik * tile_k,)),
                 jax.lax.dynamic_update_slice(im_acc, im_t,
                                              (ik * tile_k,)))
 
-    zeros = jnp.zeros((Nk,), acc_dtype)
+    zeros = vary_like(jnp.zeros((Nk,), acc_dtype), pos, w)
     return jax.lax.fori_loop(0, nkt, kbody, (zeros, zeros))
 
 

@@ -44,7 +44,15 @@ def _rank_kernel(dig_ref, rank_ref, hist_ref, base_ref, *, D, C):
     d = dig_ref[0, :]                                    # (C,)
     eq = d[:, None] == jax.lax.broadcasted_iota(jnp.int32, (C, D), 1)
     O = eq.astype(jnp.float32)                           # one-hot
-    cumO = jnp.cumsum(O, axis=0)
+    # inclusive running count down the chunk as a lower-triangular
+    # matmul: the Pallas TPU lowering has no cumsum ("Unimplemented
+    # primitive in Pallas TPU lowering for KernelType.TC: cumsum"), and
+    # this is MXU work.  0/1 operands are exact in bf16 and the counts
+    # accumulate in f32, so the result is the exact integer cumsum
+    tri = (jax.lax.broadcasted_iota(jnp.int32, (C, C), 1)
+           <= jax.lax.broadcasted_iota(jnp.int32, (C, C), 0))
+    cumO = jnp.dot(tri.astype(jnp.bfloat16), O.astype(jnp.bfloat16),
+                   preferred_element_type=jnp.float32)
     # the one-hot picks cumO[r, d_r] / base[d_r] with no gather.
     # Within-chunk counts stay < C <= 2^24, so the f32 cumsum pick is
     # exact; the cross-chunk base can exceed 2^24 and is selected in
@@ -60,32 +68,41 @@ def _rank_kernel(dig_ref, rank_ref, hist_ref, base_ref, *, D, C):
     hist_ref[...] = base[None, :]
 
 
-def pass_rank_hist_pallas(digit, D, chunk=2048, interpret=False):
+def pass_rank_hist_pallas(digit, D, chunk=256, interpret=False):
     """Drop-in for ``radix._pass_rank_hist`` backed by the VMEM kernel.
 
     digit : (n,) int32 in [0, D).
+    chunk : elements per grid step.  The running count costs
+        2 * chunk * D MXU flops per element and a (chunk, chunk)
+        triangle in VMEM, so it stays small (what a grid step costs
+        next to that is not measured).
     Returns (rank (n,) i32, hist (D,) i32).
     """
     from .radix import pad_digits
 
     n = digit.shape[0]
-    C = int(min(chunk, max(256, n)))
+    C = int(chunk)
     dig_p, npad = pad_digits(digit, D, C)
     nch = dig_p.shape[0]
     Mp = dig_p.size
 
     kern = functools.partial(_rank_kernel, D=D, C=C)
+    # one (1, C) chunk per grid step: the TPU lowering wants the last
+    # two block dimensions divisible by (8, 128) or equal to the
+    # array's, so the chunks are viewed (nch, 1, C) and the leading
+    # dimension is squeezed
+    chunk_spec = pl.BlockSpec((None, 1, C), lambda i: (i, 0, 0))
     rank_p, hist = pl.pallas_call(
         kern,
         grid=(nch,),
-        in_specs=[pl.BlockSpec((1, C), lambda i: (i, 0))],
-        out_specs=[pl.BlockSpec((1, C), lambda i: (i, 0)),
+        in_specs=[chunk_spec],
+        out_specs=[chunk_spec,
                    pl.BlockSpec((1, D), lambda i: (0, 0))],
-        out_shape=[jax.ShapeDtypeStruct((nch, C), jnp.int32),
+        out_shape=[jax.ShapeDtypeStruct((nch, 1, C), jnp.int32),
                    jax.ShapeDtypeStruct((1, D), jnp.int32)],
         scratch_shapes=[pltpu.VMEM((1, D), jnp.int32)],
         interpret=interpret,
-    )(dig_p)
+    )(dig_p.reshape(nch, 1, C))
     rank = rank_p.reshape(Mp)[:n]
     hist = hist[0].at[D - 1].add(-npad)
     return rank, hist

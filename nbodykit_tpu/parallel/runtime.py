@@ -295,6 +295,23 @@ class use_mesh(object):
         CurrentMesh.pop()
 
 
+def vary_like(x, *like):
+    """``x`` cast to vary over every manual (``shard_map``) axis that
+    any of ``like`` varies over; the identity outside ``shard_map``.
+
+    A loop carry inside ``shard_map`` that starts from a constant is
+    replicated, while the body folds device-local data into it; jax's
+    varying-manual-axes check refuses a carry whose type changes, so
+    the initial value is cast to the data's type first.  The one
+    spelling of that cast in the package."""
+    x = jax.numpy.asarray(x)
+    want = set()
+    for ref in like:
+        want |= set(jax.typeof(ref).vma)
+    need = tuple(sorted(want - set(jax.typeof(x).vma)))
+    return jax.lax.pcast(x, need, to='varying') if need else x
+
+
 def mesh_size(mesh):
     """Total number of devices in the mesh (1 when mesh is None).
 

@@ -762,9 +762,32 @@ class ParticleMesh(object):
         return ParticleMesh(Nmesh, self.BoxSize, self.dtype, self.comm)
 
 
+#: device memory by ``device_kind`` for backends that report no
+#: ``bytes_limit``.  The CPU backend is one: a CPU run rehearses the
+#: plans of one TPU v5e chip (published: 16 GB; the attached chip
+#: reports 16909336064).  A kind with no figure is an error.
+HBM_BYTES = {'cpu': 16e9}
+
+
+def device_hbm_bytes(device):
+    """The memory of ``device`` that plans and admission price
+    against: what the device itself reports, else the
+    :data:`HBM_BYTES` figure for its ``device_kind``.  Called once by
+    an entry point that owns a device (the server, the tuner,
+    ``chip_smoke.py``); everything below takes ``hbm_bytes``."""
+    limit = (device.memory_stats() or {}).get('bytes_limit')
+    if limit:
+        return float(limit)
+    if device.device_kind not in HBM_BYTES:
+        raise KeyError(
+            'device_kind %r reports no memory limit and has no figure '
+            'in pmesh.HBM_BYTES; pass hbm_bytes' % device.device_kind)
+    return HBM_BYTES[device.device_kind]
+
+
 def memory_plan(Nmesh, npart, ndevices=1, dtype='f4', resampler='cic',
                 paint_method='scatter', paint_chunk=None,
-                paint_streams=None, hbm_bytes=16e9, exchange='counted',
+                paint_streams=None, hbm_bytes=None, exchange='counted',
                 exchange_imbalance=1.5, fft_decomp='slab',
                 fft_pencil=None, ingest_chunk_rows=None,
                 catalog_bytes=None, workload='fftpower',
@@ -775,10 +798,12 @@ def memory_plan(Nmesh, npart, ndevices=1, dtype='f4', resampler='cic',
     behind chunk-size choices and the BASELINE.md scale claims
     (Nmesh=1024/1e8 on one v5e chip; Nmesh=2048/1e9 on v5e-16).
 
-    Returns a dict of per-phase byte estimates, ``peak_bytes``, and
-    ``fits`` (vs ``hbm_bytes``, 16 GB v5e default, with a 15%
-    allocator margin). Estimates, not guarantees — XLA's actual
-    buffers vary; the model errs high on the FFT workspace (2x the
+    Returns a dict of per-phase byte estimates and ``peak_bytes``;
+    with ``hbm_bytes`` (the caller's device, see
+    :func:`device_hbm_bytes` — the plan itself is arithmetic and asks
+    no backend) also ``budget_bytes``, ``headroom_bytes`` and ``fits``,
+    judged with a 15% allocator margin.  Estimates, not guarantees —
+    XLA's actual buffers vary; the model errs high on the FFT workspace (2x the
     complex field for the out-of-place transposed passes).
 
     ``exchange`` models the multi-device particle routing buffers:
@@ -1044,7 +1069,8 @@ def memory_plan(Nmesh, npart, ndevices=1, dtype='f4', resampler='cic',
     # the budget the admission controller (nbodykit_tpu.serve) prices
     # against: the raw HBM less the 15% allocator margin.  Exposed so
     # structured rejections can quote the numbers they were judged by.
-    phases['budget_bytes'] = 0.85 * hbm_bytes
-    phases['headroom_bytes'] = 0.85 * hbm_bytes - peak
-    phases['fits'] = bool(peak <= 0.85 * hbm_bytes)
+    if hbm_bytes is not None:
+        phases['budget_bytes'] = 0.85 * hbm_bytes
+        phases['headroom_bytes'] = 0.85 * hbm_bytes - peak
+        phases['fits'] = bool(peak <= 0.85 * hbm_bytes)
     return phases

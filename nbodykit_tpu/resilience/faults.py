@@ -1,6 +1,6 @@
 """Deterministic fault injection: make every recovery path testable.
 
-The faults this subsystem exists for — ``UNAVAILABLE`` tunnel deaths,
+The faults this subsystem exists for — ``UNAVAILABLE`` device losses,
 ``RESOURCE_EXHAUSTED`` OOMs, SIGKILLed workers — only occur on the
 real TPU fleet, which tier-1 never touches.  This harness injects
 them *deterministically* on the CPU mesh so the retry / degrade /

@@ -8,7 +8,8 @@
 
     Options: --trace N · --seed S · --per-task K (devices per worker
     sub-mesh) · --max-batch B · --max-delay-ms MS (batch window) ·
-    --max-queue Q · --hbm-gb G (admission budget is 0.85x this) ·
+    --max-queue Q · --hbm-gb G (admission budget is 0.85x this;
+    default: what the device reports) ·
     --deadline-s D · --devices N (CPU: force N virtual devices) ·
     --json PATH (write the full summary + per-request verdicts).
 
@@ -36,16 +37,19 @@ def main(argv=None):
     ap.add_argument('--max-batch', type=int, default=8)
     ap.add_argument('--max-delay-ms', type=float, default=20.0)
     ap.add_argument('--max-queue', type=int, default=1024)
-    ap.add_argument('--hbm-gb', type=float, default=16.0)
+    ap.add_argument('--hbm-gb', type=float, default=None,
+                    help='per-device HBM to admit against (default: '
+                         'what the device reports)')
     ap.add_argument('--deadline-s', type=float, default=300.0)
     ap.add_argument('--devices', type=int, default=None)
     ap.add_argument('--json', default=None,
                     help='write summary + per-request verdicts here')
     args = ap.parse_args(argv)
 
+    from .._jax_compat import enable_compile_cache, set_cpu_devices
     if args.devices:
-        from .._jax_compat import set_cpu_devices
         set_cpu_devices(args.devices)
+    enable_compile_cache()
 
     import nbodykit_tpu  # noqa: F401  (option/env wiring)
     from . import AnalysisServer, BatchPolicy, generate_trace, replay
@@ -54,7 +58,7 @@ def main(argv=None):
                            deadline_s=args.deadline_s)
     server = AnalysisServer(
         per_task=args.per_task, max_queue=args.max_queue,
-        hbm_bytes=args.hbm_gb * 1e9,
+        hbm_bytes=args.hbm_gb * 1e9 if args.hbm_gb else None,
         batch=BatchPolicy(max_batch=args.max_batch,
                           max_delay_s=args.max_delay_ms / 1e3))
     with server:

@@ -112,7 +112,7 @@ def _plan(request, ndevices, hbm_bytes, paint_chunk=None,
                        nbins=getattr(request, 'nbins', None))
 
 
-def catalog_fits_fn(request, ndevices=1, hbm_bytes=16e9):
+def catalog_fits_fn(request, ndevices, hbm_bytes):
     """The catalog-cache eviction predicate for one admitted data_ref
     request: ``fits(total_resident_bytes)`` is this request's
     admission plan re-priced at a candidate cache residency — the
@@ -125,7 +125,7 @@ def catalog_fits_fn(request, ndevices=1, hbm_bytes=16e9):
     return fits
 
 
-def admit(request, ndevices=1, hbm_bytes=16e9):
+def admit(request, ndevices, hbm_bytes):
     """Price ``request`` for an ``ndevices`` sub-mesh and decide.
 
     Geometry that cannot run at all (Nmesh not divisible by the

@@ -56,7 +56,7 @@ def _binned_power(pm, c, resampler, npart):
     (k, P(k), nmodes) with nmesh//2 shells."""
     import jax.numpy as jnp
     import numpy as np
-    from ..ops.histogram import lattice_shell_index
+    from ..ops.histogram import lattice_shell_index, shell_sums
     from ..ops.window import compensation_transfer
 
     nmesh = int(pm.Nmesh[0])
@@ -71,11 +71,8 @@ def _binned_power(pm, c, resampler, npart):
 
     ix, iy, iz = pm.i_list_complex()
     shell = lattice_shell_index(ix * ix + iy * iy + iz * iz, nbins)
-    wgt = jnp.broadcast_to(pm.hermitian_weights(jnp.float32), p3.shape)
-    flat = jnp.broadcast_to(shell, p3.shape).reshape(-1)
-    P = jnp.zeros(nbins, jnp.float32).at[flat].add(
-        (p3 * wgt).reshape(-1))
-    Nm = jnp.zeros(nbins, jnp.float32).at[flat].add(wgt.reshape(-1))
+    P, Nm = shell_sums(shell, p3, nbins,
+                       weight=pm.hermitian_weights(jnp.float32))
     Nm0 = Nm.at[0].set(jnp.maximum(Nm[0] - 1.0, 0.0))  # drop DC mode
     k = jnp.asarray(np.arange(nbins, dtype='f4')) \
         * jnp.float32(2 * np.pi / L)
@@ -254,14 +251,11 @@ def _build_single(request, pm):
                               .astype('i4')).reshape(
                       [1 if i != j else -1 for j in range(3)])
                   for i, n in enumerate(int(v) for v in pm.Nmesh)]
-            from ..ops.histogram import lattice_shell_index
+            from ..ops.histogram import (lattice_shell_index,
+                                         shell_sums)
             dsq = ax[0] ** 2 + ax[1] ** 2 + ax[2] ** 2
-            shell = lattice_shell_index(dsq, nbins)
-            flat = jnp.broadcast_to(shell, xi3.shape).reshape(-1)
-            S = jnp.zeros(nbins, jnp.float32).at[flat].add(
-                xi3.astype(jnp.float32).reshape(-1))
-            Nm = jnp.zeros(nbins, jnp.float32).at[flat].add(
-                jnp.ones_like(flat, jnp.float32))
+            S, Nm = shell_sums(lattice_shell_index(dsq, nbins), xi3,
+                               nbins)
             x = jnp.asarray(np.arange(nbins, dtype='f4')) \
                 * jnp.float32(L / nmesh)
             return x, S / jnp.maximum(Nm, 1.0), Nm

@@ -24,8 +24,8 @@ worker thread pinned per sub-mesh.  A request's life:
 5. **run** — under a per-request :class:`~nbodykit_tpu.resilience.Supervisor`
    (fault point ``serve.request.attempt``) with a request-scoped
    degradation ladder writing into THAT request's option overrides,
-   applied via :func:`nbodykit_tpu.option_scope` — an injected tunnel
-   death retries/degrades one request; the other tenants never see it.
+   applied via :func:`nbodykit_tpu.option_scope` — an injected device
+   loss retries/degrades one request; the other tenants never see it.
    With a checkpoint store, finished work is saved before the
    post-work fault point ``serve.request.work`` so a kill after
    compute resumes instead of recomputing.
@@ -177,7 +177,9 @@ class AnalysisServer(object):
     max_queue : bound on waiting tickets; beyond it submissions get a
         structured ``queue_full`` rejection
     hbm_bytes : per-device HBM the admission controller prices against
-        (0.85x of this is the budget)
+        (0.85x of this is the budget); by default what the fleet's
+        first device reports (:func:`~nbodykit_tpu.pmesh.
+        device_hbm_bytes`)
     batch : :class:`.batching.BatchPolicy`
     checkpoint : :class:`~nbodykit_tpu.resilience.CheckpointStore`
         or None — per-request resume across mid-run faults
@@ -198,7 +200,7 @@ class AnalysisServer(object):
         executions.
     """
 
-    def __init__(self, per_task=1, max_queue=256, hbm_bytes=16e9,
+    def __init__(self, per_task=1, max_queue=256, hbm_bytes=None,
                  batch=None, checkpoint=None, retry=None,
                  verify_fraction=0.0, name=None):
         from ..batch import TaskManager
@@ -218,6 +220,9 @@ class AnalysisServer(object):
             raise RuntimeError('no device sub-meshes to serve on')
         self.ndevices = mesh_size(self.meshes[0])
         self.max_queue = int(max_queue)
+        if hbm_bytes is None:
+            from ..pmesh import device_hbm_bytes
+            hbm_bytes = device_hbm_bytes(self.meshes[0].devices.flat[0])
         self.hbm_bytes = float(hbm_bytes)
         self.batch = batch if batch is not None else BatchPolicy()
         self.checkpoint = checkpoint

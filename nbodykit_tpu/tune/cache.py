@@ -149,18 +149,10 @@ def device_signature(count=None):
     backend.  ``count`` overrides the device count with the size of
     the mesh the op actually runs on (a paint on a 1-device
     ``ParticleMesh`` in an 8-device process is a 1-device paint)."""
-    try:
-        import jax
-        devs = jax.devices()
-        d = devs[0]
-        plat = str(d.platform)
-        kind = str(getattr(d, 'device_kind', plat))
-        n = len(devs)
-    except Exception:
-        plat, kind, n = 'unknown', 'unknown', 1
-    if count is not None:
-        n = int(count)
-    return (plat, kind, n)
+    import jax
+    devs = jax.devices()
+    n = len(devs) if count is None else int(count)
+    return (str(devs[0].platform), str(devs[0].device_kind), n)
 
 
 def make_key(platform, device_kind, device_count, op, sclass, dtype):

@@ -21,7 +21,7 @@ echo "== doctor: self-check =="
 python -m nbodykit_tpu.diagnostics --doctor --self-check-only
 
 # bench-record gate: a malformed committed BENCH_r*.json fails here;
-# stale cache replays / regressions print WARN verdicts but pass
+# regressions print a WARN verdict but pass
 echo "== doctor: bench regression gate =="
 python -m nbodykit_tpu.diagnostics --regress .
 
@@ -62,10 +62,10 @@ print("lint stats OK: " + "  ".join(
 '
 
 # bounded symbolic-peak report for the north-star 1024^3 config
-# (bench staged ladder + the dfft lowmem drivers): proves the
-# documented buffer contracts still derive from the source, and that
-# the donated staged chain stays inside the v5e budget while only the
-# (staged-gated) fused pipeline exceeds it
+# (bench.py + the dfft lowmem drivers): proves the documented buffer
+# contracts still derive from the source — the donated lowmem driver
+# stays inside the v5e budget, the fused pipeline books over it (the
+# model errs high; the chip's compiler accepts the fused program)
 echo "== memory report: 1024^3 north-star config (bounded) =="
 python -m nbodykit_tpu.lint --memory-report --nmesh 1024 \
     --npart 1e8 bench.py nbodykit_tpu/parallel/dfft.py | python -c '
@@ -73,10 +73,9 @@ import sys
 text = sys.stdin.read()
 sys.stdout.write(text)
 assert "OVER BUDGET" in text, "fused pipeline should exceed budget"
-for fn in ("run_once", "rfftn_single_lowmem"):
-    line = next(l for l in text.splitlines() if fn in l)
-    assert "OVER BUDGET" not in line, (
-        "staged/lowmem chain exceeded the budget: " + line)
+line = next(l for l in text.splitlines() if "rfftn_single_lowmem" in l)
+assert "OVER BUDGET" not in line, (
+    "lowmem driver exceeded the budget: " + line)
 '
 
 # pencil branch of the memory model (docs/PERF.md): the documented
@@ -259,7 +258,7 @@ print('resume smoke OK: %(metric)s resumed -> %(value)s s' % rec)
 EOF
 
 # multi-tenant serve gate (docs/SERVING.md): a 24-request synthetic
-# trace with a mid-request tunnel death injected at the 3rd attempt —
+# trace with a mid-request device loss injected at the 3rd attempt —
 # exactly one request retries (batching disabled so the fault lands on
 # a single tenant), nothing is lost, every submission gets a structured
 # verdict, p99 is recorded

@@ -71,8 +71,9 @@ def test_shifted_routing_counts_differently():
 
 
 def test_memory_plan_counted_vs_ceil():
-    pc = memory_plan(2048, int(1e9), 16)
-    pf = memory_plan(2048, int(1e9), 16, exchange='ceil')
+    pc = memory_plan(2048, int(1e9), 16, hbm_bytes=16e9)
+    pf = memory_plan(2048, int(1e9), 16, exchange='ceil',
+                     hbm_bytes=16e9)
     assert pc['fits'] and not pf['fits']
     assert pc['exchange_buffers'] < pf['exchange_buffers'] / 5
 

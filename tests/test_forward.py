@@ -333,7 +333,8 @@ def test_forward_admission_admit_degrade_reject():
     assert d.reason['code'] == 'over_budget'
     # reject indivisible particle lattice: ng=12 on 8 devices
     d = admit(AnalysisRequest(algorithm='Forward', nmesh=16,
-                              npart=12 ** 3, pm_steps=2), ndevices=8)
+                              npart=12 ** 3, pm_steps=2), ndevices=8,
+              hbm_bytes=16e9)
     assert d.status == REJECT
     assert d.reason['code'] == 'indivisible'
     assert 'lattice' in d.reason['detail']

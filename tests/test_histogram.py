@@ -15,7 +15,7 @@ import jax.numpy as jnp
 import pytest
 
 from nbodykit_tpu.ops.histogram import (hist2d_mxu, hist2d_bincount,
-                                        hist2d_weighted)
+                                        hist2d_weighted, shell_sums)
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
@@ -70,6 +70,47 @@ def test_hist2d_under_jit():
     got = np.asarray(f(a, b, w))
     want = np.array([[1.0, 0.0], [2.0, 4.0], [0.0, 3.0]])
     np.testing.assert_allclose(got, want, rtol=1e-6)
+
+
+def test_shell_sums_matches_numpy():
+    rng = np.random.RandomState(3)
+    shape, nbins = (6, 5, 4), 7
+    shell = rng.randint(0, nbins, shape).astype('i4')
+    value = rng.standard_normal(shape).astype('f4')
+    weight = rng.randint(1, 3, (1, 1, 4)).astype('f4')   # 1 or 2
+    S, N = jax.jit(shell_sums, static_argnums=2)(
+        jnp.asarray(shell), jnp.asarray(value), nbins,
+        jnp.asarray(weight))
+    w = np.broadcast_to(weight, shape)
+    assert N.dtype == S.dtype == jnp.float32
+    np.testing.assert_array_equal(
+        np.asarray(N), np.bincount(shell.ravel(), w.ravel(), nbins))
+    np.testing.assert_allclose(
+        np.asarray(S),
+        np.bincount(shell.ravel(), (value * w).ravel().astype('f8'),
+                    nbins), rtol=1e-5, atol=1e-6)
+    # default weight 1: plain counts
+    _, N1 = shell_sums(jnp.asarray(shell), jnp.asarray(value), nbins)
+    np.testing.assert_array_equal(
+        np.asarray(N1), np.bincount(shell.ravel(), minlength=nbins))
+
+
+def test_shell_sums_counts_past_the_f32_stall():
+    """More than 2^25 unit weights in ONE shell, the catch-all last
+    shell of a 512^3 served spectrum in miniature: a single f32
+    accumulator stops at 2^24 (shown here, so the test is known to be
+    large enough to catch a relapse); the per-row partials count every
+    cell."""
+    nbins, rows, cols = 4, 8, 2 ** 22 + 1
+    n = rows * cols                       # 2^25 + 8
+    value = jnp.ones((rows, 1, cols), jnp.float32)
+    shell = jnp.full((1, 1, 1), nbins - 1, jnp.int32)
+    S, N = jax.jit(shell_sums, static_argnums=2)(shell, value, nbins)
+    assert np.asarray(N).tolist() == [0, 0, 0, n]
+    assert float(S[-1]) == pytest.approx(n, rel=1e-6)
+    naive = jnp.zeros(1, jnp.float32).at[
+        jnp.zeros(n, jnp.int32)].add(jnp.ones(n, jnp.float32))
+    assert float(naive[0]) < n
 
 
 def test_bench_pipeline_matches_fftpower():

@@ -1,11 +1,5 @@
-"""Direct unit tests for the _jax_compat shims.
-
-Until now the backfills (jax.shard_map on 0.4.x, pvary/pcast/typeof,
-set_cpu_devices, partitionable threefry) were exercised only
-indirectly by whichever suite happened to hit them — a lint-driven
-refactor could silently break the jax-0.4.37 path.  These pin the
-contract explicitly on whatever jax the container bakes.
-"""
+"""The process-level jax configuration (``_jax_compat``) and the jax
+surface the package leans on, on the one jax that is installed."""
 
 import os
 
@@ -16,26 +10,17 @@ import numpy as np
 from nbodykit_tpu import _jax_compat
 
 
-def test_apply_is_idempotent():
-    before = (jax.shard_map, jax.lax.pvary, jax.lax.pcast, jax.typeof)
-    _jax_compat.apply()
-    _jax_compat.apply()
-    after = (jax.shard_map, jax.lax.pvary, jax.lax.pcast, jax.typeof)
-    assert before == after
-
-
 def test_modern_names_exist():
-    # the whole codebase uses ONE spelling; these must exist whether
-    # native or backfilled
+    # the whole codebase uses ONE spelling of each
     assert callable(jax.shard_map)
-    assert callable(jax.lax.pvary)
     assert callable(jax.lax.pcast)
     assert callable(jax.typeof)
+    assert callable(jax.core.trace_ctx.is_top_level)
 
 
 def test_shard_map_psum_roundtrip(cpu8):
-    # the backfilled (or native) jax.shard_map must run a real
-    # collective: replicated sum over the 8-device mesh
+    # jax.shard_map must run a real collective: replicated sum over
+    # the 8-device mesh
     from jax.sharding import NamedSharding, PartitionSpec as P
     from nbodykit_tpu.parallel.runtime import AXIS
     ndev = cpu8.shape[AXIS]
@@ -48,10 +33,12 @@ def test_shard_map_psum_roundtrip(cpu8):
 
 
 def test_shard_map_while_loop_carry(cpu8):
-    # the reason the 0.4.x shim disables check_rep: while_loop carries
-    # inside shard_map (the sort/paint kernels depend on this)
+    # a while_loop carry inside shard_map that starts from a constant
+    # and folds in device-local data (the sort/paint/pairblock kernels
+    # depend on this): the varying-manual-axes check refuses it unless
+    # the carry is cast the way the library's helper does
     from jax.sharding import NamedSharding, PartitionSpec as P
-    from nbodykit_tpu.parallel.runtime import AXIS
+    from nbodykit_tpu.parallel.runtime import AXIS, vary_like
     ndev = cpu8.shape[AXIS]
     x = jax.device_put(np.ones(ndev, 'f4'),
                        NamedSharding(cpu8, P(AXIS)))
@@ -60,13 +47,21 @@ def test_shard_map_while_loop_carry(cpu8):
         def step(state):
             i, acc = state
             return i + 1, acc + jnp.sum(v)
-        _, acc = jax.lax.while_loop(lambda s: s[0] < 3, step,
-                                    (jnp.int32(0), jnp.float32(0)))
+        _, acc = jax.lax.while_loop(
+            lambda s: s[0] < 3, step,
+            (jnp.int32(0), vary_like(jnp.float32(0), v)))
         return jax.lax.psum(acc, AXIS)
 
     total = jax.jit(jax.shard_map(body, mesh=cpu8, in_specs=P(AXIS),
                                   out_specs=P()))(x)
     assert float(total) == 3.0 * ndev
+
+
+def test_vary_like_is_identity_outside_shard_map():
+    from nbodykit_tpu.parallel.runtime import vary_like
+    x = jnp.arange(3)
+    assert vary_like(x, jnp.ones(2)) is x
+    assert jax.jit(lambda a: vary_like(a, a * 2))(x).tolist() == [0, 1, 2]
 
 
 def test_typeof_returns_aval():
@@ -75,53 +70,40 @@ def test_typeof_returns_aval():
     assert aval.dtype == jnp.float32
 
 
-def test_pvary_pcast_identity_shim(monkeypatch):
-    # force the backfill path (even on modern jax) and pin the
-    # identity contract the 0.4.x type system expects
-    monkeypatch.delattr(jax.lax, 'pvary', raising=False)
-    monkeypatch.delattr(jax.lax, 'pcast', raising=False)
-    _jax_compat.apply()
-    x = jnp.arange(3)
-    assert jax.lax.pvary(x, axis_name='dev') is x
-    assert jax.lax.pcast(x, axis_name='dev', to='varying') is x
-    # monkeypatch restores the originals; re-apply puts the world back
-    # for whatever jax version this is
-
-
 def test_threefry_partitionable_enabled():
     # rng.py's device-count-invariant draw contract depends on it
     assert jax.config.jax_threefry_partitionable
 
 
-def test_set_cpu_devices_env_fallback(monkeypatch):
-    # simulate the 0.4.x surface: no jax_num_cpu_devices config ->
-    # the XLA_FLAGS fallback must be used and reported as False
-    class _NoConfig:
-        def update(self, name, value):
-            raise AttributeError(name)
+class _Config:
+    def __init__(self):
+        self.calls = []
 
-    monkeypatch.setattr(_jax_compat.jax, 'config', _NoConfig())
-    monkeypatch.setenv('XLA_FLAGS', '')
-    assert _jax_compat.set_cpu_devices(3) is False
-    assert '--xla_force_host_platform_device_count=3' in \
-        os.environ['XLA_FLAGS']
-    # idempotent: a second call must not duplicate the flag
-    assert _jax_compat.set_cpu_devices(3) is False
-    assert os.environ['XLA_FLAGS'].count(
-        'xla_force_host_platform_device_count') == 1
+    def update(self, name, value):
+        self.calls.append((name, value))
 
 
 def test_set_cpu_devices_config_path(monkeypatch):
-    # simulate the modern surface: the config update is accepted
-    calls = []
+    cfg = _Config()
+    monkeypatch.setattr(_jax_compat.jax, 'config', cfg)
+    _jax_compat.set_cpu_devices(5)
+    assert cfg.calls == [('jax_num_cpu_devices', 5)]
 
-    class _Config:
-        def update(self, name, value):
-            calls.append((name, value))
 
-    monkeypatch.setattr(_jax_compat.jax, 'config', _Config())
-    assert _jax_compat.set_cpu_devices(5) is True
-    assert calls == [('jax_num_cpu_devices', 5)]
-    # NOTE the check_rep=False default the 0.4.x shard_map shim applies
-    # is covered functionally by test_shard_map_while_loop_carry —
-    # that program fails outright on 0.4.x with check_rep enabled
+def test_compile_cache_follows_the_environment(monkeypatch):
+    # placed from outside: the env var wins and NO path is set in code
+    cfg = _Config()
+    monkeypatch.setattr(_jax_compat.jax, 'config', cfg)
+    monkeypatch.setenv('JAX_COMPILATION_CACHE_DIR', '/some/dir')
+    assert _jax_compat.enable_compile_cache() == '/some/dir'
+    assert 'jax_compilation_cache_dir' not in dict(cfg.calls)
+
+
+def test_compile_cache_defaults_to_the_checkout(monkeypatch):
+    cfg = _Config()
+    monkeypatch.setattr(_jax_compat.jax, 'config', cfg)
+    monkeypatch.delenv('JAX_COMPILATION_CACHE_DIR', raising=False)
+    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    want = os.path.join(here, '.jax_cache')
+    assert _jax_compat.enable_compile_cache() == want
+    assert dict(cfg.calls)['jax_compilation_cache_dir'] == want

@@ -170,7 +170,7 @@ def test_multi_device_equivalence(name, cpu8):
 def test_streams_candidates_capped_by_memory_plan():
     """Stream counts whose replica meshes blow the 0.85xHBM budget at
     the trial shape are EXCLUDED from the space (ISSUE 8 acceptance:
-    the 1024^3 staged ladder must stay inside budget)."""
+    the 1024^3 pipeline must stay inside budget)."""
     from nbodykit_tpu.pmesh import memory_plan
     small = [c.name for c in registered_paint_candidates(64, 10_000)]
     assert {'streams2', 'streams4', 'streams8'} <= set(small)
@@ -180,11 +180,12 @@ def test_streams_candidates_capped_by_memory_plan():
         if name.startswith('streams'):
             k = int(name[len('streams'):])
             assert memory_plan(1024, 1e8, paint_method='streams',
-                               paint_streams=k)['fits']
+                               paint_streams=k,
+                               hbm_bytes=16e9)['fits']
     # at 16 GB HBM even k=2 replicas do not fit next to the 1024^3
     # field: every stream count is excluded there
     assert not memory_plan(1024, 1e8, paint_method='streams',
-                           paint_streams=2)['fits']
+                           paint_streams=2, hbm_bytes=16e9)['fits']
     assert 'streams8' not in big
 
 

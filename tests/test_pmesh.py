@@ -287,9 +287,12 @@ def test_memory_plan_scale_claims():
     are comfortable (pmesh.memory_plan)."""
     from nbodykit_tpu.pmesh import memory_plan
 
-    assert memory_plan(512, int(1e7), 1)['fits']
-    assert not memory_plan(2048, int(1e9), 1)['fits']
-    p16 = memory_plan(2048, int(1e9), 16)
+    v5e = 16e9      # one chip's published HBM
+    assert memory_plan(512, int(1e7), 1, hbm_bytes=v5e)['fits']
+    assert not memory_plan(2048, int(1e9), 1, hbm_bytes=v5e)['fits']
+    p16 = memory_plan(2048, int(1e9), 16, hbm_bytes=v5e)
+    # without a device's memory the plan is arithmetic only: no verdict
+    assert 'fits' not in memory_plan(512, int(1e7), 1)
     assert p16['fits'] and p16['peak_bytes'] < 10e9
     # monotonic in devices
     assert (memory_plan(1024, int(1e8), 8)['peak_bytes']

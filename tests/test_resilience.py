@@ -140,7 +140,7 @@ raise SystemExit('unreachable')
 def test_classify_error():
     X = error_class()
     assert classify_error(X('UNAVAILABLE: socket closed')) == TRANSIENT
-    assert classify_error(RuntimeError('DATA_LOSS: tunnel')) == TRANSIENT
+    assert classify_error(RuntimeError('DATA_LOSS: link')) == TRANSIENT
     assert classify_error(
         X('RESOURCE_EXHAUSTED: Out of memory while trying to allocate '
           '4294967296 bytes.')) == OOM

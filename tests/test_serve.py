@@ -119,7 +119,8 @@ def test_admission_degrade_steps_scoped_ladder():
 
 
 def test_admission_reject_indivisible():
-    d = admit(AnalysisRequest(nmesh=36, npart=1000), ndevices=8)
+    d = admit(AnalysisRequest(nmesh=36, npart=1000), ndevices=8,
+              hbm_bytes=16e9)
     assert d.status == REJECT
     assert d.reason['code'] == 'indivisible'
 
@@ -145,6 +146,23 @@ def test_serve_warm_cache_second_request_compiles_nothing():
         # tuned options resolved once per shape class, then memoized
         assert _counter('serve.tuned.resolve') == 1
         assert _counter('serve.tuned.reuse') >= 1
+
+
+def test_serve_fftcorr_counts_every_cell():
+    """The served correlation function bins through the same
+    :func:`~nbodykit_tpu.ops.histogram.shell_sums` as the served
+    spectrum: integer shell counts that add up to the mesh."""
+    with _one_worker_server(batch=BatchPolicy(max_delay_s=0)) as srv:
+        r = srv.wait(srv.submit(AnalysisRequest(
+            algorithm='FFTCorr', nmesh=32, npart=20000, seed=5)),
+            timeout=180)
+    assert r.status == 'completed'
+    nm = np.asarray(r.nmodes)
+    assert nm.sum() == 32 ** 3 and nm[0] == 1
+    # shell 1: the 6 face neighbours + 12 edge (sqrt 2) + 8 corner
+    # (sqrt 3) cells of the periodic lattice
+    assert nm[1] == 26
+    assert np.isfinite(np.asarray(r.y)).all()
 
 
 def test_serve_batched_bit_equal_to_sequential():
@@ -239,7 +257,7 @@ def test_serve_injected_fault_degrades_one_request_not_fleet():
     # the fleet survived: every request completed, nothing lost
     assert [r.status for r in results] == ['completed'] * n
     assert summary['lost'] == 0
-    # and EXACTLY ONE request absorbed the injected tunnel death
+    # and EXACTLY ONE request absorbed the injected device loss
     hit = [r for r in results if r.event_count('retries')]
     assert len(hit) == 1
     assert summary['retried'] == 1

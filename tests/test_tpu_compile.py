@@ -1,0 +1,249 @@
+"""Compile the main path for a TPU v5e at the chip_smoke sizes, without
+the chip (on-chip-measurement guide, section 2, rehearsal 3).
+
+The TPU compiler is installed here and compiles for a device that is
+described, not attached: it refuses what the chip's compiler would
+refuse — a kernel's block shapes, a program that does not fit HBM.
+Nothing runs, so these cases say nothing about results or times.
+
+This is the ONLY file that describes a topology, and it does so inside
+the ``topo`` fixture: only one process may load libtpu, and every
+xdist worker imports every test file.
+
+The tier-1 cases take ~95 s together (one compile of a paint or an
+exchange is ~20 s, whatever the particle count).  Three more are
+marked ``slow``: run the whole file (no ``-m 'not slow'``) before a
+call that selects the mxu paint or takes four chips.
+"""
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
+                          SingleDeviceSharding)
+
+NMESH, NPART = 512, 10 ** 7
+#: what one v5e offers a program ("Used 16.50G of 15.75G hbm")
+V5E_HBM = 15.75 * 2 ** 30
+
+
+@pytest.fixture(scope='module')
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform='tpu',
+                                            topology_name='v5e:2x2')
+    except Exception as e:
+        pytest.skip('no v5e:2x2 topology can be described here: %s' % e)
+
+
+@pytest.fixture(scope='module')
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope='module')
+def four_chips(topo):
+    from nbodykit_tpu.parallel.runtime import AXIS
+    return Mesh(np.array(topo.devices), (AXIS,))
+
+
+@pytest.fixture(autouse=True)
+def as_on_the_chip(monkeypatch):
+    """Trace as the chip would: no x64 (the suite turns it on), the
+    TPU branch of every ``is_mxu_backend()`` dispatch, and the compile
+    cache off (an executable for a described device can be written to
+    it but never read back)."""
+    from jax.experimental.compilation_cache import compilation_cache
+    import nbodykit_tpu.utils
+    monkeypatch.setattr(nbodykit_tpu.utils, 'is_mxu_backend',
+                        lambda: True)
+    jax.config.update('jax_enable_compilation_cache', False)
+    compilation_cache.reset_cache()
+    try:
+        with jax.enable_x64(False):
+            yield
+    finally:
+        jax.config.update('jax_enable_compilation_cache', True)
+        compilation_cache.reset_cache()
+
+
+def _pm(nmesh, comm=None):
+    from nbodykit_tpu.parallel.runtime import use_mesh
+    from nbodykit_tpu.pmesh import ParticleMesh
+    with use_mesh(comm):
+        return ParticleMesh(Nmesh=nmesh, BoxSize=1000.0, dtype='f4',
+                            comm=comm)
+
+
+def _compile(fn, *args):
+    return jax.jit(fn).lower(*args).compile()
+
+
+def _total_bytes(compiled):
+    m = compiled.memory_analysis()
+    return (m.temp_size_in_bytes + m.argument_size_in_bytes
+            + m.output_size_in_bytes)
+
+
+def test_served_fftpower_program_fits_and_matches_the_plan(one_chip):
+    # the program AnalysisServer builds for a 1-device lane: vmap over
+    # a (B,) seed array, B = 1 for requests waited in turn.  It used to
+    # need 16.5 GB (parallel/dfft.py _fft_operand)
+    from nbodykit_tpu.pmesh import memory_plan
+    from nbodykit_tpu.serve import AnalysisRequest
+    from nbodykit_tpu.serve.scheduler import _build_single
+    req = AnalysisRequest(algorithm='FFTPower', nmesh=NMESH, npart=NPART)
+    single = _build_single(req, _pm(NMESH))
+    seeds = jax.ShapeDtypeStruct((1,), jnp.uint32, sharding=one_chip)
+    compiled = _compile(jax.vmap(single), seeds)
+    need = _total_bytes(compiled)
+    assert compiled.memory_analysis().temp_size_in_bytes < V5E_HBM
+    # admission and the compiler must agree: the compiler may not need
+    # more than the plan's peak plus its 15% margin
+    plan = memory_plan(NMESH, NPART, hbm_bytes=V5E_HBM)
+    assert plan['fits']
+    assert need <= plan['peak_bytes'] / 0.85, (need, plan['peak_bytes'])
+
+
+@pytest.mark.parametrize('op', ['r2c', 'c2r'])
+def test_eager_fft_at_512(one_chip, op):
+    pm = _pm(NMESH)
+    if op == 'r2c':
+        x = jax.ShapeDtypeStruct((NMESH,) * 3, jnp.float32,
+                                 sharding=one_chip)
+        compiled = _compile(pm.r2c, x)
+    else:
+        y = jax.ShapeDtypeStruct((NMESH, NMESH, NMESH // 2 + 1),
+                                 jnp.complex64, sharding=one_chip)
+        compiled = _compile(pm.c2r, y)
+    assert _total_bytes(compiled) < 0.25 * V5E_HBM
+
+
+def test_scatter_paint_1e7_into_512(one_chip):
+    pm = _pm(NMESH)
+    pos = jax.ShapeDtypeStruct((NPART, 3), jnp.float32,
+                               sharding=one_chip)
+    compiled = _compile(
+        lambda p: pm.paint(p, 1.0, resampler='cic',
+                           return_dropped=True), pos)
+    assert _total_bytes(compiled) < 0.25 * V5E_HBM
+
+
+@pytest.mark.parametrize(
+    'chips', [pytest.param(1, marks=pytest.mark.slow), 4])
+def test_fftpower_binning_program(one_chip, four_chips, monkeypatch,
+                                  chips):
+    # the (k, mu) binning program FFTPower(mode='2d', Nmu=5,
+    # poles=[0, 2, 4]) jits: taken from project_to_basis at the point
+    # where it would be called, with the MXU histogram it uses on a TPU
+    # — at 512^3 on one chip, and at 1024^3 as the shard_map over four
+    # (where the first four-chip run found a replicated loop carry)
+    from nbodykit_tpu.algorithms import fftpower
+    from nbodykit_tpu.base.mesh import Field
+    from nbodykit_tpu.parallel.runtime import AXIS
+
+    class Taken(Exception):
+        pass
+
+    def take(fn, label=None, **kw):
+        raise Taken(fn, label)
+
+    monkeypatch.setattr(fftpower, 'instrumented_jit', take)
+    if chips == 1:
+        nmesh, pm, sharding = NMESH, _pm(NMESH), one_chip
+    else:
+        nmesh, pm = 1024, _pm(1024, comm=four_chips)
+        sharding = NamedSharding(four_chips, P(AXIS, None, None))
+    value = jax.ShapeDtypeStruct((nmesh, nmesh, nmesh // 2 + 1),
+                                 jnp.complex64, sharding=sharding)
+    dk = 2 * np.pi / 1000.0
+    edges = [np.arange(0.0, np.pi * nmesh / 1000.0 + dk / 2, dk),
+             np.linspace(-1, 1, 6)]
+    with pytest.raises(Taken) as got:
+        fftpower.project_to_basis(Field(value, pm, 'complex'), edges,
+                                  poles=[0, 2, 4])
+    fn, label = got.value.args
+    assert label == 'fftpower.binning'
+    compiled = _compile(fn, value)
+    assert _total_bytes(compiled) < 0.25 * V5E_HBM
+
+
+def test_pallas_deposit_kernel_at_512(one_chip):
+    # the shapes paint_local_mxu hands the kernel at 512^3 / 1e7
+    # (rb = cb = 8, slack 2): nty = 64 tiles, 3 pieces of 1632 slots
+    from nbodykit_tpu.ops.paint_pallas import deposit_blocks_pallas
+    nty, npieces, ck = 64, 3, 1632
+    s = jax.ShapeDtypeStruct((nty, npieces, ck), jnp.float32,
+                             sharding=one_chip)
+    txi = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+
+    def fn(txi, sx, sy, sz, sm):
+        return deposit_blocks_pallas(
+            txi, sx, sy, sz, sm, resampler='cic', rb=8, cb=8,
+            n0l=NMESH, p0=NMESH, N1=NMESH, N2=NMESH, origin=0,
+            dtype=jnp.float32)
+    compiled = _compile(fn, txi, s, s, s, s)
+    assert 'tpu_custom_call' in compiled.as_text()
+
+
+def test_pallas_radix_kernel_at_1e7(one_chip):
+    # stable_digit_dest's counting pass over the 1e7 bucket keys of the
+    # 512^3 mxu paint: 65 * 64 + 1 buckets sort in two passes over
+    # base-65 digits
+    from nbodykit_tpu.ops.radix_pallas import pass_rank_hist_pallas
+    digit = jax.ShapeDtypeStruct((NPART,), jnp.int32, sharding=one_chip)
+    compiled = _compile(lambda d: pass_rank_hist_pallas(d, 65), digit)
+    assert 'tpu_custom_call' in compiled.as_text()
+
+
+@pytest.mark.slow
+def test_mxu_paint_with_both_kernels_at_512(one_chip, monkeypatch):
+    # the kernels where the paint really calls them
+    from nbodykit_tpu import set_options
+    from nbodykit_tpu.ops import radix
+    monkeypatch.setattr(radix, 'DEFAULT_ENGINE', 'pallas')
+    pm = _pm(NMESH)
+    pos = jax.ShapeDtypeStruct((NPART, 3), jnp.float32,
+                               sharding=one_chip)
+    with set_options(paint_method='mxu', paint_deposit='pallas',
+                     paint_order='radix'):
+        compiled = _compile(
+            lambda p: pm.paint(p, 1.0, resampler='cic',
+                               return_dropped=True), pos)
+    assert compiled.as_text().count('tpu_custom_call') >= 2
+    assert _total_bytes(compiled) < 0.5 * V5E_HBM
+
+
+def test_slab_rfftn_1024_on_four_chips(four_chips):
+    from nbodykit_tpu.parallel.runtime import AXIS
+    pm = _pm(1024, comm=four_chips)
+    x = jax.ShapeDtypeStruct(
+        (1024,) * 3, jnp.float32,
+        sharding=NamedSharding(four_chips, P(AXIS, None, None)))
+    compiled = _compile(pm.r2c, x)
+    assert 'all-to-all' in compiled.as_text()
+    # per device: a quarter of the 4.3 GB field and of its spectrum,
+    # plus workspace
+    assert _total_bytes(compiled) < 0.5 * V5E_HBM
+
+
+@pytest.mark.slow
+def test_particle_exchange_1e7_on_four_chips(four_chips):
+    # the all-to-all that routes 1e7 particles to their slabs, at the
+    # ceil(N / P) capacity a traced caller gets
+    from nbodykit_tpu.parallel.exchange import exchange_by_dest
+    from nbodykit_tpu.parallel.runtime import AXIS
+    rows = NamedSharding(four_chips, P(AXIS))
+    dest = jax.ShapeDtypeStruct((NPART,), jnp.int32, sharding=rows)
+    pos = jax.ShapeDtypeStruct(
+        (NPART, 3), jnp.float32,
+        sharding=NamedSharding(four_chips, P(AXIS, None)))
+    mass = jax.ShapeDtypeStruct((NPART,), jnp.float32, sharding=rows)
+    compiled = _compile(
+        lambda d, p, m: exchange_by_dest(d, [p, m], four_chips,
+                                         NPART // 4), dest, pos, mass)
+    assert 'all-to-all' in compiled.as_text()
+    assert _total_bytes(compiled) < 0.25 * V5E_HBM

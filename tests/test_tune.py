@@ -8,6 +8,7 @@ mesh)."""
 
 import json
 import os
+import time
 
 import numpy as np
 import pytest
@@ -56,7 +57,11 @@ def _counter(name):
 
 def _entry(op='paint', sclass='mesh16-part1e3', winner=None,
            device_count=1, platform='cpu', device_kind='cpu',
-           measured_at='2026-08-04T00:00:00Z', **extra):
+           measured_at=None, **extra):
+    if measured_at is None:
+        # fresh by construction: a fixed date would age past the
+        # 30-day staleness window
+        measured_at = time.strftime('%Y-%m-%dT%H:%M:%SZ', time.gmtime())
     return dict({
         'platform': platform, 'device_kind': device_kind,
         'device_count': device_count, 'op': op, 'shape_class': sclass,
