@@ -25,7 +25,7 @@ import jax.numpy as jnp
 from ..base.catalog import CatalogSourceBase
 from ..base.mesh import MeshSource, Field, FieldMesh
 from ..binned_statistic import BinnedStatistic
-from ..diagnostics import NULL_SPAN, instrumented_jit, span_eager
+from ..diagnostics import instrumented_jit, scope
 from ..utils import JSONEncoder, JSONDecoder, as_numpy, working_dtype
 
 
@@ -204,21 +204,22 @@ def project_to_basis(y3d, edges, los=[0, 0, 1], poles=[]):
     def chunk_hists(v_c, start):
         """All weighted histograms of one leading-axis slab whose
         global row offset is ``start``."""
-        x2 = sum(slice0(f, start) for f in x2fac)
-        if exact_int:
-            # x2 stays int32 for the (exact) digitize; float only for
-            # the mean-|x| stream
-            xnorm = unit * jnp.sqrt(x2.astype(jnp.float32))
-        else:
-            xnorm = jnp.sqrt(x2)
-        mudot = sum(slice0(c, start) for c in coords)
-        mu = jnp.where(xnorm == 0, 0.0,
-                       mudot / jnp.where(xnorm == 0, 1.0, xnorm))
         shape = v_c.shape
-        dig_x = jnp.digitize(
-            jnp.broadcast_to(x2, shape).reshape(-1), x2edges)
-        dig_mu = jnp.digitize(
-            jnp.broadcast_to(mu, shape).reshape(-1), muedges_j)
+        with scope('fftpower.binning.digitize'):
+            x2 = sum(slice0(f, start) for f in x2fac)
+            if exact_int:
+                # x2 stays int32 for the (exact) digitize; float only
+                # for the mean-|x| stream
+                xnorm = unit * jnp.sqrt(x2.astype(jnp.float32))
+            else:
+                xnorm = jnp.sqrt(x2)
+            mudot = sum(slice0(c, start) for c in coords)
+            mu = jnp.where(xnorm == 0, 0.0,
+                           mudot / jnp.where(xnorm == 0, 1.0, xnorm))
+            dig_x = jnp.digitize(
+                jnp.broadcast_to(x2, shape).reshape(-1), x2edges)
+            dig_mu = jnp.digitize(
+                jnp.broadcast_to(mu, shape).reshape(-1), muedges_j)
 
         wf = jnp.broadcast_to(w_b, shape).reshape(-1)
         nonsing = (wf == 2.0)
@@ -248,8 +249,9 @@ def project_to_basis(y3d, edges, los=[0, 0, 1], poles=[]):
             streams.append(fac * yre)
             if is_cplx:
                 streams.append(fac * yim)
-        return hist2d_weighted(dig_x, dig_mu, streams,
-                               Nx + 2, Nmu + 2)
+        with scope('fftpower.binning.hist'):
+            return hist2d_weighted(dig_x, dig_mu, streams,
+                                   Nx + 2, Nmu + 2)
 
     nstreams = 3 + Nell * (2 if is_cplx else 1)
 
@@ -288,30 +290,32 @@ def project_to_basis(y3d, edges, los=[0, 0, 1], poles=[]):
     hist_dtype = jnp.float64 if jax.config.jax_enable_x64 \
         else jnp.float32
 
+    # the program's name (``jit_binning``) is part of its key in jax's
+    # persistent cache and its scopes are not: under the name it had
+    # before it carried them, a cached executable would come back bare
     if nproc > 1:
         from jax.sharding import PartitionSpec as _P
 
-        def _local(v_loc):
-            base = jax.lax.axis_index(AXIS) * S0_local
-            hs = _block_hists(v_loc, base)
-            return tuple(jax.lax.psum(h, AXIS) for h in hs)
+        def binning(v_loc):
+            with scope('fftpower.binning'):
+                base = jax.lax.axis_index(AXIS) * S0_local
+                hs = _block_hists(v_loc, base)
+                return tuple(jax.lax.psum(h, AXIS) for h in hs)
 
         _bin = instrumented_jit(jax.shard_map(
-            _local, mesh=pm.comm,
+            binning, mesh=pm.comm,
             in_specs=(_P(AXIS, None, None),),
             out_specs=(_P(),) * nstreams), label='fftpower.binning')
     else:
-        _bin = instrumented_jit(lambda v: tuple(_block_hists(v, 0)),
-                                label='fftpower.binning')
+        def binning(v):
+            with scope('fftpower.binning'):
+                return tuple(_block_hists(v, 0))
 
-    _sp = span_eager('fftpower.binning', nstreams=nstreams,
-                     shape=[int(s) for s in value.shape])
-    with _sp:
-        hs = _bin(value)
-        if _sp is not NULL_SPAN:
-            # binning is async-dispatched; sync inside the span so its
-            # wall is the work, not the dispatch (enabled-mode only)
-            hs = jax.block_until_ready(hs)
+        _bin = instrumented_jit(binning, label='fftpower.binning')
+
+    with scope('fftpower.binning', nstreams=nstreams,
+               shape=[int(s) for s in value.shape]) as sc:
+        hs = sc.done(_bin(value))
     xsum, musum, Nsum = hs[0], hs[1], hs[2]
     ys_re, ys_im = [], []
     k = 3
@@ -517,10 +521,11 @@ class FFTBase(object):
         c2 = c1 if first is second else \
             second.compute(mode='complex', Nmesh=self.attrs['Nmesh'])
 
-        p3d = c1.value * jnp.conj(c2.value)
-        # clear the DC mode (transposed layout: [0,0,0] is k=0)
-        p3d = p3d.at[0, 0, 0].set(0.0)
-        p3d = p3d * self.attrs['BoxSize'].prod()
+        with scope('fftpower.transfer') as sc:
+            p3d = c1.value * jnp.conj(c2.value)
+            # clear the DC mode (transposed layout: [0,0,0] is k=0)
+            p3d = p3d.at[0, 0, 0].set(0.0)
+            p3d = sc.done(p3d * self.attrs['BoxSize'].prod())
 
         N1 = c1.attrs.get('N', 0)
         N2 = c2.attrs.get('N', 0)
@@ -579,8 +584,8 @@ class FFTPower(FFTBase):
         self.attrs['kmin'] = kmin
         self.attrs['kmax'] = kmax
 
-        with span_eager('fftpower.run', mode=mode,
-                        nmesh=int(self.attrs['Nmesh'][0])):
+        with scope('fftpower.run', mode=mode,
+                   nmesh=int(self.attrs['Nmesh'][0])):
             self.power, self.poles = self.run()
         self.attrs.update(self.power.attrs)
 

@@ -27,7 +27,7 @@ import jax.numpy as jnp
 from ..pmesh import ParticleMesh
 from ..parallel.runtime import CurrentMesh
 from ..utils import as_numpy
-from ..diagnostics import device_watermarks, enabled, span_eager
+from ..diagnostics import device_watermarks, enabled, scope
 
 logger = logging.getLogger('MeshSource')
 
@@ -68,15 +68,13 @@ class Field(object):
 
     def r2c(self):
         assert self.kind == 'real'
-        with span_eager('mesh.r2c', shape=[int(s) for s in self.shape]):
-            return Field(self.pm.r2c(self.value), self.pm, 'complex',
-                         self.attrs)
+        return Field(self.pm.r2c(self.value), self.pm, 'complex',
+                     self.attrs)
 
     def c2r(self):
         assert self.kind == 'complex'
-        with span_eager('mesh.c2r', shape=[int(s) for s in self.shape]):
-            return Field(self.pm.c2r(self.value), self.pm, 'real',
-                         self.attrs)
+        return Field(self.pm.c2r(self.value), self.pm, 'real',
+                     self.attrs)
 
     def apply(self, func, kind=None):
         """Apply ``func(coords, value) -> value`` immediately with the
@@ -233,9 +231,9 @@ class MeshSource(object):
         if mode not in ('real', 'complex'):
             raise ValueError("mode must be 'real' or 'complex'")
 
-        with span_eager('mesh.compute', mode=mode,
-                        cls=type(self).__name__,
-                        nactions=len(self.actions)):
+        with scope('mesh.compute', mode=mode,
+                   cls=type(self).__name__,
+                   nactions=len(self.actions)):
             # decide the starting representation: prefer the native one
             native_real = (type(self).to_real_field
                            is not MeshSource.to_real_field)

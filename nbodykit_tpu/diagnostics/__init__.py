@@ -10,7 +10,9 @@ with it.  Three pieces:
 - :mod:`.trace` — a low-overhead span tracer (context manager +
   decorator, monotonic clocks, per-thread nesting, exception-safe)
   emitting crash-safe JSONL (append + fsync per completed span) and a
-  Perfetto/chrome-trace export.  No-op when disabled.
+  Perfetto/chrome-trace export.  No-op when disabled.  ``scope``
+  puts one library layer on that trace, on ``jax.profiler``'s host
+  line and in the HLO op names at once (``nbk.<layer>``).
 - :mod:`.metrics` — process-wide counters/gauges/histograms (exchange
   bytes, FFT chunk walls, paint Mpart/s per kernel, device live-buffer
   watermarks) plus compile telemetry (``instrumented_jit``, the
@@ -33,12 +35,13 @@ Enable with ``nbodykit_tpu.set_options(diagnostics='/tmp/trace')`` (or
 
 import functools
 import os
+import sys
 
-from .trace import (NULL_SPAN, RequestContext, Tracer,  # noqa: F401
-                    atomic_write, current_tracer, exemplar_fraction,
-                    export_chrome_trace, new_request_context,
-                    read_trace, trace_context, trace_files,
-                    trace_scope, trace_state_clean)
+from .trace import (NULL_SPAN, SCOPE_PREFIX, RequestContext,  # noqa: F401
+                    Tracer, _Scope, atomic_write, current_tracer,
+                    exemplar_fraction, export_chrome_trace,
+                    new_request_context, read_trace, trace_context,
+                    trace_files, trace_scope, trace_state_clean)
 from .metrics import (REGISTRY, Counter, Gauge, Histogram,  # noqa: F401
                       MetricsRegistry, counter, gauge, histogram,
                       device_watermarks, install_compile_telemetry,
@@ -121,6 +124,36 @@ def span_eager(name, **attrs):
     if t is None or not trace_state_clean():
         return NULL_SPAN
     return t.span(name, attrs)
+
+
+def scope(name, **attrs):
+    """One library layer (``paint``, ``fft.r2c``, ``fftpower.binning``)
+    on all three clocks::
+
+        with scope('fft.r2c', shape=list(shape)) as sc:
+            out = sc.done(_impl(x))
+
+    - the JSONL trace: :func:`span_eager`'s record under the same name,
+      only while the ``diagnostics`` option is on;
+    - the profiler's host line: ``TraceAnnotation('nbk.' + name)``
+      whenever jax is not staging, whatever the option says (a
+      TraceMe costs next to nothing with no profiler session open), so
+      a device trace can join each launched program to its layer;
+    - the HLO op names: ``jax.named_scope('nbk.' + name)`` while jax
+      is staging (jit / shard_map / vmap): trace-time metadata only,
+      nothing at run time and no change to the compiled program.
+
+    Never syncs by itself; ``sc.done(result)`` waits for ``result``
+    only while the JSONL span is recording.  Eager ops do not carry a
+    named scope reliably (the dispatch cache reuses whichever name
+    compiled first), hence the host annotation there."""
+    jax = sys.modules.get('jax')
+    if jax is None:             # diagnostics never requires jax
+        return _Scope(NULL_SPAN, span(name, **attrs))
+    if trace_state_clean():
+        return _Scope(jax.profiler.TraceAnnotation(SCOPE_PREFIX + name),
+                      span(name, **attrs))
+    return _Scope(jax.named_scope(SCOPE_PREFIX + name), NULL_SPAN)
 
 
 def traced(name=None):

@@ -215,6 +215,56 @@ def trace_state_clean():
     return jc.trace_ctx.is_top_level()
 
 
+#: prefix of every library layer on the profiler's host line and in the
+#: HLO op names (``nbk.paint``, ``nbk.fft.r2c``): what a reduction of
+#: a device trace looks for
+SCOPE_PREFIX = 'nbk.'
+
+
+class _Scope(object):
+    """One library layer on all three clocks (see
+    :func:`nbodykit_tpu.diagnostics.scope`): ``mark`` is the
+    profiler's ``TraceAnnotation`` (eager) or ``jax.named_scope``
+    (staging), ``span`` the JSONL :class:`_Span` or :data:`NULL_SPAN`."""
+
+    __slots__ = ('_mark', '_span')
+
+    def __init__(self, mark, span):
+        self._mark = mark
+        self._span = span
+
+    @property
+    def span_id(self):
+        """The JSONL span's id; 0 while none is recording (the option
+        off, jax staging, a request outside the exemplar sample)."""
+        return self._span.span_id
+
+    def set(self, **attrs):
+        self._span.set(**attrs)
+        return self
+
+    def done(self, result):
+        """``result``, waited for first while the JSONL tracer records
+        this scope — dispatch is asynchronous, so only then is the
+        span's wall the layer's work and not its enqueue.  Never syncs
+        otherwise."""
+        if self._span is not NULL_SPAN:
+            sys.modules['jax'].block_until_ready(result)
+        return result
+
+    def __enter__(self):
+        self._mark.__enter__()
+        self._span.__enter__()
+        return self
+
+    def __exit__(self, etype, evalue, tb):
+        try:
+            self._span.__exit__(etype, evalue, tb)
+        finally:
+            self._mark.__exit__(etype, evalue, tb)
+        return False
+
+
 def fleet_rank_hint():
     """This process's fleet rank from the environment
     (``NBKIT_FLEET_RANK`` / ``JAX_PROCESS_ID``), or None.  Env-only on

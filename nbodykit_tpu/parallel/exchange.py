@@ -37,7 +37,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from .runtime import AXIS, mesh_size
-from ..diagnostics import counter, gauge, span_if
+from ..diagnostics import counter, gauge, scope
 
 
 def counted_capacity(pm_or_nproc, pos_or_dest, slack=1.05, n0=None):
@@ -244,9 +244,8 @@ def exchange_by_dest(dest, arrays, mesh, capacity=None, fill=0.0):
         P(*((AXIS,) + (None,) * (a.ndim - 1))) for a in payloads)
     out_specs = (P(AXIS), P()) + tuple(
         P(*((AXIS,) + (None,) * (a.ndim - 1))) for a in payloads)
-    with span_if(not isinstance(dest, jax.core.Tracer), 'exchange',
-                 nproc=nproc, capacity=int(capacity), bytes=xbytes,
-                 npart=int(n)):
+    with scope('exchange', nproc=nproc, capacity=int(capacity),
+               bytes=xbytes, npart=int(n)):
         res = jax.shard_map(local, mesh=mesh, in_specs=in_specs,
                             out_specs=out_specs)(dest, *payloads)
     slot_valid, dropped, live_recv = res[0], res[1], res[2]

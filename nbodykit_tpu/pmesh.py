@@ -32,7 +32,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from . import _global_options
 from .diagnostics import counter, current_tracer, histogram, \
-    install_compile_telemetry, span, \
+    install_compile_telemetry, scope, span, \
     trace_state_clean
 from .parallel.runtime import AXIS, CurrentMesh, mesh_size, shard_leading
 from .parallel import dfft
@@ -315,8 +315,10 @@ class ParticleMesh(object):
         check cannot branch, so ``return_dropped=True`` is REQUIRED —
         silent particle loss is never possible.
 
-        Diagnostics (docs/OBSERVABILITY.md): eager calls with the
-        ``diagnostics`` option set emit a ``paint`` span and record the
+        Diagnostics (docs/OBSERVABILITY.md): every call runs under
+        ``scope('paint')`` (``nbk.paint`` on the profiler's host line,
+        or on the HLO op names under a trace); eager calls with the
+        ``diagnostics`` option set also emit a ``paint`` span and record the
         per-method throughput histogram ``paint.<method>.mpart_per_s``.
         The result is synced (``block_until_ready``) inside the span so
         the throughput is real work, not dispatch — enabled-mode only;
@@ -335,18 +337,21 @@ class ParticleMesh(object):
         ignores it has lost deposits with no trace-side record.
         """
         if current_tracer() is None or not trace_state_clean():
-            return self._paint_impl(pos, mass, resampler, out, shift,
-                                    capacity, return_dropped)
+            # no JSONL span here, but the layer still gets its name:
+            # on the profiler's host line, or on the HLO ops
+            with scope('paint'):
+                return self._paint_impl(pos, mass, resampler, out,
+                                        shift, capacity, return_dropped)
         npart = int(pos.shape[0])
         # the RESOLVED kernel labels the span/histograms — with
         # paint_method='auto' the trace must show which kernel ran,
         # not the sentinel
         method = self._paint_config(npart)['paint_method']
         t0 = time.perf_counter()
-        with span('paint', method=method, npart=npart,
-                  nproc=self.nproc,
-                  resampler=resampler or _global_options['resampler'],
-                  nmesh=int(self.Nmesh[0])):
+        with scope('paint', method=method, npart=npart,
+                   nproc=self.nproc,
+                   resampler=resampler or _global_options['resampler'],
+                   nmesh=int(self.Nmesh[0])):
             res = self._paint_impl(pos, mass, resampler, out, shift,
                                    capacity, return_dropped)
             jax.block_until_ready(res)

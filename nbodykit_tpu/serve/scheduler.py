@@ -32,7 +32,7 @@ sub-mesh the same builder produces the shard_map form.
 
 import threading
 
-from ..diagnostics import counter, instrumented_jit
+from ..diagnostics import counter, instrumented_jit, scope
 from ..parallel.runtime import mesh_size
 
 BOX_SIZE = 1000.0
@@ -64,15 +64,17 @@ def _binned_power(pm, c, resampler, npart):
     nbins = nmesh // 2
     V = L ** 3
 
-    w = pm.k_list(dtype=jnp.float32, circular=True)
-    c = compensation_transfer(resampler, False)(w, c)
-    p3 = (jnp.abs(c) ** 2).astype(jnp.float32) * V
-    p3 = p3.at[0, 0, 0].set(0.0)
+    with scope('fftpower.transfer'):
+        w = pm.k_list(dtype=jnp.float32, circular=True)
+        c = compensation_transfer(resampler, False)(w, c)
+        p3 = (jnp.abs(c) ** 2).astype(jnp.float32) * V
+        p3 = p3.at[0, 0, 0].set(0.0)
 
-    ix, iy, iz = pm.i_list_complex()
-    shell = lattice_shell_index(ix * ix + iy * iy + iz * iz, nbins)
-    P, Nm = shell_sums(shell, p3, nbins,
-                       weight=pm.hermitian_weights(jnp.float32))
+    with scope('fftpower.binning'):
+        ix, iy, iz = pm.i_list_complex()
+        shell = lattice_shell_index(ix * ix + iy * iy + iz * iz, nbins)
+        P, Nm = shell_sums(shell, p3, nbins,
+                           weight=pm.hermitian_weights(jnp.float32))
     Nm0 = Nm.at[0].set(jnp.maximum(Nm[0] - 1.0, 0.0))  # drop DC mode
     k = jnp.asarray(np.arange(nbins, dtype='f4')) \
         * jnp.float32(2 * np.pi / L)
@@ -94,6 +96,22 @@ def _uniform_pos(seed, npart, L):
                               jnp.float32, 0.0, L)
 
 
+def _rooted(fn):
+    """``fn`` under the served program's root scope, so that what a
+    program does outside the library's layers (the realization, the
+    ``/ nbar``) is named in the device trace too.
+
+    The wrapper's name is the compiled module's (``jit_program``), and
+    on purpose not ``fn``'s: jax's persistent cache keys a program by
+    its name and computation, not by its metadata, so under the old
+    name an executable cached before the scopes existed would be
+    loaded again, with no scope in it."""
+    def program(arg):
+        with scope('serve.program'):
+            return fn(arg)
+    return program
+
+
 def _build_data(request, pm):
     """The (painted field -> (k, P, nmodes)) stage of a ``data_ref``
     program.  The paint itself is NOT in here: streaming ingestion is
@@ -106,7 +124,7 @@ def _build_data(request, pm):
     def from_field(field):
         c = pm.r2c(field / (float(npart) / pm.Ntot))
         return _binned_power(pm, c, resampler, npart)
-    return from_field
+    return _rooted(from_field)
 
 
 def _build_single(request, pm):
@@ -260,7 +278,7 @@ def _build_single(request, pm):
                 * jnp.float32(L / nmesh)
             return x, S / jnp.maximum(Nm, 1.0), Nm
 
-    return single
+    return _rooted(single)
 
 
 class Program(object):

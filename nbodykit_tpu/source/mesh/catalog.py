@@ -15,6 +15,7 @@ import numpy as np
 import jax.numpy as jnp
 
 from ...base.mesh import MeshSource, Field
+from ...diagnostics import scope
 from ...ops.window import compensation_transfer, window_support
 
 
@@ -59,7 +60,13 @@ class CatalogMesh(MeshSource):
 
     def _compensation_actions(self):
         transfer = compensation_transfer(self.resampler, self.interlaced)
-        return [('complex', transfer, 'circular')]
+
+        def compensate(w, v):
+            # named here so the layer's name travels with the action;
+            # a user's own actions stay unnamed
+            with scope('fftpower.transfer') as sc:
+                return sc.done(transfer(w, v))
+        return [('complex', compensate, 'circular')]
 
     def to_real_field(self, normalize=True):
         """Paint and normalize to 1 + delta; attrs gain N, W, W2,
