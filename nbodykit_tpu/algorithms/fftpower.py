@@ -9,10 +9,12 @@ Reference: ``nbodykit/algorithms/fftpower.py`` (FFTBase :12, FFTPower
 - dk=0 "unique edges" mode; save/load via JSON.
 
 TPU redesign: the 3-D power and its (k, mu, ell) reduction run as one
-jitted XLA program over the sharded transposed complex field — digitize
-+ Legendre recurrence + weighted bincounts replace the reference's
-rank-local slab loop (HOT LOOP 2 of SURVEY.md §3.1); means/packaging
-happen on host with numpy (small arrays).
+jitted XLA program over the sharded transposed complex field — a
+gather-free bin index (``ops.histogram.edge_count_index``, numpy's
+digitize as a compare-and-count) + Legendre recurrence + weighted
+MXU histograms (``ops.histogram.hist2d_weighted``) replace the
+reference's rank-local slab loop (HOT LOOP 2 of SURVEY.md §3.1);
+means/packaging happen on host with numpy (small arrays).
 """
 
 import json
@@ -67,9 +69,11 @@ def project_to_basis(y3d, edges, los=[0, 0, 1], poles=[]):
     (xmean_2d, mumean_2d, y2d, N_2d), (xmean_1d, poles, N_1d) or None
 
     Semantics mirror the reference's project_to_basis
-    (algorithms/fftpower.py:507-701): digitize against squared x edges,
-    hermitian weights double-count kz>0 (excluding the Nyquist plane),
-    odd multipoles keep 2i*Im, even keep 2*Re on the doubled modes.
+    (algorithms/fftpower.py:507-701): digitize against squared x edges
+    (``edge_count_index``: numpy.digitize's integers without its
+    search), hermitian weights double-count kz>0 (excluding the Nyquist
+    plane), odd multipoles keep 2i*Im, even keep 2*Re on the doubled
+    modes.  Both edge arrays must be strictly ascending (ValueError).
     """
     pm = y3d.pm
     # a complex field with the full (uncompressed) kz axis is a c2c
@@ -78,6 +82,12 @@ def project_to_basis(y3d, edges, los=[0, 0, 1], poles=[]):
                     and y3d.shape[2] == int(pm.Nmesh[2]))
     hermitian = (y3d.kind == 'complex') and not full_complex
     xedges, muedges = edges
+    for name, e in (('x', xedges), ('mu', muedges)):
+        # the bin index counts the edges at or below a value
+        # (ops.histogram.edge_count_index): ascending edges only
+        if not np.all(np.diff(np.asarray(e, dtype='f8')) > 0):
+            raise ValueError(
+                "%s edges must be strictly ascending" % name)
     Nx = len(xedges) - 1
     Nmu = len(muedges) - 1
 
@@ -199,7 +209,7 @@ def project_to_basis(y3d, edges, los=[0, 0, 1], poles=[]):
             return a
         return jax.lax.dynamic_slice_in_dim(a, start, rows, 0)
 
-    from ..ops.histogram import hist2d_weighted
+    from ..ops.histogram import edge_count_index, hist2d_weighted
 
     def chunk_hists(v_c, start):
         """All weighted histograms of one leading-axis slab whose
@@ -216,9 +226,9 @@ def project_to_basis(y3d, edges, los=[0, 0, 1], poles=[]):
             mudot = sum(slice0(c, start) for c in coords)
             mu = jnp.where(xnorm == 0, 0.0,
                            mudot / jnp.where(xnorm == 0, 1.0, xnorm))
-            dig_x = jnp.digitize(
+            dig_x = edge_count_index(
                 jnp.broadcast_to(x2, shape).reshape(-1), x2edges)
-            dig_mu = jnp.digitize(
+            dig_mu = edge_count_index(
                 jnp.broadcast_to(mu, shape).reshape(-1), muedges_j)
 
         wf = jnp.broadcast_to(w_b, shape).reshape(-1)
@@ -314,7 +324,8 @@ def project_to_basis(y3d, edges, los=[0, 0, 1], poles=[]):
         _bin = instrumented_jit(binning, label='fftpower.binning')
 
     with scope('fftpower.binning', nstreams=nstreams,
-               shape=[int(s) for s in value.shape]) as sc:
+               shape=[int(s) for s in value.shape],
+               nx_edges=len(xedges), nmu_edges=len(muedges)) as sc:
         hs = sc.done(_bin(value))
     xsum, musum, Nsum = hs[0], hs[1], hs[2]
     ys_re, ys_im = [], []

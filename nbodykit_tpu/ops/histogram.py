@@ -17,8 +17,14 @@ All weight streams share one dot per chunk (their B-columns are
 concatenated), one-hots are exact in bfloat16, each weight is split
 into bf16 hi+lo parts (w = hi + lo), the MXU accumulates in f32 and
 chunk results are summed in f64. Accuracy (~2e-7 max relative error
-vs exact f64 bincount) is asserted by tests/test_histogram.py; its
-time on the chip is not measured (root PERF.md).
+vs exact f64 bincount) is asserted by tests/test_histogram.py. On the
+chip it takes 0.095 s of an ``FFTPower`` call at 512^3 with 257 x 12
+bins and 5 streams (device time under ``nbk.fftpower.binning.hist``,
+one v5e; root PERF.md, the builder's traced run of PR 25).
+
+The bin indices it sums by come from ``edge_count_index`` (edges of any
+spacing; the lab path) or ``lattice_shell_index`` (unit-width shells;
+the serve plane); ``shell_sums`` is the serve plane's scatter-add sum.
 
 ``hist2d_weighted`` picks the MXU path on TPU and plain bincount
 elsewhere (CPU bincount is exact f64 and faster than emulated matmuls).
@@ -108,7 +114,8 @@ def lattice_shell_index(isq, nbins):
     sqrt rounds modes sitting ON a shell boundary (any perfect-square
     ``isq``) to a rounding-dependent side; the two integer compares
     below correct the rounded root exactly — one rsqrt + two compares
-    per element instead of a searchsorted binary search.
+    per element, where ``edge_count_index`` (edges of any spacing)
+    spends one compare per element and edge.
 
     ``isq`` must be int32 with ``(r+1)^2`` inside int32 — true for any
     admissible mesh (3*(Nmesh/2+1)^2 ~ 1.3e7 at Nmesh=4096).
@@ -169,6 +176,35 @@ def lattice_shell_edges(xedges, unit):
     """
     qe = np.ceil((np.asarray(xedges, dtype='f8') / float(unit)) ** 2)
     return np.clip(qe, 0, np.iinfo(np.int32).max).astype('i4')
+
+
+def edge_count_index(v, edges):
+    """Bin index of every ``v`` against ascending ``edges``: the number
+    of edges at or below it, ``#{j : edges[j] <= v}``, which is
+    ``numpy.digitize(v, edges)`` integer for integer (0 below the first
+    edge, ``len(edges)`` from the last one up, a value ON an edge in
+    the bin that edge opens).
+
+    The index half of the lab binning (``fftpower.project_to_basis``):
+    int32 ``|i|^2`` against ``lattice_shell_edges``' int32 thresholds,
+    and float ``x^2`` / ``mu`` against float edges.  ``jnp.digitize``
+    lowers to a binary search, a ``while`` of log2(len(edges)) rounds
+    that each gather one table entry per element, at the TPU's gather
+    rate of ~8 ns an element and round: 5.0 s of a 6.6 s ``FFTPower``
+    call at 512^3 (root PERF.md, PR 25).  The compare-and-count here
+    has no gather; XLA fuses compare and sum into one pass over ``v``
+    with the edges held on chip and never builds the
+    ``len(edges) x v.size`` comparison.  Plain IEEE ``<=``, as numpy's:
+    ``-0.0`` counts an edge at ``0.0`` (the total order of jax's
+    searchsorted does not); a NaN counts none.
+
+    ``edges`` must be 1-d and non-decreasing (callers check their host
+    edges once); ``v`` has any shape.  Returns int32 of ``v.shape``.
+    """
+    edges = jnp.asarray(edges)
+    v = jnp.asarray(v)
+    return (edges.reshape((-1,) + (1,) * v.ndim) <= v[None]).sum(
+        axis=0, dtype=jnp.int32)
 
 
 def hist2d_bincount(abin, bbin, weights, NA, NB):

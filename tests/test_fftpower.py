@@ -278,6 +278,52 @@ def test_project_to_basis_mxu_binning(monkeypatch):
                                np.asarray(r_exact.power['modes'], 'f8'))
 
 
+def test_fftpower_index_is_numpy_digitize_end_to_end(monkeypatch):
+    # the benchmark's lab call (mode='2d', kmin=0.001, Nmu=10) at a
+    # small mesh, against the same call with every bin index taken by
+    # np.digitize on the host from the same 3-d power: the
+    # compare-and-count index (ops.histogram.edge_count_index) gives
+    # jnp.digitize's integers, so the results agree to the bit. x64 as
+    # the suite runs and the TPU's no-x64 exact-integer path.
+    import jax
+    import nbodykit_tpu.ops.histogram as hist
+    rng = np.random.RandomState(26)
+    field_np = rng.standard_normal((32, 32, 32))
+
+    def call():
+        r = FFTPower(ArrayMesh(field_np, BoxSize=5000.0), mode='2d',
+                     kmin=0.001, Nmu=10)
+        return [np.asarray(r.power[c]) for c in
+                ('power', 'modes', 'k', 'mu')]
+
+    def host_digitize(v, edges):
+        return jax.pure_callback(
+            lambda v, e: np.digitize(v, e).astype('i4'),
+            jax.ShapeDtypeStruct(v.shape, jnp.int32), v, edges)
+
+    modes = {}
+    for x64 in (True, False):
+        with jax.enable_x64(x64):
+            got = call()
+            with monkeypatch.context() as m:
+                m.setattr(hist, 'edge_count_index', host_digitize)
+                want = call()
+        for g, w in zip(got, want):
+            assert g.dtype == w.dtype
+            assert g.tobytes() == w.tobytes()
+        modes[x64] = got[1]
+    # and the mode counts against the independent numpy oracle: all of
+    # them in f8; in f4 a lattice mode ON an interior mu edge (mu = 3/5)
+    # may round to either side, so the k shells only
+    kedges = np.arange(0.001, np.pi * 32 / 5000.0
+                       + np.pi / 5000.0, 2 * np.pi / 5000.0)
+    _, modes_want = numpy_power_oracle(field_np, [5000.0] * 3, kedges, 10)
+    assert modes_want.sum() > 32 ** 3 / 2
+    np.testing.assert_array_equal(modes[True], modes_want)
+    np.testing.assert_array_equal(modes[False].sum(axis=1),
+                                  modes_want.sum(axis=1))
+
+
 def test_projected_fftpower_device_invariance():
     rng = np.random.RandomState(12)
     field_np = rng.standard_normal((16, 16, 16))

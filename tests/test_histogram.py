@@ -14,8 +14,9 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from nbodykit_tpu.ops.histogram import (hist2d_mxu, hist2d_bincount,
-                                        hist2d_weighted, shell_sums)
+from nbodykit_tpu.ops.histogram import (edge_count_index, hist2d_mxu,
+                                        hist2d_bincount, hist2d_weighted,
+                                        lattice_shell_edges, shell_sums)
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
@@ -230,3 +231,79 @@ def test_project_to_basis_chunked_matches_unchunked(monkeypatch):
         np.testing.assert_allclose(np.asarray(refp[1]),
                                    np.asarray(gotp[1]), rtol=1e-12,
                                    equal_nan=True)
+
+
+def _index_case(kind):
+    """Edges and values of one ``edge_count_index`` case: every edge,
+    its two neighbours, below the first, above the last."""
+    if kind == 'i4':
+        # the lab path's exact-int side: int32 |i|^2 against the
+        # integer thresholds of FFTPower's own k edges at Nmesh 64
+        unit = 2 * np.pi / 1000.0
+        edges = lattice_shell_edges(
+            np.arange(0.001, np.pi * 64 / 1000.0 + unit / 2, unit), unit)
+        v = np.concatenate([edges - 1, edges, edges + 1,
+                            [0, 3 * 32 ** 2]]).astype('i4')
+        return edges, v
+    # the float side: mu against linspace(-1, 1, Nmu + 1), with
+    # mu = +-1 and both zeros against the edge at 0.0
+    edges = np.linspace(-1, 1, 11).astype(kind)
+    edges[5] = 0.0
+    below = np.nextafter(edges, -np.inf, dtype=kind)
+    above = np.nextafter(edges, np.inf, dtype=kind)
+    # next to 0.0 the neighbours are subnormal, which XLA flushes to
+    # zero on the CPU: the smallest normal numbers there
+    below[5], above[5] = -np.finfo(kind).tiny, np.finfo(kind).tiny
+    v = np.concatenate([below, edges, above,
+                        np.array([-0.0, 0.0, -1.0, 1.0, -7.0, 7.0, 0.3],
+                                 dtype=kind)])
+    return edges, v
+
+
+@pytest.mark.parametrize('how', ['eager', 'jit', 'fori_loop'])
+@pytest.mark.parametrize('kind', ['i4', 'f4', 'f8'])
+def test_edge_count_index_is_numpy_digitize(kind, how):
+    edges, v = _index_case(kind)
+    want = np.digitize(v, edges)
+    assert want.min() == 0 and want.max() == len(edges)
+    ej, vj = jnp.asarray(edges), jnp.asarray(v)
+    assert vj.dtype == v.dtype
+    if how == 'eager':
+        got = edge_count_index(vj, ej)
+    elif how == 'jit':
+        got = jax.jit(edge_count_index)(vj, ej)
+    else:
+        # as chunk_hists calls it: on a slice taken inside a loop body,
+        # the edges closed over
+        half = len(v) // 2
+
+        def body(i, out):
+            idx = edge_count_index(
+                jax.lax.dynamic_slice_in_dim(vj, i * half, half), ej)
+            return jax.lax.dynamic_update_slice_in_dim(
+                out, idx, i * half, 0)
+        got = jax.lax.fori_loop(
+            0, 2, body, jnp.zeros(2 * half, jnp.int32))
+        want = want[:2 * half]
+    assert got.dtype == jnp.int32
+    np.testing.assert_array_equal(np.asarray(got), want)
+    # any shape in, the same shape out
+    got3 = edge_count_index(vj[:8].reshape(2, 2, 2), ej)
+    np.testing.assert_array_equal(np.asarray(got3).ravel(), want[:8])
+
+
+@pytest.mark.parametrize('which', ['x', 'mu'])
+@pytest.mark.parametrize('fault', ['descending', 'repeated'])
+def test_project_to_basis_refuses_edges_not_ascending(fault, which):
+    from nbodykit_tpu.algorithms.fftpower import project_to_basis
+    from nbodykit_tpu.pmesh import ParticleMesh
+    from nbodykit_tpu.base.mesh import Field
+    pm = ParticleMesh(Nmesh=8, BoxSize=8.0, dtype='f8')
+    y3d = Field(pm.r2c(jnp.ones((8, 8, 8))), pm, kind='complex')
+    edges = {'x': np.arange(0.0, 4.0, 0.5), 'mu': np.linspace(-1, 1, 6)}
+    if fault == 'descending':
+        edges[which] = edges[which][::-1]
+    else:
+        edges[which] = np.insert(edges[which], 2, edges[which][2])
+    with pytest.raises(ValueError, match=which + ' edges'):
+        project_to_basis(y3d, [edges['x'], edges['mu']])

@@ -10,7 +10,7 @@ This is the ONLY file that describes a topology, and it does so inside
 the ``topo`` fixture: only one process may load libtpu, and every
 xdist worker imports every test file.
 
-The tier-1 cases take ~95 s together (one compile of a paint or an
+The tier-1 cases take ~105 s together (one compile of a paint or an
 exchange is ~20 s, whatever the particle count).  Three more are
 marked ``slow``: run the whole file (no ``-m 'not slow'``) before a
 call that selects the mxu paint or takes four chips.
@@ -70,11 +70,11 @@ def as_on_the_chip(monkeypatch):
         compilation_cache.reset_cache()
 
 
-def _pm(nmesh, comm=None):
+def _pm(nmesh, comm=None, box=1000.0):
     from nbodykit_tpu.parallel.runtime import use_mesh
     from nbodykit_tpu.pmesh import ParticleMesh
     with use_mesh(comm):
-        return ParticleMesh(Nmesh=nmesh, BoxSize=1000.0, dtype='f4',
+        return ParticleMesh(Nmesh=nmesh, BoxSize=box, dtype='f4',
                             comm=comm)
 
 
@@ -132,15 +132,23 @@ def test_scatter_paint_1e7_into_512(one_chip):
     assert _total_bytes(compiled) < 0.25 * V5E_HBM
 
 
-@pytest.mark.parametrize(
-    'chips', [pytest.param(1, marks=pytest.mark.slow), 4])
+#: temp_size_in_bytes of the lab cell's binning program at 512^3 with
+#: jnp.digitize's binary search in it (PR 25, the parent of PR 26)
+LAB_BINNING_TEMP_PR25 = 1_612_483_584
+
+
+@pytest.mark.parametrize('chips, cell', [
+    pytest.param(1, 'poles', marks=pytest.mark.slow), (4, 'poles'),
+    (1, 'lab')])
 def test_fftpower_binning_program(one_chip, four_chips, monkeypatch,
-                                  chips):
-    # the (k, mu) binning program FFTPower(mode='2d', Nmu=5,
-    # poles=[0, 2, 4]) jits: taken from project_to_basis at the point
-    # where it would be called, with the MXU histogram it uses on a TPU
-    # — at 512^3 on one chip, and at 1024^3 as the shard_map over four
-    # (where the first four-chip run found a replicated loop carry)
+                                  chips, cell):
+    # the (k, mu) binning program FFTPower(mode='2d', ...) jits: taken
+    # from project_to_basis at the point where it would be called, with
+    # the MXU histogram it uses on a TPU. 'poles': Nmu=5, poles=[0, 2,
+    # 4] at 512^3 on one chip, and at 1024^3 as the shard_map over four
+    # (where the first four-chip run found a replicated loop carry).
+    # 'lab': the edges of the benchmark's desi_like_n512.lab cell
+    # (BoxSize 5000, kmin 0.001, Nmu 10, no poles)
     from nbodykit_tpu.algorithms import fftpower
     from nbodykit_tpu.base.mesh import Field
     from nbodykit_tpu.parallel.runtime import AXIS
@@ -152,23 +160,33 @@ def test_fftpower_binning_program(one_chip, four_chips, monkeypatch,
         raise Taken(fn, label)
 
     monkeypatch.setattr(fftpower, 'instrumented_jit', take)
+    box, kmin, nmu, poles = ((5000.0, 0.001, 10, []) if cell == 'lab'
+                             else (1000.0, 0.0, 5, [0, 2, 4]))
     if chips == 1:
-        nmesh, pm, sharding = NMESH, _pm(NMESH), one_chip
+        nmesh, pm, sharding = NMESH, _pm(NMESH, box=box), one_chip
     else:
         nmesh, pm = 1024, _pm(1024, comm=four_chips)
         sharding = NamedSharding(four_chips, P(AXIS, None, None))
     value = jax.ShapeDtypeStruct((nmesh, nmesh, nmesh // 2 + 1),
                                  jnp.complex64, sharding=sharding)
-    dk = 2 * np.pi / 1000.0
-    edges = [np.arange(0.0, np.pi * nmesh / 1000.0 + dk / 2, dk),
-             np.linspace(-1, 1, 6)]
+    # FFTPower.run's own edges for that kmin and Nmu with dk=None
+    dk = 2 * np.pi / box
+    edges = [np.arange(kmin, np.pi * nmesh / box + dk / 2, dk),
+             np.linspace(-1, 1, nmu + 1)]
     with pytest.raises(Taken) as got:
         fftpower.project_to_basis(Field(value, pm, 'complex'), edges,
-                                  poles=[0, 2, 4])
+                                  poles=poles)
     fn, label = got.value.args
     assert label == 'fftpower.binning'
     compiled = _compile(fn, value)
     assert _total_bytes(compiled) < 0.25 * V5E_HBM
+    # the bin index is a compare-and-count (ops.histogram.
+    # edge_count_index): a binary search would show as a gather in a
+    # while, 5.0 s of a 6.6 s lab call on the chip (PERF.md, PR 25)
+    assert ' gather(' not in compiled.as_text()
+    if cell == 'lab':
+        temp = compiled.memory_analysis().temp_size_in_bytes
+        assert temp <= LAB_BINNING_TEMP_PR25, temp
 
 
 def test_pallas_deposit_kernel_at_512(one_chip):
