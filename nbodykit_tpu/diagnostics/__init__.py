@@ -141,7 +141,13 @@ def scope(name, **attrs):
       a device trace can join each launched program to its layer;
     - the HLO op names: ``jax.named_scope('nbk.' + name)`` while jax
       is staging (jit / shard_map / vmap): trace-time metadata only,
-      nothing at run time and no change to the compiled program.
+      nothing at run time and no change to the compiled program.  The
+      host annotation is kept there too: an eager ``shard_map`` runs
+      its body one primitive a program, each launched from inside the
+      scope with no name stack in its op names (the slab r2c's
+      all_to_all read ``jit(<unknown>)/shard_map/all_to_all`` on the
+      chip), so only the host line can name them; under a real jit it
+      marks the tracing and launches nothing.
 
     Never syncs by itself; ``sc.done(result)`` waits for ``result``
     only while the JSONL span is recording.  Eager ops do not carry a
@@ -150,10 +156,10 @@ def scope(name, **attrs):
     jax = sys.modules.get('jax')
     if jax is None:             # diagnostics never requires jax
         return _Scope(NULL_SPAN, span(name, **attrs))
+    mark = jax.profiler.TraceAnnotation(SCOPE_PREFIX + name)
     if trace_state_clean():
-        return _Scope(jax.profiler.TraceAnnotation(SCOPE_PREFIX + name),
-                      span(name, **attrs))
-    return _Scope(jax.named_scope(SCOPE_PREFIX + name), NULL_SPAN)
+        return _Scope(mark, span(name, **attrs))
+    return _Scope(mark, NULL_SPAN, jax.named_scope(SCOPE_PREFIX + name))
 
 
 def traced(name=None):

@@ -224,14 +224,16 @@ SCOPE_PREFIX = 'nbk.'
 class _Scope(object):
     """One library layer on all three clocks (see
     :func:`nbodykit_tpu.diagnostics.scope`): ``mark`` is the
-    profiler's ``TraceAnnotation`` (eager) or ``jax.named_scope``
-    (staging), ``span`` the JSONL :class:`_Span` or :data:`NULL_SPAN`."""
+    profiler's ``TraceAnnotation``, ``staged`` the ``jax.named_scope``
+    entered inside it while jax is staging (else ``None``), ``span``
+    the JSONL :class:`_Span` or :data:`NULL_SPAN`."""
 
-    __slots__ = ('_mark', '_span')
+    __slots__ = ('_mark', '_span', '_staged')
 
-    def __init__(self, mark, span):
+    def __init__(self, mark, span, staged=None):
         self._mark = mark
         self._span = span
+        self._staged = staged
 
     @property
     def span_id(self):
@@ -254,6 +256,8 @@ class _Scope(object):
 
     def __enter__(self):
         self._mark.__enter__()
+        if self._staged is not None:
+            self._staged.__enter__()
         self._span.__enter__()
         return self
 
@@ -261,7 +265,11 @@ class _Scope(object):
         try:
             self._span.__exit__(etype, evalue, tb)
         finally:
-            self._mark.__exit__(etype, evalue, tb)
+            try:
+                if self._staged is not None:
+                    self._staged.__exit__(etype, evalue, tb)
+            finally:
+                self._mark.__exit__(etype, evalue, tb)
         return False
 
 

@@ -79,10 +79,14 @@ def hist2d_mxu(abin, bbin, weights, NA, NB, chunk=131072,
         cols = []
         for w in ws:
             w_c = jax.lax.dynamic_slice(w, (i * chunk,), (chunk,))
-            hi = w_c.astype(jnp.bfloat16)
-            lo = (w_c - hi.astype(jnp.float32)).astype(jnp.bfloat16)
-            cols.append(Boh * hi[:, None])
-            cols.append(Boh * lo[:, None])
+            # hi on bf16's grid but still f32: a convert to bf16 and
+            # back inside one fusion may be elided as excess precision
+            # (the four-chip program did: hi = w, lo = 0, 8 bits left)
+            hi = jax.lax.reduce_precision(w_c, exponent_bits=8,
+                                          mantissa_bits=7)
+            lo = w_c - hi
+            cols.append(Boh * hi.astype(jnp.bfloat16)[:, None])
+            cols.append(Boh * lo.astype(jnp.bfloat16)[:, None])
         B = jnp.concatenate(cols, axis=1)
         H = jax.lax.dot_general(A, B, (((0,), (0,)), ((), ())),
                                 preferred_element_type=jnp.float32)

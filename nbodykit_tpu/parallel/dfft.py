@@ -282,8 +282,9 @@ def _a2a_site(y, axis_name, split_axis, concat_axis, nsplit, mode,
     data-dependent scale, priced in-graph so the budget is honest).
     The guarded program emits the SAME single all_to_all plus two
     psums, identically on every rank."""
-    # always staged (a shard_map body): the scope names the collective
-    # and its guard folds ``nbk.fft.a2a.<axis>`` in the HLO op names
+    # always a shard_map body: the scope names the collective and its
+    # guard folds ``nbk.fft.a2a.<axis>``, in the HLO op names of a
+    # staged program and on the host line of an eager shard_map
     with scope('fft.a2a.%s' % axis_name):
         # ``check``/``bits`` are host-static (checks_enabled() and the
         # consumed fault rule, identical on every rank), so the arms pick

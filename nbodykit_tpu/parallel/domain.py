@@ -22,7 +22,7 @@ over a 1-D device mesh:
   ever materializes a remote shard wholesale.
 
 Everything here runs *eagerly* on global sharded arrays (capacities are
-computed exactly via :func:`...exchange.auto_capacity`); the per-device
+counted exactly via :func:`...exchange.auto_capacity`); the per-device
 compute they feed (grid-hash sweeps, label propagation) runs inside
 ``shard_map`` — see :mod:`..ops.devicehash`.
 """

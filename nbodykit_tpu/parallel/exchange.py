@@ -15,8 +15,10 @@ XLA wants static shapes, so the ragged all-to-allv becomes a
 4. the receive side is a (P, capacity) buffer with a validity mask.
 
 Capacity policy: when called eagerly (the normal case — paint/readout
-size their buffers before tracing), :func:`auto_capacity` computes the
-*exact* max per-(src,dst) count, so overflow cannot happen. Under a
+size their buffers before tracing), :func:`auto_capacity` counts the
+*exact* max per-(src,dst) count and rounds its bound up to a rung of
+:func:`ladder_capacity`, so overflow cannot happen and catalogs that
+are balanced alike share one set of static shapes. Under a
 trace, callers must pass an explicit capacity; the ``dropped`` count is
 returned so they can detect overflow outside jit and retry larger — the
 same contract as the reference's paint-chunk backoff loop
@@ -87,21 +89,62 @@ def counted_capacity(pm_or_nproc, pos_or_dest, slack=1.05, n0=None):
     return auto_capacity(dest, nproc, slack=slack)
 
 
-def auto_capacity(dest, nproc, slack=1.05):
-    """Exact sufficient per-(src,dst)-pair capacity for an exchange.
+#: ratio of the capacity ladder's rungs, as a fraction (17/16 = 1.0625).
+#: The capacity is a static shape of the bucketing, the all_to_alls and
+#: the paint kernel's particle axis, so a capacity that follows the
+#: exact count gives every catalog its own compiles; a rung 1/16 wide
+#: holds the scatter of uniform catalogs of one (N, P) (the bound of
+#: 1e7 particles on 4 devices is 1.0515-1.0526 x N/P^2) and costs at
+#: most 6.25% of buffer over the exact bound.
+RUNG = (17, 16)
 
-    Max over (src, dst) pairs of the particle count, assuming particles
-    are evenly sharded over devices in index order (the layout of a
-    freshly created global array, matching the padding in
-    :func:`exchange_by_dest`). Cheap; call *outside* jit so the result
-    can size static buffers.
-    """
+
+def pair_count_max(dest, nproc):
+    """Largest particle count over the (src, dst) pairs, assuming
+    particles are evenly sharded over devices in index order (the
+    layout of a freshly created global array, matching the padding in
+    :func:`exchange_by_dest`). Eager only."""
     n = int(dest.shape[0])
     per = -(-n // nproc)  # ceil: matches the even sharding of the pad
     src = jnp.arange(n, dtype=jnp.int32) // per
     pair = src * nproc + jnp.asarray(dest, jnp.int32)
     counts = jnp.bincount(pair, length=nproc * nproc)
-    return int(np.ceil(int(counts.max()) * slack)) + 8
+    return int(counts.max())
+
+
+def ladder_capacity(exact, n, nproc, slack=1.05):
+    """The capacity for a counted maximum of ``exact`` particles a
+    (src, dst) pair among ``n`` on ``nproc`` devices: the exact bound
+    ``ceil(exact * slack) + 8`` rounded UP to the next rung of the
+    geometric ladder ``ceil(ceil(n / nproc^2) * RUNG^k)``, k = 0, 1, ...
+
+    Never below the exact bound, so nothing can drop; a function of the
+    counted maximum, not of an assumed balance, so a clustered catalog
+    climbs the ladder instead of overflowing."""
+    bound = int(np.ceil(int(exact) * slack)) + 8
+    base = max(-(-int(n) // (nproc * nproc)), 1)
+    num, den = RUNG
+    k = 0       # in integers: a rung is the same on every host
+    while -(-base * num ** k // den ** k) < bound:
+        k += 1
+    return -(-base * num ** k // den ** k)
+
+
+def counted_rung(dest, nproc, slack=1.05):
+    """``(exact, capacity)`` for these destinations: the counted
+    maximum (:func:`pair_count_max`) and its rung of the capacity
+    ladder (:func:`ladder_capacity`). Eager only."""
+    exact = pair_count_max(dest, nproc)
+    return exact, ladder_capacity(exact, int(dest.shape[0]), nproc,
+                                  slack=slack)
+
+
+def auto_capacity(dest, nproc, slack=1.05):
+    """Sufficient per-(src,dst)-pair capacity for an exchange: the
+    capacity of :func:`counted_rung`. Cheap; call *outside* jit so the
+    result can size static buffers.
+    """
+    return counted_rung(dest, nproc, slack=slack)[1]
 
 
 def _bucket_local(dest, arrays, nproc, capacity, fill=0.0, live=None):
@@ -169,8 +212,8 @@ def exchange_by_dest(dest, arrays, mesh, capacity=None, fill=0.0):
     arrays : list of global (N, ...) payloads, sharded on axis 0
     mesh : device mesh (may be None / size 1)
     capacity : int or None — max particles shipped per (src, dst) pair;
-        None (only valid eagerly) computes the exact bound via
-        :func:`auto_capacity`.
+        None (only valid eagerly) counts the exact maximum and takes
+        its rung of the ladder, as :func:`auto_capacity` does.
 
     Returns
     -------
@@ -199,6 +242,7 @@ def exchange_by_dest(dest, arrays, mesh, capacity=None, fill=0.0):
         arrays = [jnp.concatenate(
             [a, jnp.zeros((npad,) + a.shape[1:], a.dtype)]) for a in arrays]
 
+    exact = None        # the counted maximum, where this call counted
     if capacity is None:
         if isinstance(dest, jax.core.Tracer):
             # under a trace we cannot inspect the data: use the always-
@@ -207,7 +251,7 @@ def exchange_by_dest(dest, arrays, mesh, capacity=None, fill=0.0):
             # wanting tighter buffers pass capacity explicitly.
             capacity = -(-dest.shape[0] // nproc)
         else:
-            capacity = auto_capacity(dest, nproc)  # after padding: exact
+            exact, capacity = counted_rung(dest, nproc)  # after padding
 
     payloads = [live] + list(arrays)
 
@@ -223,6 +267,8 @@ def exchange_by_dest(dest, arrays, mesh, capacity=None, fill=0.0):
     counter('exchange.calls').add(1)
     counter('exchange.bytes_sent').add(xbytes)
     gauge('exchange.capacity').set(int(capacity))
+    filled = n / float(nproc * nproc * int(capacity))
+    gauge('exchange.fill').set(filled)
 
     def local(dest_l, *payloads_l):
         # payloads_l[0] is the live mask: pad entries that overflow a
@@ -245,7 +291,8 @@ def exchange_by_dest(dest, arrays, mesh, capacity=None, fill=0.0):
     out_specs = (P(AXIS), P()) + tuple(
         P(*((AXIS,) + (None,) * (a.ndim - 1))) for a in payloads)
     with scope('exchange', nproc=nproc, capacity=int(capacity),
-               bytes=xbytes, npart=int(n)):
+               capacity_exact=exact, fill=filled, bytes=xbytes,
+               npart=int(n)):
         res = jax.shard_map(local, mesh=mesh, in_specs=in_specs,
                             out_specs=out_specs)(dest, *payloads)
     slot_valid, dropped, live_recv = res[0], res[1], res[2]

@@ -266,7 +266,9 @@ class ParticleMesh(object):
 
     def exchange_capacity(self, pos, slack=1.05, shift=0.0):
         """Two-pass counted exchange, pass 1 (run EAGERLY): the exact
-        per-(src,dst) routing count for these positions, with slack.
+        per-(src,dst) routing count for these positions, with slack,
+        on its rung of the capacity ladder
+        (:func:`~nbodykit_tpu.parallel.exchange.ladder_capacity`).
         ``slack='auto'`` consults the tune cache (exchange op) and
         falls back to 1.05 when cold.
 
@@ -309,9 +311,10 @@ class ParticleMesh(object):
 
         Overflow contract (reference analog: the paint chunk backoff
         loop, nbodykit/source/mesh/catalog.py:275-315): with the default
-        capacity, overflow is impossible (exact bound eagerly, ceil
-        bound under trace). An explicit ``capacity`` is retried eagerly
-        with doubled capacity until nothing drops; under a trace the
+        capacity, overflow is impossible (the exact bound's rung
+        eagerly, ceil bound under trace). An explicit ``capacity`` is
+        retried eagerly with doubled capacity until nothing drops;
+        under a trace the
         check cannot branch, so ``return_dropped=True`` is REQUIRED —
         silent particle loss is never possible.
 
@@ -513,18 +516,22 @@ class ParticleMesh(object):
             return block, dropped, over
 
         block, dropped, over = attempt(capacity)
-        if not traced and capacity is not None and int(dropped) > 0:
+        # eager: `over` is read below in any case and the exchange ends
+        # before the paint, so its count costs no further wait
+        lost = 0 if traced else self._count_dropped(dropped)
+        if capacity is not None and lost > 0:
             # eager exchange-capacity backoff (reference:
             # source/mesh/catalog.py:275-315), keeping all three
             # outputs from the final attempt
             cap_max = -(-npart // self.nproc) + 8
-            while int(dropped) > 0 and capacity < cap_max:
+            while lost > 0 and capacity < cap_max:
                 capacity = min(2 * capacity, cap_max)
                 self.logger.info(
                     "exchange overflow (%d dropped); retrying with "
-                    "capacity=%d" % (int(dropped), capacity))
+                    "capacity=%d" % (lost, capacity))
                 block, dropped, over = attempt(capacity)
-            if int(dropped) > 0:
+                lost = self._count_dropped(dropped)
+            if lost > 0:
                 # NBK103 (baselined, audited): this raise sits between
                 # collective stages, but `dropped` is the
                 # globally-summed overflow count — every rank computes
@@ -600,6 +607,15 @@ class ParticleMesh(object):
             tr.event('paint.dropped', {'dropped': int(count),
                                        'slack': float(slack)})
 
+    @staticmethod
+    def _count_dropped(dropped):
+        """An exchange's overflow count as an int, read eagerly, and
+        fed to the ``exchange.dropped`` counter: like ``paint.dropped``
+        it counts what each attempt lost, before a retry heals it."""
+        lost = int(dropped)
+        counter('exchange.dropped').add(lost)
+        return lost
+
     def _check_overflow_contract(self, capacity, traced, return_dropped):
         if traced and capacity is not None and not return_dropped:
             raise ValueError(
@@ -613,13 +629,15 @@ class ParticleMesh(object):
         """Eager backoff: double the exchange capacity until no
         particle drops (reference: source/mesh/catalog.py:275-315)."""
         cap_max = -(-npart // self.nproc) + 8
-        while int(dropped) > 0 and capacity < cap_max:
+        lost = self._count_dropped(dropped)
+        while lost > 0 and capacity < cap_max:
             capacity = min(2 * capacity, cap_max)
             self.logger.info(
                 "exchange overflow (%d dropped); retrying with "
-                "capacity=%d" % (int(dropped), capacity))
+                "capacity=%d" % (lost, capacity))
             block, dropped = attempt(capacity)
-        if int(dropped) > 0:
+            lost = self._count_dropped(dropped)
+        if lost > 0:
             raise RuntimeError(
                 "particle exchange still overflowing at the maximal "
                 "capacity %d — this should be impossible" % capacity)

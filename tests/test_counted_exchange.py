@@ -10,7 +10,7 @@ import jax.numpy as jnp
 
 from nbodykit_tpu.pmesh import ParticleMesh, memory_plan
 from nbodykit_tpu.parallel.runtime import cpu_mesh
-from nbodykit_tpu.parallel.exchange import counted_capacity
+from nbodykit_tpu.parallel.exchange import RUNG, counted_capacity
 
 
 def test_counted_capacity_is_exact_bound():
@@ -23,8 +23,10 @@ def test_counted_capacity_is_exact_bound():
     src = np.arange(10000) // per
     pair_counts = np.bincount(src * nproc + np.asarray(dest),
                               minlength=nproc * nproc)
-    assert cap >= pair_counts.max()
-    assert cap <= pair_counts.max() + 8 + 1   # slack=1.0 + headroom
+    # slack=1.0 + headroom, rounded up to the capacity ladder: at
+    # least the exact bound, at most one rung (17/16) above it
+    exact = int(pair_counts.max()) + 8
+    assert exact <= cap <= exact * RUNG[0] // RUNG[1] + 1
 
 
 def test_traced_paint_with_counted_capacity_matches_eager():
