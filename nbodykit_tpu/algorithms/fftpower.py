@@ -47,6 +47,21 @@ def _legendre_all(ells, mu):
     return [out[ell] for ell in ells]
 
 
+@instrumented_jit(label='fftpower.p3d')
+def _cross_power(a, b, volume):
+    """``a * conj(b) * volume`` with the DC mode cleared, as one
+    program.  Op by op these were four mesh-sized complex fields in
+    front of a host that runs ahead of the device, and how many were
+    alive at once moved with its lead: the call's peak allocation by
+    one field, run to run.  On a slab mesh the eager DC clear also
+    all-gathered the field's planes; the program keeps every device
+    to its rows."""
+    p3d = a * jnp.conj(b)
+    # clear the DC mode (transposed layout: [0,0,0] is k=0)
+    p3d = p3d.at[0, 0, 0].set(0.0)
+    return p3d * volume
+
+
 # elements per slab chunk of the binning reduction (patchable so tests
 # can exercise the chunked path on small meshes)
 _BIN_CHUNK_ELEMENTS = 1 << 22
@@ -533,10 +548,8 @@ class FFTBase(object):
             second.compute(mode='complex', Nmesh=self.attrs['Nmesh'])
 
         with scope('fftpower.transfer') as sc:
-            p3d = c1.value * jnp.conj(c2.value)
-            # clear the DC mode (transposed layout: [0,0,0] is k=0)
-            p3d = p3d.at[0, 0, 0].set(0.0)
-            p3d = sc.done(p3d * self.attrs['BoxSize'].prod())
+            p3d = sc.done(_cross_power(c1.value, c2.value,
+                                       self.attrs['BoxSize'].prod()))
 
         N1 = c1.attrs.get('N', 0)
         N2 = c2.attrs.get('N', 0)

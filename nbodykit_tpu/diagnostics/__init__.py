@@ -144,10 +144,10 @@ def scope(name, **attrs):
       nothing at run time and no change to the compiled program.  The
       host annotation is kept there too: an eager ``shard_map`` runs
       its body one primitive a program, each launched from inside the
-      scope with no name stack in its op names (the slab r2c's
-      all_to_all read ``jit(<unknown>)/shard_map/all_to_all`` on the
-      chip), so only the host line can name them; under a real jit it
-      marks the tracing and launches nothing.
+      scope with no name stack in its op names (an all_to_all reads
+      ``jit(<unknown>)/shard_map/all_to_all`` on the chip), so only
+      the host line can name them; under a real jit it marks the
+      tracing and launches nothing.
 
     Never syncs by itself; ``sc.done(result)`` waits for ``result``
     only while the JSONL span is recording.  Eager ops do not carry a

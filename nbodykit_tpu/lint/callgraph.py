@@ -12,6 +12,10 @@ the codebase uses everywhere:
   — the diagnostics drop-in, same semantics;
 - ``@functools.lru_cache`` builders and ``functools.partial`` — the
   wrapper is transparent for call-graph purposes;
+- ``raw, jitted = _slab_programs(...)`` then
+  ``(jitted if eager else raw)(x)`` — the cached program pair: the
+  names resolve through the builder's returned tuple, and the
+  conditional pick reaches the one body both forms run;
 - ``from ..parallel import dfft; dfft.rfftn_single_lowmem(box)`` —
   resolved through the import alias table to the def in the other
   module's context.
@@ -187,9 +191,17 @@ class Project(object):
         bctx = ref.ctx
         if isinstance(elt, ast.Call):
             unwrapped = self._unwrap(bctx, elt)
-            if unwrapped is not None:
-                return (bctx,) + unwrapped
-            return None
+            if unwrapped is None:
+                return None
+            # ``instrumented_jit(body, label=...)``: ``body`` is a
+            # name of the builder's scope, not of the caller's, also
+            # where both live in one module
+            target, donate, jitted = unwrapped
+            tref, donate, jitted = self._resolve(
+                bctx, target, elt, donate, jitted)
+            if tref is not None:
+                return (bctx, tref.node, donate, jitted)
+            return (bctx,) + unwrapped
         if isinstance(elt, (ast.Name, ast.Attribute)):
             tref, donate, jitted = self._resolve(
                 bctx, elt, elt, frozenset(), False)
@@ -251,18 +263,34 @@ class Project(object):
         in between."""
         if not isinstance(call, ast.Call):
             return None
+        return self._call_target(ctx, call.func, call)
+
+    def _call_target(self, ctx, func, call):
+        """CallTarget of calling the expression ``func`` at ``call``."""
+        # the pick between the two forms of one cached program,
+        # ``(jitted if eager else raw)(x)``: both arms run one body,
+        # so the call reaches it whichever is taken; arms that reach
+        # different defs stay unresolved
+        if isinstance(func, ast.IfExp):
+            a = self._call_target(ctx, func.body, call)
+            b = self._call_target(ctx, func.orelse, call)
+            if a is None or b is None or a.ref is None or \
+                    b.ref is None or a.ref.node is not b.ref.node:
+                return None
+            return CallTarget(a.ref, a.donate & b.donate,
+                              a.jitted and b.jitted)
         # immediate form: jax.jit(f, donate_argnums=..)(x)
-        if isinstance(call.func, ast.Call):
-            unwrapped = self._unwrap(ctx, call.func)
+        if isinstance(func, ast.Call):
+            unwrapped = self._unwrap(ctx, func)
             if unwrapped is not None:
                 target, donate, jitted = unwrapped
                 ref = self._ref_of(ctx, target, call)
                 return CallTarget(ref, donate, jitted)
         ref, donate, jitted = self._resolve(
-            ctx, call.func, call, frozenset(), False)
+            ctx, func, call, frozenset(), False)
         if ref is None and donate == frozenset() and not jitted:
             # dotted / unique-tail fallback
-            ref = self._dotted_ref(ctx, call.func)
+            ref = self._dotted_ref(ctx, func)
             if ref is None:
                 return None
             return CallTarget(ref, frozenset(), False)

@@ -29,6 +29,7 @@ from .window import window_weights, window_weights_grad, window_support
 # execution — they document which kernel got traced at what size, not
 # how often it ran (see diagnostics/metrics.py)
 from ..diagnostics import counter, gauge, install_compile_telemetry
+from ..parallel.runtime import vary_like
 
 # the paint kernels compile inside their enclosing jit: the *.trace.*
 # counters below count traces, the xla.compile.* histograms this hook
@@ -152,7 +153,10 @@ def paint_local(pos, mass, shape, resampler='cic', period=None, origin=0,
 
         def loop(i, flat):
             return body(pos_p[i], mass_p[i], flat)
-        flat = jax.lax.fori_loop(0, nchunks, loop, flat)
+        # inside a slab mesh's shard_map the carry starts replicated
+        # and takes device-local deposits
+        flat = jax.lax.fori_loop(0, nchunks, loop,
+                                 vary_like(flat, pos_p, mass_p))
 
     return flat.reshape(shape)
 

@@ -65,7 +65,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from .runtime import AXIS, AXIS_X, AXIS_Y, default_pencil_factor, \
-    is_pencil, mesh_size, pencil_mesh
+    is_eager, is_pencil, mesh_size, pencil_mesh
 from ..diagnostics import counter, current_tracer, histogram, \
     install_compile_telemetry, instrumented_jit, scope, span, span_if
 
@@ -282,9 +282,9 @@ def _a2a_site(y, axis_name, split_axis, concat_axis, nsplit, mode,
     data-dependent scale, priced in-graph so the budget is honest).
     The guarded program emits the SAME single all_to_all plus two
     psums, identically on every rank."""
-    # always a shard_map body: the scope names the collective and its
-    # guard folds ``nbk.fft.a2a.<axis>``, in the HLO op names of a
-    # staged program and on the host line of an eager shard_map
+    # always a shard_map body inside a program: the scope names the
+    # collective and its guard folds ``nbk.fft.a2a.<axis>`` in the HLO
+    # op names
     with scope('fft.a2a.%s' % axis_name):
         # ``check``/``bits`` are host-static (checks_enabled() and the
         # consumed fault rule, identical on every rank), so the arms pick
@@ -924,7 +924,7 @@ def _pencil_run(x, mesh, norm, kind, Nz_out=None):
     px, py = _pencil_shape(mesh)
     target = _fft_chunk_bytes(x.shape, x.dtype, mesh_shape=(px, py)) \
         or 2 ** 31
-    eager = not isinstance(x, jax.core.Tracer)
+    eager = is_eager(x)
     # integrity posture + chaos injection resolve at dispatch: each
     # stage's a2a is one 'a2a.payload' injection consult, and guard
     # comparison is eager-only (a data-dependent raise cannot live
@@ -989,6 +989,86 @@ def _pencil_dispatch(x, mesh, kind, run, fallback):
         return run()
     counter('fft.pencil.fallback').add(1)
     return fallback(_pencil_fallback_mesh(mesh, N0, N1))
+
+
+@_lru_cache(maxsize=32)
+def _slab_programs(mesh, norm, kind, n_out=None, a2a='none', check=False,
+                   bits=0):
+    """One slab transform as one program, cached per (mesh, norm, kind,
+    a2a wire format, integrity posture); shapes and dtypes key the
+    jit's own cache.  ``check`` threads the tier-0 a2a guard folds
+    through (the program then returns ``(out, stats)``); ``bits`` is a
+    transient corruption injection for the chaos matrix (cache-keyed,
+    so the clean program is never perturbed), as in
+    :func:`_pencil_programs`.
+
+    ``kind`` is 'r2c', 'c2r', 'c2c' or 'ic2c'.  Returns ``(raw, jit)``:
+    the raw shard_map callable (composable under an outer trace) and
+    its jitted form for the eager path, where an eager ``shard_map``
+    would run the body one primitive a program and trace, lower and
+    look each up again on every call."""
+    nproc = mesh_size(mesh)
+    if kind in ('r2c', 'c2c'):
+        def passes(v):
+            if kind == 'r2c':
+                y = jnp.fft.rfft(_fft_operand(v), axis=2, norm=norm)
+            else:
+                y = jnp.fft.fft(v, axis=2, norm=norm)
+            y = jnp.fft.fft(y, axis=1, norm=norm)
+            # (N0/P, N1, Nc) -> (N0, N1/P, Nc)
+            y, st = _a2a_site(y, AXIS, 1, 0, nproc, a2a, (AXIS,),
+                              check, bits)
+            y = jnp.fft.fft(y, axis=0, norm=norm)
+            return jnp.transpose(y, (1, 0, 2)), st
+    else:
+        def passes(v):
+            # (N1/P, N0, Nc) -> (N0, N1/P, Nc)
+            z = jnp.transpose(v, (1, 0, 2))
+            z = jnp.fft.ifft(z, axis=0, norm=norm)
+            # (N0, N1/P, Nc) -> (N0/P, N1, Nc)
+            z, st = _a2a_site(z, AXIS, 0, 1, nproc, a2a, (AXIS,),
+                              check, bits)
+            z = jnp.fft.ifft(z, axis=1, norm=norm)
+            if kind == 'c2r':
+                z = jnp.fft.irfft(z, n=n_out, axis=2, norm=norm)
+            else:
+                z = jnp.fft.ifft(z, axis=2, norm=norm)
+            return z, st
+
+    def local(v):
+        # the layer's name on the passes' own op names: an instruction
+        # with none takes its users', and the passes before the
+        # all_to_all would read as ``nbk.fft.a2a.<axis>``
+        with scope('fft.%s' % ('c2c' if kind == 'ic2c' else kind)):
+            out, st = passes(v)
+        return (out, st) if check else out
+
+    local.__name__ = 'slab_fft_%s' % kind     # the program's name
+    raw = jax.shard_map(
+        local, mesh=mesh, in_specs=P(AXIS, None, None),
+        out_specs=(P(AXIS, None, None), P(None)) if check
+        else P(AXIS, None, None))
+    return raw, instrumented_jit(raw, label='fft.slab.%s' % kind)
+
+
+def _slab_run(x, mesh, norm, kind, n_out=None):
+    """Run one slab transform: eagerly the cached jitted program of
+    :func:`_slab_programs`, under an outer trace its raw shard_map.
+    Integrity posture and chaos injection resolve here, at dispatch,
+    and are eager-only (a data-dependent raise cannot live under
+    trace), as in :func:`_pencil_run`."""
+    eager = is_eager(x)
+    a2a = _a2a_mode(x.shape, x.dtype)
+    bits = _corrupt_bits() if eager else 0
+    chk = eager and _integrity_on()
+    raw, jitted = _slab_programs(
+        mesh, norm, kind, None if n_out is None else int(n_out), a2a,
+        chk, bits)
+    res = (jitted if eager else raw)(x)
+    if chk:
+        res, st = res
+        _a2a_verify('a2a.slab.%s' % kind, st, a2a, int(x.size))
+    return res
 
 
 def dist_rfftn(x, mesh=None, norm=None):
@@ -1067,30 +1147,7 @@ def _dist_rfftn_impl(x, mesh, norm):
     if N0 % nproc or N1 % nproc:
         raise ValueError("Nmesh[0] and Nmesh[1] must be divisible by the "
                          "device count %d, got %s" % (nproc, (N0, N1, N2)))
-    a2a = _a2a_mode(x.shape, x.dtype)
-    eager = not isinstance(x, jax.core.Tracer)
-    bits = _corrupt_bits() if eager else 0
-    chk = eager and _integrity_on()
-
-    def local(xl):
-        y = jnp.fft.rfft(_fft_operand(xl), axis=2, norm=norm)
-        y = jnp.fft.fft(y, axis=1, norm=norm)
-        # (N0/P, N1, Nc) -> (N0, N1/P, Nc)
-        y, st = _a2a_site(y, AXIS, 1, 0, nproc, a2a, (AXIS,), chk,
-                          bits)
-        y = jnp.fft.fft(y, axis=0, norm=norm)
-        out = jnp.transpose(y, (1, 0, 2))
-        return (out, st) if chk else out
-
-    res = jax.shard_map(
-        local, mesh=mesh,
-        in_specs=P(AXIS, None, None),
-        out_specs=(P(AXIS, None, None), P(None)) if chk
-        else P(AXIS, None, None))(x)
-    if chk:
-        res, st = res
-        _a2a_verify('a2a.slab.r2c', st, a2a, int(N0 * N1 * N2))
-    return res
+    return _slab_run(x, mesh, norm, 'r2c')
 
 
 def dist_irfftn(y, Nmesh2, mesh=None, norm=None):
@@ -1132,31 +1189,7 @@ def _dist_irfftn_impl(y, Nmesh2, mesh, norm):
         yt = jnp.transpose(y, (1, 0, 2))
         return jnp.fft.irfftn(yt, s=(yt.shape[0], yt.shape[1], Nmesh2), norm=norm)
 
-    a2a = _a2a_mode(y.shape, y.dtype)
-    eager = not isinstance(y, jax.core.Tracer)
-    bits = _corrupt_bits() if eager else 0
-    chk = eager and _integrity_on()
-
-    def local(yl):
-        # (N1/P, N0, Nc) -> (N0, N1/P, Nc)
-        z = jnp.transpose(yl, (1, 0, 2))
-        z = jnp.fft.ifft(z, axis=0, norm=norm)
-        # (N0, N1/P, Nc) -> (N0/P, N1, Nc)
-        z, st = _a2a_site(z, AXIS, 0, 1, nproc, a2a, (AXIS,), chk,
-                          bits)
-        z = jnp.fft.ifft(z, axis=1, norm=norm)
-        out = jnp.fft.irfft(z, n=Nmesh2, axis=2, norm=norm)
-        return (out, st) if chk else out
-
-    res = jax.shard_map(
-        local, mesh=mesh,
-        in_specs=P(AXIS, None, None),
-        out_specs=(P(AXIS, None, None), P(None)) if chk
-        else P(AXIS, None, None))(y)
-    if chk:
-        res, st = res
-        _a2a_verify('a2a.slab.c2r', st, a2a, int(y.size))
-    return res
+    return _slab_run(y, mesh, norm, 'c2r', n_out=Nmesh2)
 
 
 def _fftn_c2c_single_chunked(x, inverse, norm, target):
@@ -1232,7 +1265,6 @@ def dist_fftn_c2c(x, mesh=None, inverse=False, norm=None):
 
 def _dist_fftn_c2c_impl(x, mesh, inverse, norm):
     nproc = mesh_size(mesh)
-    fft = jnp.fft.ifft if inverse else jnp.fft.fft
     if is_pencil(mesh) and nproc > 1:
         kind = 'ic2c' if inverse else 'c2c'
         return _pencil_dispatch(
@@ -1257,39 +1289,7 @@ def _dist_fftn_c2c_impl(x, mesh, inverse, norm):
             return jnp.fft.ifftn(y, norm=norm)
         return jnp.transpose(jnp.fft.fftn(x, norm=norm), (1, 0, 2))
 
-    a2a = _a2a_mode(x.shape, x.dtype)
-    eager = not isinstance(x, jax.core.Tracer)
-    bits = _corrupt_bits() if eager else 0
-    chk = eager and _integrity_on()
-    if not inverse:
-        def local(xl):
-            y = fft(xl, axis=2, norm=norm)
-            y = fft(y, axis=1, norm=norm)
-            y, st = _a2a_site(y, AXIS, 1, 0, nproc, a2a, (AXIS,),
-                              chk, bits)
-            y = fft(y, axis=0, norm=norm)
-            out = jnp.transpose(y, (1, 0, 2))
-            return (out, st) if chk else out
-    else:
-        def local(yl):
-            z = jnp.transpose(yl, (1, 0, 2))
-            z = fft(z, axis=0, norm=norm)
-            z, st = _a2a_site(z, AXIS, 0, 1, nproc, a2a, (AXIS,),
-                              chk, bits)
-            z = fft(z, axis=1, norm=norm)
-            out = fft(z, axis=2, norm=norm)
-            return (out, st) if chk else out
-
-    res = jax.shard_map(
-        local, mesh=mesh,
-        in_specs=P(AXIS, None, None),
-        out_specs=(P(AXIS, None, None), P(None)) if chk
-        else P(AXIS, None, None))(x)
-    if chk:
-        res, st = res
-        _a2a_verify('a2a.slab.%s' % ('ic2c' if inverse else 'c2c'),
-                    st, a2a, int(x.size))
-    return res
+    return _slab_run(x, mesh, norm, 'ic2c' if inverse else 'c2c')
 
 
 def _parse_pencil(v):

@@ -24,6 +24,19 @@ returned so they can detect overflow outside jit and retry larger — the
 same contract as the reference's paint-chunk backoff loop
 (source/mesh/catalog.py:275-315).
 
+How the eager call runs: the count is one small program
+(``compile.exchange.count``) whose result is read back as a Python
+int, because the capacity is a static shape of what follows; the pad,
+the bucketing, the all_to_alls and the ``psum`` of ``dropped`` are one
+jitted program fetched from :func:`_exchange_programs`, cached on
+(device mesh, capacity, fill, payload ranks, backend branch), so
+catalogs on one rung share one executable and a call traces nothing
+(``compile.exchange.hits``).  An eager ``shard_map`` would run the
+same body one primitive a program, each traced, lowered and looked up
+in the compile cache on every call.  The ``exchange`` span, gauges and
+counters fire in :func:`exchange_by_dest`, once a call.  A traced
+caller gets the raw ``shard_map``, which composes into its program.
+
 For LARGE traced pipelines use the two-pass counted exchange: run
 :func:`counted_capacity` eagerly (pass 1 — a tiny count program), then
 hand its result to the traced exchange as the static capacity (pass 2)
@@ -33,13 +46,15 @@ N=1e9 that is ~16 GB and cannot sit next to a 2048^3 mesh
 (pmesh.memory_plan models both).
 """
 
+from functools import lru_cache as _lru_cache, partial
+
 import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from .runtime import AXIS, mesh_size
-from ..diagnostics import counter, gauge, scope
+from .runtime import AXIS, is_eager, mesh_size
+from ..diagnostics import counter, gauge, instrumented_jit, scope
 
 
 def counted_capacity(pm_or_nproc, pos_or_dest, slack=1.05, n0=None):
@@ -103,13 +118,18 @@ def pair_count_max(dest, nproc):
     """Largest particle count over the (src, dst) pairs, assuming
     particles are evenly sharded over devices in index order (the
     layout of a freshly created global array, matching the padding in
-    :func:`exchange_by_dest`). Eager only."""
-    n = int(dest.shape[0])
+    :func:`exchange_by_dest`). Eager only: one small program and the
+    read of its result."""
+    return int(_pair_count_max(jnp.asarray(dest, jnp.int32), nproc))
+
+
+@partial(instrumented_jit, label='exchange.count', static_argnums=(1,))
+def _pair_count_max(dest, nproc):
+    n = dest.shape[0]
     per = -(-n // nproc)  # ceil: matches the even sharding of the pad
     src = jnp.arange(n, dtype=jnp.int32) // per
-    pair = src * nproc + jnp.asarray(dest, jnp.int32)
-    counts = jnp.bincount(pair, length=nproc * nproc)
-    return int(counts.max())
+    counts = jnp.bincount(src * nproc + dest, length=nproc * nproc)
+    return counts.max()
 
 
 def ladder_capacity(exact, n, nproc, slack=1.05):
@@ -232,16 +252,6 @@ def exchange_by_dest(dest, arrays, mesh, capacity=None, fill=0.0):
     if nproc == 1:
         return list(arrays), jnp.ones(n, dtype=bool), jnp.zeros((), jnp.int32)
 
-    # pad the particle axis to a multiple of P; padding goes to dest 0
-    # with live=False and is masked out on arrival
-    live = jnp.ones(n, dtype=bool)
-    npad = (-n) % nproc
-    if npad:
-        dest = jnp.concatenate([dest, jnp.zeros(npad, dest.dtype)])
-        live = jnp.concatenate([live, jnp.zeros(npad, bool)])
-        arrays = [jnp.concatenate(
-            [a, jnp.zeros((npad,) + a.shape[1:], a.dtype)]) for a in arrays]
-
     exact = None        # the counted maximum, where this call counted
     if capacity is None:
         if isinstance(dest, jax.core.Tracer):
@@ -249,26 +259,60 @@ def exchange_by_dest(dest, arrays, mesh, capacity=None, fill=0.0):
             # sufficient bound (one source sends its whole shard to one
             # destination). Memory = P*cap = n slots per device; callers
             # wanting tighter buffers pass capacity explicitly.
-            capacity = -(-dest.shape[0] // nproc)
+            capacity = -(-n // nproc)
         else:
-            exact, capacity = counted_rung(dest, nproc)  # after padding
+            # counted after padding, as the program below buckets
+            exact, capacity = counted_rung(
+                _pad_rows(dest, (-n) % nproc), nproc)
+    capacity = int(capacity)
 
-    payloads = [live] + list(arrays)
+    arrays = list(arrays)
 
     # telemetry: the all_to_all buffer volume is shape-derived (static),
     # so the counters are exact even when this runs under a trace —
     # bytes_sent == bytes_received is the global (P, P, capacity)
-    # buffer footprint actually shipped, the number the counted
-    # exchange exists to shrink (~N/P^2 vs the ceil(N/P) bound)
-    xbytes = int(sum(
-        nproc * nproc * int(capacity)
-        * int(np.prod(a.shape[1:], dtype=np.int64))
-        * jnp.dtype(a.dtype).itemsize for a in payloads))
+    # buffer footprint actually shipped (the payloads and the one-byte
+    # live mask), the number the counted exchange exists to shrink
+    # (~N/P^2 vs the ceil(N/P) bound).  Counters, gauges and the span
+    # fire here, once a call; the program is traced once a shape.
+    xbytes = nproc * nproc * capacity * (1 + int(sum(
+        int(np.prod(a.shape[1:], dtype=np.int64))
+        * jnp.dtype(a.dtype).itemsize for a in arrays)))
     counter('exchange.calls').add(1)
     counter('exchange.bytes_sent').add(xbytes)
-    gauge('exchange.capacity').set(int(capacity))
-    filled = n / float(nproc * nproc * int(capacity))
+    gauge('exchange.capacity').set(capacity)
+    filled = n / float(nproc * nproc * capacity)
     gauge('exchange.fill').set(filled)
+
+    from ..utils import is_mxu_backend
+    raw, jitted = _exchange_programs(
+        mesh, capacity, fill, tuple(a.ndim for a in arrays),
+        is_mxu_backend())
+    eager = is_eager(dest, *arrays)
+    with scope('exchange', nproc=nproc, capacity=capacity,
+               capacity_exact=exact, fill=filled, bytes=xbytes,
+               npart=int(n)):
+        return (jitted if eager else raw)(dest, *arrays)
+
+
+def _pad_rows(a, npad):
+    """``a`` with ``npad`` rows of zeros appended."""
+    if not npad:
+        return a
+    return jnp.concatenate([a, jnp.zeros((npad,) + a.shape[1:], a.dtype)])
+
+
+@_lru_cache(maxsize=64)
+def _exchange_programs(mesh, capacity, fill, ndims, mxu):
+    """The exchange of payloads of ranks ``ndims`` at one static
+    ``capacity`` as one program: the pad to a multiple of P, the
+    bucketing, the all_to_alls, the ``psum`` of ``dropped`` and the
+    live mask, cached per everything the body reads (``mxu``: the
+    bucketing's backend branch; shapes and dtypes key the jit's own
+    cache).  Returns ``(raw, jit)`` as ``dfft._slab_programs`` does:
+    the raw callable for an outer trace, the jitted form for the eager
+    call."""
+    nproc = mesh_size(mesh)
 
     def local(dest_l, *payloads_l):
         # payloads_l[0] is the live mask: pad entries that overflow a
@@ -286,15 +330,20 @@ def exchange_by_dest(dest, arrays, mesh, capacity=None, fill=0.0):
         dropped = jax.lax.psum(dropped, AXIS)
         return (v.reshape(-1), dropped) + tuple(outs)
 
-    in_specs = (P(AXIS),) + tuple(
-        P(*((AXIS,) + (None,) * (a.ndim - 1))) for a in payloads)
-    out_specs = (P(AXIS), P()) + tuple(
-        P(*((AXIS,) + (None,) * (a.ndim - 1))) for a in payloads)
-    with scope('exchange', nproc=nproc, capacity=int(capacity),
-               capacity_exact=exact, fill=filled, bytes=xbytes,
-               npart=int(n)):
-        res = jax.shard_map(local, mesh=mesh, in_specs=in_specs,
-                            out_specs=out_specs)(dest, *payloads)
-    slot_valid, dropped, live_recv = res[0], res[1], res[2]
-    valid = slot_valid & live_recv
-    return list(res[3:]), valid, dropped
+    specs = (P(AXIS),) + tuple(
+        P(*((AXIS,) + (None,) * (nd - 1))) for nd in ndims)
+    sharded = jax.shard_map(local, mesh=mesh, in_specs=(P(AXIS),) + specs,
+                            out_specs=(P(AXIS), P()) + specs)
+
+    def exchange(dest, *arrays):
+        # pad the particle axis to a multiple of P; padding goes to
+        # dest 0 with live=False and is masked out on arrival
+        n = dest.shape[0]
+        npad = (-n) % nproc
+        live = _pad_rows(jnp.ones(n, dtype=bool), npad)
+        res = sharded(_pad_rows(dest, npad), live,
+                      *[_pad_rows(a, npad) for a in arrays])
+        slot_valid, dropped, live_recv = res[0], res[1], res[2]
+        return list(res[3:]), slot_valid & live_recv, dropped
+
+    return exchange, instrumented_jit(exchange, label='exchange')

@@ -312,6 +312,15 @@ def vary_like(x, *like):
     return jax.lax.pcast(x, need, to='varying') if need else x
 
 
+def is_eager(*operands):
+    """True where none of ``operands`` is being traced.  The one test
+    by which a caller picks between the two forms of a cached program
+    pair ``(raw, jitted)``: ``(jitted if is_eager(x) else raw)(x)``,
+    the jitted form eagerly, the raw ``shard_map`` under an outer
+    trace, where it composes into the caller's program."""
+    return not any(isinstance(a, jax.core.Tracer) for a in operands)
+
+
 def mesh_size(mesh):
     """Total number of devices in the mesh (1 when mesh is None).
 

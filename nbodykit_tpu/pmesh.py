@@ -24,6 +24,7 @@ wrappers in :mod:`nbodykit_tpu.base.mesh` add attrs/convenience methods.
 
 import logging
 import time
+from functools import lru_cache as _lru_cache
 
 import numpy as np
 import jax
@@ -32,9 +33,10 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from . import _global_options
 from .diagnostics import counter, current_tracer, histogram, \
-    install_compile_telemetry, scope, span, \
+    install_compile_telemetry, instrumented_jit, scope, span, \
     trace_state_clean
-from .parallel.runtime import AXIS, CurrentMesh, mesh_size, shard_leading
+from .parallel.runtime import AXIS, CurrentMesh, is_eager, mesh_size, \
+    shard_leading
 from .parallel import dfft
 from .parallel.halo import halo_add, halo_fill
 from .parallel.exchange import exchange_by_dest
@@ -52,6 +54,115 @@ def _triplet(x, dtype):
     a = np.empty(3, dtype=dtype)
     a[:] = x
     return a
+
+
+def _paint_kernel(method, chunk, order, deposit, streams, storage_dtype,
+                  mxu_slack):
+    """The local paint kernel of one resolved configuration.  All
+    kernels return (block, overflow); only mxu can actually overflow
+    (bucket capacity)."""
+    if method == 'sort':
+        def kern(*a, **kw):
+            return (paint_local_sorted(*a, **kw),
+                    jnp.zeros((), jnp.int32))
+    elif method == 'segsum':
+        def kern(*a, **kw):
+            return (paint_local_segsum(*a, order_method=order, **kw),
+                    jnp.zeros((), jnp.int32))
+    elif method == 'streams':
+        def kern(*a, **kw):
+            return (paint_local_streams(*a, streams=streams,
+                                        chunk=chunk,
+                                        storage_dtype=storage_dtype,
+                                        **kw),
+                    jnp.zeros((), jnp.int32))
+    elif method == 'mxu':
+        def kern(*a, **kw):
+            return paint_local_mxu(*a, slack=mxu_slack,
+                                   return_overflow=True,
+                                   order_method=order,
+                                   deposit=deposit, **kw)
+    else:
+        def kern(*a, **kw):
+            return (paint_local(*a, chunk=chunk, **kw),
+                    jnp.zeros((), jnp.int32))
+    return kern
+
+
+@_lru_cache(maxsize=32)
+def _slab_paint_programs(mesh, shape_real, resampler, method, chunk,
+                         order, deposit, streams, storage_dtype,
+                         compute_dtype, mxu_slack, mxu):
+    """The paint of exchanged particles onto a slab mesh as one
+    program: the mask of empty slots, the local kernel into the
+    halo-extended slab, ``halo_add`` and the ``psum`` of the kernel's
+    overflow count.  Cached per everything the body reads: the device
+    mesh, the field's shape, the window, the resolved kernel
+    configuration (``mxu``: the backend branch of its orderings) and
+    the dtypes; the particle count and so the exchange capacity key
+    the jit's own cache.  Returns ``(raw, jit)`` as
+    ``dfft._slab_programs`` does: the raw callable for an outer trace,
+    the jitted form for the eager call."""
+    nproc = mesh_size(mesh)
+    N0, N1, N2 = shape_real
+    n0 = N0 // nproc
+    h = window_support(resampler)
+    kernel = _paint_kernel(method, chunk, order, deposit, streams,
+                           storage_dtype, mxu_slack)
+
+    def local(cpos_l, mass_l):
+        d = jax.lax.axis_index(AXIS)
+        origin = d * n0 - h
+        ext, over = kernel(cpos_l, mass_l, (n0 + 2 * h, N1, N2),
+                           resampler=resampler, period=(N0, N1, N2),
+                           origin=origin)
+        return halo_add(ext, h, nproc), jax.lax.psum(over, AXIS)
+
+    sharded = jax.shard_map(local, mesh=mesh,
+                            in_specs=(P(AXIS, None), P(AXIS)),
+                            out_specs=(P(AXIS, None, None), P()))
+
+    def paint_slab(cpos_r, mass_r, valid):
+        mass_r = jnp.where(valid, mass_r, 0.0).astype(compute_dtype)
+        return sharded(cpos_r, mass_r)
+
+    return paint_slab, instrumented_jit(paint_slab, label='paint.slab')
+
+
+def _cell_units(pos, nmesh, boxsize):
+    """Positions in units of the mesh's cells."""
+    return pos * jnp.asarray(np.divide(nmesh, boxsize), pos.dtype)
+
+
+def _slab_owner(cpos, N0, nproc):
+    """Slab owner per particle (cpos in cell units, shift already
+    applied) — THE routing rule, shared by paint/readout and the
+    counted-capacity pass so they cannot drift apart."""
+    n0 = N0 // nproc
+    cell = jnp.mod(jnp.floor(cpos[:, 0]).astype(jnp.int32), N0)
+    return cell // n0
+
+
+def paint_route(pos, mass, shift, nmesh, boxsize, nproc, compute_dtype):
+    """Where a paint's particles go: positions in cell units on the
+    (shifted) grid, the weights in compute dtype and, on a slab mesh,
+    each particle's slab owner.  A pure function of what it is given:
+    one device and a traced caller call it as it is, the eager slab
+    paint as one program (:data:`_route_jit`)."""
+    cpos = _cell_units(pos, nmesh, boxsize) - shift
+    # weights are COMPUTE dtype: with bf16 storage the deposit terms
+    # stay f32 and only the mesh buffers narrow (the streams kernel's
+    # replica meshes, via storage_dtype, plus the final field cast at
+    # the exit)
+    massa = jnp.broadcast_to(
+        jnp.asarray(mass, compute_dtype), (pos.shape[0],))
+    dest = None if nproc == 1 else _slab_owner(cpos, nmesh[0], nproc)
+    return cpos, massa, dest
+
+
+_route_jit = instrumented_jit(
+    paint_route, label='paint.route',
+    static_argnames=('nmesh', 'boxsize', 'nproc', 'compute_dtype'))
 
 
 class ParticleMesh(object):
@@ -230,8 +341,8 @@ class ParticleMesh(object):
     # -- paint / readout --------------------------------------------------
 
     def _to_cell_units(self, pos):
-        scale = jnp.asarray(self.Nmesh / self.BoxSize, pos.dtype)
-        return pos * scale
+        return _cell_units(pos, self.shape_real,
+                           tuple(float(b) for b in self.BoxSize))
 
     def _check_halo(self, h):
         """Validate halo width against the per-device slab height; the
@@ -246,13 +357,8 @@ class ParticleMesh(object):
         return n0
 
     def _route_dest(self, cpos):
-        """Slab owner per particle (cpos in cell units, shift already
-        applied) — THE routing rule, shared by paint/readout and the
-        counted-capacity pass so they cannot drift apart."""
-        N0 = int(self.Nmesh[0])
-        n0 = N0 // self.nproc
-        cell = jnp.mod(jnp.floor(cpos[:, 0]).astype(jnp.int32), N0)
-        return cell // n0
+        """Slab owner per particle: :func:`_slab_owner` on this mesh."""
+        return _slab_owner(cpos, int(self.Nmesh[0]), self.nproc)
 
     def _paint_config(self, npart):
         """The effective paint-kernel configuration for one call:
@@ -369,14 +475,14 @@ class ParticleMesh(object):
         resampler = resampler or _global_options['resampler']
         h = window_support(resampler)
         N0, N1, N2 = self.shape_real
-        cpos = self._to_cell_units(pos) - shift
         npart = pos.shape[0]
-        # weights are COMPUTE dtype: with bf16 storage the deposit
-        # terms stay f32 and only the mesh buffers narrow (the streams
-        # kernel's replica meshes, via storage_dtype below, plus the
-        # final field cast at the exit)
-        massa = jnp.broadcast_to(
-            jnp.asarray(mass, self.compute_dtype), (npart,))
+        route = _route_jit if self.nproc > 1 and \
+            is_eager(pos, mass, shift) else paint_route
+        cpos, massa, dest = route(
+            pos, mass, shift, nmesh=(N0, N1, N2),
+            boxsize=tuple(float(b) for b in self.BoxSize),
+            nproc=self.nproc,
+            compute_dtype=np.dtype(self.compute_dtype))
         # 'auto' options resolve through the tune cache here, at
         # dispatch time (cold cache -> today's defaults, no trials)
         pcfg = self._paint_config(npart)
@@ -416,43 +522,10 @@ class ParticleMesh(object):
                 "paint_method='scatter')")
 
         def make_kernel(mxu_slack):
-            """All kernels return (block, overflow); only mxu can
-            actually overflow (bucket capacity)."""
-            if pm_method == 'sort':
-                def kern(*a, **kw):
-                    return (paint_local_sorted(*a, **kw),
-                            jnp.zeros((), jnp.int32))
-            elif pm_method == 'segsum':
-                order = pcfg['paint_order']
-
-                def kern(*a, **kw):
-                    return (paint_local_segsum(*a, order_method=order,
-                                               **kw),
-                            jnp.zeros((), jnp.int32))
-            elif pm_method == 'streams':
-                nstreams = pcfg['paint_streams']
-                sdt = self.dtype
-
-                def kern(*a, **kw):
-                    return (paint_local_streams(*a, streams=nstreams,
-                                                chunk=chunk,
-                                                storage_dtype=sdt,
-                                                **kw),
-                            jnp.zeros((), jnp.int32))
-            elif pm_method == 'mxu':
-                order = pcfg['paint_order']
-                dep = pcfg['paint_deposit']
-
-                def kern(*a, **kw):
-                    return paint_local_mxu(*a, slack=mxu_slack,
-                                           return_overflow=True,
-                                           order_method=order,
-                                           deposit=dep, **kw)
-            else:
-                def kern(*a, **kw):
-                    return (paint_local(*a, chunk=chunk, **kw),
-                            jnp.zeros((), jnp.int32))
-            return kern
+            return _paint_kernel(pm_method, chunk, pcfg['paint_order'],
+                                 pcfg['paint_deposit'],
+                                 pcfg['paint_streams'], self.dtype,
+                                 mxu_slack)
 
         mxu_slack = _global_options['paint_bucket_slack']
         if self.nproc == 1:
@@ -485,34 +558,29 @@ class ParticleMesh(object):
                 return out, over
             return out
 
-        n0 = self._check_halo(h)
-        dest = self._route_dest(cpos)
+        self._check_halo(h)
         self._check_overflow_contract(capacity, traced, return_dropped)
         nproc = self.nproc
 
-        def make_local(kernel):
-            def local(cpos_l, mass_l):
-                d = jax.lax.axis_index(AXIS)
-                origin = d * n0 - h
-                ext, over = kernel(cpos_l, mass_l,
-                                   (n0 + 2 * h, N1, N2),
-                                   resampler=resampler,
-                                   period=(N0, N1, N2), origin=origin)
-                return halo_add(ext, h, nproc), jax.lax.psum(over, AXIS)
-            return local
-
         def attempt(cap, slack_val=None):
-            kernel = make_kernel(slack_val if slack_val is not None
-                                 else mxu_slack)
             recv, valid, dropped = exchange_by_dest(
                 dest, [cpos, massa], self.comm, cap)
-            cpos_r, mass_r = recv
-            mass_r = jnp.where(valid, mass_r,
-                               0.0).astype(self.compute_dtype)
-            block, over = jax.shard_map(
-                make_local(kernel), mesh=self.comm,
-                in_specs=(P(AXIS, None), P(AXIS)),
-                out_specs=(P(AXIS, None, None), P()))(cpos_r, mass_r)
+            dep = pcfg['paint_deposit']
+            if pm_method == 'mxu' and dep == 'auto':
+                # resolved here, as the kernel would on its device's
+                # slots, so that the program's key holds it
+                from .tune.resolve import resolve_paint_deposit
+                dep = resolve_paint_deposit(
+                    nmesh=N0, npart=int(valid.shape[0]) // nproc)
+            from .utils import is_mxu_backend
+            raw, jitted = _slab_paint_programs(
+                self.comm, (N0, N1, N2), resampler, pm_method, chunk,
+                pcfg['paint_order'], dep, pcfg['paint_streams'],
+                jnp.dtype(self.dtype), jnp.dtype(self.compute_dtype),
+                slack_val if slack_val is not None else mxu_slack,
+                is_mxu_backend())
+            block, over = (jitted if is_eager(*recv, valid) else raw)(
+                *recv, valid)
             return block, dropped, over
 
         block, dropped, over = attempt(capacity)
@@ -533,7 +601,7 @@ class ParticleMesh(object):
                 lost = self._count_dropped(dropped)
             if lost > 0:
                 # NBK103 (baselined, audited): this raise sits between
-                # collective stages, but `dropped` is the
+                # collective programs, but `dropped` is the
                 # globally-summed overflow count — every rank computes
                 # the same value and raises together, so the exception
                 # path is rank-uniform by construction

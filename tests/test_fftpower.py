@@ -351,3 +351,41 @@ def test_fftpower_anisotropic_box_and_mesh():
     valid = np.asarray(r.power['modes']) > 0
     ratio = np.nanmean(p[valid] / r.attrs['shotnoise'])
     assert abs(ratio - 1) < 0.3
+
+
+@pytest.mark.parametrize('ndev', [1, 4])
+@pytest.mark.parametrize('cross', [False, True])
+def test_cross_power_program_equals_its_ops_one_by_one(ndev, cross):
+    # the 3-D power is one program (op by op a free-running host kept
+    # three or four mesh-sized fields of it alive, by its lead): the
+    # same bits and the same dtype as the ops in turn, compiled once
+    import jax
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from nbodykit_tpu.algorithms.fftpower import _cross_power
+    from nbodykit_tpu.diagnostics.metrics import REGISTRY
+    from nbodykit_tpu.parallel.runtime import AXIS
+    rng = np.random.RandomState(5)
+    shape = (16, 16, 9)
+    a = (rng.normal(size=shape) + 1j * rng.normal(size=shape)
+         ).astype('c8')
+    b = (rng.normal(size=shape) + 1j * rng.normal(size=shape)
+         ).astype('c8') if cross else a
+    a, b = jnp.asarray(a), jnp.asarray(b)
+    if ndev > 1:
+        rows = NamedSharding(cpu_mesh(ndev), P(AXIS, None, None))
+        a, b = jax.device_put(a, rows), jax.device_put(b, rows)
+    volume = np.array([100.0, 50.0, 20.0]).prod()
+    want = a * jnp.conj(b)
+    want = want.at[0, 0, 0].set(0.0) * volume
+
+    def counts():
+        snap = REGISTRY.snapshot()
+        return [snap.get('compile.fftpower.p3d.' + k, {}).get('value', 0)
+                for k in ('hits', 'misses')]
+    _cross_power(a, b, volume)
+    before = counts()
+    got = _cross_power(a, b, volume)
+    assert counts() == [before[0] + 1, before[1]]
+    assert got.dtype == want.dtype and got.sharding == a.sharding
+    assert np.asarray(got).tobytes() == np.asarray(want).tobytes()
+    assert got[0, 0, 0] == 0

@@ -12,7 +12,12 @@ four virtual CPU devices, with the TPU branch of every
     climbs the ladder and loses nothing;
 (c) the trace of one four-device call: ``exchange`` spans with
     ``capacity``, ``capacity_exact`` and ``fill``, the ``fft.a2a.*``
-    scopes in the slab r2c's HLO, ``exchange.dropped`` at 0."""
+    scopes in the slab r2c's HLO, ``exchange.dropped`` at 0;
+(d) the exchange, the slab paint and the slab r2c run eagerly as one
+    cached jitted program each (``compile.exchange``, ``.paint.slab``,
+    ``.fft.slab.r2c``): a warm call re-traces none of them, whatever
+    they read is in their key, and their results are those of the
+    traced forms to the bit."""
 
 import numpy as np
 import pytest
@@ -331,18 +336,21 @@ def test_mxu_histogram_rounds_its_hi_part_where_no_pass_can_elide_it():
     assert np.max(np.abs(got / want - 1)) < 2e-6
 
 
-def test_eager_slab_r2c_puts_its_all_to_all_on_the_host_line(
+def test_eager_slab_r2c_is_one_launch_with_its_all_to_all_named_inside(
         tpu_branch, tmp_path):
-    # an eager shard_map launches its body one primitive a program and
-    # those programs' op names carry no name stack (on the chip:
-    # ``jit(<unknown>)/shard_map/all_to_all``), so the benchmark can
-    # name the all_to_all only by the annotation it was launched under
+    # the eager slab r2c is one cached program: on the profiler's host
+    # line ``nbk.fft.r2c`` stands around one launch, and the
+    # all_to_all is named where the benchmark reads a staged program,
+    # in the launched program's own op names (an eager shard_map ran
+    # one primitive a program with no name stack, and only the host
+    # annotation ``nbk.fft.a2a.dev`` could name the all_to_all)
     import glob
     import os
     from jax.profiler import ProfileData
-    from nbodykit_tpu.parallel.dfft import dist_rfftn
+    from nbodykit_tpu.parallel.dfft import _slab_programs, dist_rfftn
     mesh = cpu_mesh(4)
     x = shard_leading(mesh, jnp.ones((16, 16, 16), jnp.float32))
+    jax.block_until_ready(dist_rfftn(x, mesh))      # traced here
     opts = jax.profiler.ProfileOptions()
     opts.python_tracer_level = 0
     jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
@@ -353,13 +361,272 @@ def test_eager_slab_r2c_puts_its_all_to_all_on_the_host_line(
     path, = glob.glob(os.path.join(str(tmp_path), 'plugins', 'profile',
                                    '*', '*.xplane.pb'))
     host = ProfileData.from_file(path).find_plane_with_name('/host:CPU')
-    marks = {}
+    marks, launches = {}, []
     for line in host.lines:
         for ev in line.events:
             if ev.name.startswith('nbk.'):
                 marks.setdefault(ev.name, []).append(
                     (line.name, ev.start_ns, ev.end_ns))
-    assert set(marks) == {'nbk.fft.r2c', 'nbk.fft.a2a.dev'}
+            elif ev.name.endswith('Executable::Execute'):
+                launches.append((line.name, ev.start_ns, ev.end_ns))
+    assert set(marks) == {'nbk.fft.r2c'}
     (line, r0, r1), = marks['nbk.fft.r2c']
-    for other, a0, a1 in marks['nbk.fft.a2a.dev']:
-        assert other == line and r0 <= a0 and a1 <= r1
+    assert [(ln, r0 <= a0 and a1 <= r1) for ln, a0, a1 in launches] \
+        == [(line, True)]
+    hits = _slab_programs.cache_info().hits
+    program = _slab_programs(mesh, None, 'r2c', None, 'none', False, 0)[1]
+    assert _slab_programs.cache_info().hits == hits + 1   # the call's own
+    text = program._jitted.lower(x).as_text(debug_info=True)
+    # the passes name their layer themselves: a nameless instruction
+    # takes its users' scopes, the all_to_all's for the first two passes
+    assert 'nbk.fft.r2c' in text and 'nbk.fft.a2a.dev' in text
+
+
+# ---------------------------------------------------------------------------
+# (d) the three shard_map sites as cached programs
+
+SITES = ('exchange', 'paint.slab', 'fft.slab.r2c')
+#: every cached program of a lab call: the sites' and the 3-D power
+PROGRAMS = SITES + ('fftpower.p3d',)
+
+
+def program_counts():
+    """The process's compile-cache requests and each site's
+    ``(hits, misses)``."""
+    snap = REGISTRY.snapshot()
+
+    def value(name):
+        return snap.get(name, {}).get('value', 0)
+    out = {s: (value('compile.%s.hits' % s),
+               value('compile.%s.misses' % s)) for s in PROGRAMS}
+    out['requests'] = value('xla.cache.requests')
+    return out
+
+
+def counts_added(before):
+    after = program_counts()
+    out = {s: (after[s][0] - before[s][0], after[s][1] - before[s][1])
+           for s in PROGRAMS}
+    out['requests'] = after['requests'] - before['requests']
+    return out
+
+
+@pytest.fixture(scope='module')
+def warm_calls():
+    """What the second and third ``FFTPower(mode='2d')`` calls on four
+    devices, each on a new seed of one N, add to the counters."""
+    added = []
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(nbodykit_tpu.utils, 'is_mxu_backend', lambda: True)
+        with jax.enable_x64(False):
+            for seed in (11, 12, 13):
+                pos = np.random.RandomState(seed).uniform(
+                    0, BOX, (50000, 3)).astype('f4')
+                before = program_counts()
+                fftpower_2d(pos, 32, 4, cpu_mesh(4))
+                added.append(counts_added(before))
+    return added[1:]
+
+
+def test_warm_four_device_call_asks_the_compile_cache_almost_nothing(
+        warm_calls):
+    # 549 a call while the three sites were eager shard_maps, one
+    # primitive a program and each traced, lowered and looked up again;
+    # what is left is the binning's re-trace
+    for added in warm_calls:
+        assert added['requests'] <= 8
+
+
+@pytest.mark.parametrize('site', PROGRAMS)
+def test_warm_four_device_call_reuses_the_sites_program(warm_calls, site):
+    for added in warm_calls:
+        assert added[site] == (1, 0)
+
+
+def uniform_positions(npart, seed, box=32.0):
+    return np.random.RandomState(seed).uniform(
+        0, box, (npart, 3)).astype('f4')
+
+
+def paint_then_r2c(pm, pos, **kw):
+    from nbodykit_tpu.parallel.dfft import dist_rfftn
+    before = program_counts()
+    field = pm.paint(jnp.asarray(pos), 1.0, resampler='cic', **kw)
+    spectrum = dist_rfftn(field, pm.comm)
+    return (np.asarray(field), np.asarray(spectrum),
+            counts_added(before))
+
+
+#: what changes between two calls -> the programs it costs one miss
+KEYED = {
+    'capacity': ({}, {'capacity': 5000}, {'exchange', 'paint.slab'}),
+    'paint_chunk_size': ({'paint_chunk_size': 4096}, {}, {'paint.slab'}),
+    'integrity': ({'integrity': 'cheap'}, {}, {'fft.slab.r2c'}),
+    'a2a.payload': ({'faults': 'a2a.payload@1:corrupt'}, {},
+                    {'fft.slab.r2c'}),
+    # flipped on the painted block, after the program
+    'paint.accum': ({'faults': 'paint.accum@1:corrupt'}, {}, set()),
+}
+
+
+@pytest.mark.parametrize('what', sorted(KEYED))
+def test_what_a_site_reads_is_in_its_programs_key(tpu_branch, what):
+    from nbodykit_tpu.resilience.faults import reset_faults
+    options, paint_kw, missed = KEYED[what]
+    pm = ParticleMesh(32, 32.0, dtype='f4', comm=cpu_mesh(4))
+    pos = uniform_positions(60000, 21)
+    paint_then_r2c(pm, pos)
+    field, spectrum, added = paint_then_r2c(pm, pos)
+    assert all(added[s] == (1, 0) for s in SITES)
+    with set_options(**options):
+        reset_faults()
+        changed = paint_then_r2c(pm, pos, **paint_kw)
+        again = paint_then_r2c(pm, pos, **paint_kw)
+    # one miss of each program that reads the change, none of the rest
+    assert {s for s in SITES if changed[2][s] == (0, 1)} == missed
+    assert all(changed[2][s] == (1, 0) for s in SITES if s not in missed)
+    # compiled once: the same call again hits (a fault's rule fires
+    # once, so ``again`` is the clean call)
+    assert all(again[2][s] == (1, 0) for s in SITES)
+    if 'faults' in options:
+        assert not (np.array_equal(changed[0], field)
+                    and np.array_equal(changed[1], spectrum))
+        assert np.array_equal(again[0], field)
+        assert np.array_equal(again[1], spectrum)
+    # and the clean programs were never perturbed
+    clean = paint_then_r2c(pm, pos)
+    assert all(clean[2][s] == (1, 0) for s in SITES)
+    assert np.array_equal(clean[0], field)
+    assert np.array_equal(clean[1], spectrum)
+
+
+def traced_paint(pm, pos, capacity, **kw):
+    field, dropped = jax.jit(lambda p: pm.paint(
+        p, 1.0, capacity=capacity, return_dropped=True, **kw))(pos)
+    assert int(dropped) == 0
+    return np.asarray(field)
+
+
+@pytest.mark.parametrize('case', ['cic', 'tsc-shifted', 'unbalanced',
+                                  'capacity-retry'])
+def test_eager_paint_equals_the_traced_paint_to_the_bit(
+        tpu_branch, clean_registry, case):
+    npart = 40001                   # not a multiple of four
+    kw = {'resampler': 'cic'}
+    pos = uniform_positions(npart, 31)
+    if case == 'tsc-shifted':
+        kw = {'resampler': 'tsc', 'shift': 0.5}
+    elif case != 'cic':
+        pos = half_in_one_slab(npart, 32.0, 32)
+    pos = jnp.asarray(pos)
+    pm4 = ParticleMesh(32, 32.0, dtype='f4', comm=cpu_mesh(4))
+    pm1 = ParticleMesh(32, 32.0, dtype='f4', comm=cpu_mesh(1))
+    rung = pm4.exchange_capacity(pos, shift=kw.get('shift', 0.0))
+    uniform = pm4.exchange_capacity(jnp.asarray(uniform_positions(npart, 31)))
+    if case == 'capacity-retry':
+        # too small by half: the eager call doubles it once
+        eager = np.asarray(pm4.paint(pos, 1.0, capacity=rung // 2, **kw))
+        assert REGISTRY.snapshot()['exchange.dropped']['value'] > 0
+        capacity = 2 * (rung // 2)
+    else:
+        eager = np.asarray(pm4.paint(pos, 1.0, **kw))
+        assert REGISTRY.snapshot()['exchange.dropped']['value'] == 0
+        capacity = rung
+    # half the particles in one slab climb the ladder
+    assert (rung > uniform) == (case in ('unbalanced', 'capacity-retry'))
+    assert np.array_equal(eager, traced_paint(pm4, pos, capacity, **kw))
+    np.testing.assert_allclose(
+        eager, np.asarray(pm1.paint(pos, 1.0, **kw)), rtol=1e-5,
+        atol=1e-5)
+
+
+def test_eager_exchange_equals_the_traced_exchange_to_the_bit(
+        tpu_branch):
+    mesh = cpu_mesh(4)
+    npart = 40001
+    pos = half_in_one_slab(npart, 32.0, 33)
+    dest = jnp.asarray(np.floor(pos[:, 0]).astype('i4') // 8)
+    tag = jnp.arange(npart, dtype=jnp.int32)
+    pos = jnp.asarray(pos)
+    cap = auto_capacity(dest, 4)
+    eager = exchange_by_dest(dest, [pos, tag], mesh, cap)
+    traced = jax.jit(lambda d, p, t: exchange_by_dest(
+        d, [p, t], mesh, cap))(dest, pos, tag)
+    counted = exchange_by_dest(dest, [pos, tag], mesh)
+    for other in (traced, counted):
+        for a, b in zip(jax.tree_util.tree_leaves(eager),
+                        jax.tree_util.tree_leaves(other)):
+            assert a.shape == b.shape and a.dtype == b.dtype
+            assert np.array_equal(np.asarray(a), np.asarray(b))
+    (_, got), valid, dropped = eager
+    assert int(dropped) == 0
+    assert np.array_equal(np.sort(np.asarray(got)[np.asarray(valid)]),
+                          np.arange(npart))
+
+
+@pytest.mark.parametrize('kind', ['r2c', 'c2r'])
+def test_eager_slab_fft_equals_the_traced_one_to_the_bit(tpu_branch,
+                                                         kind):
+    from nbodykit_tpu.parallel.dfft import dist_irfftn, dist_rfftn
+    mesh, one = cpu_mesh(4), cpu_mesh(1)
+    x = jnp.asarray(np.random.RandomState(34).normal(
+        size=(32, 32, 32)).astype('f4'))
+    if kind == 'r2c':
+        def fn(v, m):
+            return dist_rfftn(v, m)
+        arg = x
+    else:
+        def fn(v, m):
+            return dist_irfftn(v, 32, m)
+        arg = dist_rfftn(x, one)
+    eager = np.asarray(fn(shard_leading(mesh, arg), mesh))
+    traced = np.asarray(jax.jit(lambda v: fn(v, mesh))(
+        shard_leading(mesh, arg)))
+    assert np.array_equal(eager, traced)
+    want = np.asarray(fn(arg, one))
+    assert np.max(np.abs(eager - want)) < 1e-5 * np.max(np.abs(want))
+
+
+def test_the_route_computes_in_the_dtype_of_the_mesh_it_is_called_for():
+    # an f8 mesh made while x64 is off computes in f4, one made with
+    # x64 on in f8: the routing program takes the compute dtype as an
+    # argument, so the first does not decide for the second
+    mesh, one = cpu_mesh(4), cpu_mesh(1)
+    pos = np.random.RandomState(41).uniform(0, 32.0, (40001, 3))
+    mass = np.random.RandomState(42).uniform(1, 2, 40001)
+    with jax.enable_x64(False):
+        narrow = ParticleMesh(32, 32.0, dtype='f8', comm=mesh)
+        assert narrow.compute_dtype == np.dtype('f4')
+        field = narrow.paint(jnp.asarray(pos, 'f4'),
+                             jnp.asarray(mass, 'f4'))
+        assert field.dtype == np.dtype('f4')
+    wide = ParticleMesh(32, 32.0, dtype='f8', comm=mesh)
+    assert wide.compute_dtype == np.dtype('f8')
+    field = wide.paint(jnp.asarray(pos), jnp.asarray(mass))
+    assert field.dtype == np.dtype('f8')
+    want = ParticleMesh(32, 32.0, dtype='f8', comm=one).paint(
+        jnp.asarray(pos), jnp.asarray(mass))
+    np.testing.assert_allclose(np.asarray(field), np.asarray(want),
+                               rtol=1e-12, atol=1e-12)
+
+
+def test_eager_gradient_through_the_slab_paint_takes_the_raw_programs(
+        tpu_branch, clean_registry):
+    # under an eager jax.grad the weights are tracers while the
+    # positions are not: every pick between a program's two forms looks
+    # at all its operands, and the raw forms compose into the gradient
+    pos = jnp.asarray(uniform_positions(20000, 43))
+    mass = jnp.asarray(np.random.RandomState(44).uniform(
+        1, 2, 20000).astype('f4'))
+    weight = jnp.asarray(np.random.RandomState(45).normal(
+        size=(32, 32, 32)).astype('f4'))
+
+    def loss(pm):
+        return lambda m: jnp.vdot(pm.paint(pos, m, resampler='cic'),
+                                  weight)
+    pm4 = ParticleMesh(32, 32.0, dtype='f4', comm=cpu_mesh(4))
+    pm1 = ParticleMesh(32, 32.0, dtype='f4', comm=cpu_mesh(1))
+    got = jax.grad(loss(pm4))(mass)
+    np.testing.assert_allclose(
+        np.asarray(got), np.asarray(jax.grad(loss(pm1))(mass)),
+        rtol=1e-4, atol=1e-5)

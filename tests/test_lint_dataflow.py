@@ -14,6 +14,8 @@ import subprocess
 import sys
 import textwrap
 
+import pytest
+
 from nbodykit_tpu import lint
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -155,6 +157,102 @@ def test_nbk103_data_divergence_in_traced_code():
     """, select=['NBK103'])
     assert codes(fs) == ['NBK103']
     assert 'traced-data' in fs[0].message
+
+
+_PROGRAM_PAIR = """
+import functools
+import jax
+from diagnostics import instrumented_jit
+
+@functools.lru_cache(maxsize=8)
+def _programs(mesh):
+    def local(v):
+        return jax.lax.psum(v, 'dev')
+
+    sharded = jax.shard_map(local, mesh=mesh, in_specs=None,
+                            out_specs=None)
+
+    def exchange(v):
+        return sharded(v)
+
+    return exchange, instrumented_jit(exchange, label='exchange')
+
+@functools.lru_cache(maxsize=8)
+def _other_programs(mesh):
+    def local(v):
+        return jax.lax.all_gather(v, 'dev')
+
+    raw = jax.shard_map(local, mesh=mesh, in_specs=None, out_specs=None)
+    return raw, instrumented_jit(raw, label='other')
+
+def run(x, mesh, eager, n):
+    raw, jitted = _programs(mesh)
+    oraw, ojit = _other_programs(mesh)
+    x = (jitted if eager else raw)(x)
+    if n < 0:
+        raise ValueError('bad shard')
+    return (%s)(x)
+"""
+
+
+def test_nbk103_follows_a_cached_program_pair():
+    # the idiom of dfft._slab_programs, exchange._exchange_programs and
+    # pmesh._slab_paint_programs: a builder returns (raw, jit) over one
+    # body and the caller picks by a conditional.  The collectives
+    # behind the handle count, so the raise between two such calls is
+    # a finding, in the builder's own module too (``exchange`` is a
+    # name of the builder's scope)
+    fs = lint_str(_PROGRAM_PAIR % 'jitted if eager else raw',
+                  select=['NBK103'])
+    assert codes(fs) == ['NBK103']
+    assert 'strands its peers' in fs[0].message
+
+
+def test_nbk103_arms_of_different_programs_stay_unresolved():
+    # a pick between two different bodies reaches no one def: the call
+    # resolves to nothing and the lint stays silent rather than guess
+    fs = lint_str(_PROGRAM_PAIR % 'ojit if eager else raw',
+                  select=['NBK103'])
+    assert fs == []
+
+
+def _collective_summaries():
+    from nbodykit_tpu.lint.collectives import analysis_for
+    from nbodykit_tpu.lint.walker import build_project
+    project = build_project([os.path.join(REPO, 'nbodykit_tpu')])[0]
+    analysis = analysis_for(project)
+    out = {}
+    for ctx, fn in project.functions():
+        name = getattr(fn, 'name', None)
+        if name is not None:
+            out.setdefault((ctx.module, name), []).append(
+                analysis.summary_of(fn))
+    return out
+
+
+@pytest.fixture(scope='module')
+def collective_summaries():
+    return _collective_summaries()
+
+
+@pytest.mark.parametrize('module, function, token', [
+    ('nbodykit_tpu.parallel.exchange', 'exchange_by_dest', 'all_to_all'),
+    ('nbodykit_tpu.parallel.dfft', '_slab_run', 'all_to_all'),
+    ('nbodykit_tpu.parallel.dfft', '_pencil_run', 'all_to_all'),
+    # paint's and readout's: the exchange, then the halo
+    ('nbodykit_tpu.pmesh', 'attempt', 'all_to_all'),
+    ('nbodykit_tpu.pmesh', 'attempt', 'ppermute'),
+])
+def test_nbk103_sees_the_collectives_of_the_slab_path(
+        collective_summaries, module, function, token):
+    # the eager multi-device paint and FFTs run as cached programs; the
+    # deadlock lint has to see through each handle to its shard_map
+    # body, or a rank-dependent edit around them goes unflagged
+    summaries = collective_summaries[(module, function)]
+    # (None is "too many paths to track", which the lint reads as
+    # no collective at all)
+    assert all(s is not None and any(token in seq for seq in s)
+               for s in summaries), summaries
 
 
 # ---------------------------------------------------------------------------

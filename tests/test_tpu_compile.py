@@ -10,7 +10,7 @@ This is the ONLY file that describes a topology, and it does so inside
 the ``topo`` fixture: only one process may load libtpu, and every
 xdist worker imports every test file.
 
-The tier-1 cases take ~105 s together (one compile of a paint or an
+The tier-1 cases take ~150 s together (one compile of a paint or an
 exchange is ~20 s, whatever the particle count).  Three more are
 marked ``slow``: run the whole file (no ``-m 'not slow'``) before a
 call that selects the mxu paint or takes four chips.
@@ -248,6 +248,30 @@ def test_slab_rfftn_1024_on_four_chips(four_chips):
     assert _total_bytes(compiled) < 0.5 * V5E_HBM
 
 
+@pytest.mark.parametrize('nmesh,chips', [(NMESH, 1), (1024, 4)])
+def test_cross_power_program_holds_one_field_of_its_own(
+        one_chip, four_chips, nmesh, chips):
+    # the lab call's 3-D power as one program: its output and the
+    # re/im planes the TPU splits a complex field into, where the ops
+    # one by one left four fields; on a slab mesh every device keeps
+    # to its rows (clearing the DC mode moves nothing)
+    from nbodykit_tpu.algorithms.fftpower import _cross_power
+    from nbodykit_tpu.parallel.runtime import AXIS
+    rows = one_chip if chips == 1 else \
+        NamedSharding(four_chips, P(AXIS, None, None))
+    c = jax.ShapeDtypeStruct((nmesh, nmesh, nmesh // 2 + 1),
+                             jnp.complex64, sharding=rows)
+    v = jax.ShapeDtypeStruct((), jnp.float32)
+    compiled = _cross_power._jitted.lower(c, c, v).compile()
+    field = 8 * nmesh * nmesh * (nmesh // 2 + 1) // chips
+    m = compiled.memory_analysis()
+    assert m.output_size_in_bytes <= 1.001 * field
+    assert m.temp_size_in_bytes <= 2.001 * field
+    text = compiled.as_text()
+    assert not any(op in text for op in (
+        'all-gather', 'all-to-all', 'all-reduce', 'collective-permute'))
+
+
 @pytest.mark.slow
 def test_particle_exchange_1e7_on_four_chips(four_chips):
     # the all-to-all that routes 1e7 particles to their slabs, at the
@@ -265,3 +289,58 @@ def test_particle_exchange_1e7_on_four_chips(four_chips):
                                          NPART // 4), dest, pos, mass)
     assert 'all-to-all' in compiled.as_text()
     assert _total_bytes(compiled) < 0.25 * V5E_HBM
+
+
+#: the four-chip cell: desi_like as published, and the capacity its
+#: 1e7 particles take on every seed (PERF.md, PR 27)
+CELL_NMESH, CELL_CAPACITY = 1024, 664063
+
+
+@pytest.mark.parametrize('site', ['exchange', 'paint.slab'])
+def test_staged_exchange_and_paint_of_the_four_chip_cell(four_chips,
+                                                         site):
+    # the programs the eager four-chip lab call launches for its
+    # exchange and its paint, as pmesh.paint fetches them: each has to
+    # fit beside the 4.29 GB field's quarter, with no temporary a
+    # staged program might add (PR 22 met a 32x padded one and a
+    # replicated loop carry when eager code first became programs)
+    from nbodykit_tpu import _global_options
+    from nbodykit_tpu.parallel.exchange import _exchange_programs
+    from nbodykit_tpu.parallel.runtime import AXIS
+    from nbodykit_tpu.pmesh import _slab_paint_programs
+    rows = NamedSharding(four_chips, P(AXIS))
+    rows3 = NamedSharding(four_chips, P(AXIS, None))
+    field = 4 * CELL_NMESH ** 3 // 4
+    if site == 'exchange':
+        raw, _ = _exchange_programs(four_chips, CELL_CAPACITY, 0.0,
+                                    (2, 1), True)
+        args = [jax.ShapeDtypeStruct((NPART,), jnp.int32, sharding=rows),
+                jax.ShapeDtypeStruct((NPART, 3), jnp.float32,
+                                     sharding=rows3),
+                jax.ShapeDtypeStruct((NPART,), jnp.float32,
+                                     sharding=rows)]
+        collective = 'all-to-all'
+        limit = 0.02 * V5E_HBM          # 3 x 45 MB of buffers a device
+    else:
+        cfg = _pm(CELL_NMESH, comm=four_chips)._paint_config(NPART)
+        assert cfg['paint_method'] == 'scatter'
+        raw, _ = _slab_paint_programs(
+            four_chips, (CELL_NMESH,) * 3, 'cic', cfg['paint_method'],
+            cfg['paint_chunk_size'], cfg['paint_order'],
+            cfg['paint_deposit'], cfg['paint_streams'],
+            jnp.dtype('f4'), jnp.dtype('f4'),
+            _global_options['paint_bucket_slack'], True)
+        slots = 16 * CELL_CAPACITY
+        args = [jax.ShapeDtypeStruct((slots, 3), jnp.float32,
+                                     sharding=rows3),
+                jax.ShapeDtypeStruct((slots,), jnp.float32,
+                                     sharding=rows),
+                jax.ShapeDtypeStruct((slots,), jnp.bool_, sharding=rows)]
+        # the halo-extended slab (256 + 2 x 2 rows), flat as the
+        # scatters fill it, as a block, and the slab cut from it
+        collective = 'collective-permute'       # halo_add
+        limit = 3 * 4 * 260 * CELL_NMESH ** 2
+    compiled = _compile(raw, *args)
+    assert collective in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes <= limit
+    assert _total_bytes(compiled) + field < V5E_HBM
