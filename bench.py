@@ -37,9 +37,9 @@ config with no same-config CPU measurement gets no ratio.
     bench.py --forward [NMESH [NPART [STEPS [SEED]]]]
     bench.py --bispectrum [NMESH [NPART [NBINS [SEED]]]]
 
-Global flags (any subcommand): --fft-decomp {slab,pencil,auto},
+Global flags (any subcommand): --fft-decomp {slab,pencil},
 --pencil PXxPY, --mesh-dtype, --a2a-compress override the run's
-options; the record's tuned:{...} stamps what actually resolved.
+options.
 """
 
 import json
@@ -82,21 +82,17 @@ def _hbm_gbps(jax):
 
 
 # global FFT decomposition overrides (--fft-decomp / --pencil), staged
-# here by _parse_fft_flags and applied by _setup_jax once jax is up;
-# every record's tuned:{...} then stamps the decomposition and device-
-# mesh shape the measurement actually ran with (tuned_snapshot)
+# here by _parse_fft_flags and applied by _setup_jax once jax is up
 _FFT_OPTS = {}
 
 
 def _parse_fft_flags(argv):
-    """Strip the global ``--fft-decomp slab|pencil|auto``,
+    """Strip the global ``--fft-decomp slab|pencil``,
     ``--pencil PXxPY``, ``--mesh-dtype f4|bf16`` and
     ``--a2a-compress none|bf16|int16`` flags from an argv list (any
     subcommand may carry them) and stage the overrides for
     :func:`_setup_jax`.  The precision flags select the ISSUE 13
-    half-storage/compressed-wire paths; every record's ``tuned:{...}``
-    block stamps the resolved values so hardware-window numbers stay
-    attributable."""
+    half-storage/compressed-wire paths."""
     out = []
     it = iter(argv)
     for a in it:
@@ -118,31 +114,25 @@ def _parse_fft_flags(argv):
             _FFT_OPTS['a2a_compress'] = a.split('=', 1)[1]
         else:
             out.append(a)
-    if _FFT_OPTS.get('fft_decomp') not in (None, 'slab', 'pencil',
-                                           'auto'):
-        raise SystemExit('--fft-decomp must be slab, pencil or auto '
+    if _FFT_OPTS.get('fft_decomp') not in (None, 'slab', 'pencil'):
+        raise SystemExit('--fft-decomp must be slab or pencil '
                          '(got %r)' % _FFT_OPTS['fft_decomp'])
-    if _FFT_OPTS.get('mesh_dtype') not in (None, 'f4', 'bf16', 'auto'):
-        raise SystemExit('--mesh-dtype must be f4, bf16 or auto '
+    if _FFT_OPTS.get('mesh_dtype') not in (None, 'f4', 'bf16'):
+        raise SystemExit('--mesh-dtype must be f4 or bf16 '
                          '(got %r)' % _FFT_OPTS['mesh_dtype'])
     if _FFT_OPTS.get('a2a_compress') not in (None, 'none', 'bf16',
-                                             'int16', 'auto'):
-        raise SystemExit('--a2a-compress must be none, bf16, int16 or '
-                         'auto (got %r)' % _FFT_OPTS['a2a_compress'])
+                                             'int16'):
+        raise SystemExit('--a2a-compress must be none, bf16 or int16 '
+                         '(got %r)' % _FFT_OPTS['a2a_compress'])
     return out
 
 
-def _bench_mesh_dtype(Nmesh=None):
+def _bench_mesh_dtype():
     """The mesh storage dtype this bench process runs with: the
-    ``--mesh-dtype`` override when given (staged into
-    ``set_options(mesh_dtype=...)`` by :func:`_setup_jax`), resolved
-    through the tune cache for 'auto', else 'f4'."""
+    ``mesh_dtype`` option, which :func:`_setup_jax` sets from the
+    ``--mesh-dtype`` override when given."""
     from nbodykit_tpu import _global_options
-    v = _global_options['mesh_dtype']
-    if v in (None, 'auto'):
-        from nbodykit_tpu.tune.resolve import resolve_mesh_dtype
-        return resolve_mesh_dtype(nmesh=Nmesh)
-    return v
+    return _global_options['mesh_dtype']
 
 
 def _utcnow():
@@ -458,12 +448,12 @@ def run_config(Nmesh, Npart, method='scatter', reps=2, phases=True):
     # process must not leak non-default engines into a rung labeled
     # only by paint_method
     nbodykit_tpu.set_options(paint_method=method, paint_order='auto',
-                             paint_deposit='auto', paint_streams='auto',
+                             paint_deposit='xla', paint_streams=4,
                              paint_chunk_size=1024 * 1024 * 16)
     from nbodykit_tpu.diagnostics import span as _span
     from nbodykit_tpu.diagnostics import instrumented_jit as _ijit
     pm = ParticleMesh(Nmesh=Nmesh, BoxSize=1000.0,
-                      dtype=_bench_mesh_dtype(Nmesh))
+                      dtype=_bench_mesh_dtype())
     with _span('bench.make_pos', npart=Npart, nmesh=Nmesh):
         pos = _make_pos(jax, jnp, Npart, 1000.0)
     fused, phase_fns = _bench_fftpower_fn(pm)
@@ -476,12 +466,6 @@ def run_config(Nmesh, Npart, method='scatter', reps=2, phases=True):
         **({"paint_method_overridden": "sort->scatter (HBM)"}
            if overridden else {}),
     }
-    # which tuned configuration this measurement actually ran with
-    # (explicit/default/cache per knob) — a bench number without its
-    # config is not reproducible evidence (nbodykit_tpu.tune)
-    from nbodykit_tpu.tune.resolve import tuned_snapshot
-    rec['tuned'] = tuned_snapshot(nmesh=Nmesh, npart=Npart,
-                                  dtype='f4', nproc=pm.nproc)
     # per-rep checkpoints keyed by metric; a relaunch after a mid-rep
     # death resumes here instead of restarting the rung
     from nbodykit_tpu.resilience import CheckpointStore
@@ -797,9 +781,9 @@ def run_fftbw(Nmesh=512, reps=3):
 
 def run_fft_decomp(Nmesh=256, reps=3):
     """Slab-vs-pencil distributed rFFT on the process-visible
-    multi-device mesh: the same ``pm.r2c`` program the tuner races
-    (tune/space.py fft space), timed under both decompositions so the
-    committed round files carry the knob's trajectory.  Needs >= 2
+    multi-device mesh: the ``pm.r2c`` program timed under both
+    decompositions so the committed round files carry the knob's
+    trajectory.  Needs >= 2
     devices (CPU: JAX_NUM_CPU_DEVICES=8); ``--pencil PXxPY`` picks the
     factorization, else the near-square default."""
     jax = _setup_jax()
@@ -833,7 +817,7 @@ def run_fft_decomp(Nmesh=256, reps=3):
     from nbodykit_tpu.pmesh import ParticleMesh
     with use_mesh(mesh):
         pm = ParticleMesh(Nmesh=Nmesh, BoxSize=1000.0,
-                          dtype=_bench_mesh_dtype(Nmesh))
+                          dtype=_bench_mesh_dtype())
         x = jax.random.uniform(jax.random.key(7), pm.shape_real,
                                jnp.float32)
         x = jax.device_put(x, pm.sharding())
@@ -852,9 +836,6 @@ def run_fft_decomp(Nmesh=256, reps=3):
                                        '%dx%d' % pxpy})):
             with nbodykit_tpu.set_options(**opts):
                 rec['%s_s' % name] = round(timed(), 4)
-        from nbodykit_tpu.tune.resolve import tuned_snapshot
-        rec['tuned'] = tuned_snapshot(nmesh=Nmesh, npart=0, dtype='f4',
-                                      nproc=nproc)
     rec['value'] = min(rec['slab_s'], rec['pencil_s'])
     rec['winner'] = ('slab' if rec['slab_s'] <= rec['pencil_s']
                      else 'pencil')
@@ -969,7 +950,6 @@ def run_serve_trace(n=1000, per_task=1, max_batch=8, seed=0):
         reset_faults
     from nbodykit_tpu.serve import (AnalysisServer, BatchPolicy,
                                     generate_trace, replay)
-    from nbodykit_tpu.tune.resolve import tuned_snapshot
 
     ndev = len(jax.devices())
     rec = {"metric": "servetrace_n%d" % n, "unit": "s",
@@ -1009,8 +989,6 @@ def run_serve_trace(n=1000, per_task=1, max_batch=8, seed=0):
     rec['waterfalls'] = _waterfall_stamp(tracedir)
     rec['faults_injected'] = {k: v for k, v in fault_counts().items()
                              if k.startswith('serve.')}
-    rec['tuned'] = tuned_snapshot(nmesh=64, npart=50000, dtype='f4',
-                                  nproc=per_task)
 
     # tracing overhead: the same closed-loop slam, fresh servers,
     # compile caches warm, with and without a live tracer
@@ -1279,7 +1257,6 @@ def run_ingest(npart=400000, nmesh=64, chunk_rows=None, seed=0):
     from nbodykit_tpu.resilience.faults import reset_faults
     from nbodykit_tpu.serve import (COMPLETED, AnalysisRequest,
                                     AnalysisServer)
-    from nbodykit_tpu.tune.resolve import tuned_snapshot
 
     ndev = len(jax.devices())
     reset_faults()
@@ -1295,7 +1272,7 @@ def run_ingest(npart=400000, nmesh=64, chunk_rows=None, seed=0):
                       columns={'Position': 'Position'},
                       options={'dtype': [('Position', 'f4', (3,))]})
         nbytes = npart * 12
-        chunk = resolve_chunk_rows(npart, ndev, chunk_rows)
+        chunk = resolve_chunk_rows(chunk_rows)
         rec = {"metric": "ingest_n%d" % npart, "unit": "GB/s",
                "platform": jax.devices()[0].platform,
                "ndevices": ndev, "nmesh": nmesh, "rows": npart,
@@ -1373,8 +1350,6 @@ def run_ingest(npart=400000, nmesh=64, chunk_rows=None, seed=0):
         rec['serve_cache_hits'] = summary['ingest_cache_hits']
         rec['serve_lost'] = summary['lost']
         rec['serve_ingest_gb'] = summary['ingest_gb']
-        rec['tuned'] = tuned_snapshot(nmesh=nmesh, npart=npart,
-                                      dtype='f4', nproc=ndev)
         rec['value'] = rec['cold_gbs']
         return _stamp(rec)
     finally:
@@ -1417,7 +1392,6 @@ def run_forward(nmesh=32, npart=None, steps=2, seed=0):
     from nbodykit_tpu.parallel.runtime import (cpu_mesh, mesh_size,
                                                tpu_mesh, use_mesh)
     from nbodykit_tpu.pmesh import memory_plan
-    from nbodykit_tpu.tune.resolve import tuned_snapshot
     from nbodykit_tpu.utils import is_mxu_backend
 
     mesh = tpu_mesh() if is_mxu_backend() else cpu_mesh()
@@ -1514,8 +1488,6 @@ def run_forward(nmesh=32, npart=None, steps=2, seed=0):
             'r_fftrecon': round(r_base, 5),
             'beats_baseline': bool(r_rec > r_base),
         }
-        rec['tuned'] = tuned_snapshot(nmesh=nmesh, npart=npart,
-                                      dtype='f8', nproc=nproc)
         rec['value'] = rec['grad_s']
     return _stamp(rec)
 
@@ -1526,8 +1498,7 @@ def run_bispectrum(nmesh=32, npart=20000, nbins=3, seed=0):
     pairwise-summation path on the SAME deterministic catalog — the
     first FLOPs-bound workload in the suite.
 
-    The record stamps the per-shape crossover evidence the ``bspec``
-    tune space turns into cached winners:
+    The record stamps the per-shape crossover evidence:
 
     - *fft_s* / *direct_s*: full-estimator wall seconds (paint + r2c +
       triangle stream vs pairblock mode sums + host combination), min
@@ -1555,8 +1526,6 @@ def run_bispectrum(nmesh=32, npart=20000, nbins=3, seed=0):
     from nbodykit_tpu.parallel.runtime import (cpu_mesh, mesh_size,
                                                tpu_mesh, use_mesh)
     from nbodykit_tpu.pmesh import ParticleMesh, memory_plan
-    from nbodykit_tpu.tune.resolve import (resolve_bispectrum,
-                                           tuned_snapshot)
     from nbodykit_tpu.utils import is_mxu_backend
 
     mesh = tpu_mesh() if is_mxu_backend() else cpu_mesh()
@@ -1581,11 +1550,10 @@ def run_bispectrum(nmesh=32, npart=20000, nbins=3, seed=0):
     with ctx:
         import jax.numpy as jnp
         comm = mesh if nproc >= 2 else None
-        cfg = resolve_bispectrum(nmesh=nmesh, npart=npart,
-                                 nproc=nproc)
-        tile = int(cfg['pairblock_tile'])
+        from nbodykit_tpu import _global_options
+        tile = int(_global_options['pairblock_tile'])
         rec['pairblock_tile'] = tile
-        rec['resolved_method'] = cfg['bspec_method']
+        rec['resolved_method'] = _global_options['bspec_method']
         pm = ParticleMesh(Nmesh=nmesh, BoxSize=L, dtype='f4',
                           comm=comm)
         posj = jnp.asarray(pos, pm.dtype)
@@ -1653,8 +1621,6 @@ def run_bispectrum(nmesh=32, npart=20000, nbins=3, seed=0):
                              pairblock_tile=tile)
         rec['plan_fft_peak_bytes'] = int(plan_f['peak_bytes'])
         rec['plan_direct_peak_bytes'] = int(plan_d['peak_bytes'])
-        rec['tuned'] = tuned_snapshot(nmesh=nmesh, npart=npart,
-                                      nproc=nproc)
         rec['value'] = min(rec['fft_s'], rec['direct_s'])
     return _stamp(rec)
 
@@ -1689,7 +1655,6 @@ def run_integrity(nmesh=64, npart=200000, reps=3, seed=7):
     from nbodykit_tpu.resilience import (Supervisor, reset_faults,
                                          reset_integrity,
                                          violation_counts)
-    from nbodykit_tpu.tune.resolve import tuned_snapshot
     from nbodykit_tpu.utils import is_mxu_backend
     import contextlib
 
@@ -1745,39 +1710,57 @@ def run_integrity(nmesh=64, npart=200000, reps=3, seed=7):
     rec['reps'] = reps
     rec['overhead'] = round(rec['cheap_s'] / max(rec['off_s'], 1e-9)
                             - 1.0, 4)
-    rec['tuned'] = tuned_snapshot(nmesh=nmesh, npart=npart, dtype='f4',
-                                  nproc=nproc)
     rec['value'] = rec['cheap_s']
     return _stamp(rec)
 
 
-def _paint_method_options(method, Nmesh, Npart):
+# the paint configurations --paint-all sweeps, by name: every engine
+# of ops/paint.py with the options that select it
+PAINT_CANDIDATES = {
+    'scatter': {'paint_method': 'scatter'},
+    'scatter-chunk4m': {'paint_method': 'scatter',
+                        'paint_chunk_size': 1024 * 1024 * 4},
+    'sort': {'paint_method': 'sort'},
+    'segsum-argsort': {'paint_method': 'segsum',
+                       'paint_order': 'argsort'},
+    'segsum-radix': {'paint_method': 'segsum', 'paint_order': 'radix'},
+    'streams2': {'paint_method': 'streams', 'paint_streams': 2},
+    'streams4': {'paint_method': 'streams', 'paint_streams': 4},
+    'streams8': {'paint_method': 'streams', 'paint_streams': 8},
+    'mxu-argsort-xla': {'paint_method': 'mxu', 'paint_order': 'argsort',
+                        'paint_deposit': 'xla'},
+    'mxu-radix-xla': {'paint_method': 'mxu', 'paint_order': 'radix',
+                      'paint_deposit': 'xla'},
+    'scatter-bf16': {'paint_method': 'scatter', 'mesh_dtype': 'bf16'},
+    'streams4-bf16': {'paint_method': 'streams', 'paint_streams': 4,
+                      'mesh_dtype': 'bf16'},
+    'streams8-bf16': {'paint_method': 'streams', 'paint_streams': 8,
+                      'mesh_dtype': 'bf16'},
+}
+
+
+def _paint_method_options(method):
     """``set_options`` kwargs selecting one paint configuration by
     name.
 
-    Accepts (1) any REGISTERED tuner candidate name for this shape
-    ('scatter', 'sort', 'segsum-radix', 'streams4', 'mxu-radix-xla',
-    ... — tune/space.py), so bench measurements and trials select
-    identical programs; (2) the legacy suffix grammar
-    'mxu:ORDER[:DEPOSIT]', 'segsum:ORDER' and 'streams:K'.  Every
-    option a configuration does NOT pin is reset to its default — a
-    prior call in this process must not leak engines into a
-    differently-labeled measurement.
+    Accepts (1) a name of :data:`PAINT_CANDIDATES` ('scatter', 'sort',
+    'segsum-radix', 'streams4', 'mxu-radix-xla', ...); (2) the legacy
+    suffix grammar 'mxu:ORDER[:DEPOSIT]', 'segsum:ORDER' and
+    'streams:K'.  Every option a configuration does NOT pin is reset
+    to its default — a prior call in this process must not leak
+    engines into a differently-labeled measurement.
     """
-    from nbodykit_tpu.tune.space import registered_paint_candidates
-    base = {'paint_order': 'auto', 'paint_deposit': 'auto',
-            'paint_streams': 'auto',
+    base = {'paint_order': 'auto', 'paint_deposit': 'xla',
+            'paint_streams': 4,
             'paint_chunk_size': 1024 * 1024 * 16}
-    for cand in registered_paint_candidates(Nmesh, Npart):
-        if cand.name == method:
-            opts = dict(base)
-            opts.update(cand.options)
-            # an explicit --mesh-dtype outranks the candidate's
-            # storage default: 'scatter --mesh-dtype bf16' means
-            # bf16 scatter, not the registered f4 variant
-            if _FFT_OPTS.get('mesh_dtype'):
-                opts['mesh_dtype'] = _FFT_OPTS['mesh_dtype']
-            return opts
+    if method in PAINT_CANDIDATES:
+        opts = {**base, 'mesh_dtype': 'f4', **PAINT_CANDIDATES[method]}
+        # an explicit --mesh-dtype outranks the candidate's
+        # storage default: 'scatter --mesh-dtype bf16' means
+        # bf16 scatter, not the f4 variant of that name
+        if _FFT_OPTS.get('mesh_dtype'):
+            opts['mesh_dtype'] = _FFT_OPTS['mesh_dtype']
+        return opts
     opts = dict(base)
     if ':' in method:
         parts = method.split(':')
@@ -1797,7 +1780,7 @@ def _paint_method_options(method, Nmesh, Npart):
 def run_paint(Nmesh, Npart, method='scatter', reps=3):
     """Paint-only microbenchmark (the #1 perf risk, SURVEY §7).
 
-    ``method`` is a registered tuner candidate name or a legacy
+    ``method`` is a name of :data:`PAINT_CANDIDATES` or a legacy
     'METHOD[:ORDER[:DEPOSIT]]' / 'streams:K' spec
     (:func:`_paint_method_options`).  The record carries the summed
     painted mass (``mass_sum``) so gates can reject a kernel that
@@ -1809,17 +1792,15 @@ def run_paint(Nmesh, Npart, method='scatter', reps=3):
     from nbodykit_tpu.pmesh import ParticleMesh
 
     method_label = method      # metric key keeps the candidate name
-    nbodykit_tpu.set_options(**_paint_method_options(
-        method, Nmesh, Npart))
+    nbodykit_tpu.set_options(**_paint_method_options(method))
     pm = ParticleMesh(Nmesh=Nmesh, BoxSize=1000.0,
-                      dtype=_bench_mesh_dtype(Nmesh))
+                      dtype=_bench_mesh_dtype())
     pos = _make_pos(jax, jnp, Npart, 1000.0)
     fn = jax.jit(lambda p: pm.paint(p, 1.0, resampler='cic',
                                     return_dropped=True)[0])
     dt, _ = _time_fn(jax, fn, (pos,), reps,
                      label='paint_%s' % method_label)
     mass_sum = float(jnp.sum(fn(pos)))
-    from nbodykit_tpu.tune.resolve import tuned_snapshot
     return _stamp({
         "metric": "paint_wallclock_nmesh%d_npart%.0e_%s"
                   % (Nmesh, Npart, method_label),
@@ -1827,25 +1808,21 @@ def run_paint(Nmesh, Npart, method='scatter', reps=3):
         "mpart_per_s": round(Npart / dt / 1e6, 1),
         "mass_sum": mass_sum,
         "platform": jax.devices()[0].platform,
-        "tuned": tuned_snapshot(nmesh=Nmesh, npart=Npart, dtype='f4',
-                                nproc=pm.nproc),
     })
 
 
 def run_paint_all(Nmesh, Npart, reps=3):
-    """Every registered paint candidate at one shape, one record each
-    (the smoke gate's CI sweep and the pre-hardware baseline for
-    ROADMAP #1).  A candidate that raises is recorded with an
-    ``error`` field instead of killing the sweep — the gate decides.
+    """Every configuration of :data:`PAINT_CANDIDATES` at one shape,
+    one record each (the smoke gate's CI sweep).  A candidate that
+    raises is recorded with an ``error`` field instead of killing the
+    sweep — the gate decides.
     """
-    from nbodykit_tpu.tune.space import registered_paint_candidates
     out = {}
-    for cand in registered_paint_candidates(Nmesh, Npart):
+    for name in PAINT_CANDIDATES:
         try:
-            out[cand.name] = run_paint(Nmesh, Npart, cand.name,
-                                       reps=reps)
+            out[name] = run_paint(Nmesh, Npart, name, reps=reps)
         except Exception as e:                      # gate fodder
-            out[cand.name] = {"error": str(e)[:300]}
+            out[name] = {"error": str(e)[:300]}
     return out
 
 

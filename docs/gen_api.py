@@ -95,7 +95,7 @@ def main():
     out = [
         "# API reference",
         "",
-        "Every public name in `nbodykit_tpu.lab` (the user-facing namespace, mirroring the reference's `nbodykit.lab`), grouped by subsystem. Generated by `docs/gen_api.py`; regenerate after changing public signatures. Deeper narrative docs: [COMPONENTS.md](COMPONENTS.md) (design map), [EXAMPLES.md](EXAMPLES.md) (cookbook), [MIGRATING.md](MIGRATING.md) (coming from nbodykit), [PERF.md](PERF.md) (performance), [OBSERVABILITY.md](OBSERVABILITY.md) (tracing/metrics/crash-safe telemetry — `set_options(diagnostics=...)`, the `nbodykit_tpu.diagnostics` span/metric API, compile telemetry via `instrumented_jit`, multi-host trace merging/straggler/critical-path analysis via `--analyze`, bench regression tracking via `--regress`, and the `nbodykit-tpu-doctor` console script), [RESILIENCE.md](RESILIENCE.md) (checkpointed, retrying, fault-injectable execution — `nbodykit_tpu.resilience`, the `Supervisor`/`CheckpointStore`/`default_ladder` API, the `set_options(faults=...)` injection harness), [LINT.md](LINT.md) (the `nbodykit-tpu-lint` interprocedural shard-safety static analyzer — NBK1xx collectives incl. NBK103 collective-order deadlock detection / NBK2xx compile hygiene / NBK3xx precision / NBK4xx trace safety / NBK5xx memory-donation with the `--memory-report` symbolic peak table, suppressions, the `lint_baseline.json` gate), [BISPECTRUM.md](BISPECTRUM.md) (the hybrid FFT/direct-pairwise bispectrum estimator — the Scoccimarro triangle-count FFT path, the blocked MXU `pairblock` direct path, the measured crossover and the `bspec_method` tuner knob), [TUNE.md](TUNE.md) (measured autotuning — `nbodykit_tpu.tune`, the persistent per-platform `TUNE_CACHE.json` performance database, `'auto'` option resolution, the `nbodykit-tpu-tune` CLI, staleness/doctor posture).",
+        "Every public name in `nbodykit_tpu.lab` (the user-facing namespace, mirroring the reference's `nbodykit.lab`), grouped by subsystem. Generated by `docs/gen_api.py`; regenerate after changing public signatures. Deeper narrative docs: [COMPONENTS.md](COMPONENTS.md) (design map), [EXAMPLES.md](EXAMPLES.md) (cookbook), [MIGRATING.md](MIGRATING.md) (coming from nbodykit), [PERF.md](PERF.md) (performance), [OBSERVABILITY.md](OBSERVABILITY.md) (tracing/metrics/crash-safe telemetry — `set_options(diagnostics=...)`, the `nbodykit_tpu.diagnostics` span/metric API, compile telemetry via `instrumented_jit`, multi-host trace merging/straggler/critical-path analysis via `--analyze`, bench regression tracking via `--regress`, and the `nbodykit-tpu-doctor` console script), [RESILIENCE.md](RESILIENCE.md) (checkpointed, retrying, fault-injectable execution — `nbodykit_tpu.resilience`, the `Supervisor`/`CheckpointStore`/`default_ladder` API, the `set_options(faults=...)` injection harness), [LINT.md](LINT.md) (the `nbodykit-tpu-lint` interprocedural shard-safety static analyzer — NBK1xx collectives incl. NBK103 collective-order deadlock detection / NBK2xx compile hygiene / NBK3xx precision / NBK4xx trace safety / NBK5xx memory-donation with the `--memory-report` symbolic peak table, suppressions, the `lint_baseline.json` gate), [BISPECTRUM.md](BISPECTRUM.md) (the hybrid FFT/direct-pairwise bispectrum estimator — the Scoccimarro triangle-count FFT path, the blocked MXU `pairblock` direct path and the `bspec_method` option).",
         "",
     ]
     for title, names in GROUPS:

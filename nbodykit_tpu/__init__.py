@@ -32,12 +32,15 @@ __version__ = "0.1.0"
 # global options (reference: nbodykit/__init__.py:22-25, set_options :215-256)
 # ---------------------------------------------------------------------------
 
+# the one table of defaults: every read site takes the option as it
+# stands here. 'auto' is a value only where the code decides from
+# something it observes (paint_order: the backend; ingest_cache_bytes:
+# memory_plan; data_steal_grace_s: the environment)
 _default_options = {
     # dtype used for meshes created via to_mesh() unless overridden.
     # 'bf16' stores mesh buffers in bfloat16 (half the HBM of 'f4')
     # with f32-compensated deposit merges and immediate re-widening on
-    # readout/FFT entry (docs/PERF.md "Halving the bytes"); 'auto'
-    # consults the tune cache, falling back to 'f4'
+    # readout/FFT entry (docs/PERF.md "Halving the bytes")
     'mesh_dtype': 'f4',
     # all_to_all payload compression for the distributed FFT
     # (parallel/dfft.py, slab AND pencil drivers): 'none' sends the
@@ -45,21 +48,17 @@ _default_options = {
     # bfloat16-on-the-wire and re-widens to f32 immediately after the
     # collective; 'int16' sends an int16-quantized payload with
     # per-slab f32 scale factors carried alongside the shards. FFT
-    # stages always COMPUTE f32 — only the wire bytes halve. 'auto'
-    # consults the tune cache, falling back to 'none'
+    # stages always COMPUTE f32 — only the wire bytes halve
     'a2a_compress': 'none',
     # number of particles painted per chunk on the host-streaming path
     'paint_chunk_size': 1024 * 1024 * 16,
-    # slack factor for fixed-capacity particle exchange buffers
-    'exchange_slack': 1.25,
     # default resampler window
     'resampler': 'cic',
     # paint kernel: 'scatter' (chunked scatter-add), 'sort'
-    # (scatter-free sort + segmented reduction), 'mxu'
-    # (tile-bucketed batched-matmul deposit; see ops/paint.py) or
-    # 'auto' (the measured winner from the tune cache for this
-    # platform/shape — nbodykit_tpu.tune, docs/TUNE.md; a cold cache
-    # falls back to 'scatter' with zero trial overhead)
+    # (scatter-free sort + segmented reduction), 'segsum', 'streams'
+    # or 'mxu' (tile-bucketed batched-matmul deposit); see
+    # ops/paint.py. On the chip the scatter beat the mxu paint
+    # (PERF.md section 6); the others have no chip row
     'paint_method': 'scatter',
     # bucket-capacity slack for the 'mxu' paint kernel
     'paint_bucket_slack': 2.0,
@@ -67,27 +66,21 @@ _default_options = {
     # (radix counting sort on TPU, bitonic argsort elsewhere),
     # 'argsort', or 'radix' (ops/radix.py)
     'paint_order': 'auto',
-    # deposit engine for the mxu paint: 'auto'/'xla' (one-hot
-    # expansions via XLA) or 'pallas' (fused VMEM kernel,
-    # ops/paint_pallas.py)
-    'paint_deposit': 'auto',
+    # deposit engine for the mxu paint: 'xla' (one-hot expansions via
+    # XLA) or 'pallas' (fused VMEM kernel, ops/paint_pallas.py)
+    'paint_deposit': 'xla',
     # replica-mesh count for the 'streams' paint kernel (the number of
     # independent scatter chains; each replica is a full mesh buffer —
-    # memory_plan counts them against the HBM budget). 'auto' takes
-    # the tune-cache winner, falling back to 4
-    'paint_streams': 'auto',
+    # memory_plan counts them against the HBM budget)
+    'paint_streams': 4,
     # single-device FFTs whose complex output exceeds this many bytes
     # run as slab-chunked per-axis passes (a single FFT op over a
     # multi-GB buffer exceeds TPU compiler limits; see parallel/dfft).
-    # 0 disables chunking; 'auto' consults the tune cache
-    # (nbodykit_tpu.tune) and falls back to 2**31 when cold.
+    # 0 disables chunking.
     'fft_chunk_bytes': 2 ** 31,
     # distributed-FFT decomposition: 'slab' (1-D mesh, one P-way
-    # all_to_all), 'pencil' (2-D Mesh(('x','y')), two smaller
-    # transposes — inner over ICI, outer over DCN; parallel/dfft.py) or
-    # 'auto' (the measured winner from the tune cache, keyed by device
-    # count AND (Px, Py) factorization; cold cache falls back to
-    # 'slab' at zero trial cost)
+    # all_to_all) or 'pencil' (2-D Mesh(('x','y')), two smaller
+    # transposes — inner over ICI, outer over DCN; parallel/dfft.py)
     'fft_decomp': 'slab',
     # explicit (Px, Py) factorization for the pencil path, as 'PXxPY'
     # (e.g. '4x2') or a tuple; None picks the most nearly square
@@ -96,9 +89,8 @@ _default_options = {
     # rows per host chunk on the streaming ingestion path
     # (nbodykit_tpu.ingest, docs/INGEST.md): the window each
     # double-buffered device_put/paint step moves — the host never
-    # holds more than two windows. 'auto' consults the tune cache
-    # (keyed by the part-count shape class), falling back to 262144
-    'ingest_chunk_rows': 'auto',
+    # holds more than two windows
+    'ingest_chunk_rows': 262144,
     # overlap H2D transfer of chunk i+1 with the paint of chunk i
     # (the double buffer). False serializes transfer-then-paint —
     # kept selectable for A/B measurement (bench --ingest)
@@ -106,11 +98,6 @@ _default_options = {
     # hard cap (bytes) on the on-device catalog cache per sub-mesh;
     # 'auto'/None defers entirely to memory_plan pricing at admission
     'ingest_cache_bytes': 'auto',
-    # performance-database file for 'auto' option resolution and
-    # nbodykit-tpu-tune (nbodykit_tpu.tune, docs/TUNE.md). None uses
-    # the committed repo-root TUNE_CACHE.json; seeded from
-    # $NBKIT_TUNE_CACHE so detached workers can be pointed elsewhere.
-    'tune_cache': os.environ.get('NBKIT_TUNE_CACHE') or None,
     # telemetry sink: None disables; a path enables the span tracer +
     # crash-safe JSONL trace (nbodykit_tpu.diagnostics, docs/
     # OBSERVABILITY.md). Seeded from $NBKIT_DIAGNOSTICS so detached
@@ -146,17 +133,15 @@ _default_options = {
     # (1.0). Must be a non-negative finite number; 0 steals freely.
     # Resolved at server construction, validated there.
     'data_steal_grace_s': 'auto',
+    # bispectrum estimator: 'fft' (Scoccimarro filtered-field
+    # triangle counts, low k) or 'direct' (blocked pairwise mode sums
+    # on the MXU, high k; catalog sources only)
+    'bspec_method': 'fft',
+    # tile edge of the direct path's dense (tile x tile) phase blocks
+    # (ops/pairblock.py)
+    'pairblock_tile': 1024,
     # live telemetry export (nbodykit_tpu.diagnostics.export,
     # docs/OBSERVABILITY.md): an integer TCP port starts a
-    # bispectrum estimator selection: 'fft' (Scoccimarro filtered-field
-    # triangle counts, low k), 'direct' (blocked pairwise mode sums on
-    # the MXU, high k), or 'auto' — consult the tune cache for the
-    # measured crossover of this platform/shape, falling back to 'fft'
-    'bspec_method': 'auto',
-    # tile edge of the direct path's dense (tile x tile) phase blocks
-    # (ops/pairblock.py). 'auto' consults the tune cache (raced inside
-    # the bspec space), falling back to 1024
-    'pairblock_tile': 'auto',
     # zero-dependency background HTTP thread serving the metrics
     # registry and SLO state as Prometheus text (/metrics), JSON
     # snapshots (/metrics.json, /slo) and the flight-recorder ring
@@ -220,6 +205,28 @@ class _Options(object):
 
 _global_options = _Options(_default_options)
 
+# kernel choices are constants: for these the read sites take the
+# value as it stands, and nothing is left to answer an 'auto'
+_NO_AUTO = ('mesh_dtype', 'a2a_compress', 'paint_method',
+            'paint_chunk_size', 'paint_deposit', 'paint_streams',
+            'fft_chunk_bytes', 'fft_decomp', 'ingest_chunk_rows',
+            'bspec_method', 'pairblock_tile')
+
+
+def _check_options(kwargs):
+    """Refuse what no read site could take: an unknown option, or
+    ``'auto'`` for an option the code does not decide itself."""
+    for key, value in kwargs.items():
+        if key not in _global_options:
+            raise KeyError('invalid option: %r (valid: %s)'
+                           % (key, sorted(_global_options)))
+        if key in _NO_AUTO and isinstance(value, str) \
+                and value == 'auto':
+            raise ValueError(
+                "%s='auto' is not a value: the choice is a constant, "
+                "%r by default; pass that or another concrete value"
+                % (key, _default_options[key]))
+
 
 class set_options(object):
     """Context manager / callable to set global framework options.
@@ -237,57 +244,41 @@ class set_options(object):
         deposits into bf16 replica meshes with an f32 compensated
         two-sum merge, readout and FFT entry re-widen to f32
         immediately; accuracy budget asserted in tests/
-        test_precision.py), or 'auto' (the tune-cache winner for this
-        platform/shape, falling back to 'f4').
+        test_precision.py).
     a2a_compress : str
         distributed-FFT ``all_to_all`` payload compression
         (parallel/dfft.py, both slab and pencil): 'none' (default),
         'bf16' (bfloat16 on the wire, f32 out — the payload is
-        re-widened immediately after the collective), 'int16'
+        re-widened immediately after the collective) or 'int16'
         (quantized payload + per-slab f32 scale factors riding
-        alongside), or 'auto' (tune-cache winner, falling back to
-        'none').  FFT butterflies always compute f32; only the wire
+        alongside).  FFT butterflies always compute f32; only the wire
         bytes halve.
     paint_chunk_size : int
         number of particles processed per chunk when streaming from host.
-    exchange_slack : float
-        capacity slack factor for the fixed-capacity particle exchange.
     resampler : str
         default window: 'nnb', 'cic', 'tsc', 'pcs'.
     paint_method : str
-        'scatter', 'sort', 'segsum', 'streams', 'mxu' — the local
-        deposit kernel — or 'auto': the measured winner recorded in
-        the tune cache for this platform/device/shape
-        (:mod:`nbodykit_tpu.tune`, docs/TUNE.md); a cold cache
-        resolves to 'scatter' at zero trial cost.
+        'scatter' (the default), 'sort', 'segsum', 'streams', 'mxu' —
+        the local deposit kernel.
     paint_bucket_slack : float
         bucket-capacity slack factor for the 'mxu' paint kernel.
-    paint_streams : int or 'auto'
+    paint_streams : int
         replica-mesh count for the 'streams' paint kernel — the number
         of independent scatter chains the s^3 window-offset streams
         are dealt onto (each replica is a full mesh buffer, counted by
-        ``memory_plan``); 'auto' consults the tune cache, falling
-        back to 4.
-    fft_chunk_bytes : int or 'auto'
+        ``memory_plan``); 4 by default.
+    fft_chunk_bytes : int
         single-device FFTs with complex output larger than this run as
-        slab-chunked per-axis passes (0 disables); 'auto' consults the
-        tune cache, falling back to 2**31 when cold.
+        slab-chunked per-axis passes (0 disables); 2**31 by default.
     fft_decomp : str
-        distributed-FFT decomposition: 'slab' (one P-way all_to_all
-        over the 1-D mesh), 'pencil' (two smaller transposes over a
-        2-D ``Mesh(('x','y'))`` — see parallel/dfft.py and
-        docs/PERF.md "Slab vs pencil"), or 'auto' (the tune-cache
-        winner for this platform, device count and (Px, Py)
-        factorization; a cold cache resolves to 'slab').
+        distributed-FFT decomposition: 'slab' (the default: one P-way
+        all_to_all over the 1-D mesh) or 'pencil' (two smaller
+        transposes over a 2-D ``Mesh(('x','y'))`` — see
+        parallel/dfft.py and docs/PERF.md "Slab vs pencil").
     fft_pencil : str, tuple or None
         explicit (Px, Py) device factorization for the pencil path
         ('4x2' or ``(4, 2)``); None picks the most nearly square
         factorization of the device count.
-    tune_cache : str or None
-        path of the performance database consulted by 'auto' options
-        and written by ``nbodykit-tpu-tune``; None (the default) uses
-        the committed repo-root ``TUNE_CACHE.json``.  Seeded from
-        ``$NBKIT_TUNE_CACHE``.
     diagnostics : str or None
         path of the telemetry sink (a directory, or a ``*.jsonl``
         file): enables the span tracer + metrics of
@@ -341,10 +332,7 @@ class set_options(object):
 
     def __init__(self, **kwargs):
         self.old = _global_options.copy()
-        for key in kwargs:
-            if key not in _global_options:
-                raise KeyError('invalid option: %r (valid: %s)'
-                               % (key, sorted(_global_options)))
+        _check_options(kwargs)
         _global_options.update(kwargs)
 
     def __enter__(self):
@@ -374,10 +362,7 @@ def option_scope(**overrides):
     degradation-ladder rung — outlives it.  The serving layer
     (:mod:`nbodykit_tpu.serve`) wraps every request in one.
     """
-    for key in overrides:
-        if key not in _global_options:
-            raise KeyError('invalid option: %r (valid: %s)'
-                           % (key, sorted(_global_options)))
+    _check_options(overrides)
     saved = _global_options.copy()
     _global_options.update(overrides)
     try:

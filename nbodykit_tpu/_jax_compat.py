@@ -21,7 +21,7 @@ def enable_compile_cache():
     and no path is set in code; otherwise the cache is
     ``<checkout>/.jax_cache`` (a fixed path: the path is part of the
     cache's key).  Every entry point — chip_smoke.py, bench.py, the
-    serve and tune CLIs, tests/conftest.py — calls this one helper."""
+    serve CLI, tests/conftest.py — calls this one helper."""
     cache = os.environ.get('JAX_COMPILATION_CACHE_DIR')
     if not cache:
         cache = os.path.join(os.path.dirname(os.path.dirname(

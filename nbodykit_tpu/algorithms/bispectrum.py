@@ -1,8 +1,8 @@
 """Bispectrum B(k1, k2, k3) in a periodic box — the hybrid FFT/direct
 higher-order estimator (ROADMAP item 2; docs/BISPECTRUM.md).
 
-Two estimators of the same statistic, selected per shape-class by the
-tuner (``bspec_method``), agreeing in their overlap k-band:
+Two estimators of the same statistic, selected by the
+``bspec_method`` option, agreeing in their overlap k-band:
 
 **FFT path** (low k) — the Scoccimarro triangle-count method.  With
 the repo's forward-normalized transform (``pmesh.r2c`` divides by
@@ -38,8 +38,8 @@ via the dense pairwise blocks of :mod:`..ops.pairblock` (the MXU
 shape), then host-side triangle combination over the enumerated
 integer-lattice shells with *true* (unwrapped) closure.  No mesh, no
 window, no aliasing — at high k this beats the FFT estimator's
-resolution requirements outright; the per-platform crossover is
-measured by the ``bspec`` tune space, never guessed.
+resolution requirements outright; where the two cross over on the
+chip is not measured.
 
 Shell convention shared by both paths: bin ``b`` covers
 ``|q| in [b+1, b+2)`` lattice units of the fundamental
@@ -241,12 +241,12 @@ def direct_bispectrum(pos, w, BoxSize, nbins, tile=None, comm=None):
 class Bispectrum(FFTBase):
     """B(k1, k2, k3) on unit-width k shells in a periodic box.
 
-    ``method`` is ``'fft'``, ``'direct'`` or ``'auto'`` — the latter
-    resolved through the tuner
-    (:func:`~nbodykit_tpu.tune.resolve.resolve_bispectrum`; cold cache
-    defaults to ``'fft'``).  The direct path requires a catalog source
-    (it sums over particles, not mesh cells); ``'auto'`` on a pure
-    mesh source resolves to ``'fft'``.
+    ``method`` is ``'fft'`` or ``'direct'``; ``None`` takes the
+    ``bspec_method`` option (``'fft'`` by default), as ``tile=None``
+    takes ``pairblock_tile``.  The direct path requires a catalog
+    source (it sums over particles, not mesh cells): asked for by
+    name on a mesh source it is an error, through the option it runs
+    ``'fft'``.
 
     Results land in :attr:`B`, a ``BinnedStatistic`` over
     ``(k1, k2, k3)`` with fields ``B`` and ``ntri`` (NaN outside the
@@ -256,10 +256,10 @@ class Bispectrum(FFTBase):
     logger = logging.getLogger('Bispectrum')
 
     def __init__(self, source, nbins=4, Nmesh=None, BoxSize=None,
-                 method='auto', tile=None):
-        if method not in ('auto', 'fft', 'direct'):
-            raise ValueError("method must be 'auto', 'fft' or "
-                             "'direct'")
+                 method=None, tile=None):
+        if method not in (None, 'fft', 'direct'):
+            raise ValueError("method must be 'fft' or 'direct' (None: "
+                             "the bspec_method option)")
         nbins = int(nbins)
         if nbins < 1:
             raise ValueError("nbins must be >= 1")
@@ -270,27 +270,12 @@ class Bispectrum(FFTBase):
             raise ValueError("the direct bispectrum path sums over "
                              "particles; pass a catalog source")
 
-        from ..parallel.runtime import mesh_size
         comm = getattr(source, 'comm', None)
-        nproc = mesh_size(comm)
-        npart = int(source.size) if is_catalog else None
-        nmesh_q = None
-        if Nmesh is not None:
-            nmesh_q = int(np.max(np.atleast_1d(Nmesh)))
-        elif 'Nmesh' in getattr(source, 'attrs', {}):
-            nmesh_q = int(np.max(np.atleast_1d(
-                source.attrs['Nmesh'])))
-
-        if method == 'auto' or tile is None:
-            from ..tune.resolve import resolve_bispectrum
-            cfg = resolve_bispectrum(nmesh=nmesh_q, npart=npart,
-                                     nproc=nproc)
-            if method == 'auto':
-                method = cfg['bspec_method']
-            if tile is None:
-                tile = cfg['pairblock_tile']
-        if method == 'direct' and not is_catalog:
-            method = 'fft'
+        if method is None:
+            from .. import _global_options
+            method = _global_options['bspec_method']
+            if method == 'direct' and not is_catalog:
+                method = 'fft'
 
         if method == 'direct':
             box = BoxSize if BoxSize is not None \

@@ -400,11 +400,6 @@ def _cast_source(source, BoxSize, Nmesh):
         # storage (compute stays f32; see pmesh.ParticleMesh)
         from .. import _global_options
         mdt = _global_options['mesh_dtype']
-        if mdt == 'auto':
-            from ..tune.resolve import resolve_mesh_dtype
-            mdt = resolve_mesh_dtype(
-                nmesh=None if Nmesh is None
-                else int(np.max(np.atleast_1d(Nmesh))))
         dtype = 'f8' if mdt in (None, 'f4') else mdt
         source = source.to_mesh(BoxSize=BoxSize, Nmesh=Nmesh,
                                 dtype=dtype, compensated=True)

@@ -229,11 +229,6 @@ class CatalogSourceBase(object):
                                  "to_mesh or set attrs['BoxSize']")
         if dtype is None:
             dtype = _global_options['mesh_dtype']
-            if dtype == 'auto':
-                # the tune cache's measured storage winner for this
-                # mesh class, else 'f4' (resolve.py cold-cache rule)
-                from ..tune.resolve import resolve_mesh_dtype
-                dtype = resolve_mesh_dtype(nmesh=Nmesh)
         return CatalogMesh(self, Nmesh=Nmesh, BoxSize=BoxSize, dtype=dtype,
                            interlaced=interlaced, compensated=compensated,
                            resampler=resampler, position=position,

@@ -34,11 +34,6 @@ them all.
         ROOT/lint_baseline.json when present.  Same exit contract as
         the ``nbodykit-tpu-lint`` console script.
 
-    python -m nbodykit_tpu.diagnostics --tune [ARGS...]
-        Forward to the autotuner CLI (``nbodykit-tpu-tune``): run the
-        measured trial plan, print it (``--dry-run``), or validate the
-        committed TUNE_CACHE.json (``--validate``).  See docs/TUNE.md.
-
     python -m nbodykit_tpu.diagnostics --doctor [--trace DIR] [--root R]
         Self-check + analyze + regress + lint, one verdict block.
         Compile-cache misses for a jit label that also carries an open
@@ -287,18 +282,16 @@ def run_doctor(trace=None, root='.', self_check_only=False,
 
     Returns 0 (OK/WARN) or 1 (FAIL).  FAIL means the diagnostics stack
     itself is broken, a trace shows a hung collective or silent
-    process, a committed bench record is malformed, the lint gate
-    has non-baselined findings, or TUNE_CACHE.json is malformed.
+    process, a committed bench record is malformed, or the lint gate
+    has non-baselined findings.
     WARN covers regressions, compile-cache misses
     whose jit label carries an open NBK2xx finding (the
     static/runtime cross-link), device live-byte watermarks past half
     a v5e's HBM while open NBK5xx (donation/peak) findings exist (the
-    same cross-link for memory), open NBK801/NBK803 host-concurrency
-    findings printed next to hung-collective / silent-process trace
-    evidence (the same cross-link for the threaded control plane),
-    and tune-cache entries measured on a different platform/device
-    kind than this host or older than 30 days — loud, but not
-    blocking.
+    same cross-link for memory), and open NBK801/NBK803
+    host-concurrency findings printed next to hung-collective /
+    silent-process trace evidence (the same cross-link for the
+    threaded control plane) — loud, but not blocking.
     """
     out = out if out is not None else sys.stdout
     lines, fail, warn = [], [], []
@@ -380,26 +373,10 @@ def run_doctor(trace=None, root='.', self_check_only=False,
                                                   'BENCH_HISTORY.json')))
             else:
                 lines.append('regress      OK: %s' % desc)
-            # a committed tune winner running a halved-bytes posture
-            # (bf16 mesh, compressed a2a) with no recorded P(k)
-            # accuracy margin is an unattested speedup — loud, not
-            # blocking
             prec = history.get('precision') or {}
-            if prec.get('unattested'):
-                warn.append('precision')
-                lines.append('precision    WARN: %d committed '
-                             'compressed winner(s) with no recorded '
-                             'P(k) margin vs the f32 oracle (%s) — '
-                             'run the precision gate '
-                             '(tests/test_precision.py writes '
-                             'PRECISION.json) before trusting the '
-                             'speedup'
-                             % (len(prec['unattested']),
-                                ', '.join(prec['unattested'])))
-            elif prec.get('margins'):
+            if prec.get('margins'):
                 lines.append('precision    OK: %d accuracy margin(s) '
-                             'on record, every committed compressed '
-                             'winner attested'
+                             'on record for the halved-bytes postures'
                              % len(prec['margins']))
 
     if root is not None and \
@@ -495,59 +472,6 @@ def run_doctor(trace=None, root='.', self_check_only=False,
                     'finding(s) — e.g. %s at %s:%d: %s'
                     % (dev, peak / 1e9, len(open_nbk5), f0.code,
                        f0.path, f0.line, f0.message))
-
-    if root is not None:
-        # tuner posture: is the performance database trustworthy for
-        # THIS host?  Entries measured on a different platform/device
-        # kind never steer dispatch (keys carry the signature), but
-        # their presence without same-platform coverage means 'auto'
-        # runs on defaults here; >30-day-old entries are evidence gone
-        # stale.  Both WARN — re-run nbodykit-tpu-tune to refresh.
-        from .regress import tune_summary
-        tune = tune_summary(root)
-        if tune is None:
-            lines.append('tune         SKIP: no TUNE_CACHE.json under '
-                         '%s (cold cache — \'auto\' options resolve '
-                         'to defaults; populate with '
-                         'nbodykit-tpu-tune)' % root)
-        elif 'error' in tune:
-            fail.append('tune')
-            lines.append('tune         FAIL: malformed '
-                         'TUNE_CACHE.json — %s' % tune['error'])
-        else:
-            try:
-                from ..tune.cache import device_signature
-                sig = device_signature()
-                here = '%s/%s' % (sig[0], sig[1])
-            except Exception:
-                here = None
-            foreign = [p for p in tune.get('platforms', [])
-                       if here is not None and p != here]
-            stale = tune.get('stale', 0)
-            desc = ('%d entr%s (%s), %d infeasible candidate(s)'
-                    % (tune['entries'],
-                       'y' if tune['entries'] == 1 else 'ies',
-                       ','.join(tune.get('platforms', [])) or '-',
-                       tune.get('infeasible', 0)))
-            if foreign or stale:
-                warn.append('tune')
-                bits = []
-                if foreign:
-                    bits.append('%d platform signature(s) differ from '
-                                'this host (%s)'
-                                % (len(foreign), here))
-                if stale:
-                    bits.append('%d entr%s older than %.0f days'
-                                % (stale,
-                                   'y' if stale == 1 else 'ies',
-                                   tune.get('stale_days', 30)))
-                lines.append('tune         WARN: %s — %s; re-run '
-                             'nbodykit-tpu-tune on this backend to '
-                             'refresh' % (desc, '; '.join(bits)))
-            else:
-                lines.append('tune         OK: %s, all measured on '
-                             'this platform within %.0f days'
-                             % (desc, tune.get('stale_days', 30)))
 
     if root is not None or trace:
         # resilience posture: what the supervisor did (retries /
@@ -1024,11 +948,6 @@ def main(argv=None):
                     help='run the shard-safety static analyzer over '
                          "ROOT's package (default .), gated on "
                          'ROOT/lint_baseline.json when present')
-    ap.add_argument('--tune', nargs=argparse.REMAINDER, default=None,
-                    metavar='ARGS',
-                    help='forward everything after --tune to the '
-                         'autotuner CLI (nbodykit-tpu-tune: trial '
-                         'runs, --dry-run plan, --validate gate)')
     ap.add_argument('--doctor', action='store_true',
                     help='self-check + analyze + regress, one verdict '
                          'block')
@@ -1040,10 +959,6 @@ def main(argv=None):
     ap.add_argument('--self-check-only', action='store_true',
                     help='restrict --doctor to the self-check')
     args = ap.parse_args(argv)
-
-    if args.tune is not None:
-        from ..tune.__main__ import main as tune_main
-        return tune_main(args.tune)
 
     if args.doctor or args.self_check_only:
         trace = args.trace if args.trace is not None \

@@ -208,23 +208,6 @@ def lint_summary(root):
         return {'error': str(e)}
 
 
-def tune_summary(root, now=None):
-    """Tuner posture for the round record: how many measured entries
-    the committed TUNE_CACHE.json carries, how many are stale (older
-    than the 30-day bar) or recorded infeasible candidates, and which
-    platform/device-kind signatures they were measured on — tracked
-    per round like a bench metric, so a decaying database is visible
-    in BENCH_HISTORY.json.  ``None`` when no cache file exists; never
-    raises."""
-    try:
-        from ..tune.cache import cache_summary
-        epoch = time.time() if now is None else now
-        return cache_summary(os.path.join(root, 'TUNE_CACHE.json'),
-                             now=epoch)
-    except Exception as e:      # pragma: no cover - defensive
-        return {'error': str(e)}
-
-
 def resilience_summary(root, now=None):
     """Resilience posture for the round record: how many committed
     records were produced by a resumed run, and whether checkpoints
@@ -682,35 +665,13 @@ def integrity_summary(root):
         return {'error': str(e)}
 
 
-# winner-option posture -> the margin key the precision harness
-# records in PRECISION.json (tests/test_precision.py and the smoke
-# precision gate both write through write_precision_margins)
-_MARGIN_KEYS = {('mesh_dtype', 'bf16'): 'mesh-bf16',
-                ('mesh_dtype', 'bfloat16'): 'mesh-bf16',
-                ('a2a_compress', 'bf16'): 'a2a-bf16',
-                ('a2a_compress', 'int16'): 'a2a-int16'}
-
-
-def _compressed_postures(options):
-    """Margin keys for every halved-bytes posture an options dict
-    carries ('' when it is the full-width default)."""
-    keys = []
-    for opt in ('mesh_dtype', 'a2a_compress'):
-        key = _MARGIN_KEYS.get((opt, str((options or {}).get(opt))))
-        if key:
-            keys.append(key)
-    return keys
-
-
 def write_precision_margins(margins, root='.', k_max='k_nyquist/2'):
     """Commit measured P(k) accuracy margins to ``PRECISION.json``
     (atomic).  ``margins`` maps margin key ('mesh-bf16' / 'a2a-bf16' /
     'a2a-int16') to ``{'max_rel_err': float, 'budget': float}``;
     existing keys are merged so the paint and fft gates can each
-    attest their own candidates.  This file is the evidence
-    :func:`precision_summary` pairs with committed tune-cache winners:
-    a compressed winner without a margin here is an unattested speedup
-    and the doctor WARNs on it."""
+    attest their own postures.  :func:`precision_summary` reads it
+    back for the round record."""
     path = os.path.join(root, PRECISION_NAME)
     try:
         with open(path) as f:
@@ -729,65 +690,24 @@ def write_precision_margins(margins, root='.', k_max='k_nyquist/2'):
 
 
 def precision_summary(root, now=None):
-    """Precision posture for the round record: which compressed
-    (halved-bytes) candidates the tuner actually raced this database,
-    the measured max P(k) relative error vs the f32 oracle each
-    posture has on record (``PRECISION.json``, written by the accuracy
-    harness up to k_Nyquist/2), and the storage/wire dtype of every
-    committed winner.  A committed winner running bf16 mesh storage or
-    compressed all_to_all payloads WITHOUT a recorded margin lands in
-    ``unattested`` — the doctor WARNs on it, because a speedup nobody
-    accuracy-gated is a liability, not a result.  ``None`` when
-    neither TUNE_CACHE.json nor PRECISION.json exists; never raises.
-    """
-    tc_path = os.path.join(root, 'TUNE_CACHE.json')
+    """Precision posture for the round record: the measured max P(k)
+    relative error vs the f32 oracle that each halved-bytes posture
+    (bf16 mesh storage, compressed all_to_all payloads) has on record
+    (``PRECISION.json``, written by the accuracy harness up to
+    k_Nyquist/2).  ``None`` when no PRECISION.json exists; never
+    raises."""
     pr_path = os.path.join(root, PRECISION_NAME)
-    if not os.path.exists(tc_path) and not os.path.exists(pr_path):
+    if not os.path.exists(pr_path):
         return None
     try:
-        margins, k_max = {}, None
-        if os.path.exists(pr_path):
-            try:
-                with open(pr_path) as f:
-                    doc = json.load(f)
-                margins = dict(doc.get('margins') or {})
-                k_max = doc.get('k_max')
-            except (OSError, ValueError) as e:
-                return {'error': 'PRECISION.json unreadable: %s' % e}
-        raced, winners, unattested = set(), [], []
-        try:
-            with open(tc_path) as f:
-                entries = json.load(f).get('entries') or {}
-        except (OSError, ValueError):
-            entries = {}
-        for entry in entries.values():
-            if not isinstance(entry, dict):
-                continue
-            for name, rec in (entry.get('trials') or {}).items():
-                if isinstance(rec, dict) and \
-                        _compressed_postures(rec.get('options')):
-                    raced.add(name)
-            winner = entry.get('winner')
-            if not isinstance(winner, dict):
-                continue
-            postures = _compressed_postures(winner)
-            win = {'op': entry.get('op'),
-                   'shape_class': entry.get('shape_class'),
-                   'name': entry.get('winner_name'),
-                   'postures': postures,
-                   'attested': all(k in margins for k in postures)}
-            winners.append(win)
-            if postures and not win['attested']:
-                unattested.append('%s/%s=%s' % (win['op'],
-                                                win['shape_class'],
-                                                win['name']))
-        out = {'raced': sorted(raced), 'margins': margins,
-               'winners': winners, 'unattested': unattested}
-        if k_max is not None:
-            out['k_max'] = k_max
-        return out
-    except Exception as e:      # pragma: no cover - defensive
-        return {'error': str(e)}
+        with open(pr_path) as f:
+            doc = json.load(f)
+    except (OSError, ValueError) as e:
+        return {'error': 'PRECISION.json unreadable: %s' % e}
+    out = {'margins': dict(doc.get('margins') or {})}
+    if doc.get('k_max') is not None:
+        out['k_max'] = doc['k_max']
+    return out
 
 
 def build_history(root='.', out=None, threshold=0.25, now=None,
@@ -803,7 +723,6 @@ def build_history(root='.', out=None, threshold=0.25, now=None,
         'threshold': threshold,
         'rounds': entries,
         'lint': lint_summary(root),
-        'tune': tune_summary(root, now=now),
         'resilience': resilience_summary(root, now=now),
         'fleet': fleet_summary(root, now=now),
         'serve': serve_summary(root),
@@ -1076,33 +995,11 @@ def render_regress(history):
                 % (k, v.get('max_rel_err', float('nan')),
                    v.get('budget', float('nan')))
                 for k, v in sorted(prec.get('margins', {}).items()))
-            w('  precision: %d compressed candidate(s) raced, %d '
-              'margin(s) on record%s%s'
-              % (len(prec.get('raced', [])),
-                 len(prec.get('margins', {})),
+            w('  precision: %d margin(s) on record%s'
+              % (len(prec.get('margins', {})),
                  ' vs f32 oracle to %s (%s)'
                  % (prec.get('k_max', '?'), attested)
-                 if attested else '',
-                 '; WARN — %d committed winner(s) running a halved-'
-                 'bytes posture with NO recorded P(k) margin: %s'
-                 % (len(prec['unattested']),
-                    ', '.join(prec['unattested']))
-                 if prec.get('unattested') else ''))
-    tune = history.get('tune')
-    if tune is not None:
-        if 'error' in tune:
-            w('  tune: MALFORMED cache (%s)' % tune['error'])
-        else:
-            w('  tune: %d entr%s in TUNE_CACHE.json (%s)%s%s'
-              % (tune['entries'],
-                 'y' if tune['entries'] == 1 else 'ies',
-                 ','.join(tune.get('platforms', [])) or '-',
-                 ', %d stale (>%.0f d)'
-                 % (tune['stale'], tune.get('stale_days', 30))
-                 if tune.get('stale') else '',
-                 ', %d infeasible candidate(s) recorded'
-                 % tune['infeasible'] if tune.get('infeasible')
-                 else ''))
+                 if attested else ''))
     lint = history.get('lint')
     if lint is not None:
         if 'error' in lint:

@@ -8,8 +8,8 @@ pipeline.  Layering:
 
   lpt.py      Zel'dovich + 2LPT displacements from the mockmaker linear
               field, via spectral gradient-of-inverse-Laplacian.
-  adjoint.py  grad-safe paint: native reverse mode where the tuned
-              winner supports it, an analytic ``jax.custom_vjp``
+  adjoint.py  grad-safe paint: native reverse mode where the paint
+              kernel supports it, an analytic ``jax.custom_vjp``
               (scatter's adjoint IS readout) where it does not.
   pm.py       kick-drift-kick PM stepper; ``ForwardModel`` is the
               modes -> density map the serve plane runs as traffic.

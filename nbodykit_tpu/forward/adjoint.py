@@ -2,7 +2,7 @@
 
 The compensated paint/readout pair is an adjoint pair — the VJP of
 scatter-add IS readout — so the backward pass of painting needs no new
-kernels.  What differs per tuned paint method is whether JAX's native
+kernels.  What differs per paint method is whether JAX's native
 reverse mode can trace the FORWARD:
 
   scatter          natively differentiable (.at[].add has a transpose
@@ -11,12 +11,12 @@ reverse mode can trace the FORWARD:
   sort / segsum /  forward is fine under jit but reverse mode either
   streams          fails to trace (sort's while_loop) or materializes
                    absurd residuals.  Wrapped in ``jax.custom_vjp``:
-                   winner kernel forward, analytic readout backward.
+                   that kernel forward, analytic readout backward.
   mxu              its traced overflow contract requires
                    return_dropped, which cannot live inside a silent
-                   custom_vjp forward — demoted via
-                   ``resolve_paint(differentiable=True)`` (source tag
-                   'grad-fallback', counter ``tune.grad_fallback``).
+                   custom_vjp forward — demoted to 'scatter' by
+                   :func:`resolve_forward_paint` (source tag
+                   'grad-fallback', counter ``forward.grad_fallback``).
 
 The analytic backward, for out = paint(pos, mass) and cotangent g:
 
@@ -30,72 +30,91 @@ derivative of the native path, so both modes agree wherever defined —
 asserted against finite differences in tests/test_forward.py.
 """
 
+import logging
+
 import numpy as np
 import jax
 import jax.numpy as jnp
 
-from .. import option_scope
-from ..tune.resolve import (resolve_paint, DIFFERENTIABLE_PAINT,
-                            GRAD_WRAPPED_PAINT)
+from .. import _global_options, option_scope
+from ..diagnostics import counter
+
+# paint kernels jax reverse mode differentiates natively: the scatter
+# chain is pure .at[].add / gather jnp ops whose VJP is the existing
+# readout
+DIFFERENTIABLE_PAINT = frozenset({'scatter'})
+# forward fine under jit, reverse mode not: wrapped by make_paint in a
+# custom_vjp pair (that kernel forward, readout-based backward)
+GRAD_WRAPPED_PAINT = frozenset({'sort', 'segsum', 'streams'})
 
 
-def resolve_forward_paint(pm, npart):
-    """Tuned paint config for a grad workload plus its adjoint mode.
+def grad_paint_method(method):
+    """The paint kernel a reverse-mode call runs when the options name
+    ``method``: itself where it has an adjoint story, else 'scatter'
+    (what admission prices for a ``Forward`` request)."""
+    if method in DIFFERENTIABLE_PAINT or method in GRAD_WRAPPED_PAINT:
+        return method
+    return 'scatter'
+
+
+def resolve_forward_paint(method=None):
+    """The paint options of a grad workload (``method`` in place of
+    the ``paint_method`` option, where given) plus their adjoint mode.
 
     Returns (cfg, mode) with mode in {'native', 'custom_vjp'}:
     'native' lets JAX reverse mode trace the kernel, 'custom_vjp'
     means :func:`make_paint` installs the analytic readout backward.
-    Cached winners without either story demote through the resolver's
-    grad fallback (never a trace error deep inside ``jax.grad``).
+    A ``paint_method`` with neither story ('mxu') is DEMOTED to
+    'scatter' — same one-chain deposit, natively adjoint via readout —
+    instead of tracing into a ``jax.grad`` error deep inside the
+    pipeline.  The demotion is never silent: ``source`` becomes
+    ``'grad-fallback'``, the method asked for stays in
+    ``winner_name``, the ``forward.grad_fallback`` counter bumps and a
+    one-line WARN is logged.
     """
-    kw = dict(nmesh=int(pm.Nmesh[0]), npart=int(npart),
-              dtype=str(np.dtype(pm.dtype)), nproc=pm.nproc)
-    cfg = resolve_paint(**kw)
-    method = cfg.get('paint_method', 'scatter')
-    if method in DIFFERENTIABLE_PAINT:
-        return cfg, 'native'
-    if method in GRAD_WRAPPED_PAINT:
-        return cfg, 'custom_vjp'
-    # mxu or unknown: ask the resolver for the grad-mode fallback.
-    cfg = resolve_paint(differentiable=True, **kw)
-    return cfg, 'native'
+    cfg = {k: _global_options[k] for k in
+           ('paint_method', 'paint_order', 'paint_deposit',
+            'paint_chunk_size', 'paint_streams')}
+    cfg['source'] = 'explicit'
+    if method is not None:
+        cfg['paint_method'] = method
+    asked = cfg['paint_method']
+    method = grad_paint_method(asked)
+    if method != asked:
+        cfg.update(paint_method=method, source='grad-fallback',
+                   winner_name=asked)
+        counter('forward.grad_fallback').add(1)
+        logging.getLogger('nbodykit_tpu.forward').warning(
+            "grad-mode paint: demoting %r (not differentiable) to %r "
+            "for this call (forward.grad_fallback)", asked, method)
+    return cfg, ('native' if method in DIFFERENTIABLE_PAINT
+                 else 'custom_vjp')
 
 
 def make_paint(pm, npart, resampler='cic', method=None):
     """Build a differentiable ``paint(pos, mass=1.0) -> mesh`` over
-    ``pm`` for ``npart`` particles, pinned to the tuned kernel.
+    ``pm`` for ``npart`` particles, pinned to the paint options as
+    they stand now.
 
-    The resolved paint options are captured eagerly and re-applied via
-    ``option_scope`` around every call, so resolution inside a
-    ``jax.grad``/``jit`` trace is deterministic regardless of ambient
-    options.  Returns (paint_fn, cfg); cfg['adjoint_mode'] records the
-    contract chosen by :func:`resolve_forward_paint`.
+    The paint options are captured eagerly and re-applied via
+    ``option_scope`` around every call, so what a ``jax.grad``/``jit``
+    trace reads does not move with the ambient options.  Returns
+    (paint_fn, cfg); cfg['adjoint_mode'] records the contract chosen
+    by :func:`resolve_forward_paint`.
 
-    ``method`` pins a specific paint kernel instead of consulting the
-    tuner (tests use this to exercise the custom_vjp path directly);
-    a method with no adjoint story ('mxu') is a ValueError here —
-    only the RESOLVER may silently demote.
+    ``method`` pins a specific paint kernel instead of the
+    ``paint_method`` option (tests use this to exercise the custom_vjp
+    path directly); a method with no adjoint story ('mxu') is a
+    ValueError here — only :func:`resolve_forward_paint` may demote.
     """
-    if method is not None:
-        cfg = dict(resolve_paint(nmesh=int(pm.Nmesh[0]),
-                                 npart=int(npart),
-                                 dtype=str(np.dtype(pm.dtype)),
-                                 nproc=pm.nproc),
-                   paint_method=method, source='explicit')
-        if method in DIFFERENTIABLE_PAINT:
-            mode = 'native'
-        elif method in GRAD_WRAPPED_PAINT:
-            mode = 'custom_vjp'
-        else:
-            raise ValueError(
-                "paint method %r has no adjoint contract; use the "
-                "resolver (method=None) for the grad fallback" % method)
-    else:
-        cfg, mode = resolve_forward_paint(pm, npart)
+    if method is not None and grad_paint_method(method) != method:
+        raise ValueError(
+            "paint method %r has no adjoint contract; use the "
+            "resolver (method=None) for the grad fallback" % method)
+    cfg, mode = resolve_forward_paint(method)
     cfg = dict(cfg, adjoint_mode=mode)
     opts = {k: cfg[k] for k in
-            ('paint_method', 'paint_chunk_size', 'paint_streams')
-            if k in cfg and cfg[k] is not None}
+            ('paint_method', 'paint_chunk_size', 'paint_streams')}
     cdt = jnp.dtype(pm.compute_dtype)
 
     def _run(pos, mass):

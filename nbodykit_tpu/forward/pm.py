@@ -36,7 +36,7 @@ host-side table, so the traced program still sees static per-step
 prefactors.  ``ForwardModel(omega_m=1)`` (the default) keeps the EdS
 closed forms bit-for-bit.
 
-``ForwardModel`` packages lattice + force mesh + tuned grad-safe paint
+``ForwardModel`` packages lattice + force mesh + grad-safe paint
 (adjoint.make_paint) into the modes -> density map the serve plane
 runs as a ``Forward`` request; ``jax.grad`` through
 ``ForwardModel.density`` is the backward pass every field-level

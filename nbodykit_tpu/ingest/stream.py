@@ -159,9 +159,8 @@ class DataRef(object):
 
 class ArraySource(FileType):
     """An in-memory FileType over named host arrays — the whole-load
-    reference the bit-identity tests stream against, and the tuner's
-    disk-free trial source.  Same ``read``/``read_chunks`` contract as
-    every on-disk reader."""
+    reference the bit-identity tests stream against.  Same
+    ``read``/``read_chunks`` contract as every on-disk reader."""
 
     def __init__(self, columns):
         names = list(columns)
@@ -206,19 +205,13 @@ def probe_ref(ref):
             'columns': cols}
 
 
-def resolve_chunk_rows(npart=None, nproc=1, chunk_rows=None):
-    """The concrete streaming window: an explicit value wins, then the
-    ``ingest_chunk_rows`` option (``'auto'`` consults the tune cache
-    keyed by the part-count shape class, falling back to the cold
-    default)."""
-    if chunk_rows is not None:
-        return max(int(chunk_rows), 1)
-    from .. import _global_options
-    v = _global_options['ingest_chunk_rows']
-    if not isinstance(v, bool) and isinstance(v, (int, float)):
-        return max(int(v), 1)
-    from ..tune.resolve import resolve_ingest_chunk_rows
-    return resolve_ingest_chunk_rows(npart=npart, nproc=nproc)
+def resolve_chunk_rows(chunk_rows=None):
+    """The streaming window: an explicit value wins, then the
+    ``ingest_chunk_rows`` option."""
+    if chunk_rows is None:
+        from .. import _global_options
+        chunk_rows = _global_options['ingest_chunk_rows']
+    return max(int(chunk_rows), 1)
 
 
 def _mesh_of(pm):
@@ -389,8 +382,7 @@ def ingest_catalog(ref, pm, resampler=None, chunk_rows=None,
     mesh = _mesh_of(pm)
     ndev = mesh_size(mesh)
     nproc = max(ndev, 1)
-    chunk_rows = resolve_chunk_rows(npart=f.size, nproc=nproc,
-                                    chunk_rows=chunk_rows)
+    chunk_rows = resolve_chunk_rows(chunk_rows)
     if overlap is None:
         overlap = bool(_global_options['ingest_overlap'])
     layout, shard_fns = _catalog_layout(f, cols, chunk_rows, mesh,

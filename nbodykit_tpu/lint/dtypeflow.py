@@ -7,7 +7,7 @@ may accumulate, and every silent demotion spends budget nobody
 accounted for.  The runtime cannot catch these cheaply — a bf16
 all_to_all result consumed as-is produces numbers that are merely
 *slightly* wrong.  This pass proves where the budget is spent,
-statically, before any bf16 candidate races in the tuner.
+statically.
 
 **The dtype lattice.**  Values carry a canonical dtype fact —
 ``float64 > float32 > bfloat16/float16`` and the int width family

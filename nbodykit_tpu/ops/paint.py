@@ -423,7 +423,7 @@ def paint_local_segsum(pos, mass, shape, resampler='cic', period=None,
     order_method : stable ordering engine for the one rank —
         'argsort', 'radix' (:func:`~nbodykit_tpu.ops.radix.
         stable_key_order` over the [0, M) cell alphabet), or 'auto'
-        (the hardware heuristic). The tuner's ``paint_order`` knob.
+        (the hardware heuristic). The ``paint_order`` option.
 
     Semantics (global cell units, ``origin``/``period``, out-of-block
     masking) match :func:`paint_local` exactly; equivalence is
@@ -485,12 +485,10 @@ def paint_local_streams(pos, mass, shape, resampler='cic', period=None,
     The price is k-1 extra mesh-sized buffers — replicas count as full
     mesh units in the NBK5xx symbolic-peak model, so
     :meth:`~nbodykit_tpu.pmesh.ParticleMesh.memory_plan` grows
-    ``paint_tmp`` by k mesh units and the tuner space
-    (tune/space.py) only admits stream counts whose 1024^3 staged
-    ladder stays inside the 0.85xHBM budget.
+    ``paint_tmp`` by k mesh units.
 
-    streams : number of replica meshes (the tuner's ``paint_streams``
-        knob; clamped to [1, s^3] — k=1 degenerates to
+    streams : number of replica meshes (the ``paint_streams``
+        option; clamped to [1, s^3] — k=1 degenerates to
         :func:`paint_local`'s chain).
     chunk : particles per scatter pass, as in :func:`paint_local`
         (the replica tuple is the fori_loop carry).
@@ -630,7 +628,7 @@ def _bucket_by_argsort(key, n, B, Kcap, order_method='auto'):
 def paint_local_mxu(pos, mass, shape, resampler='cic', period=None,
                     origin=0, out=None, rb=8, cb=8, slack=2.0,
                     return_overflow=False, zchunk_bytes=ZCHUNK_BYTES,
-                    order_method='auto', deposit='auto'):
+                    order_method='auto', deposit='xla'):
     """Scatter particles onto a local mesh block via MXU matmuls.
 
     TPU has no scatter atomics and XLA lowers scatter-add to a serial
@@ -666,26 +664,13 @@ def paint_local_mxu(pos, mass, shape, resampler='cic', period=None,
     slack : bucket capacity = slack * mean occupancy. Overflowing
         particles are DROPPED (count returned with
         ``return_overflow=True``); callers retry with doubled slack.
-    deposit : 'xla' (one-hot expansions materialized by XLA),
+    deposit : 'xla' (one-hot expansions materialized by XLA) or
         'pallas' (fused VMEM kernel, ops/paint_pallas.py — interpreted
-        off-TPU), or 'auto': cache-then-fallback resolution
-        (nbodykit_tpu.tune, docs/TUNE.md) — the measured winner's
-        deposit engine when the tune cache holds a paint entry for
-        this platform/shape (nearest shape class otherwise), falling
-        back to 'xla' (the proven-everywhere engine) on a cold cache
-        at zero trial cost.  ``nbodykit-tpu-tune`` populates the
-        cache offline; until a run commits a 'pallas' win there, the
-        resolution is byte-identical to the old hard-coded 'xla'.
+        off-TPU).
     """
-    if deposit == 'auto':
-        from ..tune.resolve import resolve_paint_deposit
-        deposit = resolve_paint_deposit(
-            nmesh=int(period[0]) if period is not None
-            else int(shape[0]),
-            npart=int(pos.shape[0]))
     if deposit not in ('xla', 'pallas'):
-        raise ValueError("unknown deposit %r (choose "
-                         "'auto'/'xla'/'pallas')" % (deposit,))
+        raise ValueError("unknown deposit %r (choose 'xla'/'pallas')"
+                         % (deposit,))
     n0l, N1, N2 = (int(x) for x in shape)
     if period is None:
         period = shape

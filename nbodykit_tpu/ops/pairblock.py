@@ -21,9 +21,9 @@ the MXU on purpose:
   matmul (a (1, tile_p) x (tile_p, tile_k) GEMV batch).
 
 Both ride the systolic array; only O(tile_p x tile_k) intermediates
-are ever live (the ``pairblock_tile`` knob raced by the ``bspec`` tune
-space bounds them).  The blocked-accumulate structure — fori_loop over
-tiles, dynamic_slice in, dynamic_update_slice out — is the idiom of
+are ever live (the ``pairblock_tile`` option bounds them).  The
+blocked-accumulate structure — fori_loop over tiles, dynamic_slice
+in, dynamic_update_slice out — is the idiom of
 ``algorithms/threeptcf.py``; the distributed driver shards particles
 over the 1-D device mesh and ``psum``s the (small) mode vector, so no
 device ever holds the full catalog.
@@ -101,8 +101,7 @@ def pairblock_sum(pos, w, kvecs, tile=None, comm=None):
     w : (Np,) weights
     kvecs : (Nk, 3) wavevectors (host numpy or jnp)
     tile : tile edge for both the particle and mode axes; ``None``
-        resolves ``pairblock_tile`` through the tuner
-        (:func:`~nbodykit_tpu.tune.resolve.resolve_bispectrum`).
+        takes the ``pairblock_tile`` option.
     comm : optional 1-D device mesh; when given, particles are sharded
         over it and the mode vector is ``psum``-reduced — each device
         runs the identical tiled program on its slab of the catalog.
@@ -116,11 +115,8 @@ def pairblock_sum(pos, w, kvecs, tile=None, comm=None):
     kvecs = jnp.asarray(kvecs, dtype=pos.dtype)
     Nk = int(kvecs.shape[0])
     if tile is None:
-        from ..tune.resolve import resolve_bispectrum
-        tile = resolve_bispectrum(
-            npart=int(pos.shape[0]),
-            dtype=jnp.dtype(pos.dtype).name,
-            nproc=mesh_size(comm))['pairblock_tile']
+        from .. import _global_options
+        tile = _global_options['pairblock_tile']
     tile = max(int(tile), 8)
 
     nproc = mesh_size(comm)

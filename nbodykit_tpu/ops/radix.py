@@ -121,7 +121,7 @@ def stable_order(key, D):
 
 def order_keys(key, D, method='auto'):
     """Stable ordering with an EXPLICIT engine choice — the dispatch
-    behind the tuner's ``paint_order`` knob (ops/paint.py bucketing
+    behind the ``paint_order`` option (ops/paint.py bucketing
     and the one-sort deposit kernels).
 
     method : 'argsort' (one bitonic lax sort — O(n log^2 n) HBM passes

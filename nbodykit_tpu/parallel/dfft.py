@@ -52,9 +52,9 @@ axis (Nc = N2//2+1) is zero-padded to a multiple of Py before the inner
 transpose; the pad columns stay exactly zero through the remaining
 (linear) stages and are sliced off the output. Output layout and
 normalization are identical to the slab path, so the two decompositions
-are interchangeable per call. Selection is a tuned knob
-(``set_options(fft_decomp='slab'|'pencil'|'auto')``) resolved at
-dispatch in :class:`dist_fft_plan`.
+are interchangeable per call. Selection is an option
+(``set_options(fft_decomp='slab'|'pencil')``) read at dispatch in
+:class:`dist_fft_plan`.
 """
 
 import time as _time
@@ -94,42 +94,23 @@ def _fft_operand(x):
     return jax.lax.optimization_barrier(x)
 
 
-def _fft_chunk_bytes(shape=None, dtype=None, mesh_shape=None):
-    """The effective chunking target.  An integer option is used
-    verbatim; ``'auto'`` resolves through the tune cache
-    (nbodykit_tpu.tune — the measured winner for the nearest mesh
-    class on this platform, else the 2**31 default at zero trial
-    cost).  ``shape``/``dtype`` of the field being transformed sharpen
-    the cache lookup when the caller has them; ``mesh_shape`` is the
-    (Px, Py) pencil factorization when one is in play, so a winner
-    measured on a 4x2 mesh is never replayed onto 8x1 (the shape-class
-    key includes the factorization — see tune/cache.py)."""
+def _fft_chunk_bytes():
+    """The chunking target, the ``fft_chunk_bytes`` option (0: no
+    chunking)."""
     from .. import _global_options
-    v = _global_options['fft_chunk_bytes']
-    if not isinstance(v, bool) and isinstance(v, (int, float)):
-        return int(v)
-    from ..tune.resolve import resolve_fft_chunk_bytes
-    return resolve_fft_chunk_bytes(shape=shape, dtype=dtype or 'f4',
-                                   mesh_shape=mesh_shape)
+    return int(_global_options['fft_chunk_bytes'])
 
 
-def _a2a_mode(shape=None, dtype=None, mesh_shape=None):
-    """The resolved ``a2a_compress`` wire format for the next
-    transform: 'none' (f32/f64 complex payload, today's behavior),
-    'bf16' (half-width planes on the wire, re-widened on receipt) or
-    'int16' (quantized planes with per-source-shard scale factors).
-    ``'auto'`` consults the tune cache like
-    :func:`_fft_chunk_bytes` does; resolution happens here, at
-    closure-build/trace time, so the compiled program carries one
-    concrete format."""
+def _a2a_mode():
+    """The ``a2a_compress`` wire format of the next transform: 'none'
+    (f32/f64 complex payload), 'bf16' (half-width planes on the wire,
+    re-widened on receipt) or 'int16' (quantized planes with
+    per-source-shard scale factors).  Read here, at closure-build /
+    trace time, so the compiled program carries one format."""
     from .. import _global_options
     v = _global_options['a2a_compress']
     if v in (None, False, 'none'):
         return 'none'
-    if v == 'auto':
-        from ..tune.resolve import resolve_a2a_compress
-        return resolve_a2a_compress(shape=shape, dtype=dtype or 'f4',
-                                    mesh_shape=mesh_shape)
     return str(v)
 
 
@@ -416,7 +397,7 @@ def rfftn_single_lowmem(x_box, norm=None, target=None):
     else:
         x = x_box
     if target is None:
-        target = _fft_chunk_bytes(x.shape, x.dtype) or 2 ** 31
+        target = _fft_chunk_bytes() or 2 ** 31
     progs = _lowmem_programs(x.shape, str(x.dtype), norm, int(target))
     r0, r1, zeros_y, zeros_out, slab_a, upd_a, slab_b, upd_b = progs
     N0, N1, _ = x.shape
@@ -447,7 +428,7 @@ def irfftn_single_lowmem(y_box, Nmesh2, norm=None, target=None):
     one-element list; ~2 full-mesh buffers peak)."""
     y = y_box.pop() if isinstance(y_box, list) else y_box
     if target is None:
-        target = _fft_chunk_bytes(y.shape, y.dtype) or 2 ** 31
+        target = _fft_chunk_bytes() or 2 ** 31
     progs = _lowmem_inv_programs(y.shape, str(y.dtype), int(Nmesh2),
                                  norm, int(target))
     r1, r0, zeros_z, zeros_out, slab_a, upd_a, slab_b, upd_b = progs
@@ -645,7 +626,7 @@ def fftn_c2c_single_lowmem(x_box, inverse=False, norm=None,
     docs/RESILIENCE.md).  Not traceable: call outside jit."""
     x = x_box.pop() if isinstance(x_box, list) else x_box
     if target is None:
-        target = _fft_chunk_bytes(x.shape, x.dtype) or 2 ** 31
+        target = _fft_chunk_bytes() or 2 ** 31
     progs = _lowmem_c2c_programs(x.shape, str(x.dtype), bool(inverse),
                                  norm, int(target))
     loops, stages, zeros_mid, zeros_out, slab_a, upd_a, slab_b, upd_b \
@@ -922,8 +903,7 @@ def _pencil_run(x, mesh, norm, kind, Nz_out=None):
     into the caller's graph (donation and spans are the trace's
     concern there)."""
     px, py = _pencil_shape(mesh)
-    target = _fft_chunk_bytes(x.shape, x.dtype, mesh_shape=(px, py)) \
-        or 2 ** 31
+    target = _fft_chunk_bytes() or 2 ** 31
     eager = is_eager(x)
     # integrity posture + chaos injection resolve at dispatch: each
     # stage's a2a is one 'a2a.payload' injection consult, and guard
@@ -932,7 +912,7 @@ def _pencil_run(x, mesh, norm, kind, Nz_out=None):
     bits1 = _corrupt_bits() if eager else 0
     bits2 = _corrupt_bits() if eager else 0
     chk = eager and _integrity_on()
-    a2a = _a2a_mode(x.shape, x.dtype, mesh_shape=(px, py))
+    a2a = _a2a_mode()
     nglobal = int(x.size)
     s1, s2, j1, j2, pad = _pencil_programs(
         mesh, tuple(int(n) for n in x.shape), str(x.dtype), norm, kind,
@@ -1058,7 +1038,7 @@ def _slab_run(x, mesh, norm, kind, n_out=None):
     and are eager-only (a data-dependent raise cannot live under
     trace), as in :func:`_pencil_run`."""
     eager = is_eager(x)
-    a2a = _a2a_mode(x.shape, x.dtype)
+    a2a = _a2a_mode()
     bits = _corrupt_bits() if eager else 0
     chk = eager and _integrity_on()
     raw, jitted = _slab_programs(
@@ -1125,7 +1105,7 @@ def _dist_rfftn_impl(x, mesh, norm):
             lambda m: _dist_rfftn_impl(x, m, norm))
     if nproc == 1:
         N0, N1, N2 = x.shape
-        target = _fft_chunk_bytes(x.shape, x.dtype)
+        target = _fft_chunk_bytes()
         out_bytes = N0 * N1 * (N2 // 2 + 1) * (
             8 if x.dtype.itemsize <= 4 else 16)
         if target and out_bytes > target:
@@ -1178,7 +1158,7 @@ def _dist_irfftn_impl(y, Nmesh2, mesh, norm):
             lambda: _pencil_run(y, mesh, norm, 'c2r', Nz_out=Nmesh2),
             lambda m: _dist_irfftn_impl(y, Nmesh2, m, norm))
     if nproc == 1:
-        target = _fft_chunk_bytes(y.shape, y.dtype)
+        target = _fft_chunk_bytes()
         if target and y.nbytes > target:
             if not isinstance(y, jax.core.Tracer):
                 box = [y]
@@ -1272,7 +1252,7 @@ def _dist_fftn_c2c_impl(x, mesh, inverse, norm):
             lambda: _pencil_run(x, mesh, norm, kind),
             lambda m: _dist_fftn_c2c_impl(x, m, inverse, norm))
     if nproc == 1:
-        target = _fft_chunk_bytes(x.shape, x.dtype)
+        target = _fft_chunk_bytes()
         if target and x.nbytes > target:
             if not isinstance(x, jax.core.Tracer):
                 # eager call on a concrete field (convpower's Ylm loop
@@ -1294,7 +1274,7 @@ def _dist_fftn_c2c_impl(x, mesh, inverse, norm):
 
 def _parse_pencil(v):
     """Parse an fft_pencil option value: 'PXxPY', (px, py) or None."""
-    if v in (None, '', 'auto'):
+    if v in (None, ''):
         return None
     if isinstance(v, str):
         px, _, py = v.lower().partition('x')
@@ -1303,16 +1283,12 @@ def _parse_pencil(v):
     return int(px), int(py)
 
 
-def resolve_decomp(nproc, shape=None, dtype=None, decomp=None,
-                   pencil=None):
-    """Resolve the fft_decomp knob to ('slab'|'pencil', (Px, Py)).
+def resolve_decomp(nproc, decomp=None, pencil=None):
+    """The fft_decomp knob as ('slab'|'pencil', (Px, Py)).
 
     Explicit arguments win over ``set_options(fft_decomp=...)`` /
-    ``set_options(fft_pencil=...)``; ``'auto'`` consults the tune cache
-    for this platform's measured winner at the factorization that WOULD
-    run (so a winner measured on 4x2 never steers an 8x1 request —
-    the shape class carries the factorization), falling back to 'slab'
-    on a cold cache. Returns ('slab', None) for nproc <= 1.
+    ``set_options(fft_pencil=...)``. Returns ('slab', None) for
+    nproc <= 1.
     """
     if nproc <= 1:
         return 'slab', None
@@ -1327,15 +1303,9 @@ def resolve_decomp(nproc, shape=None, dtype=None, decomp=None,
         raise ValueError(
             "fft_pencil %dx%d does not cover %d devices" %
             (pxpy[0], pxpy[1], nproc))
-    if decomp == 'auto':
-        from ..tune.resolve import resolve_fft_decomp
-        decomp, won = resolve_fft_decomp(
-            shape=shape, dtype=dtype or 'f4', nproc=nproc,
-            mesh_shape=pxpy)
-        pxpy = won or pxpy
     if decomp not in ('slab', 'pencil'):
-        raise ValueError("fft_decomp must be 'slab', 'pencil' or "
-                         "'auto', got %r" % (decomp,))
+        raise ValueError("fft_decomp must be 'slab' or 'pencil', "
+                         "got %r" % (decomp,))
     return decomp, pxpy
 
 
@@ -1343,26 +1313,25 @@ class dist_fft_plan(object):
     """A small plan object bundling mesh + shape, so call sites read like
     the reference's ``field.r2c()`` / ``field.c2r()``.
 
-    The slab-vs-pencil decomposition is resolved *at dispatch*, per
-    call: ``set_options(fft_decomp='pencil')`` (or ``'auto'`` once the
-    tuner has measured this platform) reroutes the next transform
-    through the 2-D pencil path with no plan rebuild. An explicit 2-D
-    mesh handed to the plan wins outright; a 1-D mesh is viewed as its
-    (Px, Py) pencil factorization on demand (same devices, row-major
-    order, so slab- and pencil-sharded fields interconvert without
-    data movement).
+    The slab-vs-pencil decomposition is read *at dispatch*, per
+    call: ``set_options(fft_decomp='pencil')`` reroutes the next
+    transform through the 2-D pencil path with no plan rebuild. An
+    explicit 2-D mesh handed to the plan wins outright; a 1-D mesh is
+    viewed as its (Px, Py) pencil factorization on demand (same
+    devices, row-major order, so slab- and pencil-sharded fields
+    interconvert without data movement).
     """
 
     def __init__(self, Nmesh, mesh=None, decomp=None, pencil=None):
         self.Nmesh = tuple(int(n) for n in Nmesh)
         self.mesh = mesh
-        self._decomp = decomp    # explicit override ('slab'|'pencil'|'auto')
+        self._decomp = decomp    # explicit override ('slab'|'pencil')
         self._pencil = pencil    # explicit (Px, Py) or 'PXxPY' override
         self._pencil_cache = {}  # (Px, Py) -> 2-D mesh view
 
-    def _dispatch_mesh(self, shape, dtype):
-        """The mesh the next transform runs on, after resolving the
-        fft_decomp knob (see :func:`resolve_decomp`)."""
+    def _dispatch_mesh(self):
+        """The mesh the next transform runs on, by the fft_decomp
+        knob (see :func:`resolve_decomp`)."""
         mesh = self.mesh
         if mesh is None or is_pencil(mesh):
             return mesh
@@ -1370,8 +1339,7 @@ class dist_fft_plan(object):
         if nproc == 1:
             return mesh
         decomp, pxpy = resolve_decomp(
-            nproc, shape=shape, dtype=dtype,
-            decomp=self._decomp, pencil=self._pencil)
+            nproc, decomp=self._decomp, pencil=self._pencil)
         if decomp != 'pencil':
             return mesh
         if pxpy not in self._pencil_cache:
@@ -1380,15 +1348,12 @@ class dist_fft_plan(object):
         return self._pencil_cache[pxpy]
 
     def r2c(self, x, norm=None):
-        return dist_rfftn(x, self._dispatch_mesh(x.shape, x.dtype),
-                          norm=norm)
+        return dist_rfftn(x, self._dispatch_mesh(), norm=norm)
 
     def c2r(self, y, norm=None):
-        return dist_irfftn(y, self.Nmesh[2],
-                           self._dispatch_mesh(self.Nmesh, y.dtype),
+        return dist_irfftn(y, self.Nmesh[2], self._dispatch_mesh(),
                            norm=norm)
 
     def c2c(self, x, inverse=False, norm=None):
-        return dist_fftn_c2c(x, self._dispatch_mesh(self.Nmesh,
-                                                    x.dtype),
+        return dist_fftn_c2c(x, self._dispatch_mesh(),
                              inverse=inverse, norm=norm)

@@ -360,23 +360,11 @@ class ParticleMesh(object):
         """Slab owner per particle: :func:`_slab_owner` on this mesh."""
         return _slab_owner(cpos, int(self.Nmesh[0]), self.nproc)
 
-    def _paint_config(self, npart):
-        """The effective paint-kernel configuration for one call:
-        current options with every ``'auto'`` resolved through the
-        tune cache (:mod:`nbodykit_tpu.tune` — measured winner for
-        this platform/device-count/shape when one exists, today's
-        defaults otherwise, zero trial overhead either way)."""
-        from .tune.resolve import resolve_paint
-        return resolve_paint(nmesh=int(self.Nmesh[0]), npart=int(npart),
-                             dtype=self.dtype, nproc=self.nproc)
-
     def exchange_capacity(self, pos, slack=1.05, shift=0.0):
         """Two-pass counted exchange, pass 1 (run EAGERLY): the exact
         per-(src,dst) routing count for these positions, with slack,
         on its rung of the capacity ladder
         (:func:`~nbodykit_tpu.parallel.exchange.ladder_capacity`).
-        ``slack='auto'`` consults the tune cache (exchange op) and
-        falls back to 1.05 when cold.
 
         Pass the result as ``capacity=`` to a *traced* :meth:`paint` /
         :meth:`readout` (with ``return_dropped=True``) so the
@@ -393,9 +381,8 @@ class ParticleMesh(object):
         if self.nproc == 1:
             return int(pos.shape[0])
         if slack == 'auto':
-            from .tune.resolve import resolve_exchange_slack
-            slack = resolve_exchange_slack(npart=int(pos.shape[0]),
-                                           nproc=self.nproc)
+            raise ValueError("slack='auto' is not a value: pass a "
+                             "number (1.05 by default)")
         dest = self._route_dest(self._to_cell_units(pos) - shift)
         return auto_capacity(dest, self.nproc, slack=slack)
 
@@ -410,8 +397,10 @@ class ParticleMesh(object):
         mass : scalar or (N,) weights; slots with mass 0 are inert
         shift : float, cell units — paint onto a half-cell-shifted grid
             (used by interlacing, reference source/mesh/catalog.py:292)
-        capacity : per-(src,dst) exchange capacity; default derived from
-            particle count and the 'exchange_slack' option.
+        capacity : per-(src,dst) exchange capacity; by default the
+            counted bound at slack 1.05 on its rung of the ladder
+            (:func:`~nbodykit_tpu.parallel.exchange.ladder_capacity`)
+            eagerly, the always-sufficient ceil(N/P) under a trace.
         return_dropped : also return the exchange-overflow count so
             traced callers can check it after the step.
 
@@ -452,10 +441,7 @@ class ParticleMesh(object):
                 return self._paint_impl(pos, mass, resampler, out,
                                         shift, capacity, return_dropped)
         npart = int(pos.shape[0])
-        # the RESOLVED kernel labels the span/histograms — with
-        # paint_method='auto' the trace must show which kernel ran,
-        # not the sentinel
-        method = self._paint_config(npart)['paint_method']
+        method = _global_options['paint_method']
         t0 = time.perf_counter()
         with scope('paint', method=method, npart=npart,
                    nproc=self.nproc,
@@ -483,12 +469,11 @@ class ParticleMesh(object):
             boxsize=tuple(float(b) for b in self.BoxSize),
             nproc=self.nproc,
             compute_dtype=np.dtype(self.compute_dtype))
-        # 'auto' options resolve through the tune cache here, at
-        # dispatch time (cold cache -> today's defaults, no trials)
-        pcfg = self._paint_config(npart)
-        chunk = pcfg['paint_chunk_size']
-
-        pm_method = pcfg['paint_method']
+        pm_method = _global_options['paint_method']
+        chunk = int(_global_options['paint_chunk_size'])
+        order = _global_options['paint_order']
+        deposit = _global_options['paint_deposit']
+        nstreams = int(_global_options['paint_streams'])
         traced = isinstance(cpos, jax.core.Tracer)
         # tier-0 integrity posture + chaos injection resolve here, at
         # dispatch: both are eager-only (a data-dependent raise cannot
@@ -501,13 +486,6 @@ class ParticleMesh(object):
             from .resilience.integrity import checks_enabled
             cbits = corrupt_spec('paint.accum')
             chk = checks_enabled()
-        if traced and pm_method == 'mxu' and not return_dropped \
-                and pcfg['source'] != 'explicit':
-            # a tune-cache winner must not impose the traced-mxu
-            # overflow contract (return_dropped) on a caller who asked
-            # for 'auto': fall back to the contract-free scatter
-            # kernel for this call; only an EXPLICIT 'mxu' raises below
-            pm_method = 'scatter'
         if traced and pm_method == 'mxu' and not return_dropped:
             # same contract as an explicit exchange capacity: the mxu
             # bucket capacity is slack-sized, not provably sufficient,
@@ -522,10 +500,8 @@ class ParticleMesh(object):
                 "paint_method='scatter')")
 
         def make_kernel(mxu_slack):
-            return _paint_kernel(pm_method, chunk, pcfg['paint_order'],
-                                 pcfg['paint_deposit'],
-                                 pcfg['paint_streams'], self.dtype,
-                                 mxu_slack)
+            return _paint_kernel(pm_method, chunk, order, deposit,
+                                 nstreams, self.dtype, mxu_slack)
 
         mxu_slack = _global_options['paint_bucket_slack']
         if self.nproc == 1:
@@ -560,22 +536,14 @@ class ParticleMesh(object):
 
         self._check_halo(h)
         self._check_overflow_contract(capacity, traced, return_dropped)
-        nproc = self.nproc
 
         def attempt(cap, slack_val=None):
             recv, valid, dropped = exchange_by_dest(
                 dest, [cpos, massa], self.comm, cap)
-            dep = pcfg['paint_deposit']
-            if pm_method == 'mxu' and dep == 'auto':
-                # resolved here, as the kernel would on its device's
-                # slots, so that the program's key holds it
-                from .tune.resolve import resolve_paint_deposit
-                dep = resolve_paint_deposit(
-                    nmesh=N0, npart=int(valid.shape[0]) // nproc)
             from .utils import is_mxu_backend
             raw, jitted = _slab_paint_programs(
                 self.comm, (N0, N1, N2), resampler, pm_method, chunk,
-                pcfg['paint_order'], dep, pcfg['paint_streams'],
+                order, deposit, nstreams,
                 jnp.dtype(self.dtype), jnp.dtype(self.compute_dtype),
                 slack_val if slack_val is not None else mxu_slack,
                 is_mxu_backend())
@@ -864,7 +832,7 @@ def device_hbm_bytes(device):
     """The memory of ``device`` that plans and admission price
     against: what the device itself reports, else the
     :data:`HBM_BYTES` figure for its ``device_kind``.  Called once by
-    an entry point that owns a device (the server, the tuner,
+    an entry point that owns a device (the server,
     ``chip_smoke.py``); everything below takes ``hbm_bytes``."""
     limit = (device.memory_stats() or {}).get('bytes_limit')
     if limit:
@@ -1015,12 +983,6 @@ def memory_plan(Nmesh, npart, ndevices=1, dtype='f4', resampler='cic',
                         'ingest_chunk_buffers': ingest_buf}
     if paint_chunk is None:
         chunk = _global_options['paint_chunk_size']
-        if isinstance(chunk, bool) or not isinstance(chunk,
-                                                     (int, float)):
-            # 'auto' (tune-cache resolution): plan with the effective
-            # concrete value
-            from .tune.resolve import effective_int_option
-            chunk = effective_int_option('paint_chunk_size')
     else:
         chunk = paint_chunk
     live = min(npart / ndev, chunk)
@@ -1038,8 +1000,7 @@ def memory_plan(Nmesh, npart, ndevices=1, dtype='f4', resampler='cic',
         # breaking the scatter chain) next to the live chunk's
         # deposit terms
         if paint_streams is None:
-            from .tune.resolve import effective_int_option
-            paint_streams = effective_int_option('paint_streams')
+            paint_streams = _global_options['paint_streams']
         k = max(int(paint_streams), 1)
         # replicas are STORAGE dtype (bf16 halves THE dominant term
         # of this method); the live chunk's deposit terms compute f32
@@ -1113,8 +1074,7 @@ def memory_plan(Nmesh, npart, ndevices=1, dtype='f4', resampler='cic',
             # compute words, erring high on what XLA fuses) plus the
             # re/im accumulators over the enumerated lattice modes
             if pairblock_tile is None:
-                from .tune.resolve import effective_int_option
-                pairblock_tile = effective_int_option('pairblock_tile')
+                pairblock_tile = _global_options['pairblock_tile']
             t = max(int(pairblock_tile), 8)
             nk = 4.0 * np.pi / 3.0 * float(nb + 1) ** 3
             pair_b = 4.0 * t * t * citem

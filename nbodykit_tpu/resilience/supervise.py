@@ -117,19 +117,13 @@ class DegradationLadder(object):
 
 
 def _halve_option(option, floor):
-    """A ladder rung halving a global option (not below ``floor``).
-    An ``'auto'`` option halves from its tune-cache-resolved effective
-    value — and the rung pins it to the concrete result, so every
-    later attempt in this degraded run stays below the OOM point
-    instead of re-resolving back up."""
+    """A ladder rung halving a global option (not below ``floor``)
+    and pinning the result, so every later attempt in this degraded
+    run stays below the OOM point."""
     def apply():
         import nbodykit_tpu
         from .. import _global_options
-        from ..tune.resolve import effective_int_option
-        cur = _global_options[option]
-        if isinstance(cur, bool) or not isinstance(cur, (int, float)):
-            cur = effective_int_option(option)
-        cur = int(cur)
+        cur = int(_global_options[option])
         new = max(int(floor), cur // 2)
         nbodykit_tpu.set_options(**{option: new})
         return {option: new, 'was': cur}
@@ -160,15 +154,14 @@ def default_ladder():
 def _halve_scoped(opts, option, floor):
     """A ladder rung halving an option INSIDE a caller-owned mapping
     (not below ``floor``).  The first step seeds from the mapping's
-    current value when present, else from the tune-cache-resolved
-    effective value — same pinning discipline as :func:`_halve_option`
-    but with zero writes to the process-wide options."""
+    current value when present, else from the option as it stands —
+    same pinning discipline as :func:`_halve_option` but with zero
+    writes to the process-wide options."""
     def apply():
-        from ..tune.resolve import effective_int_option
+        from .. import _global_options
         cur = opts.get(option)
-        if cur is None or isinstance(cur, bool) \
-                or not isinstance(cur, (int, float)):
-            cur = effective_int_option(option)
+        if cur is None:
+            cur = _global_options[option]
         cur = int(cur)
         new = max(int(floor), cur // 2)
         opts[option] = new

@@ -16,9 +16,8 @@ admission-controlled, multi-tenant analysis server.
   resilience ladder, or reject with a structured reason.
 - :mod:`.scheduler` — cache-affine placement onto
   :meth:`~nbodykit_tpu.batch.TaskManager.sub_meshes` workers and the
-  warm :class:`ProgramCache` (TUNE_CACHE winners resolved once per
-  shape class; ``compile.serve.*`` counters prove the second
-  identical-shape request compiles nothing).
+  warm :class:`ProgramCache` (``compile.serve.*`` counters prove the
+  second identical-shape request compiles nothing).
 - :mod:`.batching` — compatible FFTPower requests vmap-coalesced into
   one device launch, the window bounded so no deadline is blown.
 - :mod:`.server` — the :class:`AnalysisServer` loop: bounded queue,

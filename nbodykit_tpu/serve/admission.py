@@ -24,6 +24,7 @@ the queue.  Three outcomes:
     why and by how much.
 """
 
+from .. import _global_options
 from ..pmesh import memory_plan
 
 # decision states
@@ -71,32 +72,25 @@ class AdmissionDecision(object):
 def _plan(request, ndevices, hbm_bytes, paint_chunk=None,
           catalog_bytes=None):
     method = request.paint_method
-    if method in (None, 'auto'):
-        # price what would actually run: the tune-cache resolution for
-        # this platform/shape (scheduler resolves the same way)
-        from ..tune.resolve import resolve_paint
-        method = resolve_paint(
-            nmesh=request.nmesh, npart=request.npart,
-            dtype=request.dtype, nproc=ndevices,
-            # Forward runs the grad-safe resolution (a cached winner
-            # with no adjoint story demotes) — price what executes
-            differentiable=request.algorithm == 'Forward',
-        ).get('paint_method', 'scatter')
-        if method == 'auto':
-            method = 'scatter'
+    if method is None:
+        # price what executes: the option as it stands, and for a
+        # Forward request the kernel reverse mode takes in its place
+        method = _global_options['paint_method']
+        if request.algorithm == 'Forward':
+            from ..forward.adjoint import grad_paint_method
+            method = grad_paint_method(method)
     chunk_rows = None
     if getattr(request, 'data_ref', None) is not None:
         # a data_ref request streams+paints+transforms jointly: price
         # the resident catalog and the double-buffered staging chunks
         # alongside the mesh pipeline
         from ..ingest.stream import resolve_chunk_rows
-        chunk_rows = resolve_chunk_rows(npart=request.npart,
-                                        nproc=ndevices)
+        chunk_rows = resolve_chunk_rows()
     # a Forward request is a forward+BACKWARD pipeline: price it with
     # the reverse-mode branch (per-step residuals held live) instead
     # of the one-shot fftpower peak; a Bispectrum request is priced by
     # its streaming 3-field shell peak (the serve path always runs the
-    # FFT estimator — the direct path is a library/tuner concern)
+    # FFT estimator — the direct path is a library concern)
     workload = {'Forward': 'forward',
                 'Bispectrum': 'bispectrum'}.get(request.algorithm,
                                                 'fftpower')

@@ -53,15 +53,14 @@ from ...resilience.checkpoint import (_atomic_bytes, _canonical, _safe,
 JIT_OPTIONS = (
     'mesh_dtype', 'a2a_compress', 'resampler', 'paint_method',
     'paint_chunk_size', 'paint_bucket_slack', 'paint_streams',
-    'fft_chunk_bytes', 'fft_decomp', 'fft_pencil', 'exchange_slack',
-    'integrity', 'ingest_chunk_rows',
+    'fft_chunk_bytes', 'fft_decomp', 'fft_pencil', 'integrity',
+    'ingest_chunk_rows',
 )
 
 #: Options that only steer scheduling/telemetry — NEVER key material.
 RUNTIME_OPTIONS = (
-    'diagnostics', 'faults', 'tune_cache', 'io_verify_checksums',
-    'ingest_overlap', 'ingest_cache_bytes', 'data_steal_grace_s',
-    'telemetry_port',
+    'diagnostics', 'faults', 'io_verify_checksums', 'ingest_overlap',
+    'ingest_cache_bytes', 'data_steal_grace_s', 'telemetry_port',
 )
 
 

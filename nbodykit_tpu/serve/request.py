@@ -12,11 +12,10 @@ onto the sub-mesh — and are exactly as small.
 
 Two identity keys, deliberately distinct:
 
-- :meth:`AnalysisRequest.shape_class` — the tune-cache bucket
-  (``mesh64-part1e5``, :func:`nbodykit_tpu.tune.cache.shape_class`):
-  the granularity at which TUNE_CACHE.json winners are resolved and
-  serving statistics are aggregated.  Many concrete shapes share one
-  class.
+- :meth:`AnalysisRequest.shape_class` — the logarithmic bucket
+  (``mesh64-part1e5``, :func:`shape_class`): the granularity at which
+  serving statistics are aggregated and compile counters labelled.
+  Many concrete shapes share one class.
 - :meth:`AnalysisRequest.program_key` — the exact compiled-program
   identity (algorithm, nmesh, npart, dtype, resampler, device count):
   two requests with equal program keys can share one warm XLA
@@ -25,6 +24,7 @@ Two identity keys, deliberately distinct:
 """
 
 import itertools
+import math
 
 ALGORITHMS = ('FFTPower', 'ConvolvedFFTPower', 'FFTCorr', 'Forward',
               'Bispectrum')
@@ -34,6 +34,22 @@ ALGORITHMS = ('FFTPower', 'ConvolvedFFTPower', 'FFTCorr', 'Forward',
 _DTYPES = ('f4', 'f8', 'bf16')
 
 _ids = itertools.count(1)
+
+
+def shape_class(nmesh=None, npart=None):
+    """The logarithmic shape bucket for (nmesh, npart):
+    ``mesh512-part1e7`` / ``mesh512`` / ``part1e7``.  Nmesh buckets to
+    the nearest power of two, Npart to the nearest decade."""
+    parts = []
+    if nmesh:
+        parts.append('mesh%d' % (1 << max(0, int(round(
+            math.log2(float(nmesh)))))))
+    if npart:
+        parts.append('part1e%d' % max(0, int(round(
+            math.log10(float(npart))))))
+    if not parts:
+        raise ValueError('shape_class needs nmesh and/or npart')
+    return '-'.join(parts)
 
 
 class AnalysisRequest(object):
@@ -60,8 +76,8 @@ class AnalysisRequest(object):
     dtype : 'f4', 'f8' or 'bf16' — mesh storage dtype ('bf16' halves
         the admission-priced mesh bytes; compute stays f32)
     resampler : paint window ('nnb'/'cic'/'tsc'/'pcs')
-    paint_method : explicit paint kernel, or None for the tune-cache
-        resolution ('auto' semantics) at execution time
+    paint_method : the paint kernel admission prices, or None for
+        the ``paint_method`` option as it stands
     seed : int — realization seed (device-count invariant)
     deadline_s : float — seconds after submission by which the result
         must be DELIVERED; the queue evicts (with a structured
@@ -172,8 +188,7 @@ class AnalysisRequest(object):
 
     @property
     def shape_class(self):
-        """The tune-cache bucket this request's shape falls in."""
-        from ..tune.cache import shape_class
+        """The bucket this request's shape falls in."""
         return shape_class(nmesh=self.nmesh, npart=self.npart)
 
     def program_key(self, ndevices=1):

@@ -7,10 +7,7 @@ compiled analysis program exactly once, wrapped in
 :func:`~nbodykit_tpu.diagnostics.instrumented_jit` under a label keyed
 by shape class (``serve.fftpower.mesh64-part1e5``), so the
 ``compile.<label>.misses`` / ``.hits`` counters are the PROOF that the
-second identical-shape request compiles nothing.  TUNE_CACHE.json
-winners are resolved once per (shape class, device count) — not once
-per request — behind a lock, and the resolution is memoized alongside
-the program.
+second identical-shape request compiles nothing.
 
 **Placement is cache-affine.**  A compiled XLA executable is bound to
 the devices it was built for, so the scheduler routes a request to the
@@ -384,37 +381,13 @@ class Program(object):
 
 
 class ProgramCache(object):
-    """(program key, worker) -> warm :class:`Program`, plus the
-    once-per-shape-class tuned-option resolution.  All counters are
-    exported: ``serve.program.build`` / ``.reuse`` and
-    ``serve.tuned.resolve`` / ``.reuse`` tell the doctor how warm the
-    server is running."""
+    """(program key, worker) -> warm :class:`Program`.  Its counters
+    are exported: ``serve.program.build`` / ``.reuse`` tell the doctor
+    how warm the server is running."""
 
     def __init__(self):
         self._lock = threading.Lock()
         self._programs = {}
-        self._tuned = {}
-
-    def tuned_options(self, request, ndevices):
-        """The TUNE_CACHE.json resolution for this shape class —
-        memoized so a thousand same-class requests cost one lookup."""
-        key = (request.shape_class, request.dtype, int(ndevices))
-        with self._lock:
-            hit = self._tuned.get(key)
-        if hit is not None:
-            counter('serve.tuned.reuse').add(1)
-            return hit
-        from ..tune.resolve import resolve_paint
-        cfg = resolve_paint(nmesh=request.nmesh, npart=request.npart,
-                            dtype=request.dtype, nproc=ndevices)
-        cfg = {k: v for k, v in cfg.items()
-               if k in ('paint_method', 'paint_chunk_size',
-                        'paint_streams') and v is not None
-               and v != 'auto'}
-        counter('serve.tuned.resolve').add(1)
-        with self._lock:
-            self._tuned.setdefault(key, cfg)
-        return cfg
 
     def get(self, request, mesh, worker, opts=None):
         """The warm program for (request shape, worker), building it

@@ -108,8 +108,9 @@ class RequestResult(object):
         self.queue_wait_s = queue_wait_s
         self.service_s = service_s
         self.events = list(events or [])
-        # options: everything applied around the run (tuned winners +
-        # overrides); admit_options: ONLY what admission stepped down
+        # options: everything applied around the run (admission's
+        # rungs and the runtime ladder's); admit_options: ONLY what
+        # admission stepped down
         self.options = dict(options or {})
         self.admit_options = dict(admit_options or {})
         self.batch_size = int(batch_size)
@@ -652,8 +653,7 @@ class AnalysisServer(object):
         # one mutable option dict per run: admission's rungs seed it,
         # the supervisor's runtime ladder steps it further on OOM —
         # both scoped to this run, applied only inside option_scope
-        opts = dict(self.programs.tuned_options(req, self.ndevices))
-        opts.update(leader.decision.options or {})
+        opts = dict(leader.decision.options or {})
         sup = Supervisor('serve.request', policy=self.retry,
                          ladder=scoped_ladder(opts),
                          checkpoint=self.checkpoint)

@@ -161,52 +161,6 @@ print("precision gate OK: bf16 mesh + bf16 a2a, max P(k) rel err "
       "%.3e < 2e-2 (%d bins <= k_Nyq/2)" % (err, int(sel.sum())))
 '
 
-# autotuner gates (docs/TUNE.md): the bounded --dry-run proves the
-# deterministic trial plan still builds without touching a device —
-# and that every multi-device fft trial races BOTH decompositions
-# (chunk-laddered slab + the pencil candidate) under a
-# factorization-suffixed shape class; --validate fails the smoke run
-# on a malformed committed TUNE_CACHE.json (a broken database must
-# never silently steer dispatch)
-echo "== tune: dry-run plan + cache validation gate =="
-python -m nbodykit_tpu.tune --dry-run --devices 8 | python -c '
-import json, sys
-plan = json.load(sys.stdin)["plan"]
-ffts = [p for p in plan if p["op"] == "fft"]
-assert ffts, "no fft trials in the plan"
-for p in ffts:
-    cands = p["candidates"]
-    assert any(c.startswith("chunk") for c in cands), (
-        "slab chunk ladder missing: %r" % cands)
-    assert any(c.startswith("pencil") for c in cands), (
-        "pencil decomposition candidate missing: %r" % cands)
-    assert "-g" in p["shape_class"], (
-        "factorization suffix missing: %r" % p["shape_class"])
-    # halved-bytes wire candidates (docs/PERF.md): every multi-device
-    # fft trial must race both compressed payloads against full-width
-    assert "slab-a2a-bf16" in cands and "slab-a2a-int16" in cands, (
-        "a2a compression candidates missing: %r" % cands)
-paints = [p for p in plan if p["op"] == "paint"]
-assert paints, "no paint trials in the plan"
-for p in paints:
-    assert "scatter-bf16" in p["candidates"], (
-        "bf16 mesh candidate missing: %r" % p["candidates"])
-# the bispectrum estimator race (docs/BISPECTRUM.md): every bspec
-# trial must pit the FFT path against the direct pairblock tiles —
-# the crossover is measured, never guessed
-bspecs = [p for p in plan if p["op"] == "bspec"]
-assert bspecs, "no bspec trials in the plan"
-for p in bspecs:
-    cands = p["candidates"]
-    assert "fft" in cands, "fft estimator missing: %r" % cands
-    assert any(c.startswith("direct-tile") for c in cands), (
-        "direct pairblock candidates missing: %r" % cands)
-print("tune plan OK: fft candidates " + " ".join(ffts[0]["candidates"])
-      + " @ " + " ".join(p["shape_class"] for p in ffts)
-      + "; bspec candidates " + " ".join(bspecs[0]["candidates"]))
-'
-python -m nbodykit_tpu.tune --validate
-
 # paint candidate gate (docs/PERF.md): every registered paint
 # candidate at a bounded CPU shape (mesh128/1e5, 2 reps) must lower,
 # run and deposit finite mass — CI catches a candidate that stops
@@ -683,7 +637,7 @@ python -m pytest \
     tests/test_diagnostics_analyze.py \
     tests/test_resilience.py \
     tests/test_fleet.py \
-    tests/test_tune.py \
+    tests/test_options.py \
     tests/test_serve.py \
     tests/test_region.py \
     tests/test_observability.py \

@@ -128,6 +128,35 @@ _SLOW = {
 }
 
 
+# every paint engine of ops/paint.py with the options that select it:
+# what tests/test_paint_kernels.py and tests/test_integrity.py hold to
+# the scatter oracle. An engine added to ops/paint.py gets a line here
+# (the Pallas deposit is interpreted off the chip: tests/
+# test_paint_pallas.py and tests/test_tpu_compile.py hold it).
+_FULL = {'paint_chunk_size': 1024 * 1024 * 16, 'mesh_dtype': 'f4'}
+PAINT_CANDIDATES = {name: dict(_FULL, **opts) for name, opts in {
+    'scatter': {'paint_method': 'scatter'},
+    'scatter-chunk4m': {'paint_method': 'scatter',
+                        'paint_chunk_size': 1024 * 1024 * 4},
+    'sort': {'paint_method': 'sort'},
+    'segsum-argsort': {'paint_method': 'segsum',
+                       'paint_order': 'argsort'},
+    'segsum-radix': {'paint_method': 'segsum', 'paint_order': 'radix'},
+    'streams2': {'paint_method': 'streams', 'paint_streams': 2},
+    'streams4': {'paint_method': 'streams', 'paint_streams': 4},
+    'streams8': {'paint_method': 'streams', 'paint_streams': 8},
+    'mxu-argsort-xla': {'paint_method': 'mxu', 'paint_order': 'argsort',
+                        'paint_deposit': 'xla'},
+    'mxu-radix-xla': {'paint_method': 'mxu', 'paint_order': 'radix',
+                      'paint_deposit': 'xla'},
+    'scatter-bf16': {'paint_method': 'scatter', 'mesh_dtype': 'bf16'},
+    'streams4-bf16': {'paint_method': 'streams', 'paint_streams': 4,
+                      'mesh_dtype': 'bf16'},
+    'streams8-bf16': {'paint_method': 'streams', 'paint_streams': 8,
+                      'mesh_dtype': 'bf16'},
+}.items()}
+
+
 def pytest_collection_modifyitems(config, items):
     for item in items:
         key = "::".join(item.nodeid.split("/")[-1].split("::")[-2:])

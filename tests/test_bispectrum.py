@@ -1,7 +1,7 @@
 """The bispectrum subsystem (ISSUE 20): the FFT Scoccimarro estimator
 and the direct pairblock estimator against brute-force numpy oracles,
 cross-path agreement on the multi-device mesh, bit-identical replay and
-save/load, the MXU pairblock kernel, tuner integration, memory_plan
+save/load, the MXU pairblock kernel, memory_plan
 pricing, and the serve plane's Bispectrum requests.
 
 Oracle conventions (docs/BISPECTRUM.md): the FFT path closes triangles
@@ -26,16 +26,12 @@ from nbodykit_tpu.lab import UniformCatalog
 from nbodykit_tpu.ops.pairblock import lattice_kvecs, pairblock_sum
 from nbodykit_tpu.parallel.runtime import cpu_mesh, use_mesh
 from nbodykit_tpu.pmesh import ParticleMesh, memory_plan
-from nbodykit_tpu.tune import TuneCache, reset_cache_memo
-from nbodykit_tpu.tune.resolve import resolve_bispectrum
 
 
 @pytest.fixture(autouse=True)
 def _clean_options():
     saved = _global_options.copy()
-    reset_cache_memo()
     yield
-    reset_cache_memo()
     _global_options.clear()
     _global_options.update(saved)
 
@@ -217,57 +213,16 @@ def test_bispectrum_validates_method_and_sources():
     cat = UniformCatalog(nbar=2e-3, BoxSize=100.0, seed=1)
     with pytest.raises(ValueError):
         Bispectrum(cat, nbins=0, Nmesh=16)
-    with pytest.raises(ValueError):
-        Bispectrum(cat, nbins=2, Nmesh=16, method='exact')
+    for method in ('exact', 'auto'):
+        with pytest.raises(ValueError):
+            Bispectrum(cat, nbins=2, Nmesh=16, method=method)
     mesh = cat.to_mesh(Nmesh=16)
     with pytest.raises(ValueError):
         Bispectrum(mesh, nbins=2, method='direct')
-    # 'auto' on a mesh source resolves to the FFT path
-    r = Bispectrum(mesh, nbins=2)
+    # the option's 'direct' on a mesh source runs the FFT path
+    with nbodykit_tpu.set_options(bspec_method='direct'):
+        r = Bispectrum(mesh, nbins=2)
     assert r.attrs['method'] == 'fft'
-
-
-# ---------------------------------------------------------------------------
-# tuner integration
-
-def test_resolve_bispectrum_cold_cache_defaults(tmp_path):
-    nbodykit_tpu.set_options(tune_cache=str(tmp_path / 'ABSENT.json'))
-    cfg = resolve_bispectrum(nmesh=64, npart=10000, nproc=1)
-    assert cfg['bspec_method'] == 'fft'
-    assert cfg['pairblock_tile'] == 1024
-    assert cfg['source'] == 'default'
-
-
-def test_resolve_bispectrum_picks_up_cache_winner(tmp_path):
-    path = str(tmp_path / 'TC.json')
-    TuneCache(path).put({
-        'platform': 'cpu', 'device_kind': 'cpu', 'device_count': 1,
-        'op': 'bspec', 'shape_class': 'mesh16-part1e3',
-        'dtype': 'float32',
-        'winner': {'bspec_method': 'direct', 'pairblock_tile': 256},
-        'winner_name': 'direct-tile256', 'trials': {},
-        'infeasible': [], 'measured_at': '2026-08-04T00:00:00Z'})
-    nbodykit_tpu.set_options(tune_cache=path)
-    cfg = resolve_bispectrum(nmesh=16, npart=500, nproc=1)
-    assert cfg['bspec_method'] == 'direct'
-    assert cfg['pairblock_tile'] == 256
-    assert cfg['source'] == 'cache'
-    # an explicit option is never overridden by the cache
-    nbodykit_tpu.set_options(bspec_method='fft')
-    assert resolve_bispectrum(nmesh=16, npart=500,
-                              nproc=1)['bspec_method'] == 'fft'
-
-
-def test_tune_dry_run_lists_bspec_candidates(capsys):
-    import json as _json
-    from nbodykit_tpu.tune.__main__ import main
-    assert main(['--dry-run', '--devices', '8']) == 0
-    plan = _json.loads(capsys.readouterr().out)['plan']
-    bspec = [p for p in plan if p['op'] == 'bspec']
-    assert len(bspec) == 2            # one per default paint shape
-    names = {c for p in bspec for c in p['candidates']}
-    assert 'fft' in names
-    assert 'direct-tile1024' in names
 
 
 # ---------------------------------------------------------------------------

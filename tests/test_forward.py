@@ -81,6 +81,34 @@ def test_make_paint_refuses_mxu_pin():
         make_paint(pm, 64, 'cic', method='mxu')
 
 
+def test_resolve_forward_paint_demotes_what_has_no_adjoint():
+    """The grad-mode demotion lives with the forward model: an option
+    naming 'mxu' runs 'scatter', loudly; 'sort' keeps its kernel under
+    the custom_vjp; admission prices the same answer."""
+    import nbodykit_tpu
+    from nbodykit_tpu.diagnostics import REGISTRY
+    from nbodykit_tpu.forward.adjoint import (grad_paint_method,
+                                              resolve_forward_paint)
+
+    def fallbacks():
+        snap = REGISTRY.snapshot().get('forward.grad_fallback')
+        return snap['value'] if snap else 0
+    n0 = fallbacks()
+    with nbodykit_tpu.set_options(paint_method='mxu'):
+        cfg, mode = resolve_forward_paint()
+    assert (cfg['paint_method'], mode) == ('scatter', 'native')
+    assert (cfg['source'], cfg['winner_name']) == ('grad-fallback', 'mxu')
+    assert fallbacks() == n0 + 1
+    with nbodykit_tpu.set_options(paint_method='sort'):
+        cfg, mode = resolve_forward_paint()
+    assert (cfg['paint_method'], mode, cfg['source']) == \
+        ('sort', 'custom_vjp', 'explicit')
+    assert fallbacks() == n0 + 1
+    assert [grad_paint_method(m) for m in
+            ('scatter', 'streams', 'mxu')] == ['scatter', 'streams',
+                                               'scatter']
+
+
 @requires_x64
 def test_readout_gradient_matches_fd():
     pm = ParticleMesh(Nmesh=8, BoxSize=100.0, dtype='f8')

@@ -40,7 +40,7 @@ from nbodykit_tpu.resilience.integrity import (check_a2a, check_close,
                                                corrupt_host,
                                                flip_bits_value,
                                                violation)
-from nbodykit_tpu.tune.space import registered_paint_candidates
+from conftest import PAINT_CANDIDATES as CANDS
 
 
 @pytest.fixture(autouse=True)
@@ -150,9 +150,6 @@ def test_shadow_margin_from_options():
 
 # ---------------------------------------------------------------------------
 # zero false positives: clean programs under integrity='cheap'
-
-CANDS = {c.name: c.options for c in registered_paint_candidates(32,
-                                                                4000)}
 
 
 @pytest.mark.parametrize('name', sorted(CANDS))

@@ -1,12 +1,11 @@
-"""Equivalence suite for every registered paint candidate.
+"""Equivalence suite for every paint engine.
 
-The tuner can flip ``paint_method='auto'`` to ANY candidate in
-tune/space.py, so each one must deposit exactly the same mesh as the
+``set_options(paint_method=...)`` can select ANY engine of
+ops/paint.py, so each one must deposit exactly the same mesh as the
 reference scatter kernel — across resamplers, wrap seams, halo/origin
-offsets and the 8-device mesh. The candidate list here is the real
-one (:func:`~nbodykit_tpu.tune.space.registered_paint_candidates`),
-not a hand-kept copy: a new candidate is tested the moment it is
-registered, or the parametrize list grows a hole.
+offsets and the 8-device mesh. The list is
+``conftest.PAINT_CANDIDATES``: every engine with the options that
+select it.
 
 Also the dropped-deposit observability contract (ISSUE 8): the eager
 mxu bucket-overflow backoff must bump ``paint.dropped`` before it
@@ -26,11 +25,7 @@ from nbodykit_tpu.ops.paint import (paint_local, paint_local_sorted,
                                     paint_local_segsum,
                                     paint_local_streams,
                                     paint_local_mxu)
-from nbodykit_tpu.tune.space import registered_paint_candidates
-
-# the real candidate list at the test shape (CPU process: no pallas
-# candidate; all stream counts fit at mesh32)
-CANDS = {c.name: c.options for c in registered_paint_candidates(32, 4000)}
+from conftest import PAINT_CANDIDATES as CANDS
 
 # (n0l, N1, N2, p0, origin) — same geometry convention as
 # tests/test_paint_mxu.py: interior block, origin-offset block, and a
@@ -131,7 +126,7 @@ def test_multi_device_equivalence(name, cpu8):
     """Every candidate, end to end through ``pm.paint`` on the
     8-device mesh: allclose to the scatter oracle, exact mass
     conservation, and bit-identical across repeated paints (the
-    determinism claim a tuner A/B relies on)."""
+    determinism claim an A/B of two engines relies on)."""
     from nbodykit_tpu.pmesh import ParticleMesh
     rng = np.random.default_rng(7)
     n = 500
@@ -168,25 +163,17 @@ def test_multi_device_equivalence(name, cpu8):
 
 
 def test_streams_candidates_capped_by_memory_plan():
-    """Stream counts whose replica meshes blow the 0.85xHBM budget at
-    the trial shape are EXCLUDED from the space (ISSUE 8 acceptance:
-    the 1024^3 pipeline must stay inside budget)."""
+    """``memory_plan`` prices the streams kernel's replica meshes: every
+    stream count fits at a small shape, none next to a 1024^3 field
+    on one 16 GB chip (ISSUE 8 acceptance: the 1024^3 pipeline must
+    stay inside budget)."""
     from nbodykit_tpu.pmesh import memory_plan
-    small = [c.name for c in registered_paint_candidates(64, 10_000)]
-    assert {'streams2', 'streams4', 'streams8'} <= set(small)
-    big = [c.name for c in registered_paint_candidates(1024, int(1e8))]
-    assert 'scatter' in big and 'segsum-argsort' in big
-    for name in big:
-        if name.startswith('streams'):
-            k = int(name[len('streams'):])
-            assert memory_plan(1024, 1e8, paint_method='streams',
+    for k in (2, 4, 8):
+        assert memory_plan(64, 10_000, paint_method='streams',
+                           paint_streams=k, hbm_bytes=16e9)['fits']
+        assert not memory_plan(1024, 1e8, paint_method='streams',
                                paint_streams=k,
                                hbm_bytes=16e9)['fits']
-    # at 16 GB HBM even k=2 replicas do not fit next to the 1024^3
-    # field: every stream count is excluded there
-    assert not memory_plan(1024, 1e8, paint_method='streams',
-                           paint_streams=2, hbm_bytes=16e9)['fits']
-    assert 'streams8' not in big
 
 
 def test_mxu_dropped_counter_and_backoff():

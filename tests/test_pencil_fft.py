@@ -6,8 +6,8 @@ degenerate 8x1 (== slab) — plus ragged shapes (exact fallback, never
 zero-padded), r2c/c2r/c2c roundtrips, composition under an outer jit,
 and bit-identical determinism.  Also units for the runtime helpers
 (pencil_mesh / default_pencil_factor), dispatch-time decomp resolution
-(resolve_decomp / dist_fft_plan / set_options), the factorization-
-keyed tune-cache classes, and the memory_plan pencil branch.
+(resolve_decomp / dist_fft_plan / set_options) and the memory_plan
+pencil branch.
 
 x64 is on (conftest), so the jnp.fft oracle comparisons run at double
 precision and the 1e-10 acceptance bar is meaningful.
@@ -180,7 +180,7 @@ def test_pencil_mesh_construction():
 # ------------------------------------------------- dispatch resolution
 
 def test_resolve_decomp_defaults_and_overrides():
-    # cold cache / default options -> slab, near-square factorization
+    # default options -> slab, near-square factorization
     assert dfft.resolve_decomp(1) == ('slab', None)
     decomp, pxpy = dfft.resolve_decomp(8)
     assert decomp == 'slab' and pxpy == (2, 4)
@@ -193,8 +193,9 @@ def test_resolve_decomp_defaults_and_overrides():
         assert dfft.resolve_decomp(8) == ('pencil', (4, 2))
     with pytest.raises(ValueError):
         dfft.resolve_decomp(8, pencil='3x2')    # does not cover 8
-    with pytest.raises(ValueError):
-        dfft.resolve_decomp(8, decomp='banana')
+    for bad in ('banana', 'auto'):
+        with pytest.raises(ValueError):
+            dfft.resolve_decomp(8, decomp=bad)
 
 
 def test_plan_dispatches_pencil_via_options():
@@ -213,25 +214,6 @@ def test_plan_explicit_2d_mesh_wins():
     plan = dfft.dist_fft_plan((16, 16, 12), pencil_mesh(4, 2))
     np.testing.assert_allclose(np.asarray(plan.r2c(x)), _ref_rfftn(x),
                                atol=1e-10)
-
-
-# ------------------------------------------- factorization-keyed cache
-
-def test_shape_class_carries_factorization():
-    from nbodykit_tpu.tune.cache import (class_distance,
-                                         class_factorization,
-                                         shape_class)
-    assert shape_class(nmesh=64, mesh_shape=(4, 2)) == 'mesh64-g4x2'
-    assert class_factorization('mesh64-g4x2') == (4, 2)
-    assert class_factorization('mesh64') is None
-    # winners never travel across device-mesh factorizations: a 4x2
-    # measurement must not answer an 8x1 (or unfactorized) question
-    assert class_distance('mesh64-g4x2', 'mesh64-g8x1') is None
-    assert class_distance('mesh64-g4x2', 'mesh64') is None
-    d = class_distance('mesh64-g4x2', 'mesh128-g4x2')
-    assert d is not None and d > 0
-    # committed suffix-less entries stay reachable for slab questions
-    assert class_distance('mesh64', 'mesh128') is not None
 
 
 def test_memory_plan_pencil_branch():

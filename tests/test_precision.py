@@ -18,8 +18,8 @@ never flip a bin assignment) and scale-relative error inside the
 per-posture budget.  Measured errors (CPU, mesh64, 8 devices):
 mesh-bf16 4.3e-3, a2a-bf16 1.9e-3, a2a-int16 9.0e-5; budgets sit
 3-5x above.  Margins are committed to PRECISION.json
-(diagnostics.regress.write_precision_margins) so the doctor can attest
-any committed tune-cache winner running one of these postures.
+(diagnostics.regress.write_precision_margins), which the doctor and
+the round record read back.
 """
 
 import os
@@ -97,9 +97,8 @@ def test_compressed_pk_within_budget(oracle, posture, opts):
         '%s: max P(k) rel err %.3e exceeds budget %.0e' \
         % (posture, err, budget)
 
-    # commit the measured margin so the doctor can attest any
-    # tune-cache winner running this posture (regress.precision_summary
-    # WARNs on compressed winners with no margin on record)
+    # commit the measured margin (regress.precision_summary reads it
+    # back for the doctor and the round record)
     from nbodykit_tpu.diagnostics.regress import write_precision_margins
     write_precision_margins(
         {posture: {'max_rel_err': err, 'budget': budget}}, root=ROOT)
@@ -176,61 +175,32 @@ def test_request_rejects_unknown_dtype():
         AnalysisRequest(dtype='f2')
 
 
-def test_tuner_registers_compressed_candidates():
-    """Every compressed posture is a raced candidate with full-width
-    cold-cache defaults (tune/space.py)."""
-    from nbodykit_tpu.tune.space import paint_space, fft_space
-    ctx = {'nmesh': 256, 'npart': 10**6, 'nproc': 8,
-           'mesh_shape': (4, 2), 'dtype': 'f4'}
-    paint = {c.name: c.options for c in paint_space().candidates(ctx)}
-    fft = {c.name: c.options for c in fft_space().candidates(ctx)}
-    assert 'scatter-bf16' in paint
-    assert paint['scatter-bf16']['mesh_dtype'] == 'bf16'
-    assert 'slab-a2a-bf16' in fft and 'slab-a2a-int16' in fft
-    assert any(n.startswith('pencil') and n.endswith('a2a-bf16')
-               for n in fft)
-    # cold-cache defaults == today's behavior: plain candidates carry
-    # the full-width posture explicitly so winners are unambiguous
-    assert paint['scatter']['mesh_dtype'] == 'f4'
-    assert all('a2a_compress' in o for o in fft.values())
-    assert all(o['a2a_compress'] == 'none'
-               for n, o in fft.items() if 'a2a' not in n)
-
-
 def test_resolve_validates_postures():
-    from nbodykit_tpu.tune.resolve import (resolve_mesh_dtype,
-                                           resolve_a2a_compress)
-    # explicit non-auto values pass through; cold cache falls back to
-    # the full-width defaults
-    assert resolve_mesh_dtype(nmesh=64) in ('f4', 'bf16')
-    assert resolve_a2a_compress(shape=(64, 64, 64)) in \
-        ('none', 'bf16', 'int16')
+    """The read sites take the postures as the options stand: full
+    width by default, the halved-bytes formats when set."""
+    from nbodykit_tpu.lab import UniformCatalog
+    from nbodykit_tpu.parallel.dfft import _a2a_mode
+    cat = UniformCatalog(nbar=1e-3, BoxSize=50.0, seed=1)
+    assert _a2a_mode() == 'none'
+    assert cat.to_mesh(Nmesh=8).pm.dtype == np.dtype('f4')
+    with nbodykit_tpu.set_options(a2a_compress='int16',
+                                  mesh_dtype='bf16'):
+        assert _a2a_mode() == 'int16'
+        assert cat.to_mesh(Nmesh=8).pm.dtype.name == 'bfloat16'
 
 
 def test_precision_summary_attestation(tmp_path):
-    """regress: a committed compressed winner without a margin is
-    unattested; writing the margin attests it."""
-    import json
+    """regress: no PRECISION.json, no posture; a written margin is
+    read back and lands on the round record's posture line."""
     from nbodykit_tpu.diagnostics import regress
     root = str(tmp_path)
-    cache = {'version': 1, 'entries': {'k': {
-        'op': 'fft', 'shape_class': 'mesh256',
-        'winner_name': 'slab-a2a-bf16',
-        'winner': {'fft_decomp': 'slab', 'a2a_compress': 'bf16'},
-        'trials': {'slab-a2a-bf16': {
-            'options': {'fft_decomp': 'slab', 'a2a_compress': 'bf16'},
-            'wall_s': 0.1}}}}}
-    with open(os.path.join(root, 'TUNE_CACHE.json'), 'w') as f:
-        json.dump(cache, f)
-    p = regress.precision_summary(root)
-    assert p['raced'] == ['slab-a2a-bf16']
-    assert p['unattested'] == ['fft/mesh256=slab-a2a-bf16']
+    assert regress.precision_summary(root) is None
     regress.write_precision_margins(
         {'a2a-bf16': {'max_rel_err': 1.9e-3, 'budget': 1e-2}},
         root=root)
     p = regress.precision_summary(root)
-    assert p['unattested'] == []
-    assert 'a2a-bf16' in p['margins']
+    assert p['margins']['a2a-bf16']['budget'] == 1e-2
+    assert p['k_max'] == 'k_nyquist/2'
     # the render carries the posture line
     h = regress.build_history(root, write=False)
     assert 'precision:' in regress.render_regress(h)

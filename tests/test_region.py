@@ -119,9 +119,8 @@ def test_result_key_runtime_fields_perturb_nothing():
     assert result_key(twin)[0] == d0
     # runtime-only OPTIONS perturb nothing either
     with nbodykit_tpu.set_options(
-            diagnostics=None, tune_cache=None,
-            io_verify_checksums=False, ingest_overlap=False,
-            data_steal_grace_s=9.5,
+            diagnostics=None, io_verify_checksums=False,
+            ingest_overlap=False, data_steal_grace_s=9.5,
             faults='region.qos.admit@99:internal'):
         assert result_key(base)[0] == d0
     # the canonical text carries no runtime field by name
@@ -139,7 +138,7 @@ def test_result_key_every_jit_option_perturbs():
         'paint_chunk_size': 12345, 'paint_bucket_slack': 1.75,
         'paint_streams': 7, 'fft_chunk_bytes': 999,
         'fft_decomp': 'pencil', 'fft_pencil': (2, 4),
-        'exchange_slack': 1.5, 'integrity': 'cheap',
+        'integrity': 'cheap',
         'ingest_chunk_rows': 4242,
     }
     assert sorted(perturb) == sorted(JIT_OPTIONS)
@@ -618,6 +617,9 @@ def test_data_steal_grace_resolution(monkeypatch):
         _resolve_data_steal_grace('auto')
     with pytest.raises(KeyError):
         nbodykit_tpu.set_options(data_steal_grace=1.0)  # typo'd name
+    # 'auto' stays a value here: the environment answers it
+    with nbodykit_tpu.set_options(data_steal_grace_s='auto'):
+        assert _global_options['data_steal_grace_s'] == 'auto'
 
 
 def test_server_resolves_data_steal_grace_option():

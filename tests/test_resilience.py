@@ -301,6 +301,23 @@ def test_default_ladder_respects_floors():
     assert int(_global_options['paint_chunk_size']) == 1 << 18
 
 
+def test_ladder_halves_auto_resolved_value():
+    """The first rung halves from the option as it stands (the table's
+    2**31 here) and PINS the result; the request-scoped ladder does the
+    same into its own mapping and writes no global."""
+    from nbodykit_tpu.resilience.supervise import scoped_ladder
+    opts = {}
+    label, detail = scoped_ladder(opts).step()
+    assert label == 'fft_chunk_bytes/2'
+    assert detail == {'fft_chunk_bytes': 2 ** 30, 'was': 2 ** 31}
+    assert opts == {'fft_chunk_bytes': 2 ** 30}
+    assert _global_options['fft_chunk_bytes'] == 2 ** 31
+    label, detail = default_ladder().step()
+    assert label == 'fft_chunk_bytes/2'
+    assert detail == {'fft_chunk_bytes': 2 ** 30, 'was': 2 ** 31}
+    assert _global_options['fft_chunk_bytes'] == 2 ** 30
+
+
 def test_supervisor_resume_validate_rejects_mismatch(tmp_path):
     st = CheckpointStore(tmp_path)
     st.save('k', {'reps': 4, 'completed': 1})

@@ -5,12 +5,10 @@ nothing, proven by compile-miss counters), vmap batching
 bit-equivalence, deadline eviction, queue bounding, per-request fault
 isolation under injected faults (one request degrades, the fleet
 survives), checkpoint resume, and graceful drain/shutdown — plus the
-thread-safety satellites: the tune-cache mtime memo under concurrent
-loaders, ``option_scope`` leak-proofing across reused worker threads,
-and ``TaskManager.map`` exception propagation."""
+thread-safety satellites: ``option_scope`` leak-proofing across reused
+worker threads and ``TaskManager.map`` exception propagation."""
 
 import json
-import threading
 import time
 from concurrent.futures import ThreadPoolExecutor
 
@@ -78,6 +76,16 @@ def test_request_validation_and_keys():
         AnalysisRequest(nmesh=2)
 
 
+def test_shape_class_buckets():
+    from nbodykit_tpu.serve.request import shape_class
+    assert shape_class(64, 10_000) == 'mesh64-part1e4'
+    assert shape_class(100, 9e4) == 'mesh128-part1e5'
+    assert shape_class(512) == 'mesh512'
+    assert shape_class(npart=1e7) == 'part1e7'
+    with pytest.raises(ValueError):
+        shape_class()
+
+
 # ---------------------------------------------------------------------------
 # admission control
 
@@ -143,9 +151,6 @@ def test_serve_warm_cache_second_request_compiles_nothing():
         assert _counter(label + '.hits') >= 1
         assert _counter('serve.program.build') == build0
         assert _counter('serve.program.reuse') >= 1
-        # tuned options resolved once per shape class, then memoized
-        assert _counter('serve.tuned.resolve') == 1
-        assert _counter('serve.tuned.reuse') >= 1
 
 
 def test_serve_fftcorr_counts_every_cell():
@@ -330,33 +335,6 @@ def test_serve_trace_replay_end_to_end():
 
 # ---------------------------------------------------------------------------
 # satellites: thread safety
-
-def test_tune_cache_memo_thread_safe(tmp_path):
-    from nbodykit_tpu.tune import cache as tc
-    path = str(tmp_path / 'TUNE_CACHE.json')
-    cache = tc.TuneCache(path)
-    cache.put({'platform': 'cpu', 'device_kind': 'cpu',
-               'device_count': 1, 'op': 'paint',
-               'shape_class': 'mesh32-part1e4', 'dtype': 'f4',
-               'winner': 'scatter', 'candidates': {}})
-    tc.reset_cache_memo()
-    errs, results = [], []
-
-    def load():
-        try:
-            for _ in range(200):
-                results.append(len(tc._load_entries(path)))
-        except Exception as e:          # pragma: no cover
-            errs.append(e)
-
-    threads = [threading.Thread(target=load) for _ in range(8)]
-    for t in threads:
-        t.start()
-    for t in threads:
-        t.join()
-    assert not errs
-    assert set(results) == {1}
-
 
 def test_option_scope_restores_and_cannot_leak_across_threads():
     import random

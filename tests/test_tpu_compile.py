@@ -322,14 +322,14 @@ def test_staged_exchange_and_paint_of_the_four_chip_cell(four_chips,
         collective = 'all-to-all'
         limit = 0.02 * V5E_HBM          # 3 x 45 MB of buffers a device
     else:
-        cfg = _pm(CELL_NMESH, comm=four_chips)._paint_config(NPART)
+        cfg = _global_options
         assert cfg['paint_method'] == 'scatter'
         raw, _ = _slab_paint_programs(
             four_chips, (CELL_NMESH,) * 3, 'cic', cfg['paint_method'],
             cfg['paint_chunk_size'], cfg['paint_order'],
             cfg['paint_deposit'], cfg['paint_streams'],
             jnp.dtype('f4'), jnp.dtype('f4'),
-            _global_options['paint_bucket_slack'], True)
+            cfg['paint_bucket_slack'], True)
         slots = 16 * CELL_CAPACITY
         args = [jax.ShapeDtypeStruct((slots, 3), jnp.float32,
                                      sharding=rows3),
