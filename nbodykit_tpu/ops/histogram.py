@@ -24,7 +24,9 @@ one v5e; root PERF.md, the builder's traced run of PR 25).
 
 The bin indices it sums by come from ``edge_count_index`` (edges of any
 spacing; the lab path) or ``lattice_shell_index`` (unit-width shells;
-the serve plane); ``shell_sums`` is the serve plane's scatter-add sum.
+the serve plane). ``shell_sums`` is the serve plane's sum, the same
+matrix product with the shell as both indices (``shell = a * 8 + b``)
+and a 3-d field taken a chunk of leading rows at a time.
 
 ``hist2d_weighted`` picks the MXU path on TPU and plain bincount
 elsewhere (CPU bincount is exact f64 and faster than emulated matmuls).
@@ -35,6 +37,10 @@ import jax
 import jax.numpy as jnp
 
 
+#: cells a one-hot matrix product takes at a time
+_CHUNK = 131072
+
+
 def _pad_to(x, n, fill):
     m = x.shape[0]
     if m == n:
@@ -42,7 +48,16 @@ def _pad_to(x, n, fill):
     return jnp.concatenate([x, jnp.full((n - m,), fill, x.dtype)])
 
 
-def hist2d_mxu(abin, bbin, weights, NA, NB, chunk=131072,
+def _bf16_grid(x):
+    """f32 ``x`` rounded to bf16's grid, still f32.  Not a convert to
+    bf16 and back: inside one fusion the TPU compiler may elide that
+    pair as excess precision, which leaves a hi/lo split with
+    ``hi = x, lo = 0`` and sums 8 bits wide (the four-chip program
+    did)."""
+    return jax.lax.reduce_precision(x, exponent_bits=8, mantissa_bits=7)
+
+
+def hist2d_mxu(abin, bbin, weights, NA, NB, chunk=_CHUNK,
                acc_dtype=jnp.float64):
     """MXU-backed weighted 2-D histograms.
 
@@ -79,11 +94,7 @@ def hist2d_mxu(abin, bbin, weights, NA, NB, chunk=131072,
         cols = []
         for w in ws:
             w_c = jax.lax.dynamic_slice(w, (i * chunk,), (chunk,))
-            # hi on bf16's grid but still f32: a convert to bf16 and
-            # back inside one fusion may be elided as excess precision
-            # (the four-chip program did: hi = w, lo = 0, 8 bits left)
-            hi = jax.lax.reduce_precision(w_c, exponent_bits=8,
-                                          mantissa_bits=7)
+            hi = _bf16_grid(w_c)
             lo = w_c - hi
             cols.append(Boh * hi.astype(jnp.bfloat16)[:, None])
             cols.append(Boh * lo.astype(jnp.bfloat16)[:, None])
@@ -135,34 +146,93 @@ def lattice_shell_index(isq, nbins):
 def shell_sums(shell, value, nbins, weight=None):
     """Per-shell sum of ``value * weight`` and of ``weight``, both
     f32, for a 3-d ``value`` whose cells carry the shell index
-    ``shell`` (broadcastable to it) and an integer ``weight``
-    (broadcastable; default 1).
+    ``shell`` (broadcastable to it; a cell whose index lies outside
+    ``[0, nbins)`` counts nowhere) and an integer ``weight``
+    (broadcastable; default 1; at most 256, bf16's exact integers).
 
-    One partial histogram per leading row, the rows summed at the end:
-    a single f32 accumulator per shell stalls once it outgrows its
+    The module's matrix product with the shell as its own two indices,
+    ``shell = a * 8 + b``: ``(onehot(b) * cols) @ onehot(a)`` a chunk of
+    leading rows at a time (``hist2d_mxu``'s 131,072 cells or one row,
+    whichever is more; one row of a 512^3 complex field is 131,584),
+    contracted over the chunk's three axes as they lie.  ``cols`` are the
+    three bf16 parts of ``value * weight`` and the weight itself: the
+    parts add back to the f32 product bit for bit and a one-hot is
+    exact, so what is rounded is the MXU's f32 sum over a chunk and
+    nothing before it.  At 512^3 it takes 0.0096 s on one v5e (0.0063
+    inside the served program); on chunks flattened first 0.0217, as
+    one ``onehot(shell)`` of 256 columns against the four 0.137, and
+    the two scatter-adds it replaced took 1.19 (root PERF.md, PR 31).
+
+    One partial histogram per chunk, the chunks summed at the end: a
+    single f32 accumulator per shell stalls once it outgrows its
     addends.  At 512^3 the last shell, which takes every cell past the
     Nyquist sphere, counted 2^25 of its 6.4e7 weight-2 modes and then
     stopped, so the served P(k) there read 74% high, on the CPU as on
-    the chip.  The counts are integers throughout and rounded to f32
-    once, at the end; they are summed over the rows as two 16-bit
-    halves, so that a mesh of more than 2^31 cells (2048^3) does not
-    overflow int32 either.
+    the chip.  A chunk's weights must sum to less than 2^24, so its
+    count is an exact integer in f32 (2048^3: 4.2e6 a row); the counts
+    are int32 from there and rounded to f32 once, at the end, summed
+    over the chunks as two 16-bit halves, so that a mesh of more than
+    2^31 cells (2048^3) does not overflow int32 either.
     """
-    rows = int(value.shape[0])
-    row = jnp.arange(rows, dtype=jnp.int32).reshape(-1, 1, 1) * nbins
-    flat = jnp.broadcast_to(row + shell, value.shape).reshape(-1)
-    if weight is None:
-        weight = jnp.ones((), jnp.int32)
-    weight = jnp.broadcast_to(weight, value.shape)
-    S = jnp.zeros(rows * nbins, jnp.float32).at[flat].add(
-        (value.astype(jnp.float32) * weight.astype(jnp.float32))
-        .reshape(-1))
-    N = jnp.zeros(rows * nbins, jnp.int32).at[flat].add(
-        weight.astype(jnp.int32).reshape(-1))
-    N = N.reshape(rows, nbins)
+    rows, ny, nz = (int(n) for n in value.shape)
+    nch = -(-rows // max(1, min(rows, _CHUNK // (ny * nz))))
+    r = -(-rows // nch)                       # rows a chunk
+    nb = 8
+    na = -(-nbins // nb)
+
+    def by_row(x, dtype):
+        # (rows or 1, ...): what varies along the leading axis is sliced
+        # a chunk at a time, what does not is never broadcast beyond one
+        x = jnp.asarray(x, dtype)
+        return x.reshape((1,) * (3 - x.ndim) + x.shape)
+
+    shell = by_row(shell, jnp.int32)
+    value = by_row(value, jnp.float32)
+    weight = by_row(1 if weight is None else weight, jnp.float32)
+
+    def partial(i):
+        # the last chunk starts early where nch * r > rows; the rows it
+        # shares with the chunk before it carry weight 0
+        start = jnp.minimum(i * r, rows - r)
+
+        def take(x):
+            if x.shape[0] != 1:
+                x = jax.lax.dynamic_slice_in_dim(x, start, r)
+            return jnp.broadcast_to(x, (r, ny, nz))
+
+        w = take(weight)
+        if nch * r != rows:
+            fresh = start + jnp.arange(r, dtype=jnp.int32) >= i * r
+            w = w * fresh[:, None, None].astype(jnp.float32)
+        x = take(value) * w
+        hi = _bf16_grid(x)
+        mid = _bf16_grid(x - hi)
+        cols = jnp.stack([hi, mid, x - hi - mid, w])
+        s = take(shell)
+        B = s % nb == jnp.arange(nb, dtype=jnp.int32).reshape(nb, 1, 1, 1)
+        cols = jnp.where(B, cols[:, None], 0.0).astype(jnp.bfloat16)
+        A = jax.nn.one_hot(s // nb, na, dtype=jnp.bfloat16)
+        # the chunk stays 3-d: flattening it is a relayout of every
+        # cell (nz is no multiple of a lane row) and most of the code
+        return jax.lax.dot_general(
+            cols.reshape(4 * nb, r, ny, nz), A,
+            (((1, 2, 3), (0, 1, 2)), ((), ())),
+            preferred_element_type=jnp.float32).reshape(4, nb, na)
+
+    H = jax.lax.map(partial, jnp.arange(nch, dtype=jnp.int32))
+    # the chunks' f32 sums added by halves, log2(nch) roundings deep: a
+    # reduce over the leading axis adds them one after the other on the
+    # chip, and read 4.5 times a scatter-add's mean error at 512^3
+    S = H[:, :3]
+    while S.shape[0] > 1:
+        S = jnp.pad(S, ((0, S.shape[0] % 2),) + ((0, 0),) * 3)
+        S = S[:S.shape[0] // 2] + S[S.shape[0] // 2:]
+    S = S[0]
+    N = H[:, 3].astype(jnp.int32)
     hi = (N >> 16).sum(axis=0, dtype=jnp.int32).astype(jnp.float32)
     lo = (N & 0xFFFF).sum(axis=0, dtype=jnp.int32).astype(jnp.float32)
-    return S.reshape(rows, nbins).sum(axis=0), hi * 65536.0 + lo
+    return tuple(x.T.reshape(-1)[:nbins]             # [b, a] -> shell
+                 for x in (S[0] + (S[1] + S[2]), hi * 65536.0 + lo))
 
 
 def lattice_shell_edges(xedges, unit):
@@ -226,7 +296,7 @@ def _default_method():
 
 
 def hist2d_weighted(abin, bbin, weights, NA, NB, method=None,
-                    chunk=131072, acc_dtype=None):
+                    chunk=_CHUNK, acc_dtype=None):
     """Weighted 2-D histograms of flat index streams; see module
     docstring. ``method`` in {'mxu', 'bincount', None=auto}."""
     if method is None:

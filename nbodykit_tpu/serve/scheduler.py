@@ -49,7 +49,9 @@ def program_label(request):
 def _binned_power(pm, c, resampler, npart):
     """Window-compensated, hermitian-weighted |delta_k|^2 binned onto
     integer-lattice k shells (exact shell assignment via the shared
-    :func:`~nbodykit_tpu.ops.histogram.lattice_shell_index`).  Returns
+    :func:`~nbodykit_tpu.ops.histogram.lattice_shell_index`, the sums
+    as one-hot matrix products,
+    :func:`~nbodykit_tpu.ops.histogram.shell_sums`).  Returns
     (k, P(k), nmodes) with nmesh//2 shells."""
     import jax.numpy as jnp
     import numpy as np
@@ -68,10 +70,13 @@ def _binned_power(pm, c, resampler, npart):
         p3 = p3.at[0, 0, 0].set(0.0)
 
     with scope('fftpower.binning'):
-        ix, iy, iz = pm.i_list_complex()
-        shell = lattice_shell_index(ix * ix + iy * iy + iz * iz, nbins)
-        P, Nm = shell_sums(shell, p3, nbins,
-                           weight=pm.hermitian_weights(jnp.float32))
+        with scope('fftpower.binning.digitize'):
+            ix, iy, iz = pm.i_list_complex()
+            shell = lattice_shell_index(ix * ix + iy * iy + iz * iz,
+                                        nbins)
+        with scope('fftpower.binning.hist'):
+            P, Nm = shell_sums(shell, p3, nbins,
+                               weight=pm.hermitian_weights(jnp.float32))
     Nm0 = Nm.at[0].set(jnp.maximum(Nm[0] - 1.0, 0.0))  # drop DC mode
     k = jnp.asarray(np.arange(nbins, dtype='f4')) \
         * jnp.float32(2 * np.pi / L)

@@ -196,7 +196,8 @@ def served_text():
 
 @pytest.mark.parametrize('name', [
     'nbk.serve.program', 'nbk.paint', 'nbk.fft.r2c',
-    'nbk.fftpower.transfer', 'nbk.fftpower.binning'])
+    'nbk.fftpower.transfer', 'nbk.fftpower.binning.digitize',
+    'nbk.fftpower.binning.hist'])
 def test_served_program_names_its_layers(served_text, name):
     paths = re.findall(r'loc\("(jit\([^"]*)"', served_text)
     mine = [p for p in paths if re.findall(r'nbk\.[\w.]+', p)[-1:]

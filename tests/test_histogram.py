@@ -73,44 +73,100 @@ def test_hist2d_under_jit():
     np.testing.assert_allclose(got, want, rtol=1e-6)
 
 
-def test_shell_sums_matches_numpy():
+def _ref_shell_sums(shell, value, weight, nbins):
+    """f64 ``numpy.bincount`` of ``value * weight`` and of ``weight``."""
+    shell = np.broadcast_to(shell, value.shape).ravel()
+    w = np.broadcast_to(weight, value.shape).ravel().astype('f8')
+    return (np.bincount(shell, value.ravel().astype('f8') * w, nbins),
+            np.bincount(shell, w, nbins))
+
+
+# (6, 5, 4): one chunk.  (101, 40, 33): two chunks of 51 rows for 101,
+# the cells no multiple of the chunk.  (7, 300, 200): four chunks of 2
+# rows for 7.  In the last two the closing chunk overlaps the one
+# before it, and the shared row must count once
+@pytest.mark.parametrize("under", ["jit", "vmap"])
+@pytest.mark.parametrize("weighted", [False, True],
+                         ids=["unit", "hermitian"])
+@pytest.mark.parametrize("shape, nbins", [
+    ((6, 5, 4), 7), ((101, 40, 33), 20), ((7, 300, 200), 16)])
+def test_shell_sums_matches_numpy(shape, nbins, weighted, under):
     rng = np.random.RandomState(3)
-    shape, nbins = (6, 5, 4), 7
     shell = rng.randint(0, nbins, shape).astype('i4')
-    value = rng.standard_normal(shape).astype('f4')
-    weight = rng.randint(1, 3, (1, 1, 4)).astype('f4')   # 1 or 2
+    value = rng.standard_normal((2,) + shape).astype('f4')
+    # 1 or 2 along the last axis, as pm.hermitian_weights is
+    weight = rng.randint(1, 3, (1, 1, shape[2])).astype('f4') \
+        if weighted else None
+
+    def sums(v):
+        return shell_sums(jnp.asarray(shell), v, nbins,
+                          None if weight is None else jnp.asarray(weight))
+
+    if under == "vmap":
+        S, N = jax.jit(jax.vmap(sums))(jnp.asarray(value))
+    else:
+        S, N = (jnp.stack(x) for x in
+                zip(*[jax.jit(sums)(jnp.asarray(v)) for v in value]))
+    assert N.dtype == S.dtype == jnp.float32
+    for b in range(2):
+        S0, N0 = _ref_shell_sums(shell, value[b],
+                                 1 if weight is None else weight, nbins)
+        np.testing.assert_array_equal(np.asarray(N[b]), N0)
+        np.testing.assert_allclose(np.asarray(S[b]), S0, rtol=1e-5,
+                                   atol=1e-6)
+
+
+def test_shell_sums_error_on_a_chi_squared_field(record_property):
+    """The served spectrum's dynamic range: |delta_k|^2 is chi-squared
+    with two degrees of freedom, under a power law in the shell.  The
+    three bf16 parts give the MXU the f32 product bit for bit, so the
+    sum is no rounder than an f32 scatter-add's (on this field, on the
+    CPU: 9.8e-8 for the scatter-add's 1.05e-7; at 512^3 on the chip
+    1.0e-7 for 8.5e-7, and 4.9e-6 with two parts, on the first
+    shell)."""
+    from nbodykit_tpu.ops.histogram import lattice_shell_index
+    n, nbins = 64, 32
+    rng = np.random.RandomState(11)
+    i = np.fft.fftfreq(n, 1.0 / n).astype('i4')
+    iz = np.arange(n // 2 + 1, dtype='i4')
+    shell = np.asarray(lattice_shell_index(jnp.asarray(
+        i[:, None, None] ** 2 + i[None, :, None] ** 2
+        + iz[None, None, :] ** 2), nbins))
+    value = ((rng.standard_normal((2,) + shell.shape) ** 2).sum(axis=0)
+             * 1e4 / (1.0 + shell) ** 2).astype('f4')
+    weight = np.where((iz == 0) | (iz == n // 2), 1, 2).astype('f4')
     S, N = jax.jit(shell_sums, static_argnums=2)(
         jnp.asarray(shell), jnp.asarray(value), nbins,
         jnp.asarray(weight))
-    w = np.broadcast_to(weight, shape)
-    assert N.dtype == S.dtype == jnp.float32
-    np.testing.assert_array_equal(
-        np.asarray(N), np.bincount(shell.ravel(), w.ravel(), nbins))
-    np.testing.assert_allclose(
-        np.asarray(S),
-        np.bincount(shell.ravel(), (value * w).ravel().astype('f8'),
-                    nbins), rtol=1e-5, atol=1e-6)
-    # default weight 1: plain counts
-    _, N1 = shell_sums(jnp.asarray(shell), jnp.asarray(value), nbins)
-    np.testing.assert_array_equal(
-        np.asarray(N1), np.bincount(shell.ravel(), minlength=nbins))
+    S0, N0 = _ref_shell_sums(shell, value, weight, nbins)
+    np.testing.assert_array_equal(np.asarray(N), N0)
+    err = float(np.max(np.abs(np.asarray(S, 'f8') - S0) / S0))
+    record_property("max_rel_err_vs_f64", err)
+    assert err < 5e-7
 
 
-def test_shell_sums_counts_past_the_f32_stall():
-    """More than 2^25 unit weights in ONE shell, the catch-all last
-    shell of a 512^3 served spectrum in miniature: a single f32
-    accumulator stops at 2^24 (shown here, so the test is known to be
-    large enough to catch a relapse); the per-row partials count every
+@pytest.mark.parametrize("weight, cols", [(None, 2 ** 22 + 1),
+                                          (2, 2 ** 21 + 1)])
+def test_shell_sums_counts_past_the_f32_stall(weight, cols):
+    """More than 2^25 of weight in ONE shell (2^25 + 8 unit weights;
+    2^24 + 8 weights of 2), the catch-all last shell of a 512^3 served
+    spectrum in miniature: a single f32 accumulator stops at 2^24 (at
+    2^25 under weight 2; shown here, so the test is known to be large
+    enough to catch a relapse); the per-chunk partials count every
     cell."""
-    nbins, rows, cols = 4, 8, 2 ** 22 + 1
-    n = rows * cols                       # 2^25 + 8
+    nbins, rows = 4, 8
+    n = rows * cols * (weight or 1)
     value = jnp.ones((rows, 1, cols), jnp.float32)
     shell = jnp.full((1, 1, 1), nbins - 1, jnp.int32)
-    S, N = jax.jit(shell_sums, static_argnums=2)(shell, value, nbins)
+    S, N = jax.jit(shell_sums, static_argnums=2)(
+        shell, value, nbins,
+        None if weight is None else jnp.full((1, 1, 1), weight,
+                                             jnp.float32))
     assert np.asarray(N).tolist() == [0, 0, 0, n]
     assert float(S[-1]) == pytest.approx(n, rel=1e-6)
     naive = jnp.zeros(1, jnp.float32).at[
-        jnp.zeros(n, jnp.int32)].add(jnp.ones(n, jnp.float32))
+        jnp.zeros(rows * cols, jnp.int32)].add(
+            jnp.full(rows * cols, weight or 1, jnp.float32))
     assert float(naive[0]) < n
 
 

@@ -153,21 +153,35 @@ def test_serve_warm_cache_second_request_compiles_nothing():
         assert _counter('serve.program.reuse') >= 1
 
 
-def test_serve_fftcorr_counts_every_cell():
-    """The served correlation function bins through the same
-    :func:`~nbodykit_tpu.ops.histogram.shell_sums` as the served
-    spectrum: integer shell counts that add up to the mesh."""
+@pytest.mark.parametrize('algorithm', ['FFTPower', 'FFTCorr'])
+def test_serve_shell_counts_are_the_lattice_and_repeat(algorithm):
+    """The served spectrum and the served correlation function bin
+    through one :func:`~nbodykit_tpu.ops.histogram.shell_sums`: integer
+    shell counts equal to a numpy count of the lattice (Hermitian
+    pairs twice and the DC mode dropped in k space; every cell in real
+    space), and the same bytes for the same seed twice."""
+    n = 32
     with _one_worker_server(batch=BatchPolicy(max_delay_s=0)) as srv:
-        r = srv.wait(srv.submit(AnalysisRequest(
-            algorithm='FFTCorr', nmesh=32, npart=20000, seed=5)),
-            timeout=180)
-    assert r.status == 'completed'
+        r, again = [srv.wait(srv.submit(AnalysisRequest(
+            algorithm=algorithm, nmesh=n, npart=20000, seed=5)),
+            timeout=180) for _ in range(2)]
+    assert r.status == again.status == 'completed'
+    i = np.abs(np.fft.fftfreq(n, 1.0 / n)).astype('i8') ** 2
+    shell = np.minimum(np.floor(np.sqrt(
+        i[:, None, None] + i[None, :, None] + i[None, None, :])),
+        n // 2 - 1).astype('i8')
+    want = np.bincount(shell.ravel(), minlength=n // 2)
+    if algorithm == 'FFTPower':
+        want[0] -= 1
+    else:
+        # shell 1: the 6 face neighbours + 12 edge (sqrt 2) + 8 corner
+        # (sqrt 3) cells of the periodic lattice
+        assert want[0] == 1 and want[1] == 26
     nm = np.asarray(r.nmodes)
-    assert nm.sum() == 32 ** 3 and nm[0] == 1
-    # shell 1: the 6 face neighbours + 12 edge (sqrt 2) + 8 corner
-    # (sqrt 3) cells of the periodic lattice
-    assert nm[1] == 26
+    np.testing.assert_array_equal(nm, want)
     assert np.isfinite(np.asarray(r.y)).all()
+    assert np.asarray(r.y).tobytes() == np.asarray(again.y).tobytes()
+    np.testing.assert_array_equal(np.asarray(again.nmodes), nm)
 
 
 def test_serve_batched_bit_equal_to_sequential():

@@ -106,6 +106,16 @@ def test_served_fftpower_program_fits_and_matches_the_plan(one_chip):
     plan = memory_plan(NMESH, NPART, hbm_bytes=V5E_HBM)
     assert plan['fits']
     assert need <= plan['peak_bytes'] / 0.85, (need, plan['peak_bytes'])
+    # the binning sums are matrix products: the two scatter-adds over
+    # the 512 x 512 x 257 modes (0.59 s each a request on the chip) and
+    # the flat index they took are gone
+    hlo = compiled.as_text()
+    binning = [line for line in hlo.splitlines()
+               if 'nbk.fftpower.binning.hist' in line]
+    assert any(' convolution(' in line for line in binning)
+    assert not any('scatter' in line for line in hlo.splitlines()
+                   if 'nbk.fftpower.binning' in line)
+    assert 's32[%d]' % (NMESH * NMESH * (NMESH // 2 + 1)) not in hlo
 
 
 @pytest.mark.parametrize('op', ['r2c', 'c2r'])
