@@ -8,8 +8,8 @@ Cartesian bounding box from the randoms and hands off to FKPCatalogMesh.
 import numpy as np
 import jax.numpy as jnp
 
+from ...diagnostics import instrumented_jit
 from ...source.catalog.species import MultipleSpeciesCatalog
-from ...utils import as_numpy
 
 
 def FKPWeightFromNbar(P0, nbar):
@@ -17,6 +17,15 @@ def FKPWeightFromNbar(P0, nbar):
     if P0 != 0:
         return 1.0 / (1.0 + P0 * nbar)
     return 1.0
+
+
+@instrumented_jit(label='convpower.extent')
+def _extent(pos, sel):
+    """How many rows are selected, and their per-axis minimum and
+    maximum."""
+    keep = sel[:, None]
+    return (sel.sum(), jnp.where(keep, pos, jnp.inf).min(axis=0),
+            jnp.where(keep, pos, -jnp.inf).max(axis=0))
 
 
 class FKPCatalog(MultipleSpeciesCatalog):
@@ -57,14 +66,15 @@ class FKPCatalog(MultipleSpeciesCatalog):
         """BoxSize (padded extent) and BoxCenter from the positions of
         ``species`` (reference :110+)."""
         cat = self[species]
-        pos = as_numpy(cat[position])
-        sel = as_numpy(cat[selection]).astype(bool)
-        pos = pos[sel]
-        if len(pos) == 0:
+        pos = cat[position]
+        sel = jnp.asarray(cat[selection]).astype(bool)
+        # six scalars to the host, not the 1e7 x 3 positions
+        count, pos_min, pos_max = _extent(pos, sel) if len(cat) \
+            else (0, None, None)
+        if int(count) == 0:
             raise ValueError("no selected objects in %r to define the "
                              "bounding box" % species)
-        pos_min = pos.min(axis=0)
-        pos_max = pos.max(axis=0)
+        pos_min, pos_max = np.asarray(pos_min), np.asarray(pos_max)
         if np.isinf(pos_min).any() or np.isinf(pos_max).any():
             raise ValueError("infinite position range in %r" % species)
 

@@ -6,12 +6,59 @@ with positions re-centered to [-L/2, L/2].
 """
 
 import numpy as np
+import jax
 import jax.numpy as jnp
 
 from ...source.mesh.species import MultipleSpeciesCatalogMesh
 from ...source.mesh.catalog import CatalogMesh
 from ...base.mesh import Field
-from ...utils import as_numpy
+from ...diagnostics import instrumented_jit, scope
+
+
+@instrumented_jit(label='convpower.combine')
+def _fkp_field(data, randoms, alpha, vol_per_cell):
+    """``(data - alpha * randoms) / vol_per_cell`` as one program (no
+    randoms: ``data / vol_per_cell``).  Op by op the two painted
+    fields, their difference and the quotient were four mesh-sized
+    fields whose lifetimes moved with the host's lead over the device,
+    and the call's peak allocation with them
+    (``fftpower._cross_power`` has the story)."""
+    total = data if randoms is None else data - alpha * randoms
+    return total / vol_per_cell
+
+
+#: lanes of :func:`column_total`'s accumulator
+_LANES = 1 << 16
+
+
+@instrumented_jit(label='convpower.total')
+def _lane_sums(x):
+    """``x`` folded into rows of ``_LANES`` and summed row by row,
+    each lane with Kahan's compensation: the sums and what they lost."""
+    rows = -(-x.shape[0] // _LANES)
+    x = jnp.pad(x, (0, rows * _LANES - x.shape[0])).reshape(rows, _LANES)
+
+    def add(carry, row):
+        total, lost = carry
+        y = row - lost
+        t = total + y
+        return (t, (t - total) - y), None
+    zero = jnp.zeros(_LANES, x.dtype)
+    return jax.lax.scan(add, (zero, zero), x)[0]
+
+
+def column_total(x):
+    """Sum of a catalog column as a host float.  The survey's
+    normalisation and shot noise are sums over every row of a
+    catalog, and P(k) is divided by one of them: a plain f4 ``sum``
+    of 2e5 equal values read 1.5e-6 off on the chip, where equal
+    addends round the same way at every step.  Compensated lane sums
+    on the device (elementwise adds only, so no more than a rounding
+    of f4 whatever the backend's reduction does), the lanes added in
+    f8 on the host."""
+    total, lost = _lane_sums(jnp.asarray(x))
+    return float(np.asarray(total, 'f8').sum()
+                 - np.asarray(lost, 'f8').sum())
 
 
 class FKPCatalogMesh(MultipleSpeciesCatalogMesh):
@@ -59,7 +106,7 @@ class FKPCatalogMesh(MultipleSpeciesCatalogMesh):
         cat = self.source[name]
         sel = cat[self.selection]
         w = cat[self.comp_weight]
-        return float(jnp.where(sel, w, 0.0).sum())
+        return column_total(jnp.where(sel, w, 0.0))
 
     def __getitem__(self, species):
         if species not in self.source.species:
@@ -83,25 +130,28 @@ class FKPCatalogMesh(MultipleSpeciesCatalogMesh):
         """The FKP density field (number density units); attrs carry
         data.W / randoms.W / alpha and per-species paint meta-data."""
         attrs = {}
-        for name in self.source.species:
-            attrs[name + '.W'] = self.weighted_total(name)
+        with scope('convpower.stats'):
+            for name in self.source.species:
+                attrs[name + '.W'] = self.weighted_total(name)
         attrs['alpha'] = attrs['data.W'] / attrs['randoms.W'] \
             if attrs['randoms.W'] > 0 else 1.0
 
-        data_field = self['data'].to_real_field(normalize=False)
-        for k, v in data_field.attrs.items():
-            attrs['data.' + k] = v
-        total = data_field.value
-
-        if len(self.source['randoms']) > 0:
-            ran_field = self['randoms'].to_real_field(normalize=False)
-            for k, v in ran_field.attrs.items():
-                attrs['randoms.' + k] = v
-            total = total - attrs['alpha'] * ran_field.value
-
-        vol_per_cell = float(np.prod(self.attrs['BoxSize'] /
-                                     self.attrs['Nmesh']))
-        total = total / vol_per_cell
+        species = [name for name in self.source.species
+                   if name == 'data' or len(self.source[name]) > 0]
+        with scope('convpower.density', species=species,
+                   npart=sum(len(self.source[name]) for name in species),
+                   resampler=self.resampler) as sc:
+            painted = {}
+            for name in species:
+                field = self[name].to_real_field(normalize=False)
+                for k, v in field.attrs.items():
+                    attrs['%s.%s' % (name, k)] = v
+                painted[name] = field.value
+            vol_per_cell = float(np.prod(self.attrs['BoxSize'] /
+                                         self.attrs['Nmesh']))
+            total = sc.done(_fkp_field(
+                painted['data'], painted.get('randoms'), attrs['alpha'],
+                vol_per_cell))
         attrs.pop('data.shotnoise', None)
         attrs.pop('randoms.shotnoise', None)
         return Field(total, self.pm, 'real', attrs)

@@ -18,17 +18,18 @@ shortcut is only exact for even ell under a varying line of sight.
 """
 
 import logging
-import time
+from functools import lru_cache as _lru_cache
 
 import numpy as np
 import jax
 import jax.numpy as jnp
 
 from ...binned_statistic import BinnedStatistic
+from ...diagnostics import counter, instrumented_jit, scope
 from ...utils import JSONEncoder, JSONDecoder, working_dtype
 from ..fftpower import project_to_basis, _find_unique_edges
 from ...base.mesh import Field
-from .catalogmesh import FKPCatalogMesh
+from .catalogmesh import FKPCatalogMesh, column_total
 from .catalog import FKPCatalog
 from ...ops.window import compensation_transfer
 
@@ -82,6 +83,92 @@ def get_real_Ylm(l, m):
     return Ylm
 
 
+@_lru_cache(maxsize=8)
+def _ell_program(ell, nmesh, boxsize, dtype, comm, resampler, interlaced,
+                 use_c2c):
+    """``A_ell = 4 pi V sum_m FFT[F Ylm(x/|x|)] Ylm(k/|k|)`` of an FKP
+    density ``F``, the window divided out, as one program per ell,
+    ``prog(F, origin)`` with ``origin`` the position of the first mesh
+    point; ``ell = 0`` is ``V FFT[F]`` with no harmonic.
+
+    Cached on everything its body reads beside those two: a survey
+    analyst runs one call per mock of a covariance set, and a program
+    built per call is traced and lowered again in each.  The
+    coordinates are rebuilt from these scalars as AXIS VECTORS (a few
+    KB); the full-mesh unit vectors x/|x| and k/|k| are formed inside
+    the program, where XLA fuses them into the Ylm weights.  Built
+    eagerly (as before round 4) they were six full-mesh arrays baked,
+    with the density, into every executable as constants: ~35 GB at
+    Nmesh=1024.  The box's centre moves with every catalog, so it is
+    an argument too; its size is rounded up to whole numbers and
+    stays."""
+    from ...parallel.dfft import dist_fftn_c2c
+    from ...parallel.runtime import use_mesh
+    from ...pmesh import ParticleMesh
+    with use_mesh(comm):
+        pm = ParticleMesh(Nmesh=nmesh, BoxSize=boxsize, dtype=dtype,
+                          comm=comm)
+    volume = float(np.prod(pm.BoxSize))
+    transfer = compensation_transfer(resampler, interlaced)
+    ctype = jnp.complex64 if pm.dtype.itemsize <= 4 else jnp.complex128
+
+    def forward(x):
+        if use_c2c:
+            return dist_fftn_c2c(x.astype(ctype), pm.comm) \
+                * (1.0 / pm.Ntot)
+        return pm.r2c(x)
+
+    def compensate(A, factor):
+        with scope('fftpower.transfer'):
+            w_circ = pm.k_list(circular=True, full=use_c2c)
+            return transfer(w_circ, A) * factor
+
+    # best-available precision, decided explicitly (NBK301): f8 under
+    # x64, f4 on TPU where jnp.float64 would demote silently
+    _f8 = working_dtype('f8')
+    cshape = (pm.shape_complex if not use_c2c else
+              (int(pm.Nmesh[1]), int(pm.Nmesh[0]), int(pm.Nmesh[2])))
+    harmonics = [get_real_Ylm(ell, m) for m in range(-ell, ell + 1)]
+
+    def prog(dens, origin):
+        if ell == 0:
+            return compensate(forward(dens), volume)
+        with scope('convpower.ylm'):
+            xvec = [x + o for x, o in
+                    zip(pm.x_list(dtype=_f8), origin.astype(_f8))]
+            xn = jnp.sqrt(sum(x * x for x in xvec))
+            xn = jnp.where(xn == 0, 1.0, xn)
+            xu = [x / xn for x in xvec]
+            kvec = pm.k_list(dtype=_f8, full=use_c2c)
+            kn = jnp.sqrt(sum(k * k for k in kvec))
+            kn = jnp.where(kn == 0, jnp.inf, kn)
+            ku = [k / kn for k in kvec]
+            Aell = jnp.zeros(cshape, dtype=ctype)
+        for Ylm in harmonics:
+            # one term after the other: left to itself the TPU
+            # compiler weights the density by all 2 ell + 1 harmonics
+            # at once and keeps every transform's workspace alive
+            # (14.5 GB of temporaries for ell = 4 at 512^3, 1.9 for
+            # one transform)
+            dens, Aell = jax.lax.optimization_barrier((dens, Aell))
+            with scope('convpower.ylm'):
+                weighted = dens * Ylm(*xu).astype(dens.dtype)
+            ck = forward(weighted)
+            with scope('convpower.ylm'):
+                Aell = Aell + ck * Ylm(*ku)
+        return compensate(Aell, 4 * np.pi * volume)
+    return instrumented_jit(prog, label='convpower.ell')
+
+
+@instrumented_jit(label='convpower.p3d')
+def _pole_power(a0, aell, norm):
+    """``norm * A_0 * conj(A_ell)`` as one program: op by op it is
+    three mesh-sized complex fields in front of a host that runs ahead
+    of the device (``fftpower._cross_power`` has the story).  The DC
+    mode is kept, as upstream's estimator keeps it."""
+    return norm * a0 * jnp.conj(aell)
+
+
 class ConvolvedFFTPower(object):
     """Power-spectrum multipoles of an FKP-weighted survey catalog.
 
@@ -119,7 +206,9 @@ class ConvolvedFFTPower(object):
         self.attrs['BoxSize'] = first.attrs['BoxSize']
         self.attrs['BoxCenter'] = first.attrs['BoxCenter']
 
-        self.run()
+        with scope('convpower.run', poles=self.attrs['poles'],
+                   nmesh=int(self.attrs['Nmesh'][0])):
+            self.run()
 
     def run(self):
         pm = self.first.pm
@@ -145,8 +234,6 @@ class ConvolvedFFTPower(object):
 
     def _compute_multipoles(self, kedges):
         pm = self.first.pm
-        volume = float(np.prod(pm.BoxSize))
-
         poles = sorted(self.attrs['poles'])
         if 0 not in poles:
             poles = [0] + poles
@@ -155,15 +242,6 @@ class ConvolvedFFTPower(object):
         # the full complex spectrum — the hermitian (r2c) shortcut only
         # holds for even ell (reference: the dtype='c16' path)
         use_c2c = any(ell % 2 for ell in poles)
-        from ...parallel.dfft import dist_fftn_c2c
-
-        def forward(x):
-            if use_c2c:
-                return dist_fftn_c2c(x.astype(jnp.complex64
-                                     if pm.dtype.itemsize <= 4 else
-                                     jnp.complex128), pm.comm) \
-                    * (1.0 / pm.Ntot)
-            return pm.r2c(x)
 
         # the FKP density field
         rfield1 = self.first.compute(Nmesh=self.attrs['Nmesh'],
@@ -171,38 +249,48 @@ class ConvolvedFFTPower(object):
         meta1 = dict(rfield1.attrs)
         self.attrs['alpha'] = meta1['alpha']
 
-        transfer = compensation_transfer(self.first.resampler,
-                                         self.first.interlaced)
-        w_circ = pm.k_list(circular=True, full=use_c2c)
+        # the first mesh point, in the catalog's own coordinates
+        # (half a cell from where the deposit puts it, as upstream's
+        # ``offset = BoxCenter + 0.5 * BoxSize / Nmesh``)
+        origin = self.attrs['BoxCenter'] - pm.BoxSize / 2.0 \
+            + 0.5 * pm.cellsize
 
-        c1 = forward(rfield1.value)
-        c1 = transfer(w_circ, c1)
-        A0_1 = c1 * volume
+        def term(ell, mesh, dens):
+            """``A_ell`` of one mesh's density: the cached program of
+            :func:`_ell_program`, launched under the layer's name."""
+            prog = _ell_program(
+                ell, tuple(int(n) for n in pm.Nmesh),
+                tuple(float(b) for b in pm.BoxSize), pm.dtype.str,
+                pm.comm, mesh.resampler, bool(mesh.interlaced), use_c2c)
+            nfft = 2 * ell + 1
+            counter('convpower.ffts').add(nfft)
+            with scope('convpower.ylm', ell=ell, nfft=nfft) as sc:
+                return sc.done(prog(dens, origin))
 
+        A0_1 = term(0, self.first, rfield1.value)
         if self.first is not self.second:
             rfield2 = self.second.compute(Nmesh=self.attrs['Nmesh'],
                                           mode='real')
             meta2 = dict(rfield2.attrs)
             if not np.allclose(meta1['alpha'], meta2['alpha'],
                                rtol=1e-3):
-                # NBK103 (baselined, audited): raises between the two
-                # forward FFTs' collectives, but alpha is global
-                # catalog metadata identical on every rank — all ranks
-                # raise together, the exception path is rank-uniform
+                # NBK103 (baselined, audited; so is the check of the
+                # norms below): raised between the per-ell programs'
+                # collectives, but alpha is global catalog metadata
+                # identical on every rank, so all ranks raise together
                 raise ValueError(
                     "cross-correlations require the same FKPCatalog "
                     "geometry (matching alpha)")
-            c2 = transfer(w_circ, forward(rfield2.value)) * volume
-            A0_2 = c2
+            A0_2 = term(0, self.second, rfield2.value)
         else:
             rfield2 = rfield1
-            meta2 = meta1
             A0_2 = A0_1
 
         # normalization & shot noise from catalog sums
-        for name in ['data', 'randoms']:
-            self.attrs[name + '.norm'] = self.normalization(
-                name, self.attrs['alpha'])
+        with scope('convpower.stats'):
+            for name in ['data', 'randoms']:
+                self.attrs[name + '.norm'] = self.normalization(
+                    name, self.attrs['alpha'])
         if self.attrs['randoms.norm'] > 0:
             norm = 1.0 / self.attrs['randoms.norm']
             Adata = self.attrs['data.norm']
@@ -215,93 +303,29 @@ class ConvolvedFFTPower(object):
         else:
             norm = 1.0
 
-        # coordinate AXIS VECTORS only (a few KB): the full-mesh unit
-        # vectors x/|x| and k/|k| are formed INSIDE the jitted
-        # per-multipole program below, where XLA fuses them into the
-        # Ylm weights. Building them eagerly here (as before round 4)
-        # materialized six full-mesh f64 arrays and then baked them —
-        # plus the density field — into every per-ell executable as
-        # constants: ~35 GB of duplicated buffers at Nmesh=1024, the
-        # OOM observed in the boss_like benchmark, and a guaranteed
-        # HBM blow-up on a 16 GB TPU chip.
-        N0, N1, N2 = pm.shape_real
-        H = pm.cellsize
-        offset = self.attrs['BoxCenter'] - pm.BoxSize / 2.0 + 0.5 * H
-
-        # best-available precision, decided explicitly (NBK301): f8
-        # under x64, f4 on TPU where jnp.float64 would demote silently
-        _f8 = working_dtype('f8')
-        xvec = [(jnp.arange(N0, dtype=_f8) * H[0]
-                 + offset[0]).reshape(N0, 1, 1),
-                (jnp.arange(N1, dtype=_f8) * H[1]
-                 + offset[1]).reshape(1, N1, 1),
-                (jnp.arange(N2, dtype=_f8) * H[2]
-                 + offset[2]).reshape(1, 1, N2)]
-        kvec = pm.k_list(dtype=_f8, full=use_c2c)
-
-        cols = ['k'] + ['power_%d' % l for l in
-                        sorted(self.attrs['poles'])] + ['modes']
-        dtype = [('k', 'f8')] + [('power_%d' % l, 'c16') for l in
-                                 sorted(self.attrs['poles'])] + \
+        cols = ['power_%d' % l for l in sorted(self.attrs['poles'])]
+        dtype = [('k', 'f8')] + [(c, 'c16') for c in cols] + \
             [('modes', 'i8')]
         result = np.empty(len(kedges) - 1, dtype=np.dtype(dtype))
 
         muedges = np.linspace(-1, 1, 2)
-        density2 = rfield2.value
-
-        cshape = (pm.shape_complex if not use_c2c else
-                  (int(pm.Nmesh[1]), int(pm.Nmesh[0]),
-                   int(pm.Nmesh[2])))
-
-        def make_ell_term(ell):
-            """Aell = sum_m FFT[F * Ylm(x/|x|)] * Ylm(k/|k|),
-            compensated, * 4pi * volume — one jitted program per ell.
-            The density is a real argument (not a baked constant) and
-            the unit-vector meshes are fused into the Ylm weights."""
-            def prog(dens):
-                xn = jnp.sqrt(sum(x * x for x in xvec))
-                xn = jnp.where(xn == 0, 1.0, xn)
-                xu = [x / xn for x in xvec]
-                kn = jnp.sqrt(sum(k * k for k in kvec))
-                kn = jnp.where(kn == 0, jnp.inf, kn)
-                ku = [k / kn for k in kvec]
-                Aell = jnp.zeros(cshape, dtype=A0_1.dtype)
-                for m in range(-ell, ell + 1):
-                    Ylm = get_real_Ylm(ell, m)
-                    wx = Ylm(xu[0], xu[1], xu[2])
-                    ck = forward(dens * wx.astype(dens.dtype))
-                    Aell = Aell + ck * Ylm(ku[0], ku[1], ku[2])
-                Aell = transfer(w_circ, Aell)
-                return Aell * (4 * np.pi * volume)
-            # one program per ell BY DESIGN: each executes exactly
-            # once, and memoizing across run() calls would pin the
-            # fused Ylm/unit-vector constants (~GBs at Nmesh=1024) in
-            # HBM for the life of the process
-            return jax.jit(prog)   # nbkl: disable=NBK202
-
-        proj_result = None
-        for ell in poles[1:]:
-            t0 = time.time()
-            Aell = make_ell_term(ell)(density2)
-            p3d = norm * A0_1 * jnp.conj(Aell)
-            field = Field(p3d, pm, 'complex')
-            proj, _ = project_to_basis(field, [kedges, muedges])
+        proj = None
+        for ell in poles[1:] + poles[:1]:
+            if 'power_%d' % ell not in cols:
+                continue        # the monopole was not asked for
+            Aell = A0_2 if ell == 0 else \
+                term(ell, self.second, rfield2.value)
+            with scope('fftpower.transfer') as sc:
+                p3d = sc.done(_pole_power(A0_1, Aell, norm))
+            proj, _ = project_to_basis(Field(p3d, pm, 'complex'),
+                                       [kedges, muedges])
             result['power_%d' % ell][:] = np.squeeze(proj[2])
-            self.logger.info("ell = %d done (%d FFTs, %.2fs)"
-                             % (ell, 2 * ell + 1, time.time() - t0))
-            proj_result = proj
 
-        if 0 in self.attrs['poles']:
-            p3d = norm * A0_1 * jnp.conj(A0_2)
-            field = Field(p3d, pm, 'complex')
-            proj, _ = project_to_basis(field, [kedges, muedges])
-            result['power_0'][:] = np.squeeze(proj[2])
-            proj_result = proj
+        result['k'][:] = np.squeeze(proj[0])
+        result['modes'][:] = np.squeeze(proj[3])
 
-        result['k'][:] = np.squeeze(proj_result[0])
-        result['modes'][:] = np.squeeze(proj_result[3])
-
-        self.attrs['shotnoise'] = self.shotnoise(self.attrs['alpha'])
+        with scope('convpower.stats'):
+            self.attrs['shotnoise'] = self.shotnoise(self.attrs['alpha'])
 
         for key in ['data.W', 'randoms.W', 'data.N', 'randoms.N',
                     'data.num_per_cell', 'randoms.num_per_cell']:
@@ -320,8 +344,7 @@ class ConvolvedFFTPower(object):
         nbar = cat2[mesh2.nbar]
         w1 = cat1[mesh1.fkp_weight]
         w2 = w1 if mesh1 is mesh2 else cat2[mesh2.fkp_weight]
-        A = jnp.where(sel, nbar * comp * w1 * w2, 0.0).sum()
-        A = float(A)
+        A = column_total(jnp.where(sel, nbar * comp * w1 * w2, 0.0))
         if name == 'randoms':
             A *= alpha
         return A
@@ -339,7 +362,7 @@ class ConvolvedFFTPower(object):
             comp = cat1[mesh1.comp_weight]
             w1 = cat1[mesh1.fkp_weight]
             w2 = w1 if mesh1 is mesh2 else cat2[mesh2.fkp_weight]
-            S = float(jnp.where(sel, comp ** 2 * w1 * w2, 0.0).sum())
+            S = column_total(jnp.where(sel, comp ** 2 * w1 * w2, 0.0))
             if name == 'randoms':
                 S *= alpha ** 2
             Pshot += S

@@ -16,6 +16,8 @@ the codebase uses everywhere:
   ``(jitted if eager else raw)(x)`` — the cached program pair: the
   names resolve through the builder's returned tuple, and the
   conditional pick reaches the one body both forms run;
+- ``prog = _ell_program(...)`` then ``prog(x)`` — a builder whose one
+  return is one wrapped body: the name resolves to that body;
 - ``from ..parallel import dfft; dfft.rfftn_single_lowmem(box)`` —
   resolved through the import alias table to the def in the other
   module's context.
@@ -157,14 +159,12 @@ class Project(object):
                         break
             if not isinstance(call, ast.Call):
                 continue
-            ref = self._resolve(ctx, call.func, call,
-                                frozenset(), False)[0]
+            ref = self._builder_ref(ctx, call)
             if ref is None:
-                ref = self._dotted_ref(ctx, call.func)
-            if ref is None or isinstance(ref.node, ast.Lambda):
                 continue
-            ret = self._literal_return_tuple(ref)
-            if ret is None or len(ret.elts) != len(targets.elts):
+            ret = self._single_return(ref)
+            if not isinstance(ret, (ast.Tuple, ast.List)) or \
+                    len(ret.elts) != len(targets.elts):
                 continue
             for t, elt in zip(targets.elts, ret.elts):
                 if not isinstance(t, ast.Name):
@@ -172,10 +172,28 @@ class Project(object):
                 ent = self._element_entry(ref, elt)
                 if ent is not None:
                     table.setdefault(scope, {})[t.id] = ent
+        for (scope, name), call in call_assigns.items():
+            # ``prog = _ell_program(...)`` (convpower/fkp.py): a
+            # builder whose one return is one wrapped body
+            ref = self._builder_ref(ctx, call)
+            ret = ref and self._single_return(ref)
+            if isinstance(ret, ast.Call):
+                ent = self._element_entry(ref, ret)
+                if ent is not None:
+                    table.setdefault(scope, {})[name] = ent
         return table
 
-    def _literal_return_tuple(self, ref):
-        """The single literal Tuple a function returns, or None."""
+    def _builder_ref(self, ctx, call):
+        """The def a builder call reaches, or None."""
+        ref = self._resolve(ctx, call.func, call, frozenset(), False)[0]
+        if ref is None:
+            ref = self._dotted_ref(ctx, call.func)
+        if ref is None or isinstance(ref.node, ast.Lambda):
+            return None
+        return ref
+
+    def _single_return(self, ref):
+        """The value of the one ``return`` of a def, or None."""
         ret = None
         for node in ast.walk(ref.node):
             if isinstance(node, ast.Return) and \
@@ -183,7 +201,7 @@ class Project(object):
                 if ret is not None:
                     return None     # several returns: ambiguous
                 ret = node.value
-        return ret if isinstance(ret, (ast.Tuple, ast.List)) else None
+        return ret
 
     def _element_entry(self, ref, elt):
         """Wrapper-table entry for one element of a builder's return

@@ -19,6 +19,8 @@ fuses and tiles cleanly. Particles are chunked with a fori_loop to bound
 the live set.
 """
 
+import functools
+
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -28,7 +30,8 @@ from .window import window_weights, window_weights_grad, window_support
 # enclosing program (these kernels run inside jit/shard_map), not per
 # execution — they document which kernel got traced at what size, not
 # how often it ran (see diagnostics/metrics.py)
-from ..diagnostics import counter, gauge, install_compile_telemetry
+from ..diagnostics import counter, gauge, install_compile_telemetry, \
+    instrumented_jit
 from ..parallel.runtime import vary_like
 
 # the paint kernels compile inside their enclosing jit: the *.trace.*
@@ -53,6 +56,23 @@ def _axis_terms(pos_ax, resampler, period, grad=False):
     return jnp.mod(idx, period), w
 
 
+@functools.partial(
+    instrumented_jit, label='paint.window',
+    static_argnames=('resampler', 'period', 'grad_axis'))
+def _window_terms(pos, resampler, period, grad_axis):
+    """:func:`_axis_terms` of the three axes, as one program on the
+    eager path (a staged caller inlines it: the served and the
+    four-chip paint programs compile to the same instructions with
+    and without the jit).  Op by op it is some thirty (n, 3)
+    temporaries (160 MB each at 1e7 particles), and how many were
+    still alive under the paint's first scatters moved with the
+    host's lead and with when the runtime's callbacks released them:
+    the survey call's peak allocation read 5.973, 6.142 or 6.302 GB
+    from one call to the next (PERF.md section 6, PR 32)."""
+    return tuple(_axis_terms(pos[:, ax], resampler, period[ax],
+                             grad=grad_axis == ax) for ax in range(3))
+
+
 def _offset_terms(pos, mass, resampler, period, origin, n0l,
                   grad_axis=None):
     """Yield (flat_rows_valid, lin_index, weight) triples — one per
@@ -72,12 +92,9 @@ def _offset_terms(pos, mass, resampler, period, origin, n0l,
             'local block (%d, %d, %d) overflows int32 flat indexing; '
             'shard the mesh over more devices or reduce nmesh'
             % (n0l, N1, N2))
-    i0, w0 = _axis_terms(pos[:, 0], resampler, period[0],
-                         grad=grad_axis == 0)
-    i1, w1 = _axis_terms(pos[:, 1], resampler, period[1],
-                         grad=grad_axis == 1)
-    i2, w2 = _axis_terms(pos[:, 2], resampler, period[2],
-                         grad=grad_axis == 2)
+    (i0, w0), (i1, w1), (i2, w2) = _window_terms(
+        pos, resampler=resampler,
+        period=tuple(int(p) for p in period), grad_axis=grad_axis)
     # local row index relative to block origin
     for a in range(s):
         row = jnp.mod(i0[:, a] - origin, period[0])

@@ -216,6 +216,38 @@ def test_nbk103_arms_of_different_programs_stay_unresolved():
     assert fs == []
 
 
+def test_nbk103_follows_a_cached_single_program():
+    # the idiom of convpower/fkp.py:_ell_program: a builder whose one
+    # return is one wrapped body, bound to a name and called.  The
+    # raise between two such calls is a finding
+    fs = lint_str("""
+import functools
+import jax
+from diagnostics import instrumented_jit
+
+@functools.lru_cache(maxsize=8)
+def _program(ell, mesh):
+    def prog(v):
+        if ell == 0:
+            return v
+        return jax.lax.psum(v, 'dev')
+
+    return instrumented_jit(prog, label='ell')
+
+def run(x, mesh, n):
+    def term(ell, v):
+        prog = _program(ell, mesh)
+        return prog(v)
+
+    x = term(0, x)
+    if n < 0:
+        raise ValueError('bad shard')
+    return term(2, x)
+""", select=['NBK103'])
+    assert codes(fs) == ['NBK103']
+    assert 'strands its peers' in fs[0].message
+
+
 def _collective_summaries():
     from nbodykit_tpu.lint.collectives import analysis_for
     from nbodykit_tpu.lint.walker import build_project
@@ -242,6 +274,8 @@ def collective_summaries():
     # paint's and readout's: the exchange, then the halo
     ('nbodykit_tpu.pmesh', 'attempt', 'all_to_all'),
     ('nbodykit_tpu.pmesh', 'attempt', 'ppermute'),
+    # the survey call's per-ell programs, behind ``_ell_program``
+    ('nbodykit_tpu.algorithms.convpower.fkp', 'term', 'all_to_all'),
 ])
 def test_nbk103_sees_the_collectives_of_the_slab_path(
         collective_summaries, module, function, token):

@@ -23,6 +23,20 @@ import pytest
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 REHEARSALS = [
+    'test_convpower_cell.py::test_convpower_driver_end_to_end',
+    'test_convpower_cell.py::'
+    'test_convpower_verify_catches_a_result_that_moved',
+    'test_convpower_cell.py::'
+    'test_convpower_oracle_catches_a_wrong_answer',
+    'test_convpower_cell.py::test_convpower_readers_on_a_survey_call',
+    'test_convpower_cell.py::'
+    'test_convpower_readers_without_the_survey_scopes',
+    'test_convpower_cell.py::'
+    'test_convpower_rooflines_withheld_above_the_unscoped_limit',
+    'test_convpower_cell.py::test_ylm_bytes_and_nfft_by_hand',
+    'test_convpower_cell.py::test_the_new_cell_reports_its_metrics',
+    'test_convpower_cell.py::'
+    'test_convpower_timed_result_is_held_to_the_reference',
     'test_four_chip_cell.py::test_a2a_bytes',
     'test_four_chip_cell.py::test_readers_with_both_layers[host]',
     'test_four_chip_cell.py::test_readers_with_both_layers[op_name]',

@@ -354,3 +354,47 @@ def test_staged_exchange_and_paint_of_the_four_chip_cell(four_chips,
     assert collective in compiled.as_text()
     assert compiled.memory_analysis().temp_size_in_bytes <= limit
     assert _total_bytes(compiled) + field < V5E_HBM
+
+
+#: the survey cell: boss_like's box after FKPCatalog pads the randoms'
+#: extent by 2% (PERF.md, PR 32)
+SURVEY_BOX = 2550.0
+
+
+def test_convpower_ell4_program_nine_transforms_at_512(one_chip):
+    # the hexadecapole of the survey call as ConvolvedFFTPower fetches
+    # it: nine Ylm-weighted r2c of 512^3 and their accumulation in one
+    # program.  Left to itself the compiler keeps all nine transforms'
+    # workspaces alive: 14.5 GB of temporaries, which compiles and then
+    # cannot run beside the density and A_0 (PR 22 met a 32x padded
+    # temporary between paint and r2c at this very mesh).  Taken one
+    # after the other they need 4.0 GB: one transform's 1.9, A_4, the
+    # weighted density and the three unit-vector fields
+    from nbodykit_tpu.algorithms.convpower.fkp import _ell_program
+    prog = _ell_program(4, (NMESH,) * 3, (SURVEY_BOX,) * 3,
+                        '<f4', None, 'tsc', False, False)
+    dens = jax.ShapeDtypeStruct((NMESH,) * 3, jnp.float32,
+                                sharding=one_chip)
+    origin = jax.ShapeDtypeStruct((3,), jnp.float32, sharding=one_chip)
+    compiled = prog._jitted.lower(dens, origin).compile()
+    field = 4 * NMESH ** 3
+    m = compiled.memory_analysis()
+    assert m.output_size_in_bytes <= 1.01 * field
+    assert m.temp_size_in_bytes < 0.3 * V5E_HBM, m.temp_size_in_bytes
+    text = compiled.as_text()
+    for name in ('nbk.convpower.ylm', 'nbk.fft.r2c',
+                 'nbk.fftpower.transfer'):
+        assert name in text, name
+
+
+def test_tsc_weighted_scatter_paint_1e7_into_512(one_chip):
+    # the randoms of the survey cell: 27 deposits a particle for CIC's
+    # 8, each with the particle's own signed weight
+    pm = _pm(NMESH, box=SURVEY_BOX)
+    pos = jax.ShapeDtypeStruct((NPART, 3), jnp.float32,
+                               sharding=one_chip)
+    mass = jax.ShapeDtypeStruct((NPART,), jnp.float32, sharding=one_chip)
+    compiled = _compile(
+        lambda p, m: pm.paint(p, m, resampler='tsc',
+                              return_dropped=True), pos, mass)
+    assert _total_bytes(compiled) < 0.25 * V5E_HBM, _total_bytes(compiled)
