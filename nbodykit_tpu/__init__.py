@@ -54,17 +54,17 @@ _default_options = {
     'paint_chunk_size': 1024 * 1024 * 16,
     # default resampler window
     'resampler': 'cic',
-    # paint kernel: 'scatter' (chunked scatter-add), 'sort'
-    # (scatter-free sort + segmented reduction), 'segsum', 'streams'
-    # or 'mxu' (tile-bucketed batched-matmul deposit); see
-    # ops/paint.py. On the chip the scatter beat the mxu paint
-    # (PERF.md section 6); the others have no chip row
-    'paint_method': 'scatter',
-    # bucket-capacity slack for the 'mxu' paint kernel
-    'paint_bucket_slack': 2.0,
-    # stable ordering engine for the mxu paint's bucketing: 'auto'
-    # (radix counting sort on TPU, bitonic argsort elsewhere),
-    # 'argsort', or 'radix' (ops/radix.py)
+    # paint kernel: 'mxu' (the tile deposit: one payload-carrying
+    # sort, contiguous bucket slices, per-tile matrix products at f32
+    # grade; on a block too small for its tiles, the scatter),
+    # 'scatter' (chunked scatter-add, what jax.grad runs), 'sort'
+    # (scatter-free sort + segmented reduction), 'segsum' or
+    # 'streams'; see ops/paint.py and PERF.md section 6, PR 33. The
+    # last three have no chip row
+    'paint_method': 'mxu',
+    # stable ordering engine of the 'segsum' paint: 'auto' (radix
+    # counting sort on TPU, bitonic argsort elsewhere), 'argsort', or
+    # 'radix' (ops/radix.py)
     'paint_order': 'auto',
     # deposit engine for the mxu paint: 'xla' (one-hot expansions via
     # XLA) or 'pallas' (fused VMEM kernel, ops/paint_pallas.py)
@@ -258,10 +258,9 @@ class set_options(object):
     resampler : str
         default window: 'nnb', 'cic', 'tsc', 'pcs'.
     paint_method : str
-        'scatter' (the default), 'sort', 'segsum', 'streams', 'mxu' —
-        the local deposit kernel.
-    paint_bucket_slack : float
-        bucket-capacity slack factor for the 'mxu' paint kernel.
+        'mxu' (the default: the tile deposit, the scatter where the
+        block is too small for its tiles), 'scatter', 'sort',
+        'segsum', 'streams' — the local deposit kernel.
     paint_streams : int
         replica-mesh count for the 'streams' paint kernel — the number
         of independent scatter chains the s^3 window-offset streams

@@ -25,7 +25,8 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from .window import window_weights, window_weights_grad, window_support
+from .window import (window_base, window_support, window_weights,
+                     window_weights_grad)
 # '.trace.' metrics below are bumped once per COMPILATION of the
 # enclosing program (these kernels run inside jit/shard_map), not per
 # execution — they document which kernel got traced at what size, not
@@ -39,9 +40,11 @@ from ..parallel.runtime import vary_like
 # feeds time the actual backend compiles
 install_compile_telemetry()
 
-# default cap on the mxu paint's per-piece one-hot Z expansion; shared
-# with pmesh.memory_plan so the estimate tracks the kernel
-ZCHUNK_BYTES = 1 << 28
+# particles a row of the tile paint's sorted payload holds (a TPU
+# vector's lanes) and the rows of particles a tile takes per piece
+# (paint_local_mxu: ``ck``; pmesh.memory_plan prices it)
+LANES = 128
+PIECE_ROWS = 256
 
 
 def _axis_terms(pos_ax, resampler, period, grad=False):
@@ -605,85 +608,112 @@ def paint_local_streams(pos, mass, shape, resampler='cic', period=None,
 
 
 # ---------------------------------------------------------------------------
-# MXU paint: tile-bucketed batched-matmul deposit
+# tile paint: one payload-carrying sort, contiguous bucket slices and
+# per-tile matrix products (the option value and the function keep the
+# name 'mxu')
 
-def _bucket_by_argsort(key, n, B, Kcap, order_method='auto'):
-    """Assign each particle a slot in a (B, Kcap) padded bucket layout.
+def _bf16_parts(w):
+    """f32 ``w`` as three bf16 addends, largest first: 24 mantissa
+    bits, so their sum is ``w`` itself.  Split on bf16's grid with
+    ``reduce_precision`` (ops/histogram.py:_bf16_grid: a convert pair
+    in one fusion is excess precision to the TPU compiler)."""
+    from .histogram import _bf16_grid
+    hi = _bf16_grid(w)
+    rest = w - hi
+    mid = _bf16_grid(rest)
+    return [p.astype(jnp.bfloat16)
+            for p in (hi, mid, _bf16_grid(rest - mid))]
 
-    Returns ``src`` (B*Kcap,) int32 — source particle index per padded
-    slot (== n for empty slots) — and ``overflow``, the number of
-    particles whose bucket exceeded Kcap (their deposits are dropped;
-    callers retry with a larger slack, mirroring the exchange-overflow
-    contract in parallel/exchange.py).
 
-    ``order_method`` picks the stable ordering engine: 'argsort' (one
-    bitonic lax sort — O(n log^2 n) HBM passes on TPU, but the fast
-    native sort on CPU), 'radix' (ops.radix.stable_key_order — O(n)
-    counting passes, the TPU-shaped choice), or 'auto' (radix on
-    MXU backends, argsort elsewhere). Both are stable, so the slot
-    assignment is IDENTICAL — tests/test_radix.py asserts it.
-    """
-    from .radix import order_keys
-    # alphabet is [0, B] (B = trash bucket)
-    order = order_keys(key, B + 1, order_method)
-    skey = key[order]
-    iot = jnp.arange(n, dtype=jnp.int32)
-    is_start = jnp.concatenate(
-        [jnp.ones((1,), bool), skey[1:] != skey[:-1]]) if n else \
-        jnp.zeros((0,), bool)
-    start = jax.lax.cummax(jnp.where(is_start, iot, 0))
-    rank = iot - start
-    over = (rank >= Kcap) & (skey < B)   # key == B is the trash bucket
-    slot = jnp.where((rank >= Kcap) | (skey >= B), B * Kcap,
-                     skey * Kcap + rank)
-    src = jnp.full(B * Kcap, n, jnp.int32)
-    src = src.at[slot].set(order.astype(jnp.int32), mode='drop',
-                           unique_indices=True)
-    return src, jnp.sum(over.astype(jnp.int32))
+def tile_geometry(shape, resampler, rb=8, cb=8):
+    """The tile deposit's geometry on a local block, or None where the
+    block does not admit it (a window wider than the block or a tile,
+    a wrap strip wider than an axis: test-sized meshes, which the
+    scatter kernel paints).  THE rule by which the default paint picks
+    its engine: a function of the block's shape and the window alone.
+
+    Returns ``(rb, cb, ntx, nty)``: tile height and width in cells,
+    x stripes over [0, n0l) (the deposit adds one leading wrap stripe)
+    and y tiles."""
+    n0l, N1, N2 = (int(x) for x in shape)
+    s = window_support(resampler)
+    # the leading tile must fit wrapped-to-valid deposits (rb) and the
+    # y-halo fold pads cb - (s-1) columns (cb)
+    rb, cb = max(rb, s), max(cb, s)
+    rb, cb = min(rb, n0l), min(cb, N1)
+    if n0l < max(s, 2) or N1 < s or N2 < s or n0l < rb:
+        return None
+    for rb, cb in ((rb, cb), (min(rb, max(s, n0l // 2)),
+                              min(cb, max(s, N1 // 2)))):
+        ntx, nty = -(-n0l // rb), -(-N1 // cb)
+        # a wrap strip wider than its axis would double-wrap in the
+        # single dense fold below
+        if ntx * rb - n0l + s - 1 <= n0l and nty * cb - N1 + s - 1 <= N1:
+            return rb, cb, ntx, nty
+    return None
 
 
 def paint_local_mxu(pos, mass, shape, resampler='cic', period=None,
-                    origin=0, out=None, rb=8, cb=8, slack=2.0,
-                    return_overflow=False, zchunk_bytes=ZCHUNK_BYTES,
-                    order_method='auto', deposit='xla'):
-    """Scatter particles onto a local mesh block via MXU matmuls.
+                    origin=0, out=None, rb=8, cb=8, ck=PIECE_ROWS,
+                    deposit='xla'):
+    """Scatter particles onto a local mesh block with no per-particle
+    scatter or gather: one sort that carries the payload, contiguous
+    bucket slices, per-tile matrix products.
 
-    TPU has no scatter atomics and XLA lowers scatter-add to a serial
-    per-element loop, so :func:`paint_local` is latency-bound at a few
-    Mpart/s. Here the deposit is reformulated as dense matrix products
-    (the B-spline window is separable): particles are bucketed by the
-    (x-row-tile, y-col-tile) of their *base* cell, each bucket padded to
-    a fixed capacity K, and for every tile the deposit is
+    On the chip one irregular access costs 8.9 ns an element and a
+    two-operand sort 2.2 ns (PERF.md section 6, PR 33), and
+    :func:`paint_local` issues one scatter-add of n per window offset.
+    Here the window's separability makes the deposit dense: particles
+    are bucketed by the (x-row-tile, y-col-tile) of their *base* cell
+    and for every tile
 
         block[(r, y), z] = sum_p W0Y[p, (r, y)] * Z[p, z]
 
-    i.e. one (M, K) @ (K, N2) matmul per tile with M = (rb+s-1)*(cb+s-1)
-    <= 128 rows — MXU work instead of serial scatters. W0Y carries the
-    x*y window product (times mass), Z the z window; both are built as
-    dense one-hot expansions on the VPU. Tiles are batched over y and
-    scanned over x with the mesh as carry, then halo/wrap strips are
-    folded in with dense shifted adds. Periodic wrapping never produces
-    a scatter: base cells near the boundary deposit into tile halos and
-    the fold maps them home.
+    is one product with M = (rb+s-1)*(cb+s-1) rows.  W0Y carries the
+    x*y window product times the mass, Z the z window.  Tiles are
+    batched over y and scanned over x with the mesh as carry, then
+    halo and wrap strips are folded in with dense shifted adds.
+    Periodic wrapping never produces a scatter: base cells near the
+    boundary deposit into tile halos and the fold maps them home.
 
-    The only irregular ops left are one sort of the n bucket keys and
-    one gather of the particle payload into the padded layout.
+    1. One ``lax.sort`` of ``(key, x, y, z, mass)`` by the bucket key
+       (stable): the sort moves the payload itself.  Zero-mass slots
+       and rows outside a slab block sort to a trash bucket.
+    2. A bucket is a contiguous run ``sorted[lo[b] : hi[b]]``; the
+       ``B + 1`` run edges come from one ``searchsorted`` of the
+       bucket ids.
+    3. The sorted columns are read as rows of LANES particles.  Piece
+       ``j`` of a stripe is, for each of its ``nty`` tiles, the ``ck /
+       LANES`` whole rows from row ``lo[b] // LANES + j * ck / LANES``
+       on (one row gather a column), the particles outside ``[lo[b],
+       hi[b])`` masked; the stripe takes as many pieces as its fullest
+       tile has rows, a trip count read from the data.  There is no
+       capacity: a catalog with every particle in one cell takes more
+       pieces and gives the exact field.
+    4. In f32 one operand of each product is a pure 0/1 one-hot of the
+       z cell (exact in bf16) and every weight sits in the other,
+       split into three bf16 parts (:func:`_bf16_parts`), accumulated
+       in f32: ``s`` one-hots against ``3 M`` columns a piece.  Wider
+       dtypes (f64, the CPU tests) take one product at full precision.
 
     Semantics (positions in global cell units, ``origin``/``period``/
-    valid-row masking) match :func:`paint_local` exactly; tested against
-    it in tests/test_paint_mxu.py. Reference analog: pmesh's C CIC paint
-    consumed at nbodykit/source/mesh/catalog.py:287-296.
+    valid-row masking) match :func:`paint_local`; both are held to an
+    f64 deposit in tests/test_paint_mxu.py. Reference analog: pmesh's C
+    CIC paint consumed at nbodykit/source/mesh/catalog.py:287-296.
 
     Parameters beyond :func:`paint_local`:
 
-    rb, cb : tile height (x rows) and width (y cols). (rb+s-1)*(cb+s-1)
-        is the matmul M dimension — keep it <= 128.
-    slack : bucket capacity = slack * mean occupancy. Overflowing
-        particles are DROPPED (count returned with
-        ``return_overflow=True``); callers retry with doubled slack.
-    deposit : 'xla' (one-hot expansions materialized by XLA) or
-        'pallas' (fused VMEM kernel, ops/paint_pallas.py — interpreted
-        off-TPU).
+    rb, cb : tile height (x rows) and width (y cols).
+    ck : rows a tile takes per piece, in whole rows of LANES.  A
+        constant: on the chip 256 took 0.134 s of a 512^3 / 1e7 paint
+        where 128 took 0.131 and 512 0.166, and 0.135 s of the
+        four-chip cell's slab block where 128 took 0.158, 384 0.137
+        and 640 0.160 (PERF.md section 6, PR 33): fewer pieces
+        re-read the stripe's accumulator less often, larger ones pad
+        more.
+    deposit : 'xla' (one-hot expansions by XLA) or 'pallas' (fused VMEM
+        kernel at the MXU's default precision, ops/paint_pallas.py —
+        interpreted off-TPU).
     """
     if deposit not in ('xla', 'pallas'):
         raise ValueError("unknown deposit %r (choose 'xla'/'pallas')"
@@ -697,209 +727,232 @@ def paint_local_mxu(pos, mass, shape, resampler='cic', period=None,
                          "(period[1:] == shape[1:]); x is the sliced "
                          "axis in this framework")
     p0 = period[0]
-    full = (n0l == p0)
     s = window_support(resampler)
-    # the leading tile must fit wrapped-to-valid deposits (rb) and the
-    # y-halo fold pads cb - (s-1) columns (cb)
-    rb, cb = max(rb, s), max(cb, s)
-    rb, cb = min(rb, n0l), min(cb, N1)
-
-    def _scatter_fallback():
-        r = paint_local(pos, mass, shape, resampler=resampler,
-                        period=period, origin=origin, out=out)
-        return (r, jnp.zeros((), jnp.int32)) if return_overflow else r
-
-    if n0l < max(s, 2) or N1 < s or N2 < s or n0l < rb:
-        # window wider than the block: single-fold wrap arithmetic does
-        # not apply; such meshes are test-sized, use the scatter kernel
-        return _scatter_fallback()
+    geometry = tile_geometry(shape, resampler, rb, cb)
+    if geometry is None:
+        return paint_local(pos, mass, shape, resampler=resampler,
+                           period=period, origin=origin, out=out)
+    rb, cb, ntx, nty = geometry
     n = pos.shape[0]
     dtype = out.dtype if out is not None else (
         mass.dtype if hasattr(mass, 'dtype') else pos.dtype)
+    dtype = jnp.dtype(dtype)
     mass = jnp.broadcast_to(jnp.asarray(mass, dtype=dtype), (n,))
 
     rbh, cbh = rb + s - 1, cb + s - 1
     M = rbh * cbh
-    ntx = -(-n0l // rb)        # tiles over [0, n0l); +1 leading wrap tile
-    nty = -(-N1 // cb)
-    if ntx * rb - n0l + s - 1 > n0l or nty * cb - N1 + s - 1 > N1:
-        # wrap strip wider than the axis (tile-size/axis mismatch on a
-        # tiny mesh): the single dense fold below would double-wrap.
-        # Retry once with smaller tiles, else scatter fallback.
-        rb2, cb2 = min(rb, max(s, n0l // 2)), min(cb, max(s, N1 // 2))
-        if (rb, cb) != (rb2, cb2):
-            return paint_local_mxu(pos, mass, shape,
-                                   resampler=resampler, period=period,
-                                   origin=origin, out=out, rb=rb2,
-                                   cb=cb2, slack=slack,
-                                   return_overflow=return_overflow,
-                                   order_method=order_method,
-                                   deposit=deposit)
-        return _scatter_fallback()
     B = (ntx + 1) * nty
-    # expected occupancy of the FULLEST tile, not the all-bucket mean:
-    # a tile covers min(rb, n0l)/n0l of the rows (slab blocks are often
-    # shorter than one tile, concentrating particles in one x-stripe)
-    # and 1/nty of the columns
-    frac = min(rb, n0l) / float(n0l * nty)
-    Kcap = max(8, int(n * frac * slack) + 1)
-    Kcap = -(-Kcap // 8) * 8
+    cr = max(-(-int(ck) // LANES), 1)
+    ck = cr * LANES
+    split = dtype == jnp.float32 and deposit == 'xla'
 
-    # ---- bucket keys from the base cell --------------------------------
-    i0b, _ = window_weights(pos[:, 0], resampler)
-    i1b, _ = window_weights(pos[:, 1], resampler)
-    row0 = jnp.mod(i0b[:, 0].astype(jnp.int32) - origin, p0)
-    # slab blocks (n0l < p0): rows in [n0l, p0) sit "below" the block;
-    # shift them negative so their wrapped-to-valid offsets (row0+a >= 0)
-    # land in the leading tile and everything else is provably dropped
-    row0s = jnp.where(row0 >= n0l, row0 - p0, row0)
-    # zero-mass slots deposit nothing — route them to the trash bucket
-    # so exchange capacity padding (pmesh.paint masks invalid slots to
-    # mass 0 with garbage positions) cannot crowd real buckets into
-    # overflow
-    keep = (row0s >= -rb) & (mass != 0)
-    txf = jnp.clip((row0s + rb) // rb, 0, ntx)
-    y0 = jnp.mod(i1b[:, 0].astype(jnp.int32), N1)
-    ty = y0 // cb
-    # fully-invalid particles (entirely below the slab block) go to the
-    # trash bucket so they cannot crowd real buckets into overflow
-    key = jnp.where(keep, txf * nty + ty, B)
+    counter('paint.trace.tile').add(1)
+    counter('paint.trace.tile_particles').add(int(n))
+    gauge('paint.tile.buckets').set(int(B))
+    gauge('paint.tile.ck').set(int(ck))
 
-    # ---- per-stripe deposit: batched matmul over the y tiles -----------
-    # bound the one-hot Z expansion's live size: each stripe's K axis
-    # is processed in pieces of ck slots per bucket so the (nty*ck, N2)
-    # Z block stays under ~zchunk_bytes (at 1024^3/1e8 an unchunked
-    # stripe Z would be 6.4 GB — OOM next to the mesh). npieces is
-    # chosen first and ck = ceil(Kcap/npieces), so the Kcap padding to
-    # a piece multiple is bounded by 8*npieces slots (sizing ck first
-    # could inflate the padded payload by up to ~2x)
-    zrow = max(nty * N2 * np.dtype(dtype).itemsize, 1)
-    npieces = max(1, -(-Kcap * zrow // max(int(zchunk_bytes), zrow * 8)))
-    ck = max(8, -(-Kcap // npieces))
-    ck = -(-ck // 8) * 8
-    Kcap = npieces * ck              # pieces tile Kcap exactly
+    # a slab's origin (d * n0 - h inside shard_map) is an operand, a
+    # literal one a constant
+    org = (origin,) if isinstance(origin, jax.Array) else ()
 
-    counter('paint.trace.mxu').add(1)
-    counter('paint.trace.mxu_particles').add(int(n))
-    gauge('paint.mxu.buckets').set(int(B))
-    gauge('paint.mxu.kcap').set(int(Kcap))
-    gauge('paint.mxu.pieces').set(int(npieces))
+    @jax.custom_batching.sequential_vmap
+    def tile_block(pos, mass, *org):
+        """The block of one catalog.  Under ``vmap`` (the served
+        program batches seeds) catalogs take turns: a batched sort
+        along the minor axis of (1, n) operands read 0.413 s for the
+        0.061 s of the same sort unbatched (PERF.md section 6, PR 33),
+        and a batch of meshes would not fit anyway."""
+        first_row = org[0] if org else origin
 
-    src, overflow = _bucket_by_argsort(key, n, B, Kcap,
-                                       order_method=order_method)
-    vsrc = src < n
-    srcc = jnp.minimum(src, max(n - 1, 0))
-    ppos = jnp.take(pos, srcc, axis=0)
-    pmass = jnp.where(vsrc & jnp.take(keep, srcc), jnp.take(mass, srcc),
-                      jnp.zeros((), dtype))
+        def tile_row(x):
+            """Block row of the base cell, the rows of a slab block's
+            absent part shifted negative: rows in [n0l, p0) sit "below"
+            the block, so that their wrapped-to-valid offsets (row + a >=
+            0) land in the leading stripe and everything else is provably
+            dropped."""
+            row = jnp.mod(window_base(x, resampler) - first_row, p0)
+            return jnp.where(row >= n0l, row - p0, row)
 
-    KX = nty * ck
-    xs = (ppos.reshape(ntx + 1, nty, npieces, ck, 3),
-          pmass.reshape(ntx + 1, nty, npieces, ck))
-    col_i = jax.lax.broadcasted_iota(jnp.int32, (KX, M), 1)
-    z_i = jax.lax.broadcasted_iota(jnp.int32, (KX, N2), 1)
-    ty_k = jnp.repeat(jnp.arange(nty, dtype=jnp.int32), ck)
+        # ---- 1. bucket key of the base cell, one sort with the payload -----
+        row0 = tile_row(pos[:, 0])
+        # zero-mass slots deposit nothing: exchange capacity padding
+        # (pmesh.paint masks invalid slots to mass 0 with garbage
+        # positions) goes to the trash bucket with the rows entirely below
+        # a slab block
+        keep = (row0 >= -rb) & (mass != 0)
+        txf = jnp.clip((row0 + rb) // rb, 0, ntx)
+        ty = jnp.mod(window_base(pos[:, 1], resampler), N1) // cb
+        key = jnp.where(keep, txf * nty + ty, B).astype(jnp.int32)
+        skey, *cols = jax.lax.sort(
+            (key, pos[:, 0], pos[:, 1], pos[:, 2], mass),
+            num_keys=1, is_stable=True)
+        # the sorted columns as rows of LANES: a piece is whole rows,
+        # read by one row gather of nty * ck / LANES indices (slices at
+        # arbitrary offsets lower to a loop of nty dynamic slices a
+        # column: 0.22 s a call on the chip, PERF.md section 6, PR 33)
+        nrow = -(-n // LANES)
+        sx, sy, sz, sm = (
+            jnp.concatenate([c, jnp.zeros((nrow * LANES - n,), c.dtype)]
+                            ).reshape(nrow, LANES) for c in cols)
 
-    P0, P1 = (ntx + 1) * rb + s - 1, nty * cb + s - 1
+        # ---- 2. buckets as contiguous runs ---------------------------------
+        edges = jnp.searchsorted(skey, jnp.arange(B + 1, dtype=jnp.int32),
+                                 side='left',
+                                 method='scan_unrolled').astype(jnp.int32)
+        lo = edges[:-1].reshape(ntx + 1, nty)
+        hi = edges[1:].reshape(ntx + 1, nty)
+        first = lo // LANES
+        rows_b = jnp.where(hi > lo, -(-hi // LANES) - first, 0)
+        trips = -(-jnp.max(rows_b, axis=1) // cr)
 
-    def piece(txi, spos, smass):
-        ii0, ww0 = window_weights(spos[:, 0], resampler)
-        ii1, ww1 = window_weights(spos[:, 1], resampler)
-        ii2, ww2 = window_weights(spos[:, 2], resampler)
-        r0 = jnp.mod(ii0[:, 0].astype(jnp.int32) - origin, p0)
-        r0 = jnp.where(r0 >= n0l, r0 - p0, r0)
-        rloc = jnp.clip(r0 + rb - txi * rb, 0, rb - 1)
-        yy0 = jnp.mod(ii1[:, 0].astype(jnp.int32), N1)
-        yloc = yy0 - ty_k * cb
-        w0y = jnp.zeros((KX, M), dtype)
-        zm = jnp.zeros((KX, N2), dtype)
-        for a in range(s):
-            for b in range(s):
-                # tile-local: rloc < rb, |yloc| < N1, so col <
-                # (rb+s)*cbh + N1 — orders of magnitude inside int32
-                # for any tile geometry  # nbkl: disable=NBK704
-                col = (rloc + a) * cbh + (yloc + b)
-                w = (ww0[:, a] * ww1[:, b]).astype(dtype) * smass
-                w0y = w0y + jnp.where(col[:, None] == col_i,
-                                      w[:, None], 0)
-        for c in range(s):
-            zc = jnp.mod(ii2[:, c].astype(jnp.int32), N2)
-            zw = ww2[:, c].astype(dtype)
-            zm = zm + jnp.where(zc[:, None] == z_i, zw[:, None], 0)
-        return jax.lax.dot_general(
-            w0y.reshape(nty, ck, M), zm.reshape(nty, ck, N2),
-            dimension_numbers=(((1,), (1,)), ((0,), (0,))),
-            preferred_element_type=dtype)          # (nty, M, N2)
+        # ---- 3./4. per-stripe deposit: batched products over the y tiles ---
+        KX = nty * ck
+        col_i = jax.lax.broadcasted_iota(jnp.int32, (KX, M), 1)
+        z_i = jax.lax.broadcasted_iota(jnp.int32, (KX, N2), 1)
+        ty_k = jnp.repeat(jnp.arange(nty, dtype=jnp.int32), ck)
+        row_k = jax.lax.broadcasted_iota(jnp.int32, (nty, cr), 1)
+        lane_k = jax.lax.broadcasted_iota(jnp.int32, (nty, cr, LANES), 2)
+        width = 3 * M if split else M
 
-    def stripe(carry, xs):
-        mesh_pad, txi = carry
-        spos, smass = xs                  # (nty, npieces, ck, [3])
-        if deposit == 'pallas':
-            from .paint_pallas import deposit_blocks_pallas
-            from ..utils import is_mxu_backend
-            blocks = deposit_blocks_pallas(
-                txi, spos[..., 0], spos[..., 1], spos[..., 2], smass,
-                resampler=resampler, rb=rb, cb=cb, n0l=n0l, p0=p0,
-                N1=N1, N2=N2, origin=origin, dtype=dtype,
-                interpret=not is_mxu_backend())
-        else:
-            spos_p = spos.transpose(1, 0, 2, 3)    # piece-major
-            smass_p = smass.transpose(1, 0, 2)
+        P0, P1 = (ntx + 1) * rb + s - 1, nty * cb + s - 1
 
-            def body(j, blocks):
-                return blocks + piece(
-                    txi,
-                    jax.lax.dynamic_index_in_dim(
-                        spos_p, j, keepdims=False).reshape(KX, 3),
-                    jax.lax.dynamic_index_in_dim(
-                        smass_p, j, keepdims=False).reshape(KX))
+        def piece(txi, x, y, z, m):
+            """(nty, width, N2) deposit of KX rows, ck a tile."""
+            ii2, ww2 = window_weights(z, resampler)
+            rloc = jnp.clip(tile_row(x) + rb - txi * rb, 0, rb - 1)
+            yloc = jnp.mod(window_base(y, resampler), N1) - ty_k * cb
+            _, ww0 = window_weights(x, resampler)
+            _, ww1 = window_weights(y, resampler)
+            w0y = jnp.zeros((KX, M), dtype)
+            for a in range(s):
+                for b in range(s):
+                    # tile-local: rloc < rb, |yloc| < N1, so col <
+                    # (rb+s)*cbh + N1 — orders of magnitude inside int32
+                    # for any tile geometry  # nbkl: disable=NBK704
+                    col = (rloc + a) * cbh + (yloc + b)
+                    w = (ww0[:, a] * ww1[:, b]).astype(dtype) * m
+                    w0y = w0y + jnp.where(col[:, None] == col_i,
+                                          w[:, None], 0)
+            hot = [jnp.mod(ii2[:, c], N2)[:, None] == z_i for c in range(s)]
+            if not split:
+                zm = sum(jnp.where(hot[c], ww2[:, c, None].astype(dtype), 0)
+                         for c in range(s))
+                return jax.lax.dot_general(
+                    w0y.reshape(nty, ck, M), zm.reshape(nty, ck, N2),
+                    dimension_numbers=(((1,), (1,)), ((0,), (0,))),
+                    precision=jax.lax.Precision.HIGHEST,
+                    preferred_element_type=dtype)
+            # the z cell as a 0/1 one-hot per window offset, every weight
+            # on the other side in three bf16 parts side by side; the
+            # offsets stack along the contraction
+            lhs = jnp.concatenate(
+                [jnp.concatenate(_bf16_parts(w0y * ww2[:, c, None]),
+                                 axis=1).reshape(nty, ck, width)
+                 for c in range(s)], axis=1)
+            rhs = jnp.concatenate(
+                [h.astype(jnp.bfloat16).reshape(nty, ck, N2) for h in hot],
+                axis=1)
+            return jax.lax.dot_general(
+                lhs, rhs, dimension_numbers=(((1,), (1,)), ((0,), (0,))),
+                preferred_element_type=jnp.float32)
 
-            # data-derived zero init (shard_map varying-manual-axes,
-            # as for the scan carry below)
-            blocks0 = jnp.zeros((nty, M, N2), dtype) \
-                + smass.ravel()[0] * 0
-            blocks = jax.lax.fori_loop(0, npieces, body, blocks0)
-        # fold the y tiles into a (rbh, P1, N2) slab: interior cols by
-        # reshape, halo cols by a cb-shifted dense add
-        blocks = blocks.reshape(nty, rbh, cbh, N2).transpose(1, 0, 2, 3)
-        interior = blocks[:, :, :cb].reshape(rbh, nty * cb, N2)
-        halo = jnp.pad(blocks[:, :, cb:],
-                       ((0, 0), (0, 0), (0, cb - (s - 1)), (0, 0)))
-        halo = halo.reshape(rbh, nty * cb, N2)
-        slab = jnp.pad(interior, ((0, 0), (0, s - 1), (0, 0)))
-        slab = slab + jnp.pad(halo, ((0, 0), (cb, 0), (0, 0))
-                              )[:, :P1]
-        # wrap strip: cols >= N1 are the periodic y images
-        slab = slab[:, :N1] + jnp.pad(slab[:, N1:],
-                                      ((0, 0), (0, 2 * N1 - P1), (0, 0)))
-        row = txi * rb
-        zero = jnp.zeros((), row.dtype)
-        upd = jax.lax.dynamic_slice(mesh_pad, (row, zero, zero),
-                                    (rbh, N1, N2)) + slab
-        mesh_pad = jax.lax.dynamic_update_slice(mesh_pad, upd,
-                                                (row, zero, zero))
-        return (mesh_pad, txi + 1), None
+        def stripe(carry, xs):
+            mesh_pad, txi = carry
+            lo_t, hi_t, first_t, trips_t = xs
 
-    # data-derived zero init: under shard_map the carry must carry the
-    # same varying-manual-axes type as the per-step update (a literal
-    # zeros() is unvarying and trips the scan carry type check)
-    zinit = jnp.zeros((), dtype) * jnp.sum(pmass[:1])
-    mesh_pad = jnp.zeros((P0, N1, N2), dtype) + zinit
-    txi0 = jnp.int32(0) + jnp.sum(src[:1]) * 0
-    (mesh_pad, _), _ = jax.lax.scan(stripe, (mesh_pad, txi0), xs)
+            def body(j, acc):
+                at = first_t[:, None] + j * cr + row_k
+                at_k = at[:, :, None] * LANES + lane_k
+                # the rest of a row is the neighbouring buckets' (or a
+                # trash slot's garbage): inert at position 0
+                live = (at_k >= lo_t[:, None, None]) \
+                    & (at_k < hi_t[:, None, None])
 
-    # ---- unpad x: rows [rb, rb+n0l) are the block; fold the periodic
-    # images (leading wrap tile + trailing halo) when the block IS the
-    # full mesh, drop them for slab blocks (invalid rows by contract)
-    block = mesh_pad[rb:rb + n0l]
-    if full:
-        head = mesh_pad[:rb]          # true rows [-rb, 0) -> wrap + n0l
-        block = block + jnp.pad(head, ((n0l - rb, 0), (0, 0), (0, 0)))
-        tail = mesh_pad[rb + n0l:]    # true rows >= n0l -> wrap - n0l
-        block = block + jnp.pad(
-            tail, ((0, n0l - tail.shape[0]), (0, 0), (0, 0)))
+                def rows(c):
+                    return jnp.where(
+                        live, jnp.take(c, at, axis=0, mode='clip'),
+                        0).reshape(nty, ck)
+
+                x, y, z, m = rows(sx), rows(sy), rows(sz), rows(sm)
+                if deposit == 'pallas':
+                    from .paint_pallas import deposit_blocks_pallas
+                    from ..utils import is_mxu_backend
+                    return acc + deposit_blocks_pallas(
+                        txi, x[:, None], y[:, None], z[:, None], m[:, None],
+                        resampler=resampler, rb=rb, cb=cb, n0l=n0l, p0=p0,
+                        N1=N1, N2=N2, origin=first_row, dtype=dtype,
+                        interpret=not is_mxu_backend())
+                return acc + piece(txi, x.reshape(KX), y.reshape(KX),
+                                   z.reshape(KX), m.reshape(KX))
+
+            acc = jax.lax.fori_loop(
+                0, trips_t, body,
+                vary_like(jnp.zeros((nty, width, N2), dtype), pos, mass))
+            # the three parts' sums, smallest first
+            blocks = acc[:, 2 * M:] + acc[:, M:2 * M] + acc[:, :M] \
+                if split else acc
+            # fold the y tiles into a (rbh, P1, N2) slab: interior cols by
+            # reshape, halo cols by a cb-shifted dense add
+            blocks = blocks.reshape(nty, rbh, cbh, N2).transpose(1, 0, 2, 3)
+            interior = blocks[:, :, :cb].reshape(rbh, nty * cb, N2)
+            halo = jnp.pad(blocks[:, :, cb:],
+                           ((0, 0), (0, 0), (0, cb - (s - 1)), (0, 0)))
+            halo = halo.reshape(rbh, nty * cb, N2)
+            slab = jnp.pad(interior, ((0, 0), (0, s - 1), (0, 0)))
+            slab = slab + jnp.pad(halo, ((0, 0), (cb, 0), (0, 0))
+                                  )[:, :P1]
+            # wrap strip: cols >= N1 are the periodic y images
+            slab = slab[:, :N1] + jnp.pad(slab[:, N1:],
+                                          ((0, 0), (0, 2 * N1 - P1), (0, 0)))
+            row = txi * rb
+            zero = jnp.zeros((), row.dtype)
+            upd = jax.lax.dynamic_slice(mesh_pad, (row, zero, zero),
+                                        (rbh, N1, N2)) + slab
+            mesh_pad = jax.lax.dynamic_update_slice(mesh_pad, upd,
+                                                    (row, zero, zero))
+            return (mesh_pad, txi + 1), None
+
+        # inside a slab mesh's shard_map the carry starts replicated and
+        # takes device-local deposits
+        (mesh_pad, _), _ = jax.lax.scan(
+            stripe,
+            (vary_like(jnp.zeros((P0, N1, N2), dtype), pos, mass),
+             vary_like(jnp.int32(0), pos, mass)),
+            (lo, hi, first, trips))
+
+        # ---- unpad x: rows [rb, rb+n0l) are the block; fold the periodic
+        # images (leading wrap tile + trailing halo) into it when the block
+        # IS the full mesh, drop them for slab blocks (invalid rows by
+        # contract)
+        block = mesh_pad[rb:rb + n0l]
+        if n0l == p0:
+            # true rows [-rb, 0) wrap to + n0l, true rows >= n0l to - n0l
+            nt = P0 - rb - n0l
+            block = block.at[n0l - rb:].add(mesh_pad[:rb])
+            block = block.at[:nt].add(mesh_pad[rb + n0l:])
+        return block
+
+    @jax.custom_vjp
+    def painted(pos, mass, *org):
+        return tile_block(pos, mass, *org)
+
+    def painted_fwd(pos, mass, *org):
+        return tile_block(pos, mass, *org), (pos, mass, org)
+
+    def painted_bwd(saved, g):
+        """The deposit's adjoint is the readout (forward/adjoint.py):
+        reverse mode cannot pass the data-dependent piece loop."""
+        pos, mass, org = saved
+        at = dict(resampler=resampler, period=period,
+                  origin=org[0] if org else origin)
+        dpos = jnp.stack([mass * readout_local(g, pos, grad_axis=ax, **at)
+                          for ax in range(3)], axis=-1)
+        return (dpos.astype(pos.dtype),
+                readout_local(g, pos, **at).astype(mass.dtype)) \
+            + (None,) * len(org)
+
+    painted.defvjp(painted_fwd, painted_bwd)
+    block = painted(pos, mass, *org)
     if out is not None:
         block = jnp.asarray(out) + block
-    if return_overflow:
-        return block, overflow
     return block

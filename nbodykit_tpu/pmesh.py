@@ -22,6 +22,7 @@ Everything returned is a plain jnp array; the RealField/ComplexField
 wrappers in :mod:`nbodykit_tpu.base.mesh` add attrs/convenience methods.
 """
 
+import functools
 import logging
 import time
 from functools import lru_cache as _lru_cache
@@ -43,7 +44,8 @@ from .parallel.exchange import exchange_by_dest
 from .ops.window import window_support
 from .ops.paint import (paint_local, paint_local_sorted,
                         paint_local_segsum, paint_local_streams,
-                        paint_local_mxu, readout_local)
+                        paint_local_mxu, readout_local, tile_geometry,
+                        PIECE_ROWS)
 
 # compile telemetry for the paint/FFT entry points below: XLA compiles
 # and compilation-cache hits/misses land in the metric registry
@@ -56,51 +58,56 @@ def _triplet(x, dtype):
     return a
 
 
-def _paint_kernel(method, chunk, order, deposit, streams, storage_dtype,
-                  mxu_slack):
-    """The local paint kernel of one resolved configuration.  All
-    kernels return (block, overflow); only mxu can actually overflow
-    (bucket capacity)."""
+def paint_engine(method, shape, resampler):
+    """Which engine ``paint_method=method`` runs on a local block of
+    ``shape``: 'mxu', the default, is the tile deposit wherever the
+    block admits it (:func:`~nbodykit_tpu.ops.paint.tile_geometry`: a
+    rule of the block's shape and the window, not of the particle
+    count, so a cell's 64^3 oracle checks the engine its timed call
+    runs) and the scatter on the test-sized rest; every other method
+    is its own engine.  The ``engine`` attribute of the ``paint``
+    span."""
+    if method != 'mxu':
+        return method
+    return 'scatter' if tile_geometry(shape, resampler) is None \
+        else 'tile'
+
+
+def _paint_kernel(method, chunk, order, deposit, streams, storage_dtype):
+    """The local paint kernel of one resolved configuration."""
     if method == 'sort':
-        def kern(*a, **kw):
-            return (paint_local_sorted(*a, **kw),
-                    jnp.zeros((), jnp.int32))
-    elif method == 'segsum':
-        def kern(*a, **kw):
-            return (paint_local_segsum(*a, order_method=order, **kw),
-                    jnp.zeros((), jnp.int32))
-    elif method == 'streams':
-        def kern(*a, **kw):
-            return (paint_local_streams(*a, streams=streams,
-                                        chunk=chunk,
-                                        storage_dtype=storage_dtype,
-                                        **kw),
-                    jnp.zeros((), jnp.int32))
-    elif method == 'mxu':
-        def kern(*a, **kw):
-            return paint_local_mxu(*a, slack=mxu_slack,
-                                   return_overflow=True,
-                                   order_method=order,
-                                   deposit=deposit, **kw)
-    else:
-        def kern(*a, **kw):
-            return (paint_local(*a, chunk=chunk, **kw),
-                    jnp.zeros((), jnp.int32))
-    return kern
+        return paint_local_sorted
+    if method == 'segsum':
+        return functools.partial(paint_local_segsum, order_method=order)
+    if method == 'streams':
+        return functools.partial(paint_local_streams, streams=streams,
+                                 chunk=chunk,
+                                 storage_dtype=storage_dtype)
+    if method == 'mxu':
+        return functools.partial(paint_local_mxu, deposit=deposit)
+    return functools.partial(paint_local, chunk=chunk)
+
+
+@functools.partial(instrumented_jit, label='paint.tile',
+                   static_argnames=('shape', 'resampler', 'deposit'))
+def paint_tile(cpos, massa, shape, resampler, deposit):
+    """The one-chip tile deposit of an eager call as one program: op
+    by op its stripe scan would be a launch a fold."""
+    return paint_local_mxu(cpos, massa, shape, resampler=resampler,
+                           deposit=deposit)
 
 
 @_lru_cache(maxsize=32)
 def _slab_paint_programs(mesh, shape_real, resampler, method, chunk,
                          order, deposit, streams, storage_dtype,
-                         compute_dtype, mxu_slack, mxu):
+                         compute_dtype, mxu):
     """The paint of exchanged particles onto a slab mesh as one
     program: the mask of empty slots, the local kernel into the
-    halo-extended slab, ``halo_add`` and the ``psum`` of the kernel's
-    overflow count.  Cached per everything the body reads: the device
-    mesh, the field's shape, the window, the resolved kernel
-    configuration (``mxu``: the backend branch of its orderings) and
-    the dtypes; the particle count and so the exchange capacity key
-    the jit's own cache.  Returns ``(raw, jit)`` as
+    halo-extended slab and ``halo_add``.  Cached per everything the
+    body reads: the device mesh, the field's shape, the window, the
+    resolved kernel configuration (``mxu``: the backend branch of its
+    orderings) and the dtypes; the particle count and so the exchange
+    capacity key the jit's own cache.  Returns ``(raw, jit)`` as
     ``dfft._slab_programs`` does: the raw callable for an outer trace,
     the jitted form for the eager call."""
     nproc = mesh_size(mesh)
@@ -108,19 +115,19 @@ def _slab_paint_programs(mesh, shape_real, resampler, method, chunk,
     n0 = N0 // nproc
     h = window_support(resampler)
     kernel = _paint_kernel(method, chunk, order, deposit, streams,
-                           storage_dtype, mxu_slack)
+                           storage_dtype)
 
     def local(cpos_l, mass_l):
         d = jax.lax.axis_index(AXIS)
         origin = d * n0 - h
-        ext, over = kernel(cpos_l, mass_l, (n0 + 2 * h, N1, N2),
-                           resampler=resampler, period=(N0, N1, N2),
-                           origin=origin)
-        return halo_add(ext, h, nproc), jax.lax.psum(over, AXIS)
+        ext = kernel(cpos_l, mass_l, (n0 + 2 * h, N1, N2),
+                     resampler=resampler, period=(N0, N1, N2),
+                     origin=origin)
+        return halo_add(ext, h, nproc)
 
     sharded = jax.shard_map(local, mesh=mesh,
                             in_specs=(P(AXIS, None), P(AXIS)),
-                            out_specs=(P(AXIS, None, None), P()))
+                            out_specs=P(AXIS, None, None))
 
     def paint_slab(cpos_r, mass_r, valid):
         mass_r = jnp.where(valid, mass_r, 0.0).astype(compute_dtype)
@@ -416,23 +423,13 @@ class ParticleMesh(object):
         Diagnostics (docs/OBSERVABILITY.md): every call runs under
         ``scope('paint')`` (``nbk.paint`` on the profiler's host line,
         or on the HLO op names under a trace); eager calls with the
-        ``diagnostics`` option set also emit a ``paint`` span and record the
-        per-method throughput histogram ``paint.<method>.mpart_per_s``.
+        ``diagnostics`` option set also emit a ``paint`` span (attribute
+        ``engine``: 'tile' or 'scatter' under the default method,
+        :func:`paint_engine`) and record the per-method throughput
+        histogram ``paint.<method>.mpart_per_s``.
         The result is synced (``block_until_ready``) inside the span so
         the throughput is real work, not dispatch — enabled-mode only;
         the disabled path is byte-identical to the undiagnosed one.
-
-        Dropped-deposit contract for ``paint_method='mxu'``: the mxu
-        kernel's slack-sized tile buckets CAN overflow. Eagerly the
-        overflow self-heals — each retry of the slack-backoff ladder
-        first bumps the process-wide ``paint.dropped`` counter and
-        emits a ``paint.dropped`` trace event (count + failing slack),
-        so no loss is silent even though the final mesh is exact.
-        Under a trace the backoff cannot branch, so
-        ``return_dropped=True`` is REQUIRED (enforced above): the
-        traced path's ONLY overflow signal is the returned count —
-        counters and events cannot fire inside jit — and a caller who
-        ignores it has lost deposits with no trace-side record.
         """
         if current_tracer() is None or not trace_state_clean():
             # no JSONL span here, but the layer still gets its name:
@@ -443,9 +440,11 @@ class ParticleMesh(object):
         npart = int(pos.shape[0])
         method = _global_options['paint_method']
         t0 = time.perf_counter()
+        window = resampler or _global_options['resampler']
         with scope('paint', method=method, npart=npart,
-                   nproc=self.nproc,
-                   resampler=resampler or _global_options['resampler'],
+                   engine=paint_engine(method,
+                                       self._local_block(window), window),
+                   nproc=self.nproc, resampler=window,
                    nmesh=int(self.Nmesh[0])):
             res = self._paint_impl(pos, mass, resampler, out, shift,
                                    capacity, return_dropped)
@@ -455,6 +454,15 @@ class ParticleMesh(object):
         histogram('paint.%s.mpart_per_s' % method).observe(
             npart / dt / 1e6)
         return res
+
+    def _local_block(self, resampler):
+        """Shape of the block the local paint kernel fills: the mesh
+        on one device, a slab with the window's halo on either side
+        on a slab mesh."""
+        N0, N1, N2 = self.shape_real
+        if self.nproc == 1:
+            return N0, N1, N2
+        return (N0 // self.nproc + 2 * window_support(resampler), N1, N2)
 
     def _paint_impl(self, pos, mass, resampler, out, shift, capacity,
                     return_dropped):
@@ -486,40 +494,17 @@ class ParticleMesh(object):
             from .resilience.integrity import checks_enabled
             cbits = corrupt_spec('paint.accum')
             chk = checks_enabled()
-        if traced and pm_method == 'mxu' and not return_dropped:
-            # same contract as an explicit exchange capacity: the mxu
-            # bucket capacity is slack-sized, not provably sufficient,
-            # and under a trace the eager backoff cannot run — silent
-            # particle loss must be impossible, so the caller has to
-            # receive (and check) the dropped count
-            raise ValueError(
-                "paint_method='mxu' inside jit requires "
-                "return_dropped=True: bucket overflow cannot retry "
-                "under a trace, so the dropped count must be checked "
-                "after the step (or paint eagerly / use "
-                "paint_method='scatter')")
-
-        def make_kernel(mxu_slack):
-            return _paint_kernel(pm_method, chunk, order, deposit,
-                                 nstreams, self.dtype, mxu_slack)
-
-        mxu_slack = _global_options['paint_bucket_slack']
+        kernel = _paint_kernel(pm_method, chunk, order, deposit,
+                               nstreams, self.dtype)
         if self.nproc == 1:
-            block, over = make_kernel(mxu_slack)(
-                cpos, massa, self.shape_real, resampler=resampler,
-                period=self.shape_real, origin=0)
-            # eager mxu bucket-overflow backoff, mirroring the exchange
-            # retry contract (traced callers see the count via
-            # return_dropped)
-            while not traced and int(over) > 0 and mxu_slack < 1e6:
-                self._note_dropped(int(over), mxu_slack)
-                mxu_slack *= 4
-                self.logger.info(
-                    "mxu paint bucket overflow (%d dropped); retrying "
-                    "with slack=%g" % (int(over), mxu_slack))
-                block, over = make_kernel(mxu_slack)(
-                    cpos, massa, self.shape_real, resampler=resampler,
-                    period=self.shape_real, origin=0)
+            if not traced and paint_engine(
+                    pm_method, self.shape_real, resampler) == 'tile':
+                block = paint_tile(cpos, massa, self.shape_real,
+                                   resampler, deposit)
+            else:
+                block = kernel(cpos, massa, self.shape_real,
+                               resampler=resampler,
+                               period=self.shape_real, origin=0)
             # kernels return compute dtype; widen any caller-held
             # accumulator before adding (never mix widths on a
             # mesh-sized operand) and narrow once at the exit
@@ -531,13 +516,13 @@ class ParticleMesh(object):
                 self._verify_mass(block, massa, out, h, npart)
             out = block.astype(self.dtype)
             if return_dropped:
-                return out, over
+                return out, jnp.zeros((), jnp.int32)
             return out
 
         self._check_halo(h)
         self._check_overflow_contract(capacity, traced, return_dropped)
 
-        def attempt(cap, slack_val=None):
+        def attempt(cap):
             recv, valid, dropped = exchange_by_dest(
                 dest, [cpos, massa], self.comm, cap)
             from .utils import is_mxu_backend
@@ -545,15 +530,14 @@ class ParticleMesh(object):
                 self.comm, (N0, N1, N2), resampler, pm_method, chunk,
                 order, deposit, nstreams,
                 jnp.dtype(self.dtype), jnp.dtype(self.compute_dtype),
-                slack_val if slack_val is not None else mxu_slack,
                 is_mxu_backend())
-            block, over = (jitted if is_eager(*recv, valid) else raw)(
+            block = (jitted if is_eager(*recv, valid) else raw)(
                 *recv, valid)
-            return block, dropped, over
+            return block, dropped
 
-        block, dropped, over = attempt(capacity)
-        # eager: `over` is read below in any case and the exchange ends
-        # before the paint, so its count costs no further wait
+        block, dropped = attempt(capacity)
+        # eager: the exchange ends before the paint, so reading its
+        # count costs no further wait
         lost = 0 if traced else self._count_dropped(dropped)
         if capacity is not None and lost > 0:
             # eager exchange-capacity backoff (reference:
@@ -565,25 +549,16 @@ class ParticleMesh(object):
                 self.logger.info(
                     "exchange overflow (%d dropped); retrying with "
                     "capacity=%d" % (lost, capacity))
-                block, dropped, over = attempt(capacity)
+                block, dropped = attempt(capacity)
                 lost = self._count_dropped(dropped)
             if lost > 0:
-                # NBK103 (baselined, audited): this raise sits between
-                # collective programs, but `dropped` is the
-                # globally-summed overflow count — every rank computes
-                # the same value and raises together, so the exception
-                # path is rank-uniform by construction
+                # `dropped` is the globally-summed overflow count:
+                # every rank computes the same value and raises
+                # together, and no collective program follows
                 raise RuntimeError(
                     "particle exchange still overflowing at the "
                     "maximal capacity %d — this should be impossible"
                     % capacity)
-        while not traced and int(over) > 0 and mxu_slack < 1e6:
-            self._note_dropped(int(over), mxu_slack)
-            mxu_slack *= 4
-            self.logger.info(
-                "mxu paint bucket overflow (%d dropped); retrying "
-                "with slack=%g" % (int(over), mxu_slack))
-            block, dropped, over = attempt(capacity, mxu_slack)
         # same merge-then-narrow contract as the single-device exit:
         # the halo_add ran in compute dtype inside the shard_map, the
         # storage cast happens exactly once, here
@@ -595,7 +570,7 @@ class ParticleMesh(object):
             self._verify_mass(block, massa, out, h, npart)
         out = block.astype(self.dtype)
         if return_dropped:
-            return out, dropped + over
+            return out, dropped
         return out
 
     def _corrupt_accum(self, block, bits):
@@ -629,25 +604,11 @@ class ParticleMesh(object):
                              float(scale), n, self.compute_dtype,
                              self.dtype)
 
-    def _note_dropped(self, count, slack):
-        """Observability of an eager mxu bucket overflow, BEFORE the
-        backoff retry heals it: the ``paint.dropped`` counter carries
-        the would-have-been-lost deposit count across the whole
-        process, and an enabled tracer gets a zero-duration
-        ``paint.dropped`` event with the count and the slack that
-        proved too small — so a post-mortem can see how often the
-        ladder climbed and from where."""
-        counter('paint.dropped').add(int(count))
-        tr = current_tracer()
-        if tr is not None:
-            tr.event('paint.dropped', {'dropped': int(count),
-                                       'slack': float(slack)})
-
     @staticmethod
     def _count_dropped(dropped):
         """An exchange's overflow count as an int, read eagerly, and
-        fed to the ``exchange.dropped`` counter: like ``paint.dropped``
-        it counts what each attempt lost, before a retry heals it."""
+        fed to the ``exchange.dropped`` counter: it counts what each
+        attempt lost, before a retry heals it."""
         lost = int(dropped)
         counter('exchange.dropped').add(lost)
         return lost
@@ -845,7 +806,7 @@ def device_hbm_bytes(device):
 
 
 def memory_plan(Nmesh, npart, ndevices=1, dtype='f4', resampler='cic',
-                paint_method='scatter', paint_chunk=None,
+                paint_method='mxu', paint_chunk=None,
                 paint_streams=None, hbm_bytes=None, exchange='counted',
                 exchange_imbalance=1.5, fft_decomp='slab',
                 fft_pencil=None, ingest_chunk_rows=None,
@@ -986,6 +947,12 @@ def memory_plan(Nmesh, npart, ndevices=1, dtype='f4', resampler='cic',
     else:
         chunk = paint_chunk
     live = min(npart / ndev, chunk)
+    # the default engine is the tile deposit wherever the local block
+    # (a slab with the window's halo either side) admits its tiles
+    tiles = tile_geometry(
+        (max(int(N[0]) // ndev, 1) + (2 * s if ndev > 1 else 0),
+         int(N[1]), int(N[2])), resampler) \
+        if paint_method == 'mxu' else None
     if paint_method == 'sort':
         # all s^3 deposit terms live at once: (key i32 + val) pairs,
         # doubled by the sort's out-of-place buffers
@@ -1005,30 +972,26 @@ def memory_plan(Nmesh, npart, ndevices=1, dtype='f4', resampler='cic',
         # replicas are STORAGE dtype (bf16 halves THE dominant term
         # of this method); the live chunk's deposit terms compute f32
         paint_tmp = k * real + (s ** 3) * (4 + citem) * live
-    elif paint_method == 'mxu':
-        # padded bucket payload (slack * (pos + mass)), the argsort of
-        # the n keys (key + order i32, out-of-place), one x-stripe's
-        # W0Y/Z one-hot expansions (transient inside the scan), and the
-        # halo-padded mesh rows
-        slack = _global_options['paint_bucket_slack']
+    elif tiles is not None:
+        # the tile deposit (ops/paint.py:paint_local_mxu): the mesh
+        # with its wrap stripe and halo rows, alive beside the block
+        # it is folded into; the four sorted columns the pieces read;
+        # and, one after the other in the same bytes, the sort's other
+        # buffers (the key and the columns it came from) and one
+        # piece's operands (the z one-hots and the weights' three
+        # parts in bf16, the f32 x*y expansion) with a stripe's f32
+        # accumulator, read and written.  Compiled for a v5e at 512^3
+        # / 1e7 the program's temporaries are 0.708 GB for the 0.95
+        # this prices
         nl = npart / ndev
-        rb = cb = 8
-        rbh, cbh = rb + s - 1, cb + s - 1
-        n0l = max(int(N[0]) // ndev, 1)
-        ntx = max(-(-n0l // rb), 1)
-        # the kernel K-chunks each stripe so the one-hot Z expansion is
-        # capped (ops/paint.py ZCHUNK_BYTES); the per-stripe blocks
-        # accumulator (nty, M, N2) stays live across all pieces
-        from .ops.paint import ZCHUNK_BYTES
-        nty = max(-(-int(N[1]) // cb), 1)
-        blocks_acc = nty * rbh * cbh * int(N[2]) * citem
-        stripe = min(slack * nl / ntx * (rbh * cbh + int(N[2])) * citem,
-                     float(ZCHUNK_BYTES) * (1 + rbh * cbh / int(N[2]))
-                     ) + blocks_acc
-        paint_tmp = (slack * nl * 4 * citem    # padded pos+mass
-                     + nl * 8 * 2              # sort keys + order
-                     + stripe
-                     + (rb + s) * int(N[1]) * int(N[2]) * citem)
+        rb, cb, ntx, nty = tiles
+        M = (rb + s - 1) * (cb + s - 1)
+        N1, N2 = int(N[1]), int(N[2])
+        rows = nty * PIECE_ROWS
+        piece = rows * (s * (N2 + 3 * M) * 2 + 2 * M * citem) \
+            + 2 * nty * 3 * M * N2 * citem
+        mesh_pad = ((ntx + 1) * rb + s - 1) * N1 * N2 * citem
+        paint_tmp = mesh_pad + 4 * 4 * nl + max(6 * 4 * nl, piece)
     else:
         paint_tmp = (s ** 3) * (4 + citem) * live
     p3 = cplx / 2               # |delta_k|^2 as real of the half-spec

@@ -52,7 +52,7 @@ from ...resilience.checkpoint import (_atomic_bytes, _canonical, _safe,
 #: serves wrong bytes.
 JIT_OPTIONS = (
     'mesh_dtype', 'a2a_compress', 'resampler', 'paint_method',
-    'paint_chunk_size', 'paint_bucket_slack', 'paint_streams',
+    'paint_chunk_size', 'paint_streams',
     'fft_chunk_bytes', 'fft_decomp', 'fft_pencil', 'integrity',
     'ingest_chunk_rows',
 )

@@ -80,14 +80,16 @@ def test_memory_plan_counted_vs_ceil():
     assert pc['exchange_buffers'] < pf['exchange_buffers'] / 5
 
 
-def test_mxu_traced_requires_return_dropped():
+def test_mxu_traced_needs_no_dropped_count():
+    """The tile paint has no capacity: under a trace it runs without
+    ``return_dropped``, and with it the count is the exchange's."""
     from nbodykit_tpu import set_options
     pm = ParticleMesh(16, 16.0, dtype='f4', comm=None)
     pos = jnp.asarray(np.random.RandomState(1)
                       .uniform(0, 16.0, (100, 3)).astype('f4'))
     with set_options(paint_method='mxu'):
-        with pytest.raises(ValueError, match="return_dropped"):
-            jax.jit(lambda p: pm.paint(p, 1.0))(pos)
+        f = jax.jit(lambda p: pm.paint(p, 1.0))(pos)
+        assert abs(float(f.sum()) - 100) < 1e-3
         f, dropped = jax.jit(
             lambda p: pm.paint(p, 1.0, return_dropped=True))(pos)
         assert int(dropped) == 0

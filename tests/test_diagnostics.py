@@ -374,7 +374,7 @@ def test_fftpower_acceptance_trace(tmp_path, cpu8):
     # byte + throughput metrics landed
     assert snap['exchange.bytes_sent']['value'] > 0
     assert snap['exchange.calls']['value'] >= 1
-    assert snap['paint.scatter.mpart_per_s']['count'] >= 1
+    assert snap['paint.mxu.mpart_per_s']['count'] >= 1
     # device watermarks were sampled for the 8 virtual devices
     assert snap['device.cpu:0.live_bytes']['max'] > 0
     # compile telemetry (ISSUE 2 acceptance): the binning program's

@@ -280,3 +280,41 @@ def test_lab_call_trace_names_every_layer_once_synced(tmp_path,
     end = {s['name']: s['ts'] + s['dur'] for s in spans}
     start = {s['name']: s['ts'] for s in spans}
     assert end['fft.r2c'] <= start['fftpower.binning'] + 1e-6
+
+
+# ---------------------------------------------------------------------------
+# the paint's engine: an attribute of the span, a counter per program
+
+@pytest.mark.parametrize('nmesh, engine', [(32, 'tile'), (2, 'scatter')])
+def test_paint_span_names_its_engine(tmp_path, nmesh, engine):
+    """The default ``paint_method`` picks its engine from the block's
+    shape (``pmesh.paint_engine``): the tile deposit wherever the
+    block admits it, the scatter on a mesh narrower than the window.
+    The ``paint`` span says which ran, and ``paint.trace.<engine>``
+    counts one per compiled program, not one per call."""
+    from nbodykit_tpu.pmesh import ParticleMesh
+    pm = ParticleMesh(nmesh, float(nmesh), dtype='f4')
+    rng = np.random.default_rng(nmesh)
+    with nbodykit_tpu.set_options(diagnostics=str(tmp_path)):
+        for seed in range(2):
+            # a shape no other test paints, so the program is new
+            pos = jnp.asarray(rng.uniform(0, nmesh, (1237, 3)), 'f4')
+            pm.paint(pos, 1.0, resampler='tsc')
+    paints = [s for s in _spans(str(tmp_path)) if s['name'] == 'paint']
+    assert [s['attrs']['engine'] for s in paints] == [engine] * 2
+    assert all(s['attrs']['method'] == 'mxu' for s in paints)
+    snap = REGISTRY.snapshot()
+
+    def value(name):
+        return snap[name]['value'] if name in snap else 0
+
+    other = 'scatter' if engine == 'tile' else 'tile'
+    assert value('paint.trace.' + other) == 0
+    if engine == 'tile':
+        # two calls, one program (compile.paint.tile: a miss, a hit)
+        assert value('paint.trace.tile') == 1
+        assert value('paint.trace.tile_particles') == 1237
+        assert value('compile.paint.tile.misses') == 1
+        assert value('compile.paint.tile.hits') == 1
+        assert value('paint.tile.buckets') == (4 + 1) * 4
+        assert value('paint.tile.ck') == 256

@@ -118,7 +118,7 @@ def _bispectrum_method():
 # (id, what reaches the read site under default options, the constant)
 READ_SITES = [
     ('paint.method', lambda mp: _paint_kernel_args(mp)['method'],
-     'scatter'),
+     'mxu'),
     ('paint.chunk', lambda mp: _paint_kernel_args(mp)['chunk'], CHUNK),
     ('paint.order', lambda mp: _paint_kernel_args(mp)['order'], 'auto'),
     ('paint.deposit', lambda mp: _paint_kernel_args(mp)['deposit'],
@@ -137,9 +137,10 @@ READ_SITES = [
     ('bispectrum.method', lambda mp: _bispectrum_method(), 'fft'),
     ('bispectrum.tile', _tile_rows, 1024),
     ('plan.chunk',
-     lambda mp: _plan()['peak_bytes']
-     == _plan(paint_chunk=CHUNK)['peak_bytes']
-     != _plan(paint_chunk=CHUNK // 2)['peak_bytes'], True),
+     lambda mp: _plan(paint_method='scatter')['peak_bytes']
+     == _plan(paint_method='scatter', paint_chunk=CHUNK)['peak_bytes']
+     != _plan(paint_method='scatter',
+              paint_chunk=CHUNK // 2)['peak_bytes'], True),
     ('plan.streams',
      lambda mp: _plan(paint_method='streams')['peak_bytes']
      == _plan(paint_method='streams', paint_streams=4)['peak_bytes']
@@ -162,7 +163,8 @@ def test_default_reaches_read_site(site, monkeypatch):
 
 
 def test_one_table_of_defaults():
-    assert len(_default_options) == 23
+    assert len(_default_options) == 22
+    assert 'paint_bucket_slack' not in _default_options
     assert 'tune_cache' not in _default_options
     assert 'exchange_slack' not in _default_options
     autos = sorted(k for k, v in _default_options.items()

@@ -94,12 +94,7 @@ def _run_candidate(opts, pos, mass, shape, res, period, origin):
         return paint_local_streams(
             *args, streams=opts['paint_streams'], chunk=101, **kw)
     if method == 'mxu':
-        out, over = paint_local_mxu(
-            *args, return_overflow=True,
-            order_method=opts.get('paint_order', 'auto'),
-            deposit='xla', **kw)
-        assert int(over) == 0
-        return out
+        return paint_local_mxu(*args, deposit='xla', **kw)
     raise AssertionError('unknown candidate method %r' % method)
 
 
@@ -176,20 +171,19 @@ def test_streams_candidates_capped_by_memory_plan():
                                hbm_bytes=16e9)['fits']
 
 
-def test_mxu_dropped_counter_and_backoff():
-    """Overflowing a tiny mxu Kcap eagerly: the backoff ladder heals
-    the mesh, and each failed attempt lands in the ``paint.dropped``
-    counter BEFORE the retry (the observability satellite of
-    ISSUE 8)."""
+def test_mxu_one_cell_needs_no_retry():
+    """Every particle in one cell, eagerly through ``pm.paint``: one
+    tile bucket holds all n and the deposit takes more pieces.  There
+    is no capacity to overflow: one program, one call, nothing lands
+    in ``paint.dropped``."""
     from nbodykit_tpu.pmesh import ParticleMesh
     rng = np.random.default_rng(3)
     n = 3000
-    # every particle in one cell: one tile bucket holds all n, so a
-    # slack of 0.01 makes Kcap provably too small on the first try
     pos = jnp.asarray(rng.uniform(4.0, 4.9, (n, 3)))
     pm = ParticleMesh(Nmesh=16, BoxSize=16.0, dtype='f8')
-    with nbodykit_tpu.set_options(paint_method='mxu',
-                                  paint_bucket_slack=0.01):
+    before = _counter('paint.trace.tile')
+    with nbodykit_tpu.set_options(paint_method='mxu'):
         out = pm.paint(pos, 1.0)
-    assert _counter('paint.dropped') > 0
+    assert _counter('paint.trace.tile') - before == 1
+    assert _counter('paint.dropped') == 0
     assert np.isclose(float(jnp.sum(out)), n, rtol=1e-10)

@@ -19,11 +19,12 @@ def test_pallas_deposit_matches_xla(res):
     rng = np.random.RandomState(11)
     shape = (32, 32, 32)
     pos = _pos(rng, 4000, shape)
-    ref, _ = paint_local_mxu(pos, 1.0, shape, resampler=res,
-                             return_overflow=True, deposit='xla')
-    got, over = paint_local_mxu(pos, 1.0, shape, resampler=res,
-                                return_overflow=True, deposit='pallas')
-    np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
+    ref = paint_local_mxu(pos, 1.0, shape, resampler=res, deposit='xla')
+    got = paint_local_mxu(pos, 1.0, shape, resampler=res,
+                          deposit='pallas')
+    # one f32 product in the kernel, three bf16 parts outside it
+    np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                               atol=2e-6)
     # and both agree with the scatter oracle
     sc = paint_local(pos, 1.0, shape, resampler=res)
     np.testing.assert_allclose(np.asarray(got), np.asarray(sc),
@@ -38,13 +39,12 @@ def test_pallas_deposit_slab_block():
     shape = (n0l, 32, 32)
     pos = _pos(rng, 3000, period)
     w = jnp.asarray(rng.uniform(0.5, 2.0, 3000).astype('f4'))
-    ref, _ = paint_local_mxu(pos, w, shape, resampler='tsc',
-                             period=period, origin=origin,
-                             return_overflow=True, deposit='xla')
-    got, _ = paint_local_mxu(pos, w, shape, resampler='tsc',
-                             period=period, origin=origin,
-                             return_overflow=True, deposit='pallas')
-    np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
+    ref = paint_local_mxu(pos, w, shape, resampler='tsc',
+                          period=period, origin=origin, deposit='xla')
+    got = paint_local_mxu(pos, w, shape, resampler='tsc',
+                          period=period, origin=origin, deposit='pallas')
+    np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                               atol=2e-6)
     sc = paint_local(pos, w, shape, resampler='tsc', period=period,
                      origin=origin)
     np.testing.assert_allclose(np.asarray(got), np.asarray(sc),
@@ -66,5 +66,6 @@ def test_pallas_deposit_via_options():
     with nbodykit_tpu.set_options(paint_method='mxu',
                                   paint_deposit='xla'):
         f_xla = pm.paint(pos, 1.0, resampler='cic')
-    np.testing.assert_array_equal(np.asarray(f_pal), np.asarray(f_xla))
+    np.testing.assert_allclose(np.asarray(f_pal), np.asarray(f_xla),
+                               atol=2e-6)
     assert abs(float(jnp.sum(f_pal)) - 2000.0) < 0.1

@@ -293,7 +293,9 @@ def test_memory_plan_scale_claims():
     p16 = memory_plan(2048, int(1e9), 16, hbm_bytes=v5e)
     # without a device's memory the plan is arithmetic only: no verdict
     assert 'fits' not in memory_plan(512, int(1e7), 1)
-    assert p16['fits'] and p16['peak_bytes'] < 10e9
+    # the tile paint's padded mesh and sorted payload beside the field
+    # (9.35 GB with the scatter's chunk)
+    assert p16['fits'] and p16['peak_bytes'] < 12.5e9
     # monotonic in devices
     assert (memory_plan(1024, int(1e8), 8)['peak_bytes']
             < memory_plan(1024, int(1e8), 1)['peak_bytes'])

@@ -135,7 +135,7 @@ def test_result_key_every_jit_option_perturbs():
     perturb = {
         'mesh_dtype': 'bf16', 'a2a_compress': 'bf16',
         'resampler': 'tsc', 'paint_method': 'sort',
-        'paint_chunk_size': 12345, 'paint_bucket_slack': 1.75,
+        'paint_chunk_size': 12345,
         'paint_streams': 7, 'fft_chunk_bytes': 999,
         'fft_decomp': 'pencil', 'fft_pencil': (2, 4),
         'integrity': 'cheap',

@@ -10,11 +10,14 @@ This is the ONLY file that describes a topology, and it does so inside
 the ``topo`` fixture: only one process may load libtpu, and every
 xdist worker imports every test file.
 
-The tier-1 cases take ~150 s together (one compile of a paint or an
-exchange is ~20 s, whatever the particle count).  Three more are
-marked ``slow``: run the whole file (no ``-m 'not slow'``) before a
-call that selects the mxu paint or takes four chips.
+One compile of an exchange is ~20 s and of a paint ~2 min here (the
+sort of 1e7 rows with its payload is most of it: 58.9 s alone on the
+chip's host, PR 33), whatever the particle count.  Three more cases
+are marked ``slow``: run the whole file (no ``-m 'not slow'``) before
+a call that selects the Pallas deposit or takes four chips.
 """
+
+import re
 
 import numpy as np
 import pytest
@@ -82,6 +85,33 @@ def _compile(fn, *args):
     return jax.jit(fn).lower(*args).compile()
 
 
+def _paint_ops(compiled):
+    """The compiled program's instructions under ``nbk.paint``, and
+    what the tile paint promises of them: one sort of the particle
+    count (the payload inside it), the deposit as a convolution, and
+    no scatter or gather whose index count is the particles'."""
+    lines = [line for line in compiled.as_text().splitlines()
+             if 'nbk.paint' in line and ' = ' in line]
+    ops = [(line.split(' = ', 1)[1], line) for line in lines]
+    sorts = [head for head, _ in ops
+             if re.search(r'\) sort\(', head.split('metadata')[0])]
+    assert len(sorts) == 1, sorts
+    # key, x, y, z, mass (and the iota that makes the sort stable)
+    assert sorts[0].split(' sort(')[0].count('[%d]' % NPART) in (5, 6), \
+        sorts[0]
+    assert any(re.search(r'[\]}] convolution\(', head)
+               for head, _ in ops)
+    for head, line in ops:
+        kind = re.search(r'[\]})] (scatter|gather)\(', head)
+        if kind:
+            # the row gathers of a piece and the bucket edges'
+            # searchsorted: a few thousand indices at most
+            shape = re.match(r'\(?\w+\[([\d,]*)\]', head).group(1)
+            assert np.prod([int(d) for d in shape.split(',') if d]) \
+                < 10 ** 5, line
+    return ops
+
+
 def _total_bytes(compiled):
     m = compiled.memory_analysis()
     return (m.temp_size_in_bytes + m.argument_size_in_bytes
@@ -116,6 +146,19 @@ def test_served_fftpower_program_fits_and_matches_the_plan(one_chip):
     assert not any('scatter' in line for line in hlo.splitlines()
                    if 'nbk.fftpower.binning' in line)
     assert 's32[%d]' % (NMESH * NMESH * (NMESH // 2 + 1)) not in hlo
+    # and so is the paint (PR 33): one sort with the payload in it,
+    # row gathers, per-tile products.  The eight scatters of 1e7 and
+    # their eight sorts were 0.886 s of a 1.01 s request; under vmap
+    # the catalogs take turns, so no sort is batched
+    _paint_ops(compiled)
+    assert 'f32[%d]' % NMESH ** 3 not in hlo        # the flat mesh
+    assert '[1,%d]' % NPART not in hlo
+    # what the program held with the scatter paint in it (PR 32): the
+    # served cell's peak_hbm_gb is the loaded programs' text
+    m = compiled.memory_analysis()
+    assert m.temp_size_in_bytes <= 2_149_835_264, m.temp_size_in_bytes
+    assert m.generated_code_size_in_bytes <= 68_154_368, \
+        m.generated_code_size_in_bytes
 
 
 @pytest.mark.parametrize('op', ['r2c', 'c2r'])
@@ -132,13 +175,23 @@ def test_eager_fft_at_512(one_chip, op):
     assert _total_bytes(compiled) < 0.25 * V5E_HBM
 
 
-def test_scatter_paint_1e7_into_512(one_chip):
-    pm = _pm(NMESH)
+@pytest.mark.parametrize('resampler, box', [
+    ('cic', 1000.0),
+    # the randoms of the survey cell: 27 deposits a particle for CIC's
+    # 8, each with the particle's own signed weight
+    ('tsc', 2550.0)])
+def test_tile_paint_1e7_into_512(one_chip, resampler, box):
+    pm = _pm(NMESH, box=box)
     pos = jax.ShapeDtypeStruct((NPART, 3), jnp.float32,
                                sharding=one_chip)
+    mass = jax.ShapeDtypeStruct((NPART,), jnp.float32, sharding=one_chip)
     compiled = _compile(
-        lambda p: pm.paint(p, 1.0, resampler='cic',
-                           return_dropped=True), pos)
+        lambda p, m: pm.paint(p, m, resampler=resampler), pos, mass)
+    _paint_ops(compiled)
+    # the padded mesh beside the field and the sorted payload: less
+    # than the scatter paint's flat mesh and its sorts' buffers
+    # (1.06 GB of temporaries for CIC)
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.9e9
     assert _total_bytes(compiled) < 0.25 * V5E_HBM
 
 
@@ -201,9 +254,9 @@ def test_fftpower_binning_program(one_chip, four_chips, monkeypatch,
 
 def test_pallas_deposit_kernel_at_512(one_chip):
     # the shapes paint_local_mxu hands the kernel at 512^3 / 1e7
-    # (rb = cb = 8, slack 2): nty = 64 tiles, 3 pieces of 1632 slots
+    # (rb = cb = 8): nty = 64 tiles, one piece of 256 rows a call
     from nbodykit_tpu.ops.paint_pallas import deposit_blocks_pallas
-    nty, npieces, ck = 64, 3, 1632
+    nty, npieces, ck = 64, 1, 256
     s = jax.ShapeDtypeStruct((nty, npieces, ck), jnp.float32,
                              sharding=one_chip)
     txi = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
@@ -228,20 +281,17 @@ def test_pallas_radix_kernel_at_1e7(one_chip):
 
 
 @pytest.mark.slow
-def test_mxu_paint_with_both_kernels_at_512(one_chip, monkeypatch):
-    # the kernels where the paint really calls them
+def test_mxu_paint_with_pallas_deposit_at_512(one_chip):
+    # the kernel where the paint really calls it
     from nbodykit_tpu import set_options
-    from nbodykit_tpu.ops import radix
-    monkeypatch.setattr(radix, 'DEFAULT_ENGINE', 'pallas')
     pm = _pm(NMESH)
     pos = jax.ShapeDtypeStruct((NPART, 3), jnp.float32,
                                sharding=one_chip)
-    with set_options(paint_method='mxu', paint_deposit='pallas',
-                     paint_order='radix'):
+    with set_options(paint_method='mxu', paint_deposit='pallas'):
         compiled = _compile(
             lambda p: pm.paint(p, 1.0, resampler='cic',
                                return_dropped=True), pos)
-    assert compiled.as_text().count('tpu_custom_call') >= 2
+    assert 'tpu_custom_call' in compiled.as_text()
     assert _total_bytes(compiled) < 0.5 * V5E_HBM
 
 
@@ -333,13 +383,12 @@ def test_staged_exchange_and_paint_of_the_four_chip_cell(four_chips,
         limit = 0.02 * V5E_HBM          # 3 x 45 MB of buffers a device
     else:
         cfg = _global_options
-        assert cfg['paint_method'] == 'scatter'
+        assert cfg['paint_method'] == 'mxu'
         raw, _ = _slab_paint_programs(
             four_chips, (CELL_NMESH,) * 3, 'cic', cfg['paint_method'],
             cfg['paint_chunk_size'], cfg['paint_order'],
             cfg['paint_deposit'], cfg['paint_streams'],
-            jnp.dtype('f4'), jnp.dtype('f4'),
-            cfg['paint_bucket_slack'], True)
+            jnp.dtype('f4'), jnp.dtype('f4'), True)
         slots = 16 * CELL_CAPACITY
         args = [jax.ShapeDtypeStruct((slots, 3), jnp.float32,
                                      sharding=rows3),
@@ -385,16 +434,3 @@ def test_convpower_ell4_program_nine_transforms_at_512(one_chip):
     for name in ('nbk.convpower.ylm', 'nbk.fft.r2c',
                  'nbk.fftpower.transfer'):
         assert name in text, name
-
-
-def test_tsc_weighted_scatter_paint_1e7_into_512(one_chip):
-    # the randoms of the survey cell: 27 deposits a particle for CIC's
-    # 8, each with the particle's own signed weight
-    pm = _pm(NMESH, box=SURVEY_BOX)
-    pos = jax.ShapeDtypeStruct((NPART, 3), jnp.float32,
-                               sharding=one_chip)
-    mass = jax.ShapeDtypeStruct((NPART,), jnp.float32, sharding=one_chip)
-    compiled = _compile(
-        lambda p, m: pm.paint(p, m, resampler='tsc',
-                              return_dropped=True), pos, mass)
-    assert _total_bytes(compiled) < 0.25 * V5E_HBM, _total_bytes(compiled)
