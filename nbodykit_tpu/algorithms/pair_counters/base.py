@@ -46,6 +46,35 @@ def package_result(counts, **attrs):
     return out
 
 
+def catalog_weights(cat, weight):
+    """The catalog's ``weight`` column as a device array, or None
+    where it is the default unit ``Weight`` that nobody set (or no
+    column at all): the kernel then sums no weights, and ``wnpairs``
+    is ``npairs``."""
+    import jax.numpy as jnp
+    from ...base.catalog import CatalogSource, find_columns
+    if weight not in cat:
+        return None
+    if weight not in getattr(cat, '_columns', {weight: None}) and \
+            find_columns(type(cat)).get(weight) is CatalogSource.Weight:
+        return None
+    return jnp.asarray(cat[weight])
+
+
+def weight_totals(w1, n1, w2, n2, is_auto):
+    """``(W1, W2, total)``: the summed weights of the two catalogs
+    (their sizes where a catalog has no weights) and the total weighted
+    pair count the estimators normalize by, self-pairs taken out of an
+    autocorrelation."""
+    W1 = float(n1) if w1 is None else float(np.sum(np.asarray(w1, 'f8')))
+    W2 = float(n2) if w2 is None else float(np.sum(np.asarray(w2, 'f8')))
+    if not is_auto:
+        return W1, W2, W1 * W2
+    sumw2 = float(n1) if w1 is None \
+        else float(np.sum(np.asarray(w1, 'f8') ** 2))
+    return W1, W2, W1 * W1 - sumw2
+
+
 class PairCountBase(object):
     """Base for SimulationBoxPairCount / SurveyDataPairCount; holds
     .pairs and JSON persistence (reference base.py:5)."""

@@ -8,10 +8,10 @@ is non-periodic in a data-derived bounding box.
 
 import numpy as np
 
-from .base import PairCountBase, package_result
+from .base import (PairCountBase, catalog_weights, package_result,
+                   weight_totals)
 from .core import paircount, paircount_dist, rmax_of
 from ...parallel.runtime import mesh_size
-from ...utils import as_numpy
 from ... import transform
 
 
@@ -53,14 +53,13 @@ class SurveyDataPairCount(PairCountBase):
             return jnp.asarray(pos)
 
         pos1 = get_pos(first)
-        w1 = jnp.asarray(first[weight]) if weight in first else None
+        w1 = catalog_weights(first, weight)
         if second is None or second is first:
             pos2, w2 = pos1, w1
             is_auto = True
         else:
             pos2 = get_pos(second)
-            w2 = jnp.asarray(second[weight]) if weight in second \
-                else None
+            w2 = catalog_weights(second, weight)
             is_auto = False
 
         if mode == 'angular':
@@ -84,21 +83,10 @@ class SurveyDataPairCount(PairCountBase):
             counts = paircount_dist(pos1, w1, pos2, w2, box, edges,
                                     self.comm, **kw)
         else:
-            p1n = as_numpy(pos1)
-            p2n = p1n if pos2 is pos1 else as_numpy(pos2)
-            w1n = as_numpy(w1) if w1 is not None else None
-            w2n = w1n if w2 is w1 else (
-                as_numpy(w2) if w2 is not None else None)
-            counts = paircount(p1n, w1n, p2n, w2n, box, edges, **kw)
+            counts = paircount(pos1, w1, pos2, w2, box, edges, **kw)
 
-        W1 = float(np.sum(w1)) if w1 is not None else float(len(pos1))
-        W2 = float(np.sum(w2)) if w2 is not None else float(len(pos2))
-        if is_auto:
-            sumw2 = float(np.sum((w1 if w1 is not None
-                                  else np.ones(len(pos1))) ** 2))
-            total = W1 * W1 - sumw2
-        else:
-            total = W1 * W2
+        W1, W2, total = weight_totals(w1, len(pos1), w2, len(pos2),
+                                      is_auto)
         self.attrs.update(total_wnpairs=total, W1=W1, W2=W2,
                           N1=len(pos1), N2=len(pos2), is_auto=is_auto)
 

@@ -1,16 +1,17 @@
 """SimulationBoxPairCount: pair counts in a periodic box.
 
 Reference: ``nbodykit/algorithms/pair_counters/simbox.py:6`` (wrapping
-Corrfunc theory kernels DD/DDsmu/DDrppi). Here the grid-hash kernel of
-:mod:`.core` does the counting on device.
+Corrfunc theory kernels DD/DDsmu/DDrppi). Here the tile kernel of
+:mod:`.core` does the counting on device; the catalog stays there.
 """
 
 import numpy as np
+import jax.numpy as jnp
 
-from .base import PairCountBase, package_result
+from .base import (PairCountBase, catalog_weights, package_result,
+                   weight_totals)
 from .core import paircount, paircount_dist, rmax_of
 from ...parallel.runtime import mesh_size
-from ...utils import as_numpy
 
 
 class SimulationBoxPairCount(PairCountBase):
@@ -20,8 +21,11 @@ class SimulationBoxPairCount(PairCountBase):
     {'1d','2d','projected','angular'}, first/second catalogs, edges,
     BoxSize, periodic, weight column, Nmu, pimax, los ('x'|'y'|'z').
 
-    Results in :attr:`pairs` (npairs, wnpairs); attrs hold the total
-    weighted pair normalizations used by the estimators.
+    Results in :attr:`pairs`: ``npairs`` is int64 and exact (as
+    Corrfunc's uint64: every pair counted twice in an
+    autocorrelation), ``wnpairs`` f8 (``npairs`` as floats where the
+    catalogs carry no weight column); attrs hold the total weighted
+    pair normalizations used by the estimators.
     """
 
     def __init__(self, mode, first, edges, BoxSize=None, periodic=True,
@@ -55,23 +59,14 @@ class SimulationBoxPairCount(PairCountBase):
         workx = 4.0 if mode == 'angular' else BoxSize[0]
         use_dist = nproc > 1 and rmax <= workx / nproc
 
-        def get(cat, col, conv):
-            if col not in cat:
-                return None
-            return conv(cat[col])
-
-        conv = (lambda x: x) if use_dist else as_numpy
-        import jax.numpy as jnp
-        aspos = (lambda x: jnp.asarray(x)) if use_dist else as_numpy
-
-        pos1 = aspos(first['Position'])
-        w1 = get(first, weight, conv)
+        pos1 = jnp.asarray(first['Position'])
+        w1 = catalog_weights(first, weight)
         if second is None or second is first:
             pos2, w2 = pos1, w1
             is_auto = True
         else:
-            pos2 = aspos(second['Position'])
-            w2 = get(second, weight, conv)
+            pos2 = jnp.asarray(second['Position'])
+            w2 = catalog_weights(second, weight)
             is_auto = False
 
         kw = dict(mode=mode, Nmu=Nmu, pimax=pimax, los=los_i,
@@ -82,14 +77,8 @@ class SimulationBoxPairCount(PairCountBase):
         else:
             counts = paircount(pos1, w1, pos2, w2, BoxSize, edges, **kw)
 
-        W1 = float(np.sum(w1)) if w1 is not None else float(len(pos1))
-        W2 = float(np.sum(w2)) if w2 is not None else float(len(pos2))
-        if is_auto:
-            sumw2 = float(np.sum(np.asarray(w1) ** 2)) \
-                if w1 is not None else float(len(pos1))
-            total = W1 * W1 - sumw2
-        else:
-            total = W1 * W2
+        W1, W2, total = weight_totals(w1, len(pos1), w2, len(pos2),
+                                      is_auto)
         self.attrs['total_wnpairs'] = total
         self.attrs['W1'] = W1
         self.attrs['W2'] = W2

@@ -222,3 +222,198 @@ def test_2pcf_angular_analytic_randoms():
                                rtol=1e-6, atol=1e-6)
     # uniform sphere points: no angular clustering
     assert np.nanmax(np.abs(xi_oracle)) < 0.2
+
+
+# ---------------------------------------------------------------------
+# the tile body (algorithms/pair_counters/core.py) against every pair
+# counted in numpy: exact integer counts in all four modes
+
+from nbodykit_tpu.algorithms.pair_counters import core  # noqa: E402
+
+
+@pytest.fixture
+def small_cells(monkeypatch):
+    """Cells of 8 points and more, so that a few hundred points make a
+    grid of three cells an axis: runs across the periodic faces, blocks
+    that span cells, several pencils."""
+    monkeypatch.setattr(core, '_CELL_FILL', 8)
+    core._tile_program.cache_clear()
+    yield
+    core._tile_program.cache_clear()
+
+
+BOX, RMAX = 30.0, 8.0
+
+
+def layout_points(layout, n, rng, mode):
+    if mode == 'angular':
+        # unit vectors: all over the sphere, or in one cap
+        z = rng.uniform(-1, 1, n) if layout == 'uniform' \
+            else rng.uniform(0.995, 1, n)
+        phi = rng.uniform(0, 2 * np.pi, n)
+        s = np.sqrt(1 - z * z)
+        return np.stack([s * np.cos(phi), s * np.sin(phi), z], axis=1)
+    if layout == 'one_cell':
+        return rng.uniform(1.0, 4.0, (n, 3))
+    pos = rng.uniform(0, BOX, (n, 3))
+    if layout == 'faces':
+        # on the faces themselves, and a hair inside them
+        pos[:n // 4, 0] = 0.0
+        pos[n // 4:n // 2, 1] = BOX
+        pos[n // 2:3 * n // 4, 2] = np.nextafter(BOX, 0)
+        pos[3 * n // 4:, 0] = np.nextafter(0, 1)
+    return pos
+
+
+def brute_counts(p1, w1, p2, w2, edges, mode, periodic, is_auto,
+                 Nmu=None, pimax=None):
+    """(npairs, wnpairs) of every pair, numpy f8 and int64."""
+    d = p1[:, None, :] - p2[None, :, :]
+    if periodic and mode != 'angular':
+        d -= BOX * np.round(d / BOX)
+    r2 = (d * d).sum(-1)
+    ok = r2 > 0 if is_auto else np.ones(r2.shape, bool)
+    ww = w1[:, None] * w2[None, :]
+    if mode == 'angular':
+        edges = 2 * np.sin(0.5 * np.radians(edges))
+    key, nb2, second = r2, 1, np.zeros(r2.shape, 'i8')
+    dlos = np.abs(d[..., 2])
+    if mode == '2d':
+        nb2 = Nmu
+        mu = np.where(r2 > 0, dlos / np.sqrt(np.where(r2 > 0, r2, 1)), 0)
+        second = np.clip((mu * Nmu).astype('i8'), 0, Nmu - 1)
+    elif mode == 'projected':
+        nb2 = int(pimax)
+        key = r2 - dlos ** 2
+        second = np.clip(dlos.astype('i8'), 0, nb2 - 1)
+        ok &= dlos < pimax
+    first = np.digitize(key, edges ** 2)
+    ok &= (first >= 1) & (first <= len(edges) - 1)
+    flat = ((first - 1) * nb2 + second)[ok]
+    nbins = (len(edges) - 1) * nb2
+    shape = (len(edges) - 1, nb2)
+    return (np.bincount(flat, minlength=nbins).reshape(shape).squeeze(),
+            np.bincount(flat, weights=ww[ok], minlength=nbins
+                        ).reshape(shape).squeeze())
+
+
+CASES = [(mode, layout, periodic)
+         for mode in ('1d', '2d', 'projected')
+         for layout, periodic in (('uniform', True), ('one_cell', True),
+                                  ('faces', True), ('uniform', False))
+         ] + [('angular', 'uniform', False), ('angular', 'one_cell', False)]
+
+
+@pytest.mark.parametrize('weights', ['unit', 'f32'])
+@pytest.mark.parametrize('pairing', ['auto', 'cross'])
+@pytest.mark.parametrize('mode,layout,periodic', CASES)
+def test_tiles_match_every_pair(small_cells, mode, layout, periodic,
+                                pairing, weights):
+    rng = np.random.RandomState(sum(map(ord, mode + layout)))
+    n1, n2 = 400, 330
+    kw = {'2d': dict(Nmu=3), 'projected': dict(pimax=4.0)}.get(mode, {})
+    edges = np.array([1.0, 5.0, 10.0, 25.0, 60.0]) if mode == 'angular' \
+        else np.array([0.5, 2.0, 3.5, 5.0, RMAX])
+    if mode == 'projected':
+        edges = edges[:-1]
+    p1 = layout_points(layout, n1, rng, mode)
+    p2 = p1 if pairing == 'auto' else layout_points(layout, n2, rng, mode)
+    w1 = w2 = None
+    if weights == 'f32':
+        w1 = rng.uniform(0.5, 2.0, len(p1)).astype('f4')
+        w2 = w1 if pairing == 'auto' else \
+            rng.uniform(0.5, 2.0, len(p2)).astype('f4')
+    got = core.paircount(p1, w1, p2, w2, BOX, edges, mode=mode,
+                         periodic=periodic, is_auto=pairing == 'auto',
+                         **kw)
+    if mode != 'angular' and periodic:
+        p1, p2 = p1 % BOX, p2 % BOX
+    ones = np.ones
+    want_n, want_w = brute_counts(
+        p1, ones(len(p1)) if w1 is None else w1.astype('f8'),
+        p2, ones(len(p2)) if w2 is None else w2.astype('f8'),
+        edges, mode, periodic, pairing == 'auto', **kw)
+    assert got['npairs'].dtype == np.int64
+    assert want_n.sum() > 100
+    np.testing.assert_array_equal(got['npairs'], want_n)
+    np.testing.assert_allclose(got['wnpairs'], want_w, rtol=1e-6)
+    if weights == 'unit':
+        np.testing.assert_array_equal(got['wnpairs'], got['npairs'])
+
+
+def test_more_than_2_24_pairs_in_one_bin():
+    """6,000 points whose separations all fall in one bin: 3.6e7
+    ordered pairs, past what an f4 holds exactly (2^24 = 1.68e7); a
+    float count fails the type check, an f4 running sum the value."""
+    rng = np.random.RandomState(24)
+    n = 6000
+    pos = 50.0 + rng.uniform(-0.1, 0.1, (n, 3))
+    cat = ArrayCatalog({'Position': pos}, BoxSize=100.0)
+    r = SimulationBoxPairCount('1d', cat, np.array([1e-6, 1.0, 2.0]))
+    npairs = np.asarray(r.pairs['npairs'])
+    assert np.issubdtype(npairs.dtype, np.integer)
+    assert npairs.tolist() == [n * (n - 1), 0]
+    assert n * (n - 1) > 2 ** 24
+    assert np.asarray(r.pairs['wnpairs']).tolist() == [n * (n - 1.0), 0.0]
+
+
+def test_two_calls_the_same_bytes_and_one_program(small_cells):
+    from nbodykit_tpu.diagnostics.metrics import REGISTRY
+    rng = np.random.RandomState(3)
+    pos = rng.uniform(0, BOX, (500, 3))
+    w = rng.uniform(0.5, 2.0, 500)
+    cat = ArrayCatalog({'Position': pos, 'Weight': w}, BoxSize=BOX)
+    edges = np.linspace(0.5, RMAX, 6)
+
+    def value(name):
+        return (REGISTRY.snapshot().get(name) or {'value': 0})['value']
+
+    a = SimulationBoxPairCount('1d', cat, edges).pairs
+    hits, misses, slots = (value('compile.paircount.tiles.hits'),
+                           value('compile.paircount.tiles.misses'),
+                           value('paircount.slots'))
+    b = SimulationBoxPairCount('1d', cat, edges).pairs
+    for name in ('npairs', 'wnpairs'):
+        assert np.asarray(a[name]).tobytes() == np.asarray(b[name]).tobytes()
+    # the warm call traced nothing, and counted its slots and pairs
+    assert value('compile.paircount.tiles.hits') == hits + 1
+    assert value('compile.paircount.tiles.misses') == misses
+    assert value('paircount.slots') > slots
+    assert value('paircount.pairs') >= int(np.sum(a['npairs']))
+
+
+def test_unit_weights_are_not_summed():
+    """The default ``Weight`` column nobody set is no weight: the
+    kernel sums none and ``wnpairs`` is ``npairs``."""
+    from nbodykit_tpu.algorithms.pair_counters.base import catalog_weights
+    pos = np.random.RandomState(5).uniform(0, 10, (50, 3))
+    plain = ArrayCatalog({'Position': pos}, BoxSize=10.0)
+    assert catalog_weights(plain, 'Weight') is None
+    plain['Weight'] = np.full(50, 2.0)
+    assert np.all(np.asarray(catalog_weights(plain, 'Weight')) == 2.0)
+    other = ArrayCatalog({'Position': pos, 'W': np.ones(50)}, BoxSize=10.0)
+    assert catalog_weights(other, 'W') is not None
+    assert catalog_weights(other, 'nope') is None
+
+
+@pytest.mark.parametrize('mode,kw', [('1d', {}), ('2d', dict(Nmu=3))])
+def test_paircount_dist_four_devices_equals_single(small_cells, mode, kw):
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import Mesh
+    from nbodykit_tpu.parallel.runtime import AXIS, shard_leading
+    mesh = Mesh(np.array(jax.devices('cpu')[:4]), (AXIS,))
+    rng = np.random.RandomState(11)
+    n = 1200
+    pos = rng.uniform(0, 40.0, (n, 3))
+    pos[:300] = 20.0 + rng.standard_normal((300, 3))    # a clump
+    w = rng.uniform(0.5, 2.0, n)
+    edges = np.linspace(0.5, 6.0, 7)
+    one = core.paircount(pos, w, pos, w, 40.0, edges, mode=mode,
+                         is_auto=True, **kw)
+    pj, wj = (shard_leading(mesh, jnp.asarray(x)) for x in (pos, w))
+    four = core.paircount_dist(pj, wj, pj, wj, 40.0, edges, mesh,
+                               mode=mode, is_auto=True, **kw)
+    assert four['npairs'].dtype == np.int64
+    np.testing.assert_array_equal(four['npairs'], one['npairs'])
+    np.testing.assert_allclose(four['wnpairs'], one['wnpairs'], rtol=1e-12)

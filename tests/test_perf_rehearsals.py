@@ -45,6 +45,18 @@ REHEARSALS = [
     'test_four_chip_cell.py::'
     'test_a2a_ici_share_withheld_above_the_unscoped_limit',
     'test_four_chip_cell.py::test_the_cell_reports_the_new_metrics',
+    'test_paircount_cell.py::test_paircount_driver_end_to_end',
+    'test_paircount_cell.py::test_paircount_verify_catches_what_moved',
+    'test_paircount_cell.py::'
+    'test_paircount_oracle_refuses_a_float_count',
+    'test_paircount_cell.py::'
+    'test_plain_reference_two_ways_and_its_bracket',
+    'test_paircount_cell.py::test_paircount_readers_on_one_call',
+    'test_paircount_cell.py::test_paircount_readers_on_the_parent',
+    'test_paircount_cell.py::'
+    'test_pair_rate_share_withheld_above_the_unscoped_limit',
+    'test_paircount_cell.py::test_pair_flops_by_hand',
+    'test_paircount_cell.py::test_the_new_cell_reports_its_metrics',
     'test_perf_harness.py::test_lab_driver_one_device',
     'test_perf_harness.py::test_lab_driver_four_virtual_devices',
     'test_perf_harness.py::test_lab_oracle_catches_a_wrong_answer',

@@ -434,3 +434,51 @@ def test_convpower_ell4_program_nine_transforms_at_512(one_chip):
     for name in ('nbk.convpower.ylm', 'nbk.fft.r2c',
                  'nbk.fftpower.transfer'):
         assert name in text, name
+
+
+def test_paircount_tiles_program_at_the_cell_size(one_chip):
+    # the pair-counting cell's one program (mr19_like: 1.2e6 points in
+    # a 420 box, 14 log bins to 25; PR 35): under nbk.paircount one
+    # sort of the point count with the coordinates inside it, no
+    # scatter, and no gather whose index count grows with the
+    # candidates (the searches of the run edges and the block table:
+    # thousands of indices; the run tables: 27 entries a block; the
+    # row reads of a piece: 256 blocks x 4 rows of 3 x 128 coordinates)
+    from nbodykit_tpu.algorithms.pair_counters import core
+    n, box = 1200000, 420.0
+    edges = np.logspace(np.log10(0.1), np.log10(25.0), 15)
+    work_box, redges, rmax, nb2, periodic = core._mode_setup(
+        box, edges, '1d', None, None, True)
+    ncell = core._grid_cells(work_box, rmax, n)
+    assert ncell == (16, 16, 16)
+    core._tile_program.cache_clear()
+    program = core._tile_program(
+        None, True, False, False, ncell, tuple(work_box), periodic,
+        tuple(redges ** 2), '1d', nb2, None, 2, 'axis', (0.0,) * 3, True,
+        'float32')
+    try:
+        compiled = program._jitted.lower(jax.ShapeDtypeStruct(
+            (n, 3), jnp.float32, sharding=one_chip)).compile()
+    finally:
+        core._tile_program.cache_clear()
+    lines = [line for line in compiled.as_text().splitlines()
+             if 'nbk.paircount' in line and ' = ' in line]
+    heads = [line.split(' = ', 1)[1].split('metadata')[0]
+             for line in lines]
+    sorts = [h for h in heads if re.search(r'\) sort\(', h)]
+    assert len(sorts) == 1, sorts
+    # cell id, x, y, z (and the iota that makes the sort stable)
+    assert sorts[0].split(' sort(')[0].count('[%d]' % n) in (4, 5), sorts[0]
+    assert not any(re.search(r'[\]})] scatter\(', h) for h in heads)
+    gathers = [h for h in heads if re.search(r'[\]})] gather\(', h)]
+    assert gathers
+    for h in gathers:
+        shape = re.match(r'\(?\w+\[([\d,]*)\]', h).group(1)
+        assert np.prod([int(d) for d in shape.split(',') if d]) \
+            < 10 ** 6, h
+    # the catalog (14.4 MB), its sorted copy and the run tables; the
+    # tiles are reduced in one pass and never stored: 23 MB when this
+    # was written
+    m = compiled.memory_analysis()
+    assert m.temp_size_in_bytes < 64 * 2 ** 20, m.temp_size_in_bytes
+    assert _total_bytes(compiled) < 0.01 * V5E_HBM
