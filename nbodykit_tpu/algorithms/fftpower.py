@@ -27,7 +27,7 @@ import jax.numpy as jnp
 from ..base.catalog import CatalogSourceBase
 from ..base.mesh import MeshSource, Field, FieldMesh
 from ..binned_statistic import BinnedStatistic
-from ..diagnostics import instrumented_jit, scope
+from ..diagnostics import counter, instrumented_jit, scope
 from ..utils import JSONEncoder, JSONDecoder, as_numpy, working_dtype
 
 
@@ -224,7 +224,9 @@ def project_to_basis(y3d, edges, los=[0, 0, 1], poles=[]):
             return a
         return jax.lax.dynamic_slice_in_dim(a, start, rows, 0)
 
-    from ..ops.histogram import edge_count_index, hist2d_weighted
+    from ..ops.histogram import (edge_count_index, hist2d_weighted,
+                                 mxu_split)
+    from ..utils import is_mxu_backend
 
     def chunk_hists(v_c, start):
         """All weighted histograms of one leading-axis slab whose
@@ -241,25 +243,25 @@ def project_to_basis(y3d, edges, los=[0, 0, 1], poles=[]):
             mudot = sum(slice0(c, start) for c in coords)
             mu = jnp.where(xnorm == 0, 0.0,
                            mudot / jnp.where(xnorm == 0, 1.0, xnorm))
-            dig_x = edge_count_index(
-                jnp.broadcast_to(x2, shape).reshape(-1), x2edges)
-            dig_mu = edge_count_index(
-                jnp.broadcast_to(mu, shape).reshape(-1), muedges_j)
+            dig_x = edge_count_index(jnp.broadcast_to(x2, shape),
+                                     x2edges)
+            dig_mu = edge_count_index(mu, muedges_j)
 
-        wf = jnp.broadcast_to(w_b, shape).reshape(-1)
-        nonsing = (wf == 2.0)
-        xw = jnp.broadcast_to(xnorm, shape).reshape(-1) * wf
-        muw = jnp.broadcast_to(mu, shape).reshape(-1) * wf
-
-        streams = [xw, muw, wf]
+        # the chunk and its factors as they lie: what is constant along
+        # an axis stays size 1 there (hist2d_weighted broadcasts)
+        nonsing = (w_b == 2.0)
+        streams = [xnorm * w_b, mu * w_b,
+                   # 1.0 and 2.0, exact in bfloat16, and handed over as
+                   # such: one part of the MXU histogram's product
+                   w_b.astype(jnp.bfloat16)]
         legs = _legendre_all(_poles, mu)
         # accumulate the spectrum in the widest dtype the backend has
         # (f8 under x64, f4 on TPU) — explicit, not silently demoted
-        vre = v_c.real.astype(working_dtype('f8')).reshape(-1)
-        vim = (v_c.imag.astype(working_dtype('f8')).reshape(-1)
+        vre = v_c.real.astype(working_dtype('f8'))
+        vim = (v_c.imag.astype(working_dtype('f8'))
                if is_cplx else None)
         for iell, ell in enumerate(_poles):
-            leg = jnp.broadcast_to(legs[iell], shape).reshape(-1)
+            leg = legs[iell]
             yre = leg * vre
             yim = leg * vim if is_cplx else None
             if hermitian:
@@ -279,6 +281,12 @@ def project_to_basis(y3d, edges, los=[0, 0, 1], poles=[]):
                                    Nx + 2, Nmu + 2)
 
     nstreams = 3 + Nell * (2 if is_cplx else 1)
+    # what the MXU histogram's product looks like for these bins (None
+    # where hist2d_weighted sums by bincount): two bf16 parts a stream,
+    # one for the count
+    parts = 2 * nstreams - 1
+    split = (list(mxu_split(Nx + 2, Nmu + 2, parts))
+             if is_mxu_backend() else None)
 
     def _block_hists(v_loc, base):
         """Histograms of one device's (S0_local, S1, S2) block starting
@@ -286,6 +294,8 @@ def project_to_basis(y3d, edges, los=[0, 0, 1], poles=[]):
         temporaries are live. Cross-chunk sums are Kahan-compensated:
         in the no-x64 (TPU) regime the carry is f32 and a plain sum
         over many chunks loses low bits of the per-bin totals."""
+        if split:       # traced once a program, by either ``binning``
+            counter('fftpower.binning.trace.split').add(1)
         if not chunked:
             return list(chunk_hists(v_loc, base))
 
@@ -340,7 +350,8 @@ def project_to_basis(y3d, edges, los=[0, 0, 1], poles=[]):
 
     with scope('fftpower.binning', nstreams=nstreams,
                shape=[int(s) for s in value.shape],
-               nx_edges=len(xedges), nmu_edges=len(muedges)) as sc:
+               nx_edges=len(xedges), nmu_edges=len(muedges),
+               split=split, parts=parts if split else None) as sc:
         hs = sc.done(_bin(value))
     xsum, musum, Nsum = hs[0], hs[1], hs[2]
     ys_re, ys_im = [], []

@@ -6,21 +6,32 @@ lowers to scatter-add, which TPUs execute at ~10 ns/element — at
 Nmesh=1024 (5.4e8 modes x several weight streams) that is tens of
 seconds, dominating the whole FFTPower pipeline.
 
-TPU-native redesign: the bin index splits as ``dig = a * NB + b`` with
-``a`` (the k bin) taking hundreds of values and ``b`` (the mu bin) a
-dozen, so the histogram is a *matrix product* that rides the MXU:
+TPU-native redesign: the histogram is a *matrix product* that rides the
+MXU.  The flat bin index ``f = a * NB + b`` (``a`` the k bin, hundreds
+of values; ``b`` the mu bin, a dozen or three) splits as ``f = f_hi * 8
++ f_lo``, and with ``parts`` the streams' bf16 parts
 
-    H_w[a, b] = sum_e w[e] * onehot(a_e)[a] * onehot(b_e)[b]
-             => H_w = A^T @ (B * w[:, None]),  A = onehot(a), B = onehot(b)
+    H[part, f_lo, f_hi] = sum_e part[e] * onehot(f_lo_e)[f_lo]
+                                        * onehot(f_hi_e)[f_hi]
+                        = (onehot(f_lo) * parts) @ onehot(f_hi)
 
-All weight streams share one dot per chunk (their B-columns are
-concatenated), one-hots are exact in bfloat16, each weight is split
-into bf16 hi+lo parts (w = hi + lo), the MXU accumulates in f32 and
-chunk results are summed in f64. Accuracy (~2e-7 max relative error
-vs exact f64 bincount) is asserted by tests/test_histogram.py. On the
-chip it takes 0.095 s of an ``FFTPower`` call at 512^3 with 257 x 12
-bins and 5 streams (device time under ``nbk.fftpower.binning.hist``,
-one v5e; root PERF.md, the builder's traced run of PR 25).
+over a chunk of cells taken as it lies (3-d for the lab binning: a
+slab chunk whole, never flattened), the B side's ``8 * parts`` columns
+a ``where`` of the parts against an iota on a leading axis, so that
+the compiler builds both one-hot operands inside the product's fusion
+and stores neither (``hist2d_mxu``, ``mxu_split``).  One-hots are
+exact in bfloat16, each weight is split into bf16 hi+lo parts (w = hi
++ lo; a stream handed over AS bfloat16, the count's 1.0 and 2.0, is
+its own one part), the MXU accumulates in f32 and chunk results are
+summed in ``acc_dtype``.  Accuracy (2e-6 of the largest bin at worst,
+two bf16 parts a weight) is asserted by tests/test_histogram.py
+against an exact f64 bincount and against the form it replaced, which
+concatenated and stored a ``[131072, 2 * nw * NB]`` block of one-hot
+columns a chunk of flattened cells: 0.122 s of an ``FFTPower`` call at
+512^3 with 257 x 12 bins and 5 streams, 0.551 s of a survey call's
+three 128 x 3 binnings (device time under ``nbk.fftpower.binning``,
+one v5e; ledger, PR 35); what this form takes is in root PERF.md,
+PR 36.
 
 The bin indices it sums by come from ``edge_count_index`` (edges of any
 spacing; the lab path) or ``lattice_shell_index`` (unit-width shells;
@@ -37,15 +48,14 @@ import jax
 import jax.numpy as jnp
 
 
-#: cells a one-hot matrix product takes at a time
+#: cells a one-hot matrix product of ``shell_sums`` takes at a time
 _CHUNK = 131072
-
-
-def _pad_to(x, n, fill):
-    m = x.shape[0]
-    if m == n:
-        return x
-    return jnp.concatenate([x, jnp.full((n - m,), fill, x.dtype)])
+#: and of ``hist2d_mxu``: a slab chunk of the lab binning whole
+#: (``fftpower._BIN_CHUNK_ELEMENTS``); a row of it at a time, every
+#: stream is sliced and re-tiled a row, a third of the kernel's time
+_HIST_CHUNK = 1 << 22
+#: the part of the flat bin index that rides on the product's B side
+_LO = 8
 
 
 def _bf16_grid(x):
@@ -57,63 +67,123 @@ def _bf16_grid(x):
     return jax.lax.reduce_precision(x, exponent_bits=8, mantissa_bits=7)
 
 
-def hist2d_mxu(abin, bbin, weights, NA, NB, chunk=_CHUNK,
+def mxu_split(NA, NB, parts):
+    """``(rows, columns)`` of ``hist2d_mxu``'s product for ``NA x NB``
+    bins and ``parts`` bf16 parts of the streams.
+
+    The flat bin index ``f = a * NB + b`` is split as ``f_hi * 8 +
+    f_lo``, whatever ``NA`` and ``NB`` are: ``rows = ceil(NA NB / 8)``
+    one-hot rows of ``f_hi`` on the A side, ``columns = 8 parts`` on
+    the B side (``f_lo`` against each part).  So a binning with few
+    ``b`` bins (the survey's 3, whose 128 x 30 product ran at 2% of
+    the bf16 peak) gets as many columns as one with 12, and its rows
+    shrink instead.  Why 8: a ``where`` against an iota of 8 on a
+    leading axis is what the v5e compiler builds inside the product's
+    operand; with the ``b`` bins themselves there (12, 3, 6) or with 16,
+    24 or 32 it stores the B side, 19-38 MB a row of 512 x 257 cells,
+    and re-lays it (root PERF.md, PR 36: every form as timed).  What it
+    gives the cells: lab 257 x 12 x 9 -> 386 x 72, four chips 513 x 12
+    x 9 -> 770 x 72, the survey's 128 x 3 x 9 -> 48 x 72."""
+    return -(-NA * NB // _LO), _LO * parts
+
+
+def hist2d_mxu(abin, bbin, weights, NA, NB, chunk=_HIST_CHUNK,
                acc_dtype=jnp.float64):
     """MXU-backed weighted 2-D histograms.
 
-    abin : (M,) int32 in [0, NA)
-    bbin : (M,) int32 in [0, NB)
-    weights : sequence of (M,) float arrays (any float dtype)
+    abin : int32 in [0, NA), of any shape ``(n0, ...)``: the cells
+    bbin : int32 in [0, NB), broadcastable to ``abin``
+    weights : sequence of float arrays broadcastable to ``abin`` (a
+        factor that is constant along an axis stays size 1 there)
     Returns a list of (NA, NB) ``acc_dtype`` arrays, one per weight.
 
     Traceable (jit-safe); shapes are static. Elements with bins outside
     the valid range must be pre-clipped by the caller (the fftpower
     binning reserves explicit under/overflow bins, so this holds).
 
+    The product is ``shell_sums``'s: a chunk of leading rows at a time
+    (``chunk`` cells or one row, whichever is more), contracted over
+    the cells' axes as they lie; the B side's columns are a ``where``
+    of the parts against an iota on a leading axis, so the compiler
+    builds both one-hot operands inside the product and stores neither
+    (``mxu_split``).  The last chunk starts early where the chunks do
+    not divide the rows, and the cells it shares with the one before
+    weigh 0.  A chunk's weights of one bin must sum to less than 2^24
+    for a count to come out as an exact integer (``chunk`` cells of
+    weight 2 do).
+
     Precision contract: weights are cast to f32 before the bf16 hi/lo
     split, so per-element fidelity is f32-grade (~1e-7 relative) even
     for f64 inputs; ``acc_dtype`` only sets the cross-chunk
-    accumulation width. Callers needing exact f64 sums must use the
-    bincount path (``hist2d_weighted`` auto-picks it off-TPU).
+    accumulation width. A weight handed over AS bfloat16 is its own one
+    part (the count stream's 1.0 and 2.0: their lo part is identically
+    zero). Callers needing exact f64 sums must use the bincount path
+    (``hist2d_weighted`` auto-picks it off-TPU).
     """
-    M = int(abin.shape[0])
-    nw = len(weights)
-    nch = max(1, -(-M // chunk))
-    Mp = nch * chunk
-    abin = _pad_to(abin.astype(jnp.int32), Mp, 0)
-    bbin = _pad_to(bbin.astype(jnp.int32), Mp, 0)
-    ws = [_pad_to(w.astype(jnp.float32), Mp, 0.0) for w in weights]
+    shape = tuple(int(n) for n in abin.shape)
+    nd = len(shape)
+    lead, inner = shape[0], int(np.prod(shape[1:], dtype=np.int64))
+    nch = -(-lead // max(1, min(lead, chunk // inner)))
+    r = -(-lead // nch)                       # leading rows a chunk
+    cells = (r,) + shape[1:]
 
-    ncols = 2 * nw * NB
+    def by_row(x, dtype=None):
+        x = jnp.asarray(x, dtype)
+        return x.reshape((1,) * (nd - x.ndim) + x.shape)
 
-    def body(i, acc):
-        a_c = jax.lax.dynamic_slice(abin, (i * chunk,), (chunk,))
-        b_c = jax.lax.dynamic_slice(bbin, (i * chunk,), (chunk,))
-        A = jax.nn.one_hot(a_c, NA, dtype=jnp.bfloat16)
-        Boh = jax.nn.one_hot(b_c, NB, dtype=jnp.bfloat16)
-        cols = []
-        for w in ws:
-            w_c = jax.lax.dynamic_slice(w, (i * chunk,), (chunk,))
-            hi = _bf16_grid(w_c)
-            lo = w_c - hi
-            cols.append(Boh * hi.astype(jnp.bfloat16)[:, None])
-            cols.append(Boh * lo.astype(jnp.bfloat16)[:, None])
-        B = jnp.concatenate(cols, axis=1)
-        H = jax.lax.dot_general(A, B, (((0,), (0,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        return acc + H.astype(acc_dtype)
+    abin = by_row(abin, jnp.int32)
+    bbin = by_row(bbin, jnp.int32)
+    exact = [w.dtype == jnp.bfloat16 for w in weights]
+    ws = [by_row(w, jnp.float32) for w in weights]
+    nparts = sum(1 if e else 2 for e in exact)
+    rows, ncols = mxu_split(NA, NB, nparts)
+
+    def partial(i):
+        start = jnp.minimum(i * r, lead - r)
+
+        def take(x):
+            if x.shape[0] != 1:
+                x = jax.lax.dynamic_slice_in_dim(x, start, r)
+            return x
+
+        fresh = None
+        if nch * r != lead:
+            fresh = (start + jnp.arange(r, dtype=jnp.int32) >= i * r
+                     ).reshape((r,) + (1,) * (nd - 1)).astype(jnp.float32)
+        parts = []
+        for w, e in zip(ws, exact):
+            w_c = take(w) if fresh is None else take(w) * fresh
+            hi = w_c if e else _bf16_grid(w_c)
+            parts.append(hi)
+            if not e:
+                parts.append(w_c - hi)
+        cols = jnp.stack([jnp.broadcast_to(p, cells) for p in parts])
+        f = jnp.broadcast_to(take(abin) * NB + take(bbin), cells)
+        B = f % _LO == jnp.arange(_LO, dtype=jnp.int32).reshape(
+            (_LO,) + (1,) * nd)
+        cols = jnp.where(B, cols[:, None], 0.0).astype(jnp.bfloat16)
+        A = jax.nn.one_hot(f // _LO, rows, dtype=jnp.bfloat16)
+        # the chunk stays as it lies: flattening it is a relayout of
+        # every cell (shell_sums)
+        axes = tuple(range(nd))
+        return jax.lax.dot_general(
+            cols.reshape((ncols,) + cells), A,
+            ((tuple(1 + n for n in axes), axes), ((), ())),
+            preferred_element_type=jnp.float32)
 
     # inside shard_map (the multi-device binning) the chunks are
     # device-local, so the accumulator starts with their varying type
     from ..parallel.runtime import vary_like
     H = jax.lax.fori_loop(
-        0, nch, body,
-        vary_like(jnp.zeros((NA, ncols), acc_dtype), abin, bbin, *ws))
-    out = []
-    for iw in range(nw):
-        hi = H[:, (2 * iw) * NB:(2 * iw + 1) * NB]
-        lo = H[:, (2 * iw + 1) * NB:(2 * iw + 2) * NB]
-        out.append(hi + lo)
+        0, nch, lambda i, acc: acc + partial(i).astype(acc_dtype),
+        vary_like(jnp.zeros((ncols, rows), acc_dtype), abin, bbin, *ws))
+    # [part, f_lo, f_hi] -> [part, a, b]
+    H = H.reshape(nparts, _LO, rows).transpose(0, 2, 1).reshape(
+        nparts, rows * _LO)[:, :NA * NB].reshape(nparts, NA, NB)
+    out, k = [], 0
+    for e in exact:
+        out.append(H[k] if e else H[k] + H[k + 1])
+        k += 1 if e else 2
     return out
 
 
@@ -296,9 +366,10 @@ def _default_method():
 
 
 def hist2d_weighted(abin, bbin, weights, NA, NB, method=None,
-                    chunk=_CHUNK, acc_dtype=None):
-    """Weighted 2-D histograms of flat index streams; see module
-    docstring. ``method`` in {'mxu', 'bincount', None=auto}."""
+                    chunk=_HIST_CHUNK, acc_dtype=None):
+    """Weighted 2-D histograms of index streams of any one shape (the
+    weights broadcastable to it); see module docstring. ``method`` in
+    {'mxu', 'bincount', None=auto}."""
     if method is None:
         method = _default_method()
     if acc_dtype is None:
@@ -307,4 +378,12 @@ def hist2d_weighted(abin, bbin, weights, NA, NB, method=None,
     if method == 'mxu':
         return hist2d_mxu(abin, bbin, weights, NA, NB, chunk=chunk,
                           acc_dtype=acc_dtype)
-    return hist2d_bincount(abin, bbin, weights, NA, NB)
+
+    def flat(x):
+        # a bfloat16 weight is exact by its type (hist2d_mxu); summed
+        # as bfloat16 it would not be
+        if x.dtype == jnp.bfloat16:
+            x = x.astype(acc_dtype)
+        return jnp.broadcast_to(x, abin.shape).reshape(-1)
+    return hist2d_bincount(flat(abin), flat(bbin),
+                           [flat(w) for w in weights], NA, NB)

@@ -282,6 +282,50 @@ def test_lab_call_trace_names_every_layer_once_synced(tmp_path,
     assert end['fft.r2c'] <= start['fftpower.binning'] + 1e-6
 
 
+@pytest.mark.parametrize('on_mxu', [True, False], ids=['mxu', 'bincount'])
+def test_binning_span_names_its_split(tmp_path, monkeypatch, on_mxu):
+    """Where the sums are the MXU histogram's product, the
+    ``fftpower.binning`` span says how ``ops.histogram.mxu_split`` cut
+    the bin index between the product's two sides (``split``: rows of
+    the A side, columns of the B side) and how many bf16 ``parts`` the
+    streams are, and ``fftpower.binning.trace.split`` counts one a
+    compiled program (the program is traced anew each call, ROADMAP
+    S9a: two calls, two); where they are a bincount, neither."""
+    import nbodykit_tpu.utils
+    from nbodykit_tpu.algorithms.fftpower import project_to_basis
+    from nbodykit_tpu.base.mesh import Field
+    from nbodykit_tpu.ops.histogram import mxu_split
+    from nbodykit_tpu.pmesh import ParticleMesh
+    monkeypatch.setattr(nbodykit_tpu.utils, 'is_mxu_backend',
+                        lambda: on_mxu)
+    pm = ParticleMesh(Nmesh=16, BoxSize=32.0, dtype='f4')
+    rng = np.random.default_rng(36)
+    y3d = Field(pm.r2c(jnp.asarray(rng.standard_normal((16,) * 3), 'f4')),
+                pm, 'complex')
+    dk = 2 * np.pi / 32.0
+    edges = [np.arange(0.0, np.pi * 16 / 32.0 + dk / 2, dk),
+             np.linspace(-1, 1, 6)]
+    with nbodykit_tpu.set_options(diagnostics=str(tmp_path)):
+        for _ in range(2):
+            project_to_basis(y3d, edges)
+    spans = [s for s in _spans(str(tmp_path))
+             if s['name'] == 'fftpower.binning']
+    assert len(spans) == 2
+    snap = REGISTRY.snapshot()
+    traced = snap.get('fftpower.binning.trace.split', {'value': 0})
+    for s in spans:
+        assert s['attrs']['nstreams'] == 5
+        if on_mxu:
+            # two bf16 parts a stream, one for the count's 1.0 and 2.0
+            assert s['attrs']['parts'] == 9
+            assert s['attrs']['split'] == list(
+                mxu_split(len(edges[0]) + 1, len(edges[1]) + 1, 9))
+        else:
+            assert s['attrs']['split'] is None
+            assert s['attrs']['parts'] is None
+    assert traced['value'] == (2 if on_mxu else 0)
+
+
 # ---------------------------------------------------------------------------
 # the paint's engine: an attribute of the span, a counter per program
 

@@ -48,8 +48,142 @@ def test_hist2d_matches_numpy(method):
                                refs[1] / scale, atol=3e-6)
 
 
+def _hist2d_mxu_pr35(abin, bbin, weights, NA, NB, chunk,
+                     acc_dtype=jnp.float64):
+    """``hist2d_mxu`` as it stood before PR 36, the reference for the
+    new product's rounding: flat arrays padded to whole chunks, a
+    ``[chunk, 2 * nw * NB]`` block of one-hot columns concatenated and
+    stored a chunk, ``A^T @ B`` contracted over the flat axis."""
+    from nbodykit_tpu.ops.histogram import _bf16_grid
+    M, nw = int(abin.shape[0]), len(weights)
+    nch = max(1, -(-M // chunk))
+
+    def pad(x, fill):
+        return jnp.concatenate(
+            [x, jnp.full((nch * chunk - M,), fill, x.dtype)])
+    abin, bbin = pad(abin.astype(jnp.int32), 0), pad(
+        bbin.astype(jnp.int32), 0)
+    ws = [pad(w.astype(jnp.float32), 0.0) for w in weights]
+
+    def body(i, acc):
+        def cut(x):
+            return jax.lax.dynamic_slice(x, (i * chunk,), (chunk,))
+        A = jax.nn.one_hot(cut(abin), NA, dtype=jnp.bfloat16)
+        Boh = jax.nn.one_hot(cut(bbin), NB, dtype=jnp.bfloat16)
+        cols = []
+        for w in ws:
+            hi = _bf16_grid(cut(w))
+            for part in (hi, cut(w) - hi):
+                cols.append(Boh * part.astype(jnp.bfloat16)[:, None])
+        H = jax.lax.dot_general(A, jnp.concatenate(cols, axis=1),
+                                (((0,), (0,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+        return acc + H.astype(acc_dtype)
+    H = jax.lax.fori_loop(0, nch, body,
+                          jnp.zeros((NA, 2 * nw * NB), acc_dtype))
+    return [H[:, 2 * i * NB:(2 * i + 1) * NB]
+            + H[:, (2 * i + 1) * NB:(2 * i + 2) * NB] for i in range(nw)]
+
+
+#: the (NA, NB, streams) classes of project_to_basis's callers, the
+#: third stream the count: ``hermitian`` where it is 1.0 / 2.0 along
+#: the last axis, else all ones (a real field, a c2c spectrum)
+_CALLERS = {
+    'lab': (257, 12, 5, True),          # FFTPower 2d, Nmu 10, 512^3
+    'four_chips': (513, 12, 5, True),   # the same at 1024^3
+    'survey': (128, 3, 5, True),        # ConvolvedFFTPower, one mu bin
+    'poles': (66, 7, 9, True),          # Nmu 5, poles 0, 2, 4
+    '1d': (34, 3, 5, True),             # FFTPower 1d
+    'real': (34, 7, 5, False),          # FFTCorr: 3 + Nell real streams
+}
+
+
+@pytest.mark.parametrize('how', ['eager', 'jit', 'shard_map'])
+@pytest.mark.parametrize('layout', ['3d', 'flat'])
+@pytest.mark.parametrize('caller', list(_CALLERS))
+def test_hist2d_mxu_against_f64_bincount(caller, layout, how,
+                                         record_property):
+    """The MXU product, forced (the CPU picks bincount), over its
+    callers' classes of bins and streams: cells as a 3-d chunk with the
+    count stream constant along two axes, or flat; eagerly, under jit,
+    and a device's rows at a time inside a four-device ``shard_map``
+    with ``psum``.  Every case ends in a chunk that does not divide its
+    rows.  The count stream comes out as exact integers; every other
+    stream's error against an f64 ``numpy.bincount`` is recorded and no
+    worse than that of the kernel this one replaced (same two bf16
+    parts a stream: what differs is the order of the f32 sums)."""
+    from jax.sharding import PartitionSpec as P
+    from nbodykit_tpu.parallel.runtime import AXIS, cpu_mesh
+    NA, NB, nw, hermitian = _CALLERS[caller]
+    shape = (20, 7, 11)
+    # 3-d: 3 rows of 77 cells a chunk, 7 chunks for 20 rows (2 for a
+    # device's 5); flat: 8 chunks of 193 for 1540 cells (2 for 385)
+    chunk = 250 if layout == '3d' else 200
+    rng = np.random.RandomState(NA + nw)
+    a = rng.randint(0, NA, shape).astype('i4')
+    b = rng.randint(0, NB, shape).astype('i4')
+    count = (rng.randint(1, 3, (1, 1, shape[2])) if hermitian
+             else np.ones((1, 1, 1))).astype('f4')
+    # a spectrum's dynamic range: chi-squared under a power law in a
+    full = [(rng.standard_normal(shape) ** 2 * 1e4 / (1.0 + a) ** 2
+             * rng.choice([-1, 1], shape)).astype('f4')
+            for _ in range(nw - 1)]
+    flat_count = np.broadcast_to(count, shape)
+    streams = full[:2] + [flat_count] + full[2:]
+    refs = [np.bincount((a * NB + b).ravel(), np.asarray(w, 'f8').ravel(),
+                        NA * NB).reshape(NA, NB) for w in streams]
+
+    if layout == 'flat':
+        a_in, b_in, full_in = a.ravel(), b.ravel(), [w.ravel() for w in full]
+        count_in = jnp.asarray(flat_count.ravel(), jnp.bfloat16)
+        spec = P(AXIS)
+    else:
+        a_in, b_in, full_in = a, b, full
+        count_in = jnp.asarray(count, jnp.bfloat16)
+        spec = P(AXIS, None, None)
+
+    def hists(a, b, count, *full):
+        return tuple(hist2d_weighted(
+            a, b, list(full[:2]) + [count] + list(full[2:]), NA, NB,
+            method='mxu', chunk=chunk))
+
+    args = [jnp.asarray(x) for x in [a_in, b_in] + full_in]
+    if how == 'shard_map':
+        # a flat count is a device's own cells; the 3-d one is the same
+        # (1, 1, nz) on every device, closed over as w_b is
+        sharded_count = layout == 'flat'
+
+        def local(a, b, *rest):
+            c, full = (rest[0], rest[1:]) if sharded_count \
+                else (count_in, rest)
+            return tuple(jax.lax.psum(h, AXIS)
+                         for h in hists(a, b, c, *full))
+        args = args[:2] + ([count_in] if sharded_count else []) + args[2:]
+        got = jax.jit(jax.shard_map(
+            local, mesh=cpu_mesh(4), in_specs=(spec,) * len(args),
+            out_specs=(P(),) * nw))(*args)
+    else:
+        fn = jax.jit(hists) if how == 'jit' else hists
+        got = fn(args[0], args[1], count_in, *args[2:])
+    old = _hist2d_mxu_pr35(jnp.asarray(a.ravel()), jnp.asarray(b.ravel()),
+                           [jnp.asarray(w.ravel()) for w in streams],
+                           NA, NB, chunk=200)
+
+    assert len(got) == nw and got[0].shape == (NA, NB)
+    np.testing.assert_array_equal(np.asarray(got[2]), refs[2])
+    for i in (0, 1) + tuple(range(3, nw)):
+        scale = np.abs(refs[i]).max()
+        err = float(np.abs(np.asarray(got[i], 'f8') - refs[i]).max() / scale)
+        was = float(np.abs(np.asarray(old[i], 'f8') - refs[i]).max() / scale)
+        record_property('stream%d_max_err_vs_f64' % i, err)
+        record_property('stream%d_pr35_max_err_vs_f64' % i, was)
+        assert err < 1e-5           # two bf16 parts: 16 bits a weight
+        assert err <= 1.5 * was + 1e-8, (i, err, was)
+
+
 def test_hist2d_mxu_chunk_tail():
-    """M not divisible by chunk: the padded tail must not contribute."""
+    """M not divisible by chunk: the cells the last chunk shares with
+    the one before it must not contribute twice."""
     rng = np.random.RandomState(1)
     M, NA, NB = 10_001, 9, 5
     a = rng.randint(0, NA, M).astype('i4')
@@ -71,6 +205,19 @@ def test_hist2d_under_jit():
     got = np.asarray(f(a, b, w))
     want = np.array([[1.0, 0.0], [2.0, 4.0], [0.0, 3.0]])
     np.testing.assert_allclose(got, want, rtol=1e-6)
+
+
+@pytest.mark.parametrize('NA, NB, parts, want', [
+    (257, 12, 9, (386, 72)),            # desi_like_n512.lab
+    (513, 12, 9, (770, 72)),            # desi_like_n1024.lab_x4
+    (128, 3, 9, (48, 72)),              # boss_like_n512.convpower
+    (258, 7, 17, (226, 136)),           # Nmu 5, poles 0, 2, 4
+    (3, 2, 2, (1, 16))])
+def test_mxu_split(NA, NB, parts, want):
+    from nbodykit_tpu.ops.histogram import mxu_split
+    rows, cols = mxu_split(NA, NB, parts)
+    assert (rows, cols) == want
+    assert rows * cols >= NA * NB * parts
 
 
 def _ref_shell_sums(shell, value, weight, nbins):

@@ -198,11 +198,17 @@ def test_tile_paint_1e7_into_512(one_chip, resampler, box):
 #: temp_size_in_bytes of the lab cell's binning program at 512^3 with
 #: jnp.digitize's binary search in it (PR 25, the parent of PR 26)
 LAB_BINNING_TEMP_PR25 = 1_612_483_584
+#: the same of the other cases at PR 35, whose hist2d_mxu stored a
+#: bf16[131072, 2 * nw * NB] block of one-hot columns a chunk
+BINNING_TEMP_PR35 = {(1, 'lab'): LAB_BINNING_TEMP_PR25,
+                     (1, 'survey'): 1_611_354_624,
+                     (1, 'poles'): 1_612_386_816,
+                     (4, 'poles'): 2_686_515_712}
 
 
 @pytest.mark.parametrize('chips, cell', [
     pytest.param(1, 'poles', marks=pytest.mark.slow), (4, 'poles'),
-    (1, 'lab')])
+    (1, 'lab'), (1, 'survey')])
 def test_fftpower_binning_program(one_chip, four_chips, monkeypatch,
                                   chips, cell):
     # the (k, mu) binning program FFTPower(mode='2d', ...) jits: taken
@@ -211,9 +217,12 @@ def test_fftpower_binning_program(one_chip, four_chips, monkeypatch,
     # 4] at 512^3 on one chip, and at 1024^3 as the shard_map over four
     # (where the first four-chip run found a replicated loop carry).
     # 'lab': the edges of the benchmark's desi_like_n512.lab cell
-    # (BoxSize 5000, kmin 0.001, Nmu 10, no poles)
+    # (BoxSize 5000, kmin 0.001, Nmu 10, no poles).  'survey': those of
+    # boss_like_n512.convpower's three binnings (box 2550, dk 0.005,
+    # one mu bin: 128 x 3 bins)
     from nbodykit_tpu.algorithms import fftpower
     from nbodykit_tpu.base.mesh import Field
+    from nbodykit_tpu.ops.histogram import mxu_split
     from nbodykit_tpu.parallel.runtime import AXIS
 
     class Taken(Exception):
@@ -223,8 +232,10 @@ def test_fftpower_binning_program(one_chip, four_chips, monkeypatch,
         raise Taken(fn, label)
 
     monkeypatch.setattr(fftpower, 'instrumented_jit', take)
-    box, kmin, nmu, poles = ((5000.0, 0.001, 10, []) if cell == 'lab'
-                             else (1000.0, 0.0, 5, [0, 2, 4]))
+    box, kmin, nmu, poles, dk = {
+        'lab': (5000.0, 0.001, 10, [], None),
+        'survey': (2550.0, 0.0, 1, [], 0.005),
+        'poles': (1000.0, 0.0, 5, [0, 2, 4], None)}[cell]
     if chips == 1:
         nmesh, pm, sharding = NMESH, _pm(NMESH, box=box), one_chip
     else:
@@ -232,8 +243,8 @@ def test_fftpower_binning_program(one_chip, four_chips, monkeypatch,
         sharding = NamedSharding(four_chips, P(AXIS, None, None))
     value = jax.ShapeDtypeStruct((nmesh, nmesh, nmesh // 2 + 1),
                                  jnp.complex64, sharding=sharding)
-    # FFTPower.run's own edges for that kmin and Nmu with dk=None
-    dk = 2 * np.pi / box
+    # FFTPower.run's own edges for that kmin, dk and Nmu
+    dk = dk or 2 * np.pi / box
     edges = [np.arange(kmin, np.pi * nmesh / box + dk / 2, dk),
              np.linspace(-1, 1, nmu + 1)]
     with pytest.raises(Taken) as got:
@@ -242,14 +253,34 @@ def test_fftpower_binning_program(one_chip, four_chips, monkeypatch,
     fn, label = got.value.args
     assert label == 'fftpower.binning'
     compiled = _compile(fn, value)
+    text = compiled.as_text()
     assert _total_bytes(compiled) < 0.25 * V5E_HBM
     # the bin index is a compare-and-count (ops.histogram.
     # edge_count_index): a binary search would show as a gather in a
     # while, 5.0 s of a 6.6 s lab call on the chip (PERF.md, PR 25)
-    assert ' gather(' not in compiled.as_text()
-    if cell == 'lab':
-        temp = compiled.memory_analysis().temp_size_in_bytes
-        assert temp <= LAB_BINNING_TEMP_PR25, temp
+    assert ' gather(' not in text
+    # the sums are one matrix product a chunk of rows, taken as the
+    # rows lie, its shape mxu_split's: no block of one-hot columns is
+    # concatenated and stored first (0.053 s of the lab call's 0.122 s
+    # of binning, twice the product; PERF.md, PR 36), no stream is
+    # flattened into chunks of 131072
+    NA, NB = len(edges[0]) + 1, nmu + 2
+    nstreams = 3 + 2 * max(len(poles), 1)
+    rows, cols = mxu_split(NA, NB, 2 * nstreams - 1)
+    convs = [re.search(r'f32\[([\d,]+)\]\S* convolution\(', line)
+             for line in text.splitlines()
+             if 'nbk.fftpower.binning.hist' in line]
+    # the compiler may keep the columns as [parts, 8]
+    shapes = [[int(d) for d in m.group(1).split(',') if d != '1']
+              for m in convs if m]
+    assert [s for s in shapes if s[-1] == rows
+            and np.prod(s[:-1]) == cols], (shapes, rows, cols)
+    assert '[131072' not in text
+    assert not [line for line in text.splitlines()
+                if ' concatenate(' in line
+                and re.search(r'\[\d{5,}', line.split(' concatenate(')[0])]
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp <= BINNING_TEMP_PR35[chips, cell], temp
 
 
 def test_pallas_deposit_kernel_at_512(one_chip):
