@@ -12,7 +12,7 @@ import jax.numpy as jnp
 from ...source.mesh.species import MultipleSpeciesCatalogMesh
 from ...source.mesh.catalog import CatalogMesh
 from ...base.mesh import Field
-from ...diagnostics import instrumented_jit, scope
+from ...diagnostics import fetch, instrumented_jit, scope
 
 
 @instrumented_jit(label='convpower.combine')
@@ -56,9 +56,8 @@ def column_total(x):
     on the device (elementwise adds only, so no more than a rounding
     of f4 whatever the backend's reduction does), the lanes added in
     f8 on the host."""
-    total, lost = _lane_sums(jnp.asarray(x))
-    return float(np.asarray(total, 'f8').sum()
-                 - np.asarray(lost, 'f8').sum())
+    total, lost = fetch(_lane_sums(jnp.asarray(x)), 'convpower.total')
+    return float(total.astype('f8').sum() - lost.astype('f8').sum())
 
 
 class FKPCatalogMesh(MultipleSpeciesCatalogMesh):

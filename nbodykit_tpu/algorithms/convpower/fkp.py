@@ -183,31 +183,32 @@ class ConvolvedFFTPower(object):
 
     def __init__(self, first, poles, second=None, Nmesh=None, kmin=0.,
                  kmax=None, dk=None):
-        if isinstance(first, FKPCatalog):
-            first = first.to_mesh(Nmesh=Nmesh)
-        if not isinstance(first, FKPCatalogMesh):
-            raise TypeError("first must be an FKPCatalog or "
-                            "FKPCatalogMesh")
-        if second is None:
-            second = first
-        self.first = first
-        self.second = second
-        self.comm = first.comm
+        # the call's root, from its first line (``to_mesh`` included)
+        with scope('convpower.run') as root:
+            if isinstance(first, FKPCatalog):
+                first = first.to_mesh(Nmesh=Nmesh)
+            if not isinstance(first, FKPCatalogMesh):
+                raise TypeError("first must be an FKPCatalog or "
+                                "FKPCatalogMesh")
+            if second is None:
+                second = first
+            self.first = first
+            self.second = second
+            self.comm = first.comm
 
-        if np.isscalar(poles):
-            poles = [poles]
-        self.attrs = {
-            'poles': sorted(poles),
-            'dk': dk,
-            'kmin': kmin,
-            'kmax': kmax,
-        }
-        self.attrs['Nmesh'] = first.attrs['Nmesh'].copy()
-        self.attrs['BoxSize'] = first.attrs['BoxSize']
-        self.attrs['BoxCenter'] = first.attrs['BoxCenter']
-
-        with scope('convpower.run', poles=self.attrs['poles'],
-                   nmesh=int(self.attrs['Nmesh'][0])):
+            if np.isscalar(poles):
+                poles = [poles]
+            self.attrs = {
+                'poles': sorted(poles),
+                'dk': dk,
+                'kmin': kmin,
+                'kmax': kmax,
+            }
+            self.attrs['Nmesh'] = first.attrs['Nmesh'].copy()
+            self.attrs['BoxSize'] = first.attrs['BoxSize']
+            self.attrs['BoxCenter'] = first.attrs['BoxCenter']
+            root.set(poles=self.attrs['poles'],
+                     nmesh=int(self.attrs['Nmesh'][0]))
             self.run()
 
     def run(self):

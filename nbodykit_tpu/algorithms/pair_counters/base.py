@@ -66,12 +66,20 @@ def weight_totals(w1, n1, w2, n2, is_auto):
     (their sizes where a catalog has no weights) and the total weighted
     pair count the estimators normalize by, self-pairs taken out of an
     autocorrelation."""
-    W1 = float(n1) if w1 is None else float(np.sum(np.asarray(w1, 'f8')))
-    W2 = float(n2) if w2 is None else float(np.sum(np.asarray(w2, 'f8')))
+    from ...diagnostics import fetch
+
+    def on_host(w):
+        return None if w is None else np.asarray(
+            fetch(w, 'paircount.weights'), 'f8')
+
+    same = w2 is w1
+    w1 = on_host(w1)
+    w2 = w1 if same else on_host(w2)
+    W1 = float(n1) if w1 is None else float(np.sum(w1))
+    W2 = float(n2) if w2 is None else float(np.sum(w2))
     if not is_auto:
         return W1, W2, W1 * W2
-    sumw2 = float(n1) if w1 is None \
-        else float(np.sum(np.asarray(w1, 'f8') ** 2))
+    sumw2 = float(n1) if w1 is None else float(np.sum(w1 ** 2))
     return W1, W2, W1 * W1 - sumw2
 
 

@@ -66,7 +66,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from ...diagnostics import counter, instrumented_jit, scope
+from ...diagnostics import counter, fetch, instrumented_jit, scope
 from ...ops.gridhash import neighbor_offsets
 from ...parallel.runtime import vary_like
 from ...utils import working_dtype
@@ -548,7 +548,9 @@ def _tile_program(mesh, same, weighted, masked, ncell, box, periodic,
 def _run(mesh, pos1, w1, live1, pos2, w2, live2, box, edges, mode, Nmu,
          pimax, los, periodic, is_auto, grid_origin, pair_los, same):
     """Both drivers' common half: the program for these sizes, its one
-    launch under the ``paircount`` scopes, the counts as host arrays."""
+    launch under the ``paircount`` scopes, the counts as host arrays.
+    (``paircount.run`` is the user's call, ``SimulationBoxPairCount``;
+    a direct call of a driver is its own root, ``paircount.count``.)"""
     work_box, redges, rmax, nb2, periodic = _mode_setup(
         box, edges, mode, Nmu, pimax, periodic)
     n1, n2 = int(pos1.shape[0]), int(pos2.shape[0])
@@ -560,7 +562,7 @@ def _run(mesh, pos1, w1, live1, pos2, w2, live2, box, edges, mode, Nmu,
     if n1 == 0 or n2 == 0:
         zero = np.zeros((nb1, nb2)).squeeze()
         return dict(npairs=zero.astype('i8'), wnpairs=zero)
-    with scope('paircount.run', mode=mode, n1=n1, n2=n2, nbins=nb1,
+    with scope('paircount.count', mode=mode, n1=n1, n2=n2, nbins=nb1,
                rmax=rmax, is_auto=bool(is_auto)):
         program = _tile_program(
             mesh, bool(same), weighted, live1 is not None, ncell,
@@ -573,7 +575,7 @@ def _run(mesh, pos1, w1, live1, pos2, w2, live2, box, edges, mode, Nmu,
                                  if x is not None]
         with scope('paircount.tiles', tile=LANES,
                    cells=int(np.prod(ncell))) as sc:
-            out = jax.device_get(program(*(side1 + side2)))
+            out = fetch(program(*(side1 + side2)), 'paircount.counts')
             cum = _from_hilo(out['hi'], out['lo'])
             slots = int(round(float(out['slots'])))
             pairs = int(cum[-1].sum())

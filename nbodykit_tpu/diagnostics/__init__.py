@@ -14,7 +14,7 @@ with it.  Three pieces:
   puts one library layer on that trace, on ``jax.profiler``'s host
   line and in the HLO op names at once (``nbk.<layer>``).
 - :mod:`.metrics` — process-wide counters/gauges/histograms (exchange
-  bytes, FFT chunk walls, paint Mpart/s per kernel, device live-buffer
+  bytes, FFT chunks, device live-buffer
   watermarks) plus compile telemetry (``instrumented_jit``, the
   ``jax.monitoring`` hook).
 - :mod:`.report` — end-of-run summary (per-phase wall, top spans,
@@ -37,8 +37,9 @@ import functools
 import os
 import sys
 
-from .trace import (NULL_SPAN, SCOPE_PREFIX, RequestContext,  # noqa: F401
-                    Tracer, _Scope, atomic_write, current_tracer,
+from .trace import (NULL_SPAN, SCOPE_PREFIX, SYNC_PREFIX,  # noqa: F401
+                    RequestContext, Tracer, _Scope, atomic_write,
+                    current_tracer,
                     exemplar_fraction, export_chrome_trace,
                     new_request_context, read_trace, trace_context,
                     trace_files, trace_scope, trace_state_clean)
@@ -54,8 +55,8 @@ from .analyze import render_analysis, request_report  # noqa: F401
 from .regress import build_history, render_regress  # noqa: F401
 from .slo import (DEFAULT_SLOS, SLObjective, SLOPolicy,  # noqa: F401
                   SLOTracker)
-from .export import (FLIGHT, FlightRecorder, TelemetryExporter,  # noqa: F401
-                     ensure_exporter, flight_recorder,
+from .export import (FLIGHT, HOST_CALLS, FlightRecorder,  # noqa: F401
+                     TelemetryExporter, ensure_exporter, flight_recorder,
                      prometheus_text, register_source)
 
 
@@ -149,17 +150,44 @@ def scope(name, **attrs):
       the host line can name them; under a real jit it marks the
       tracing and launches nothing.
 
+    - the host ledger: whenever jax is not staging, whatever the
+      option says, the scope's self time (its wall less its
+      children's) on the calling thread; the outermost scope is the
+      call's root, and when it closes its parts go to the registry
+      (``host.<name>.self_s``, ``.n``) and one record to the ring
+      ``HOST_CALLS`` (``trace._Scope``).  Two clock readings and a
+      dict update; no sync, no file.
+
     Never syncs by itself; ``sc.done(result)`` waits for ``result``
     only while the JSONL span is recording.  Eager ops do not carry a
     named scope reliably (the dispatch cache reuses whichever name
     compiled first), hence the host annotation there."""
     jax = sys.modules.get('jax')
     if jax is None:             # diagnostics never requires jax
-        return _Scope(NULL_SPAN, span(name, **attrs))
+        return _Scope(name, NULL_SPAN, span(name, **attrs))
     mark = jax.profiler.TraceAnnotation(SCOPE_PREFIX + name)
     if trace_state_clean():
-        return _Scope(mark, span(name, **attrs))
-    return _Scope(mark, NULL_SPAN, jax.named_scope(SCOPE_PREFIX + name))
+        return _Scope(name, mark, span(name, **attrs))
+    return _Scope(name, mark, NULL_SPAN,
+                  jax.named_scope(SCOPE_PREFIX + name))
+
+
+def fetch(x, what):
+    """``x`` (an array, or a tree of them) as host arrays, under
+    ``scope('sync.' + what)``: the one marked way from the device to
+    the host.  The conversion waits for whatever produces ``x``, so a
+    ``sync.*`` scope's self time is time the host *waited*, where
+    every other scope's is time it worked; the ledger counts them
+    (``host.syncs``, a call record's ``syncs`` / ``sync_wait_s``).
+    Launch the op that produces ``x`` before the call, outside the
+    scope (``fetch(total(x), 'catalog.total')`` does: arguments are
+    evaluated first), so that its device time keeps its layer."""
+    with scope(SYNC_PREFIX + what):
+        jax = sys.modules.get('jax')
+        if jax is None:
+            import numpy
+            return numpy.asarray(x)
+        return jax.device_get(x)
 
 
 def traced(name=None):

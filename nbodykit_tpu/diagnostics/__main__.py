@@ -186,8 +186,10 @@ def _lint_findings(root):
 def _compile_miss_labels(trace):
     """jit labels with observed cache misses: live registry counters
     (``compile.<label>.misses``) merged with ``compile.<label>`` spans
-    found in the analyzed trace directory."""
+    found in the analyzed trace directory (the three stage spans of
+    the ``jax.monitoring`` hook are no label's)."""
     from . import REGISTRY
+    from .metrics import STAGE_SPANS
     labels = {}
     for name, snap in REGISTRY.snapshot().items():
         if name.startswith('compile.') and name.endswith('.misses') \
@@ -205,7 +207,7 @@ def _compile_miss_labels(trace):
                 name = r.get('name', '')
                 if r.get('t') == 'span' and \
                         name.startswith('compile.') and \
-                        name != 'compile.backend':
+                        name not in STAGE_SPANS:
                     lbl = name[len('compile.'):]
                     labels[lbl] = labels.get(lbl, 0) + 1
     return labels

@@ -12,7 +12,8 @@ module is the *live* window: a zero-dependency background HTTP thread
 - ``/metrics.json``  the raw registry snapshot,
 - ``/slo``           every registered source (SLO trackers, server
   summaries) as one JSON document,
-- ``/flight``        the flight-recorder ring,
+- ``/flight``        the flight-recorder ring, and the ring of host
+  calls (``host_calls``: one record per outermost ``scope`` closed),
 - ``/healthz``       liveness.
 
 Enable with ``set_options(telemetry_port=9464)`` (or
@@ -205,6 +206,7 @@ class FlightRecorder(object):
             body = {'v': 1, 'reason': str(reason), 'pid': os.getpid(),
                     'ts': round(time.time(), 6),
                     'requests': self.snapshot(),
+                    'host_calls': HOST_CALLS.snapshot(),
                     'metrics': REGISTRY.snapshot(),
                     'sources': _sources_snapshot()}
             atomic_write(path, json.dumps(body, indent=1, default=str))
@@ -217,6 +219,14 @@ class FlightRecorder(object):
 
 #: The process-wide flight recorder the serve/region stacks feed.
 FLIGHT = FlightRecorder()
+
+#: The host ledger's ring (trace.py:_Scope): one record per call, the
+#: outermost ``scope`` of its thread: ``root``, ``t0_ns``
+#: (``time.time_ns()`` at its start), ``wall_s``, ``self_s`` by scope
+#: (they sum to ``wall_s``), ``syncs``, ``sync_wait_s`` (the self time
+#: of the ``sync.*`` scopes: what the host waited), ``retrace_s``.
+#: Kept with no instrument on; room for a benchmark window's calls.
+HOST_CALLS = FlightRecorder(maxlen=1024)
 
 
 def flight_recorder():
@@ -268,6 +278,7 @@ class TelemetryExporter(object):
                     elif path == '/flight':
                         self._send(json.dumps(
                             {'requests': exporter.flight.snapshot(),
+                             'host_calls': HOST_CALLS.snapshot(),
                              'dumps': exporter.flight.dumps},
                             default=str), 'application/json')
                     elif path == '/healthz':

@@ -20,9 +20,11 @@ Compile telemetry ("why was rep 1 slow") lives here too:
 
 - :func:`install_compile_telemetry` hooks ``jax.monitoring`` so every
   XLA compile lands as ``xla.compile.*`` histograms plus persistent
-  compilation-cache hit/miss counters (``xla.cache.*``), and — when a
-  tracer is active — a retroactive ``compile.backend`` span in the
-  trace file.
+  compilation-cache hit/miss counters (``xla.cache.*``); each stage's
+  seconds (trace, lower, backend) are charged to the innermost scope
+  the compiling thread has open (``host.<scope>.retrace_s``) and —
+  when a tracer is active — land as retroactive ``compile.trace`` /
+  ``.lower`` / ``.backend`` spans in the trace file.
 - :func:`instrumented_jit` is a drop-in ``jax.jit`` that attributes
   compiles to a *named* entry point: per-label hit/miss counters, a
   first-call-wall histogram, and a ``compile.<label>`` span on every
@@ -146,6 +148,13 @@ class MetricsRegistry(object):
     def histogram(self, name):
         return self._get(Histogram, name)
 
+    def add(self, items):
+        """Add to several counters (``(name, n)`` pairs) under one
+        hold of the lock: the host ledger's flush when a call ends."""
+        with self._lock:
+            for name, n in items:
+                self._get(Counter, name).value += n
+
     def snapshot(self):
         """A plain-dict copy of every metric, sorted by name."""
         with self._lock:
@@ -226,14 +235,19 @@ _XLA_EVENT_COUNTERS = {
     '/jax/compilation_cache/compile_requests_use_cache':
         'xla.cache.requests',
 }
-# jax.monitoring duration event -> registry histogram
+# jax.monitoring duration event -> (registry histogram, span name):
+# the three stages of a jit cache miss
 _XLA_DURATION_EVENTS = {
-    '/jax/core/compile/jaxpr_trace_duration': 'xla.compile.trace_s',
+    '/jax/core/compile/jaxpr_trace_duration':
+        ('xla.compile.trace_s', 'compile.trace'),
     '/jax/core/compile/jaxpr_to_mlir_module_duration':
-        'xla.compile.lower_s',
+        ('xla.compile.lower_s', 'compile.lower'),
     '/jax/core/compile/backend_compile_duration':
-        'xla.compile.backend_s',
+        ('xla.compile.backend_s', 'compile.backend'),
 }
+#: the stage spans' names: ``compile.<anything else>`` is a labelled
+#: jit's first-call wall (:func:`instrumented_jit`)
+STAGE_SPANS = tuple(span for _, span in _XLA_DURATION_EVENTS.values())
 _monitoring_lock = threading.Lock()
 _monitoring_installed = False
 
@@ -243,9 +257,13 @@ def install_compile_telemetry():
 
     Idempotent and cheap; called at import by the jit hot paths (they
     all import jax anyway) so XLA recompiles are never invisible.  Each
-    backend compile also lands as a retroactive ``compile.backend``
-    span when a tracer is active — the out-of-band path, since jax
-    reports the duration only after the fact.  Returns True when the
+    stage of a cache miss (``jaxpr_trace``, ``jaxpr_to_mlir_module``,
+    ``backend_compile``) is charged to the scope it happened under
+    (``trace.note_retrace``) and lands as a retroactive
+    ``compile.trace`` / ``.lower`` / ``.backend`` span, ``attrs.scope``
+    naming that scope, when a tracer is active — the out-of-band
+    path, since jax reports the duration only after the fact.
+    Returns True when the
     hook is (already) installed, False when jax.monitoring is missing.
     """
     global _monitoring_installed
@@ -263,16 +281,16 @@ def install_compile_telemetry():
                 REGISTRY.counter(name).add(1)
 
         def _on_duration(event, duration, **kw):
-            name = _XLA_DURATION_EVENTS.get(event)
-            if name is None:
+            names = _XLA_DURATION_EVENTS.get(event)
+            if names is None:
                 return
-            REGISTRY.histogram(name).observe(duration)
-            if event.endswith('backend_compile_duration'):
-                from .trace import current_tracer
-                tr = current_tracer()
-                if tr is not None:
-                    tr.emit_span('compile.backend',
-                                 time.time() - duration, duration)
+            REGISTRY.histogram(names[0]).observe(duration)
+            from .trace import current_tracer, note_retrace
+            under = note_retrace(duration)
+            tr = current_tracer()
+            if tr is not None:
+                tr.emit_span(names[1], time.time() - duration, duration,
+                             {'scope': under})
 
         monitoring.register_event_listener(_on_event)
         monitoring.register_event_duration_secs_listener(_on_duration)

@@ -47,6 +47,8 @@ import sys
 import threading
 import time
 
+from .metrics import REGISTRY
+
 _lock = threading.Lock()
 _tracer = None
 
@@ -221,19 +223,93 @@ def trace_state_clean():
 SCOPE_PREFIX = 'nbk.'
 
 
+class _Ledger(threading.local):
+    """One thread's half of the host ledger: the scopes it has open,
+    innermost last, and what its call (the outermost of them) has
+    gathered so far."""
+
+    def __init__(self):
+        self.stack = []
+        self.call = None
+
+
+_LEDGER = _Ledger()
+_ring = None
+
+#: prefix of the scopes whose self time the host spent waiting for the
+#: device (:func:`nbodykit_tpu.diagnostics.fetch`); every other
+#: scope's self time is time the host worked
+SYNC_PREFIX = 'sync.'
+
+
+def open_scope():
+    """Name of the innermost scope the calling thread has open on the
+    host ledger, or ``None``."""
+    st = _LEDGER.stack
+    return st[-1]._name if st else None
+
+
+def note_retrace(seconds):
+    """Charge ``seconds`` of tracing, lowering or compiling to the
+    innermost open scope of the calling thread (``metrics.py``'s
+    ``jax.monitoring`` hook, which jax calls on the thread that
+    compiles).  The seconds stay part of that scope's self time: this
+    is an attribution, not a part of the partition.  Returns the
+    scope's name, or ``None`` where none is open."""
+    name = open_scope()
+    if name is not None:
+        REGISTRY.counter('host.%s.retrace_s' % name).add(seconds)
+        _LEDGER.call['retrace_s'] += seconds
+    return name
+
+
+def _call_done(call, wall_ns):
+    """The outermost scope of a thread closed: its call's parts go to
+    the registry and one record into the ring of calls."""
+    global _ring
+    if _ring is None:           # export.py imports this module
+        from .export import HOST_CALLS as _ring
+    self_s, adds, syncs, waited = {}, [], 0, 0.0
+    for name, (ns, n) in call['self'].items():
+        self_s[name] = sec = ns / 1e9
+        adds.append(('host.%s.self_s' % name, sec))
+        adds.append(('host.%s.n' % name, n))
+        if name.startswith(SYNC_PREFIX):
+            syncs += n
+            waited += sec
+    if syncs:
+        adds.append(('host.syncs', syncs))
+    REGISTRY.add(adds)
+    _ring.record({
+        'root': call['root'], 't0_ns': call['t0_ns'],
+        'wall_s': wall_ns / 1e9, 'self_s': self_s, 'syncs': syncs,
+        'sync_wait_s': waited, 'retrace_s': call['retrace_s']})
+
+
 class _Scope(object):
     """One library layer on all three clocks (see
     :func:`nbodykit_tpu.diagnostics.scope`): ``mark`` is the
     profiler's ``TraceAnnotation``, ``staged`` the ``jax.named_scope``
     entered inside it while jax is staging (else ``None``), ``span``
-    the JSONL :class:`_Span` or :data:`NULL_SPAN`."""
+    the JSONL :class:`_Span` or :data:`NULL_SPAN`.
 
-    __slots__ = ('_mark', '_span', '_staged')
+    While jax is not staging it also keeps the **host ledger**,
+    whatever the ``diagnostics`` option says: two ``perf_counter_ns``
+    readings and a per-thread stack, no sync and no file.  On exit
+    the scope's *self* time (its wall less its children's) is added
+    to its call's parts; when the outermost scope of the thread
+    closes, the parts (which sum to its wall by construction) go to
+    the registry (``host.<scope>.self_s`` / ``.n``) and one record to
+    ``export.HOST_CALLS``."""
 
-    def __init__(self, mark, span, staged=None):
+    __slots__ = ('_name', '_mark', '_span', '_staged', '_t0', '_kids')
+
+    def __init__(self, name, mark, span, staged=None):
+        self._name = name
         self._mark = mark
         self._span = span
         self._staged = staged
+        self._t0 = None         # on the ledger while not None
 
     @property
     def span_id(self):
@@ -258,8 +334,50 @@ class _Scope(object):
         self._mark.__enter__()
         if self._staged is not None:
             self._staged.__enter__()
+        else:
+            led = _LEDGER
+            if not led.stack:
+                # the call's root: its start on the wall clock, which
+                # is the profiler's (PERF.md section 3), read as near
+                # the annotation's own start as Python allows
+                led.call = {'root': self._name, 't0_ns': time.time_ns(),
+                            'self': {}, 'retrace_s': 0.0}
+            led.stack.append(self)
+            self._kids = 0
+            self._t0 = time.perf_counter_ns()
         self._span.__enter__()
         return self
+
+    def _close(self):
+        """Off the ledger.  A scope that exits while others opened
+        after it are still on the stack (a generator collected late)
+        closes them first, as of now, so that the parts still sum to
+        the root's wall; their own late exits then find ``_t0`` unset
+        and do nothing."""
+        now = time.perf_counter_ns()
+        led = _LEDGER
+        st = led.stack
+        if self not in st:      # entered on another thread
+            self._t0 = None
+            return
+        while True:
+            top = st.pop()
+            wall = now - top._t0
+            top._t0 = None
+            parts = led.call['self']
+            mine = parts.get(top._name)
+            if mine is None:
+                parts[top._name] = [wall - top._kids, 1]
+            else:
+                mine[0] += wall - top._kids
+                mine[1] += 1
+            if st:
+                st[-1]._kids += wall
+            else:
+                call, led.call = led.call, None
+                _call_done(call, wall)
+            if top is self:
+                return
 
     def __exit__(self, etype, evalue, tb):
         try:
@@ -268,6 +386,8 @@ class _Scope(object):
             try:
                 if self._staged is not None:
                     self._staged.__exit__(etype, evalue, tb)
+                elif self._t0 is not None:
+                    self._close()
             finally:
                 self._mark.__exit__(etype, evalue, tb)
         return False
@@ -295,7 +415,7 @@ class _Span(object):
     :meth:`set` land in the trace record's ``attrs``."""
 
     __slots__ = ('_tr', 'name', 'attrs', '_id', '_par', '_depth',
-                 '_ts', '_tm', '_ctx')
+                 '_t0_ns', '_ts', '_tm', '_ctx')
 
     def __init__(self, tr, name, attrs):
         self._tr = tr
@@ -335,7 +455,11 @@ class _Span(object):
         self._depth = len(st)
         self._ctx = _CTX.get()
         st.append(self)
-        self._ts = time.time()
+        # one reading of the wall clock, kept whole (``t0_ns``: the
+        # profiler's host line and the ring of calls keep the same
+        # clock) and as the rounded seconds older readers know
+        self._t0_ns = time.time_ns()
+        self._ts = self._t0_ns / 1e9
         self._tm = time.perf_counter()
         # begin event: flushed (not fsynced — an OS-level flush already
         # survives a SIGKILL of this process) so a post-mortem shows
@@ -360,6 +484,7 @@ class _Span(object):
                 pass
         rec = {'t': 'span', 'id': self._id, 'par': self._par,
                'name': self.name, 'ts': round(self._ts, 6),
+               't0_ns': self._t0_ns,
                'dur': round(dur, 6), 'depth': self._depth,
                'pid': tr.pid, 'ok': etype is None}
         self._stamp(rec)
@@ -507,6 +632,7 @@ class Tracer(object):
         record into its request's trace."""
         rec = {'t': 'span', 'id': self._new_id(), 'par': 0,
                'name': name, 'ts': round(float(ts), 6),
+               't0_ns': int(float(ts) * 1e9),
                'dur': round(float(dur), 6), 'depth': 0,
                'pid': self.pid, 'ok': bool(ok)}
         if ctx is None:

@@ -57,7 +57,6 @@ are interchangeable per call. Selection is an option
 :class:`dist_fft_plan`.
 """
 
-import time as _time
 from functools import lru_cache as _lru_cache
 
 import jax
@@ -66,7 +65,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from .runtime import AXIS, AXIS_X, AXIS_Y, default_pencil_factor, \
     is_eager, is_pencil, mesh_size, pencil_mesh
-from ..diagnostics import counter, current_tracer, histogram, \
+from ..diagnostics import counter, current_tracer, \
     install_compile_telemetry, instrumented_jit, scope, span, span_if
 
 # every XLA compile triggered by the FFT paths lands in the metric
@@ -337,18 +336,15 @@ def _parseval_verify(site, shape, sx, y, norm):
 
 def _lowmem_step(emit, upd, slab, buf, arr, k, r, stage):
     """One eager chunk of a lowmem pass, optionally wrapped in an
-    ``fft.chunk`` span + wall histogram.  The per-chunk wall is
-    *dispatch* time (the stage programs are async); stalls show up on
-    the chunks that fill the dispatch queue, and the enclosing
-    ``fft.lowmem.*`` span has the true total."""
+    ``fft.chunk`` span.  The per-chunk wall is *dispatch* time (the
+    stage programs are async); stalls show up on the chunks that fill
+    the dispatch queue, and the enclosing ``fft.lowmem.*`` span has
+    the true total."""
     idx = jnp.int32(k * r)
     if not emit:
         return upd(buf, slab(arr, idx), idx)
-    t0 = _time.perf_counter()
     with span('fft.chunk', stage=stage, index=k, rows=r):
-        buf = upd(buf, slab(arr, idx), idx)
-    histogram('fft.chunk_wall_s').observe(_time.perf_counter() - t0)
-    return buf
+        return upd(buf, slab(arr, idx), idx)
 
 
 def _chunk_rows(n, bytes_per_row, target):

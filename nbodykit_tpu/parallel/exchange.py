@@ -54,7 +54,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from .runtime import AXIS, is_eager, mesh_size
-from ..diagnostics import counter, gauge, instrumented_jit, scope
+from ..diagnostics import counter, fetch, gauge, instrumented_jit, scope
 
 
 def counted_capacity(pm_or_nproc, pos_or_dest, slack=1.05, n0=None):
@@ -120,7 +120,8 @@ def pair_count_max(dest, nproc):
     layout of a freshly created global array, matching the padding in
     :func:`exchange_by_dest`). Eager only: one small program and the
     read of its result."""
-    return int(_pair_count_max(jnp.asarray(dest, jnp.int32), nproc))
+    return int(fetch(_pair_count_max(jnp.asarray(dest, jnp.int32), nproc),
+                     'exchange.count'))
 
 
 @partial(instrumented_jit, label='exchange.count', static_argnums=(1,))

@@ -24,7 +24,6 @@ wrappers in :mod:`nbodykit_tpu.base.mesh` add attrs/convenience methods.
 
 import functools
 import logging
-import time
 from functools import lru_cache as _lru_cache
 
 import numpy as np
@@ -33,7 +32,7 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from . import _global_options
-from .diagnostics import counter, current_tracer, histogram, \
+from .diagnostics import counter, current_tracer, fetch, \
     install_compile_telemetry, instrumented_jit, scope, span, \
     trace_state_clean
 from .parallel.runtime import AXIS, CurrentMesh, is_eager, mesh_size, \
@@ -425,11 +424,11 @@ class ParticleMesh(object):
         or on the HLO op names under a trace); eager calls with the
         ``diagnostics`` option set also emit a ``paint`` span (attribute
         ``engine``: 'tile' or 'scatter' under the default method,
-        :func:`paint_engine`) and record the per-method throughput
-        histogram ``paint.<method>.mpart_per_s``.
-        The result is synced (``block_until_ready``) inside the span so
-        the throughput is real work, not dispatch — enabled-mode only;
-        the disabled path is byte-identical to the undiagnosed one.
+        :func:`paint_engine`, ``npart`` beside it: the span's wall over
+        ``npart`` is the throughput).
+        The result is synced (``sc.done``) inside the span so its wall
+        is real work, not dispatch — enabled-mode only; the disabled
+        path is byte-identical to the undiagnosed one.
         """
         if current_tracer() is None or not trace_state_clean():
             # no JSONL span here, but the layer still gets its name:
@@ -437,23 +436,16 @@ class ParticleMesh(object):
             with scope('paint'):
                 return self._paint_impl(pos, mass, resampler, out,
                                         shift, capacity, return_dropped)
-        npart = int(pos.shape[0])
         method = _global_options['paint_method']
-        t0 = time.perf_counter()
         window = resampler or _global_options['resampler']
-        with scope('paint', method=method, npart=npart,
+        with scope('paint', method=method, npart=int(pos.shape[0]),
                    engine=paint_engine(method,
                                        self._local_block(window), window),
                    nproc=self.nproc, resampler=window,
-                   nmesh=int(self.Nmesh[0])):
-            res = self._paint_impl(pos, mass, resampler, out, shift,
-                                   capacity, return_dropped)
-            jax.block_until_ready(res)
-        dt = max(time.perf_counter() - t0, 1e-9)
-        histogram('paint.%s.wall_s' % method).observe(dt)
-        histogram('paint.%s.mpart_per_s' % method).observe(
-            npart / dt / 1e6)
-        return res
+                   nmesh=int(self.Nmesh[0])) as sc:
+            return sc.done(self._paint_impl(
+                pos, mass, resampler, out, shift, capacity,
+                return_dropped))
 
     def _local_block(self, resampler):
         """Shape of the block the local paint kernel fills: the mesh
@@ -609,7 +601,7 @@ class ParticleMesh(object):
         """An exchange's overflow count as an int, read eagerly, and
         fed to the ``exchange.dropped`` counter: it counts what each
         attempt lost, before a retry heals it."""
-        lost = int(dropped)
+        lost = int(fetch(dropped, 'exchange.dropped'))
         counter('exchange.dropped').add(lost)
         return lost
 
@@ -658,15 +650,11 @@ class ParticleMesh(object):
         if current_tracer() is None or not trace_state_clean():
             return self._readout_impl(real, pos, resampler, capacity,
                                       return_dropped, grad_axis)
-        npart = int(pos.shape[0])
-        t0 = time.perf_counter()
-        with span('readout', npart=npart, nproc=self.nproc,
+        with span('readout', npart=int(pos.shape[0]), nproc=self.nproc,
                   nmesh=int(self.Nmesh[0])):
             res = self._readout_impl(real, pos, resampler, capacity,
                                      return_dropped, grad_axis)
             jax.block_until_ready(res)
-        dt = max(time.perf_counter() - t0, 1e-9)
-        histogram('readout.mpart_per_s').observe(npart / dt / 1e6)
         return res
 
     def _readout_impl(self, real, pos, resampler, capacity,

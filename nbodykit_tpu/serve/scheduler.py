@@ -29,7 +29,7 @@ sub-mesh the same builder produces the shard_map form.
 
 import threading
 
-from ..diagnostics import counter, instrumented_jit, scope
+from ..diagnostics import counter, fetch, instrumented_jit, scope
 from ..parallel.runtime import mesh_size
 
 BOX_SIZE = 1000.0
@@ -351,20 +351,21 @@ class Program(object):
         programs run them as one vmapped launch."""
         import jax
         import jax.numpy as jnp
-        import numpy as np
         if self.batchable:
-            arr = jnp.asarray(list(seeds), jnp.uint32)
-            if self._device is not None:
-                arr = jax.device_put(arr, self._device)
-            x, y, nm = self._fn(arr)
-            x, y, nm = (np.asarray(v) for v in (x, y, nm))
+            with scope('serve.launch'):
+                arr = jnp.asarray(list(seeds), jnp.uint32)
+                if self._device is not None:
+                    arr = jax.device_put(arr, self._device)
+                out = self._fn(arr)
+            x, y, nm = fetch(out, 'serve.result')
             return [(x[i], y[i], nm[i]) for i in range(len(seeds))]
         out = []
         from ..parallel.runtime import use_mesh
         with use_mesh(self.mesh):
             for s in seeds:
-                x, y, nm = self._fn(jnp.uint32(s))
-                out.append(tuple(np.asarray(v) for v in (x, y, nm)))
+                with scope('serve.launch'):
+                    res = self._fn(jnp.uint32(s))
+                out.append(tuple(fetch(res, 'serve.result')))
         return out
 
     def run_data(self, ref, cache=None, fits=None, overlap=None):
@@ -373,15 +374,15 @@ class Program(object):
         executable.  Returns ``([(x, y, nmodes)], ingest_stats)`` —
         the stats carry cache_hit / bytes / seconds so the server can
         expose ingestion throughput per request."""
-        import numpy as np
         from ..ingest.stream import ingest_catalog
         from ..parallel.runtime import use_mesh
         with use_mesh(self.mesh):
             field, _, stats = ingest_catalog(
                 ref, self._pm, resampler=self._resampler, cache=cache,
                 fits=fits, overlap=overlap)
-            x, y, nm = self._fn(field)
-            out = tuple(np.asarray(v) for v in (x, y, nm))
+            with scope('serve.launch'):
+                res = self._fn(field)
+            out = tuple(fetch(res, 'serve.result'))
         return [out], stats
 
 

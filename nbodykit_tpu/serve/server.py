@@ -44,8 +44,8 @@ import threading
 import time
 
 from ..diagnostics import (counter, current_tracer, gauge, histogram,
-                           new_request_context, span, trace_context,
-                           trace_scope)
+                           new_request_context, scope, span,
+                           trace_context, trace_scope)
 from ..diagnostics.export import FLIGHT, ensure_exporter, \
     register_source
 from ..diagnostics.slo import SLOTracker
@@ -399,10 +399,12 @@ class AnalysisServer(object):
         owns_ctx = ctx is None
         if owns_ctx and current_tracer() is not None:
             ctx = new_request_context(request.request_id)
+        # a scope, not a bare span: the client thread's half of the
+        # request is on the profiler's host line and the host ledger too
         with trace_scope(ctx if owns_ctx else None), \
-                span('serve.submit', request_id=request.request_id,
-                     algorithm=request.algorithm,
-                     shape_class=request.shape_class) as sp:
+                scope('serve.submit', request_id=request.request_id,
+                      algorithm=request.algorithm,
+                      shape_class=request.shape_class) as sp:
             if owns_ctx and ctx is not None and not ctx.span_id:
                 # this span IS the request's root: every cross-thread
                 # span re-parents to it via ctx.span_id
@@ -488,6 +490,15 @@ class AnalysisServer(object):
     # -- the worker loop --------------------------------------------------
 
     def _finish(self, ticket, result):
+        # the terminal mark, as a scope: stamped into the request's
+        # own trace whichever thread finishes it, and the delivery's
+        # host time on the ledger under its own name
+        with trace_scope(ticket.ctx), \
+                scope('serve.deliver', request_id=result.request_id,
+                      status=result.status, latency_s=result.latency_s):
+            self._deliver(ticket, result)
+
+    def _deliver(self, ticket, result):
         ticket.result = result
         self.results[result.request_id] = result
         if result.status == COMPLETED:
@@ -513,15 +524,6 @@ class AnalysisServer(object):
             slo_status = result.status
         self.slo.observe(result.shape_class or 'default',
                          result.latency_s, slo_status)
-        # terminal trace mark, stamped into the request's own trace
-        # regardless of which thread finishes it
-        tr = current_tracer()
-        if tr is not None and ticket.ctx is not None:
-            tr.event('serve.deliver',
-                     {'request_id': result.request_id,
-                      'status': result.status,
-                      'latency_s': result.latency_s},
-                     ctx=ticket.ctx)
         if ticket.ctx_owned:
             # front-door-less serving: this server owns the request's
             # flight-recorder entry (a Region records its own)
@@ -752,30 +754,36 @@ class AnalysisServer(object):
                             {'request_id': t.request.request_id,
                              'leader_trace': leader.ctx.trace_id,
                              'leader_request': rid}, ctx=t.ctx)
+        # the worker's root: a scope, so that the request's host time
+        # is on the profiler's host line and the ledger by name
         with trace_scope(leader.ctx), \
-                span('serve.request', request_id=rid,
-                     algorithm=req.algorithm,
-                     shape_class=req.shape_class,
-                     batch=len(group), worker=wi):
+                scope('serve.request', request_id=rid,
+                      algorithm=req.algorithm,
+                      shape_class=req.shape_class,
+                      batch=len(group), worker=wi):
             try:
-                out = sup.run(work)
+                out, failed = sup.run(work), None
             except Exception as e:
-                done_at = time.monotonic()
-                for t in group:
-                    self._finish(t, RequestResult(
-                        t.request.request_id, FAILED,
-                        reason={'code': 'execution',
-                                'error': str(e)[:500],
-                                'type': type(e).__name__},
-                        latency_s=done_at - t.submitted_at,
-                        events=sup.events, options=opts,
-                        admit_options=t.decision.options,
-                        batch_size=len(group),
-                        algorithm=t.request.algorithm,
-                        shape_class=t.request.shape_class,
-                        queue_wait_s=now - t.submitted_at,
-                        service_s=done_at - now))
-                return
+                failed = e
+        if failed is not None:
+            # delivered outside the leader's span: each member's
+            # ``serve.deliver`` hangs off its own request's root
+            done_at = time.monotonic()
+            for t in group:
+                self._finish(t, RequestResult(
+                    t.request.request_id, FAILED,
+                    reason={'code': 'execution',
+                            'error': str(failed)[:500],
+                            'type': type(failed).__name__},
+                    latency_s=done_at - t.submitted_at,
+                    events=sup.events, options=opts,
+                    admit_options=t.decision.options,
+                    batch_size=len(group),
+                    algorithm=t.request.algorithm,
+                    shape_class=t.request.shape_class,
+                    queue_wait_s=now - t.submitted_at,
+                    service_s=done_at - now))
+            return
         sup.done(rid)
         if sup.events:
             counter('serve.fault_degraded').add(1)

@@ -15,7 +15,7 @@ import numpy as np
 import jax.numpy as jnp
 
 from ...base.mesh import MeshSource, Field
-from ...diagnostics import scope
+from ...diagnostics import fetch, scope
 from ...ops.window import compensation_transfer, window_support
 
 
@@ -114,10 +114,10 @@ class CatalogMesh(MeshSource):
             combined = 0.5 * (c1 + c2 * jnp.exp(-0.5j * kH))
             field = pm.c2r(combined)
 
-        # to host scalars for attrs (cheap; small reductions)
-        N = float(N)
-        W = float(W)
-        W2 = float(W2)
+        # to host scalars for attrs (small reductions, launched before
+        # the paint: the wait is for them, not for the paint)
+        N, W, W2 = (float(v) for v in
+                    fetch((N, W, W2), 'catalog.totals'))
         nbar = W / pm.Ntot  # mean weighted objects per cell
         shotnoise = float(np.prod(pm.BoxSize)) * W2 / W ** 2 if W > 0 \
             else 0.0

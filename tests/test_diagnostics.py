@@ -332,8 +332,9 @@ def test_timer_routes_through_tracer(tmp_path):
 
 
 def test_fft_chunk_spans_lowmem(tmp_path):
-    """The eager lowmem FFT driver emits per-chunk spans + the chunk
-    wall histogram."""
+    """The eager lowmem FFT driver emits per-chunk spans, each with
+    its own wall and start (the ``fft.chunk_wall_s`` histogram that
+    repeated them went with PR 37)."""
     import jax.numpy as jnp
     from nbodykit_tpu.parallel.dfft import rfftn_single_lowmem
     x = jnp.zeros((16, 16, 16), jnp.float32)
@@ -350,7 +351,10 @@ def test_fft_chunk_spans_lowmem(tmp_path):
     assert all(c['par'] == low['id'] for c in chunk_spans)
     snap = REGISTRY.snapshot()
     assert snap['fft.chunks']['value'] == len(chunk_spans)
-    assert snap['fft.chunk_wall_s']['count'] == len(chunk_spans)
+    assert 'fft.chunk_wall_s' not in snap
+    assert all(c['dur'] >= 0 and c['t0_ns'] > 0 and
+               c['attrs']['rows'] >= 1 for c in chunk_spans)
+    assert sum(c['dur'] for c in chunk_spans) <= low['dur'] + 1e-3
 
 
 def test_fftpower_acceptance_trace(tmp_path, cpu8):
@@ -374,7 +378,17 @@ def test_fftpower_acceptance_trace(tmp_path, cpu8):
     # byte + throughput metrics landed
     assert snap['exchange.bytes_sent']['value'] > 0
     assert snap['exchange.calls']['value'] >= 1
-    assert snap['paint.mxu.mpart_per_s']['count'] >= 1
+    # the paint's throughput is its span: the synced wall with the
+    # particle count beside it; its host time is on the ledger with
+    # no tracer needed (PR 37 took the paint.<method>.* histograms)
+    paints = [s for s in spans if s['name'] == 'paint']
+    assert paints and all(s['dur'] > 0 and s['attrs']['npart'] > 0
+                          and s['attrs']['method'] == 'mxu'
+                          for s in paints)
+    assert not [k for k in snap if k.endswith('mpart_per_s')]
+    assert snap['host.paint.n']['value'] == len(paints)
+    assert snap['host.paint.self_s']['value'] > 0
+    assert snap['host.syncs']['value'] >= 2
     # device watermarks were sampled for the 8 virtual devices
     assert snap['device.cpu:0.live_bytes']['max'] > 0
     # compile telemetry (ISSUE 2 acceptance): the binning program's

@@ -103,7 +103,10 @@ def test_scope_staging_writes_no_span_but_names_the_ops(tmp_path):
     with scope('eager') as sc:
         assert sc.span_id > 0
     diagnostics.configure(None)
-    assert [s['name'] for s in _spans(str(tmp_path))] == ['eager']
+    # (lowering with the tracer on leaves its own compile.trace /
+    # compile.lower spans: the staged scope leaves none)
+    assert [s['name'] for s in _spans(str(tmp_path))
+            if not s['name'].startswith('compile.')] == ['eager']
 
 
 def test_scope_done_waits_only_while_the_span_records(tmp_path,
@@ -362,3 +365,300 @@ def test_paint_span_names_its_engine(tmp_path, nmesh, engine):
         assert value('compile.paint.tile.hits') == 1
         assert value('paint.tile.buckets') == (4 + 1) * 4
         assert value('paint.tile.ck') == 256
+
+
+# ---------------------------------------------------------------------------
+# the host ledger (PR 37): every eager scope's self time, with no
+# instrument on
+
+from nbodykit_tpu.diagnostics import HOST_CALLS, fetch  # noqa: E402
+
+
+def _last_call(root):
+    rec = [r for r in HOST_CALLS.snapshot() if r['root'] == root]
+    assert rec, 'no call record under %r' % root
+    return rec[-1]
+
+
+def _nested():
+    with scope('t.root'):
+        with scope('t.a'):
+            with scope('t.b'):
+                sum(range(2000))
+        sum(range(2000))
+
+
+def _repeated():
+    with scope('t.root'):
+        for _ in range(5):
+            with scope('t.a'):
+                sum(range(500))
+            with scope('t.root'):       # the root's name again, inside
+                sum(range(500))
+
+
+def _misnested():
+    root = scope('t.root')
+    a, b = scope('t.a'), scope('t.b')
+    root.__enter__()
+    a.__enter__()
+    b.__enter__()
+    sum(range(2000))
+    a.__exit__(None, None, None)        # before b: a generator's gc
+    sum(range(2000))
+    b.__exit__(None, None, None)        # late: off the ledger already
+    root.__exit__(None, None, None)
+
+
+@pytest.mark.parametrize('body,names', [
+    (_nested, {'t.root', 't.a', 't.b'}),
+    (_repeated, {'t.root', 't.a'}),
+    (_misnested, {'t.root', 't.a', 't.b'})],
+    ids=['nested', 'repeated', 'misnested'])
+def test_ledger_parts_sum_to_the_roots_wall(body, names):
+    """The parts are a partition of the host thread's time: they sum
+    to the root's wall to the nanosecond, however the scopes nest, and
+    they are kept with no tracer and no profiler on."""
+    assert diagnostics.current_tracer() is None
+    body()
+    rec = _last_call('t.root')
+    assert set(rec['self_s']) == names
+    assert all(v >= 0 for v in rec['self_s'].values())
+    assert sum(rec['self_s'].values()) == pytest.approx(
+        rec['wall_s'], abs=2e-9)
+    assert rec['syncs'] == 0 and rec['sync_wait_s'] == 0
+    snap = REGISTRY.snapshot()
+    for name in names:
+        assert snap['host.%s.self_s' % name]['value'] == pytest.approx(
+            rec['self_s'][name])
+        assert snap['host.%s.n' % name]['value'] >= 1
+    assert trace_mod._LEDGER.stack == []
+
+
+def test_ledger_two_threads_keep_two_stacks():
+    import threading
+    inside = threading.Event()
+    leave = threading.Event()
+
+    def other():
+        with scope('t.other'):
+            with scope('t.a'):
+                inside.set()
+                leave.wait(5)
+
+    th = threading.Thread(target=other)
+    with scope('t.main'):
+        th.start()
+        assert inside.wait(5)
+        # the other thread's open scopes are not this thread's
+        assert trace_mod.open_scope() == 't.main'
+        with scope('t.b'):
+            pass
+        leave.set()
+        th.join()
+    main, side = _last_call('t.main'), _last_call('t.other')
+    assert set(main['self_s']) == {'t.main', 't.b'}
+    assert set(side['self_s']) == {'t.other', 't.a'}
+    for rec in (main, side):
+        assert sum(rec['self_s'].values()) == pytest.approx(
+            rec['wall_s'], abs=2e-9)
+
+
+def test_ledger_records_nothing_and_never_syncs_while_staging(
+        monkeypatch):
+    waited = []
+    monkeypatch.setattr(jax, 'block_until_ready',
+                        lambda x: waited.append(x) or x)
+    before = len(HOST_CALLS.snapshot())
+
+    open_inside = []
+
+    def body(x):
+        with scope('t.staged') as sc:
+            open_inside.append(trace_mod.open_scope())
+            return sc.done(2 * x)
+
+    jax.jit(body)(jnp.ones(3))
+    assert waited == [] and open_inside == [None]
+    assert len(HOST_CALLS.snapshot()) == before
+    assert not [k for k in REGISTRY.snapshot() if 't.staged' in k]
+    # eagerly the same scope is on the ledger, and still never syncs
+    body(jnp.ones(3))
+    assert open_inside == [None, 't.staged']
+    assert waited == [] and _last_call('t.staged')['wall_s'] > 0
+
+
+def test_scope_off_costs_a_bare_annotation_plus_a_stated_budget():
+    """With the option off a scope is its ``TraceAnnotation``, two
+    clock readings and a dict update: the median over 10,000 scopes
+    inside one root stays within 10 us of the bare annotation's (the
+    figure measured on an idle machine is in PERF.md, section 7: some
+    3.5 us a scope, 2.6 of them the parent's)."""
+    import statistics
+    import time
+    assert diagnostics.current_tracer() is None
+    clock = time.perf_counter_ns
+
+    def median_ns(enter):
+        laps = []
+        for _ in range(10000):
+            t0 = clock()
+            with enter():
+                pass
+            laps.append(clock() - t0)
+        return statistics.median(laps)
+
+    bare = median_ns(lambda: jax.profiler.TraceAnnotation('nbk.t.x'))
+    with scope('t.root'):
+        scoped = median_ns(lambda: scope('t.x'))
+    rec = _last_call('t.root')
+    assert REGISTRY.snapshot()['host.t.x.n']['value'] == 10000
+    assert sum(rec['self_s'].values()) == pytest.approx(
+        rec['wall_s'], abs=2e-9)
+    assert scoped - bare < 10e3, (scoped, bare)
+
+
+def test_fetch_returns_host_arrays_and_counts_one_sync():
+    tree = {'a': jnp.arange(4.0), 'b': (jnp.ones(2), 3.0)}
+    with scope('t.root'):
+        got = fetch(tree, 't.tree')
+    assert isinstance(got['a'], np.ndarray)
+    assert isinstance(got['b'][0], np.ndarray) and got['b'][1] == 3.0
+    np.testing.assert_array_equal(got['a'], np.arange(4.0))
+    rec = _last_call('t.root')
+    assert rec['syncs'] == 1
+    assert rec['sync_wait_s'] == rec['self_s']['sync.t.tree'] > 0
+    snap = REGISTRY.snapshot()
+    assert snap['host.syncs']['value'] == 1
+    assert snap['host.sync.t.tree.n']['value'] == 1
+    # a fetch outside every scope is its own root
+    assert float(fetch(jnp.float32(2.5), 't.alone')) == 2.5
+    assert _last_call('sync.t.alone')['syncs'] == 1
+    assert REGISTRY.snapshot()['host.syncs']['value'] == 2
+
+
+def test_retrace_lands_on_the_scope_it_happened_under(tmp_path):
+    """All three stages of a jit cache miss are charged to the
+    innermost open scope of the compiling thread, and with the tracer
+    on each is a span that names it."""
+    diagnostics.install_compile_telemetry()
+    diagnostics.configure(str(tmp_path))
+    with scope('t.root'):
+        with scope('t.quiet'):
+            pass
+        with scope('t.retrace'):
+            # a new function object: traced, lowered and compiled anew
+            jax.jit(lambda x: x * 3 + 1)(jnp.ones(7))
+    diagnostics.configure(None)
+    rec = _last_call('t.root')
+    snap = REGISTRY.snapshot()
+    assert rec['retrace_s'] > 0
+    assert snap['host.t.retrace.retrace_s']['value'] == pytest.approx(
+        rec['retrace_s'])
+    assert 'host.t.quiet.retrace_s' not in snap
+    assert 'host.t.root.retrace_s' not in snap
+    # an attribution, not a part: the scope's self time holds it
+    assert rec['self_s']['t.retrace'] >= 0.5 * rec['retrace_s']
+    stages = [s for s in _spans(str(tmp_path))
+              if s['name'] in ('compile.trace', 'compile.lower',
+                               'compile.backend')]
+    assert {s['name'] for s in stages} == {
+        'compile.trace', 'compile.lower', 'compile.backend'}
+    assert all(s['attrs']['scope'] == 't.retrace' for s in stages)
+    assert sum(s['dur'] for s in stages) == pytest.approx(
+        rec['retrace_s'], abs=1e-5 * len(stages))
+    # the doctor reads ``compile.<label>`` spans as labelled jits that
+    # missed their cache: the stage spans are no label's
+    from nbodykit_tpu.diagnostics.__main__ import _compile_miss_labels
+    assert not {'trace', 'lower', 'backend'} \
+        & set(_compile_miss_labels(str(tmp_path)))
+
+
+def test_ring_keeps_the_last_1024_roots_in_time_order(tmp_path):
+    for i in range(1030):
+        with scope('t.ring'):
+            pass
+    calls = HOST_CALLS.snapshot()
+    assert len(calls) == 1024
+    assert all(r['root'] == 't.ring' for r in calls)
+    stamps = [r['t0_ns'] for r in calls]
+    assert stamps == sorted(stamps)
+    import time
+    assert abs(stamps[-1] - time.time_ns()) < 60e9      # the wall clock
+    assert set(calls[-1]) == {'root', 't0_ns', 'wall_s', 'self_s',
+                              'syncs', 'sync_wait_s', 'retrace_s'}
+    # the JSONL span keeps the same clock
+    diagnostics.configure(str(tmp_path))
+    with scope('t.ring'):
+        pass
+    diagnostics.configure(None)
+    span_rec = _spans(str(tmp_path))[-1]
+    assert abs(span_rec['t0_ns'] - HOST_CALLS.snapshot()[-1]['t0_ns']) \
+        < 5e6
+    assert span_rec['t0_ns'] == pytest.approx(span_rec['ts'] * 1e9,
+                                              abs=1e3)
+
+
+def _lab_call():
+    from nbodykit_tpu.lab import FFTPower, UniformCatalog
+    cat = UniformCatalog(nbar=2e-4, BoxSize=256.0, seed=11)
+    return lambda: FFTPower(cat, mode='2d', Nmesh=32, kmin=0.001, Nmu=10)
+
+
+def _survey_call():
+    from nbodykit_tpu.lab import (ConvolvedFFTPower, FKPCatalog,
+                                  UniformCatalog)
+    data = UniformCatalog(nbar=1e-4, BoxSize=256.0, seed=12)
+    randoms = UniformCatalog(nbar=1e-3, BoxSize=256.0, seed=13)
+    for cat in (data, randoms):
+        cat['NZ'] = jnp.full(cat.size, 1e-4, 'f4')
+    mesh = FKPCatalog(data, randoms).to_mesh(Nmesh=32, resampler='tsc')
+    return lambda: ConvolvedFFTPower(mesh, poles=[0, 2], dk=0.05)
+
+
+def _pair_call():
+    from nbodykit_tpu.lab import SimulationBoxPairCount, UniformCatalog
+    cat = UniformCatalog(nbar=3e-4, BoxSize=256.0, seed=14)
+    edges = np.logspace(0, np.log10(20.0), 9)
+    return lambda: SimulationBoxPairCount('1d', cat, edges, BoxSize=256.0)
+
+
+def _served_call():
+    from nbodykit_tpu.serve import AnalysisRequest, AnalysisServer
+    server = AnalysisServer(per_task=1, hbm_bytes=16e9)
+    server.__enter__()
+    seeds = iter(range(100, 200))
+
+    def call():
+        req = AnalysisRequest(algorithm='FFTPower', nmesh=32,
+                              npart=20000, seed=next(seeds),
+                              deadline_s=120.0)
+        res = server.wait(server.submit(req), timeout=120.0)
+        assert res.status == 'completed', res.to_dict()
+    call.close = lambda: server.__exit__(None, None, None)
+    return call
+
+
+@pytest.mark.parametrize('make,root,syncs', [
+    (_lab_call, 'fftpower.run', 2),
+    (_survey_call, 'convpower.run', 10),
+    (_pair_call, 'paircount.run', 1),
+    (_served_call, 'serve.request', 1)],
+    ids=['lab', 'survey', 'paircount', 'served'])
+def test_no_host_second_without_a_name(make, root, syncs):
+    """The five cells' calls at 32^3 on the CPU: warm, the root's own
+    self time (host code under no scope but the root) is under 5% of
+    its wall, the parts sum to it, and every fetch is a ``sync.*``."""
+    call = make()
+    try:
+        for _ in range(3):
+            call()
+    finally:
+        getattr(call, 'close', lambda: None)()
+    rec = _last_call(root)
+    assert sum(rec['self_s'].values()) == pytest.approx(
+        rec['wall_s'], abs=2e-9)
+    assert rec['self_s'][root] < 0.05 * rec['wall_s'], rec
+    assert rec['syncs'] == syncs
+    assert rec['sync_wait_s'] == pytest.approx(sum(
+        v for k, v in rec['self_s'].items() if k.startswith('sync.')))

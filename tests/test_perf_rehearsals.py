@@ -45,6 +45,18 @@ REHEARSALS = [
     'test_four_chip_cell.py::'
     'test_a2a_ici_share_withheld_above_the_unscoped_limit',
     'test_four_chip_cell.py::test_the_cell_reports_the_new_metrics',
+    'test_host_ledger.py::test_gaps_by_the_hosts_innermost_scope',
+    'test_host_ledger.py::test_gaps_under_a_root_alone_are_unscoped',
+    'test_host_ledger.py::'
+    'test_every_host_line_counts_and_roots_do_not',
+    'test_host_ledger.py::test_ring_records_are_selected_by_time',
+    'test_host_ledger.py::test_retrace_from_the_three_compile_spans',
+    'test_host_ledger.py::'
+    'test_readers_say_nothing_where_there_is_nothing_to_read',
+    'test_host_ledger.py::'
+    'test_a_recorded_lab_call_reads_the_same_gaps_both_ways',
+    'test_host_ledger.py::'
+    'test_load_reads_annotations_and_the_sessions_clock',
     'test_paircount_cell.py::test_paircount_driver_end_to_end',
     'test_paircount_cell.py::test_paircount_verify_catches_what_moved',
     'test_paircount_cell.py::'
