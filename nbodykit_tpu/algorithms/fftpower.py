@@ -19,6 +19,7 @@ means/packaging happen on host with numpy (small arrays).
 
 import json
 import logging
+from functools import lru_cache as _lru_cache
 
 import numpy as np
 import jax
@@ -67,6 +68,263 @@ def _cross_power(a, b, volume):
 _BIN_CHUNK_ELEMENTS = 1 << 22
 
 
+@_lru_cache(maxsize=32)
+def _binning_program(kind, shape, dtype, nmesh, boxsize, xedges, muedges,
+                     los, poles, comm, chunk_elements, mxu, x64):
+    """The (x, mu, ell) binning of one field geometry as one program:
+    ``(prog, nstreams, split, parts)``.  ``prog(value)`` takes the
+    field's array and nothing else, and returns the ``nstreams``
+    weighted ``(Nx + 2, Nmu + 2)`` histograms ``project_to_basis``
+    packages; the rest are the ``fftpower.binning`` span's attrs.
+
+    Cached on everything the body reads at trace time, as hashable host
+    values: ``kind`` ('hermitian' / 'full' / 'real'), the value's
+    ``shape`` (what a valid field's kind and ``nmesh`` already fix: a
+    guard, since the chunking and the ``shard_map`` form follow it) and
+    ``dtype``, ``nmesh`` and ``boxsize`` as tuples, both
+    edge arrays as their f8 bytes, ``los``, the sorted ``poles`` (0
+    among them), the device mesh ``comm``, and the ambient switches
+    ``_BIN_CHUNK_ELEMENTS``, ``is_mxu_backend()`` and
+    ``jax_enable_x64``.  An analyst runs one call per mock of a set on
+    one geometry: built per call, the program was traced, lowered and
+    loaded again in each while the device waited (0.15-0.49 s of a
+    0.47 / 0.77 s lab call at 512^3), behind the 58 small launches
+    that made the constants below.  Here they are made on a miss only
+    (a few KB of axis vectors, baked into the program as before); a
+    hit launches the one program."""
+    from ..ops.histogram import (edge_count_index, hist2d_weighted,
+                                 lattice_shell_edges, mxu_split)
+    from ..parallel.runtime import AXIS, mesh_size, vary_like
+    from ..pmesh import mode_hermitian_weights, mode_i_list, mode_k_list
+    hermitian, full_complex = kind == 'hermitian', kind == 'full'
+    is_cplx = np.issubdtype(dtype, np.complexfloating)
+    xedges = np.frombuffer(xedges, dtype='f8')
+    muedges = np.frombuffer(muedges, dtype='f8')
+    Nx = len(xedges) - 1
+    Nmu = len(muedges) - 1
+
+    N0, N1, N2 = nmesh
+    L = boxsize
+    # best available precision for the mode coordinates/weights: f8
+    # under x64, f4 on TPU — an explicit demotion decision (NBK301)
+    # instead of a silent one (jnp.float64 with x64 off quietly
+    # returns f32)
+    _f8 = working_dtype('f8')
+    if hermitian or full_complex:
+        kx, ky, kz = mode_k_list(nmesh, L, _f8, full=full_complex)
+        coords = [kx * los[0], ky * los[1], kz * los[2]]
+        x2fac = [kx ** 2, ky ** 2, kz ** 2]
+        units = 2 * np.pi / np.asarray(L, dtype='f8')
+        if full_complex:
+            w_b = jnp.ones((1, 1, 1), dtype=_f8)
+        else:
+            w_b = mode_hermitian_weights(N2, _f8)  # (1,1,nz)
+    else:
+        # real field: separation coordinates in fftfreq ordering
+        rx = (jnp.fft.fftfreq(N0, d=1.0 / N0) * (L[0] / N0)
+              ).reshape(N0, 1, 1)
+        ry = (jnp.fft.fftfreq(N1, d=1.0 / N1) * (L[1] / N1)
+              ).reshape(1, N1, 1)
+        rz = (jnp.fft.fftfreq(N2, d=1.0 / N2) * (L[2] / N2)
+              ).reshape(1, 1, N2)
+        coords = [rx * los[0], ry * los[1], rz * los[2]]
+        x2fac = [rx ** 2, ry ** 2, rz ** 2]
+        units = np.asarray(L, dtype='f8') / np.asarray(
+            [N0, N1, N2], dtype='f8')
+        w_b = jnp.ones((1, 1, 1), dtype=_f8)
+
+    # Exact-integer lattice binning for the no-x64 (TPU) regime. With
+    # f64 unavailable, x^2 computed in f32 rounds differently from the
+    # f64 reference and modes sitting exactly ON a bin edge (any
+    # perfect-square |i|^2 when dk is the fundamental) flip bins
+    # unpredictably. On a uniform lattice x^2 = unit^2 * |i|^2 with
+    # |i|^2 an exact int32, so digitizing |i|^2 (exactly representable
+    # in f32 up to Nmesh=4096) against host-f64-quantized edges
+    # (xedges/unit)^2 is deterministic and edge-exact — the f32 story
+    # of round-2 VERDICT weak #3. The x64 path is left byte-identical.
+    # the |i|^2 lattice must stay exactly representable in f32
+    # (< 2^24), i.e. Nmesh <= 4096 — beyond that the cast itself
+    # rounds and the path would reintroduce the edge flips it fixes
+    _isq_max = 3 * (max(N0, N1, N2) // 2) ** 2
+    exact_int = (not x64) \
+        and np.allclose(units, units[0], rtol=1e-12) \
+        and _isq_max < (1 << 24)
+    if exact_int:
+        unit = float(units[0])
+        if hermitian or full_complex:
+            ix, iy, iz = mode_i_list(nmesh)
+            if full_complex:
+                iz = jnp.fft.fftfreq(N2, d=1.0 / N2).astype(
+                    jnp.int32).reshape(1, 1, N2)
+        else:
+            ix = jnp.fft.fftfreq(N0, d=1.0 / N0).astype(
+                jnp.int32).reshape(N0, 1, 1)
+            iy = jnp.fft.fftfreq(N1, d=1.0 / N1).astype(
+                jnp.int32).reshape(1, N1, 1)
+            iz = jnp.fft.fftfreq(N2, d=1.0 / N2).astype(
+                jnp.int32).reshape(1, 1, N2)
+        x2fac = [ix * ix, iy * iy, iz * iz]  # int32, exact
+        # integer edge thresholds: for integer v, (e <= v) == (ceil(e)
+        # <= v), so digitizing int32 |i|^2 against the ceil'd edges is
+        # FULLY exact — see ops.histogram.lattice_shell_edges
+        x2edges = jnp.asarray(lattice_shell_edges(xedges, unit))
+    else:
+        unit = 1.0
+        x2edges = jnp.asarray(xedges ** 2)
+    muedges_j = jnp.asarray(muedges)
+
+    # slab-chunk the reduction over the leading axis so no full-mesh
+    # f64 temporary (x2 / mu / legendre / digitize) is ever live at
+    # once — at Nmesh >= 1024 the unchunked version needs several
+    # multi-GB buffers (round-1 VERDICT weak #6). With a device mesh
+    # the same chunking runs per-device inside shard_map (each device
+    # loops over its own rows and psums the small histograms) — the
+    # per-device memory hazard is worst exactly in the multi-chip
+    # configuration (round-2 VERDICT weak #4).
+    S0, S1, S2 = shape
+    nproc = mesh_size(comm)
+    if nproc > 1 and S0 % nproc != 0:
+        nproc = 1  # unexpected layout: fused single-program path
+    S0_local = S0 // nproc
+    target_rows = max(1, chunk_elements // max(1, S1 * S2))
+    rows = min(S0_local, target_rows)
+    while S0_local % rows:
+        rows -= 1
+    nch = S0_local // rows
+    chunked = nch > 1
+    if not chunked:
+        rows = S0_local
+
+    def slice0(a, start):
+        """Slice the leading axis of a broadcastable factor at a global
+        row offset. Whether a factor varies along axis 0 depends on the
+        layout (transposed complex: ky leads; real: rx leads) — size-1
+        axes pass through."""
+        if a.shape[0] == 1:
+            return a
+        return jax.lax.dynamic_slice_in_dim(a, start, rows, 0)
+
+    def chunk_hists(v_c, start):
+        """All weighted histograms of one leading-axis slab whose
+        global row offset is ``start``."""
+        shape = v_c.shape
+        with scope('fftpower.binning.digitize'):
+            x2 = sum(slice0(f, start) for f in x2fac)
+            if exact_int:
+                # x2 stays int32 for the (exact) digitize; float only
+                # for the mean-|x| stream
+                xnorm = unit * jnp.sqrt(x2.astype(jnp.float32))
+            else:
+                xnorm = jnp.sqrt(x2)
+            mudot = sum(slice0(c, start) for c in coords)
+            mu = jnp.where(xnorm == 0, 0.0,
+                           mudot / jnp.where(xnorm == 0, 1.0, xnorm))
+            dig_x = edge_count_index(jnp.broadcast_to(x2, shape),
+                                     x2edges)
+            dig_mu = edge_count_index(mu, muedges_j)
+
+        # the chunk and its factors as they lie: what is constant along
+        # an axis stays size 1 there (hist2d_weighted broadcasts)
+        nonsing = (w_b == 2.0)
+        streams = [xnorm * w_b, mu * w_b,
+                   # 1.0 and 2.0, exact in bfloat16, and handed over as
+                   # such: one part of the MXU histogram's product
+                   w_b.astype(jnp.bfloat16)]
+        legs = _legendre_all(poles, mu)
+        # accumulate the spectrum in the widest dtype the backend has
+        # (f8 under x64, f4 on TPU) — explicit, not silently demoted
+        vre = v_c.real.astype(working_dtype('f8'))
+        vim = (v_c.imag.astype(working_dtype('f8'))
+               if is_cplx else None)
+        for iell, ell in enumerate(poles):
+            leg = legs[iell]
+            yre = leg * vre
+            yim = leg * vim if is_cplx else None
+            if hermitian:
+                if ell % 2:   # odd: real parts cancel between +k/-k
+                    yre = jnp.where(nonsing, 0.0, yre)
+                    yim = jnp.where(nonsing, 2.0 * yim, yim)
+                else:         # even: imaginary parts cancel
+                    yre = jnp.where(nonsing, 2.0 * yre, yre)
+                    if is_cplx:
+                        yim = jnp.where(nonsing, 0.0, yim)
+            fac = (2.0 * ell + 1.0)
+            streams.append(fac * yre)
+            if is_cplx:
+                streams.append(fac * yim)
+        with scope('fftpower.binning.hist'):
+            return hist2d_weighted(dig_x, dig_mu, streams,
+                                   Nx + 2, Nmu + 2,
+                                   method='mxu' if mxu else 'bincount')
+
+    nstreams = 3 + len(poles) * (2 if is_cplx else 1)
+    # what the MXU histogram's product looks like for these bins (None
+    # where hist2d_weighted sums by bincount): two bf16 parts a stream,
+    # one for the count
+    parts = 2 * nstreams - 1
+    # (a tuple: the cache hands every caller the same object)
+    split = mxu_split(Nx + 2, Nmu + 2, parts) if mxu else None
+    hist_dtype = jnp.float64 if x64 else jnp.float32
+
+    def _block_hists(v_loc, base):
+        """Histograms of one device's (S0_local, S1, S2) block starting
+        at global row ``base``, chunk-looped so only ``rows`` rows of
+        temporaries are live. Cross-chunk sums are Kahan-compensated:
+        in the no-x64 (TPU) regime the carry is f32 and a plain sum
+        over many chunks loses low bits of the per-bin totals."""
+        if split:       # traced once a program, by either ``binning``
+            counter('fftpower.binning.trace.split').add(1)
+        if not chunked:
+            return list(chunk_hists(v_loc, base))
+
+        def body(i, state):
+            acc, comp = state
+            hs_c = chunk_hists(
+                jax.lax.dynamic_slice_in_dim(v_loc, i * rows, rows, 0),
+                base + i * rows)
+            new_acc, new_comp = [], []
+            for a, c, h in zip(acc, comp, hs_c):
+                y = h - c
+                t = a + y
+                new_comp.append((t - a) - y)
+                new_acc.append(t)
+            return (new_acc, new_comp)
+        init_a = [jnp.zeros((Nx + 2, Nmu + 2), hist_dtype)
+                  for _ in range(nstreams)]
+        init_c = [jnp.zeros((Nx + 2, Nmu + 2), hist_dtype)
+                  for _ in range(nstreams)]
+        # inside shard_map the body folds device-local rows into the
+        # carry, so it must start with the block's varying type
+        init_a = [vary_like(a, v_loc) for a in init_a]
+        init_c = [vary_like(a, v_loc) for a in init_c]
+        acc, _ = jax.lax.fori_loop(0, nch, body, (init_a, init_c))
+        return acc
+
+    # the program's name (``jit_binning``) is part of its key in jax's
+    # persistent cache and its scopes are not: under the name it had
+    # before it carried them, a cached executable would come back bare
+    if nproc > 1:
+        from jax.sharding import PartitionSpec as _P
+
+        def binning(v_loc):
+            with scope('fftpower.binning'):
+                base = jax.lax.axis_index(AXIS) * S0_local
+                hs = _block_hists(v_loc, base)
+                return tuple(jax.lax.psum(h, AXIS) for h in hs)
+
+        prog = instrumented_jit(jax.shard_map(
+            binning, mesh=comm,
+            in_specs=(_P(AXIS, None, None),),
+            out_specs=(_P(),) * nstreams), label='fftpower.binning')
+    else:
+        def binning(v):
+            with scope('fftpower.binning'):
+                return tuple(_block_hists(v, 0))
+
+        prog = instrumented_jit(binning, label='fftpower.binning')
+    return prog, nstreams, split, parts if split else None
+
+
 def project_to_basis(y3d, edges, los=[0, 0, 1], poles=[]):
     """Bin a 3-D statistic into (x, mu) bins and optional multipoles.
 
@@ -90,21 +348,26 @@ def project_to_basis(y3d, edges, los=[0, 0, 1], poles=[]):
     plane), odd multipoles keep 2i*Im, even keep 2*Re on the doubled
     modes.  Both edge arrays must be strictly ascending (ValueError).
     """
-    # what the call does before the binning program is launched: the
-    # edges, the mode coordinates and weights (some forty small eager
-    # ops on the mesh's axis vectors), the program's body
+    # what the call does before the binning program is launched, all of
+    # it on the host: the edges' validation and the look-up of the
+    # program for this geometry (``_binning_program``)
     with scope('fftpower.coords'):
         pm = y3d.pm
+        value = y3d.value
         # a complex field with the full (uncompressed) kz axis is a c2c
         # spectrum: all modes present, no hermitian double-counting
-        full_complex = (y3d.kind == 'complex'
-                        and y3d.shape[2] == int(pm.Nmesh[2]))
-        hermitian = (y3d.kind == 'complex') and not full_complex
-        xedges, muedges = edges
+        if y3d.kind != 'complex':
+            kind = 'real'
+        elif y3d.shape[2] == int(pm.Nmesh[2]):
+            kind = 'full'
+        else:
+            kind = 'hermitian'
+        xedges, muedges = (np.ascontiguousarray(e, dtype='f8')
+                           for e in edges)
         for name, e in (('x', xedges), ('mu', muedges)):
             # the bin index counts the edges at or below a value
             # (ops.histogram.edge_count_index): ascending edges only
-            if not np.all(np.diff(np.asarray(e, dtype='f8')) > 0):
+            if not np.all(np.diff(e) > 0):
                 raise ValueError(
                     "%s edges must be strictly ascending" % name)
         Nx = len(xedges) - 1
@@ -117,255 +380,27 @@ def project_to_basis(y3d, edges, los=[0, 0, 1], poles=[]):
         if any(ell < 0 for ell in _poles):
             raise ValueError("multipole numbers must be non-negative integers")
 
-        nbins = (Nx + 2) * (Nmu + 2)
-
-        N0, N1, N2 = pm.shape_real
-        L = pm.BoxSize
-        # best available precision for the mode coordinates/weights: f8
-        # under x64, f4 on TPU — an explicit demotion decision (NBK301)
-        # instead of a silent one (jnp.float64 with x64 off quietly
-        # returns f32)
-        _f8 = working_dtype('f8')
-        if hermitian or full_complex:
-            kx, ky, kz = pm.k_list(dtype=_f8, full=full_complex)
-            coords = [kx * los[0], ky * los[1], kz * los[2]]
-            x2fac = [kx ** 2, ky ** 2, kz ** 2]
-            units = 2 * np.pi / np.asarray(L, dtype='f8')
-            if full_complex:
-                w_b = jnp.ones((1, 1, 1), dtype=_f8)
-            else:
-                w_b = pm.hermitian_weights(dtype=_f8)  # (1,1,nz)
-        else:
-            # real field: separation coordinates in fftfreq ordering
-            rx = (jnp.fft.fftfreq(N0, d=1.0 / N0) * (L[0] / N0)
-                  ).reshape(N0, 1, 1)
-            ry = (jnp.fft.fftfreq(N1, d=1.0 / N1) * (L[1] / N1)
-                  ).reshape(1, N1, 1)
-            rz = (jnp.fft.fftfreq(N2, d=1.0 / N2) * (L[2] / N2)
-                  ).reshape(1, 1, N2)
-            coords = [rx * los[0], ry * los[1], rz * los[2]]
-            x2fac = [rx ** 2, ry ** 2, rz ** 2]
-            units = np.asarray(L, dtype='f8') / np.asarray(
-                [N0, N1, N2], dtype='f8')
-            w_b = jnp.ones((1, 1, 1), dtype=_f8)
-
-        # Exact-integer lattice binning for the no-x64 (TPU) regime. With
-        # f64 unavailable, x^2 computed in f32 rounds differently from the
-        # f64 reference and modes sitting exactly ON a bin edge (any
-        # perfect-square |i|^2 when dk is the fundamental) flip bins
-        # unpredictably. On a uniform lattice x^2 = unit^2 * |i|^2 with
-        # |i|^2 an exact int32, so digitizing |i|^2 (exactly representable
-        # in f32 up to Nmesh=4096) against host-f64-quantized edges
-        # (xedges/unit)^2 is deterministic and edge-exact — the f32 story
-        # of round-2 VERDICT weak #3. The x64 path is left byte-identical.
-        # the |i|^2 lattice must stay exactly representable in f32
-        # (< 2^24), i.e. Nmesh <= 4096 — beyond that the cast itself
-        # rounds and the path would reintroduce the edge flips it fixes
-        _isq_max = 3 * (max(N0, N1, N2) // 2) ** 2
-        exact_int = (not jax.config.jax_enable_x64) \
-            and np.allclose(units, units[0], rtol=1e-12) \
-            and _isq_max < (1 << 24)
-        if exact_int:
-            unit = float(units[0])
-            if hermitian or full_complex:
-                ix, iy, iz = pm.i_list_complex()
-                if full_complex:
-                    iz = jnp.fft.fftfreq(N2, d=1.0 / N2).astype(
-                        jnp.int32).reshape(1, 1, N2)
-            else:
-                ix = jnp.fft.fftfreq(N0, d=1.0 / N0).astype(
-                    jnp.int32).reshape(N0, 1, 1)
-                iy = jnp.fft.fftfreq(N1, d=1.0 / N1).astype(
-                    jnp.int32).reshape(1, N1, 1)
-                iz = jnp.fft.fftfreq(N2, d=1.0 / N2).astype(
-                    jnp.int32).reshape(1, 1, N2)
-            x2fac = [ix * ix, iy * iy, iz * iz]  # int32, exact
-            # integer edge thresholds: for integer v, (e <= v) == (ceil(e)
-            # <= v), so digitizing int32 |i|^2 against the ceil'd edges is
-            # FULLY exact — see ops.histogram.lattice_shell_edges
-            from ..ops.histogram import lattice_shell_edges
-            x2edges = jnp.asarray(lattice_shell_edges(xedges, unit))
-        else:
-            unit = 1.0
-            x2edges = jnp.asarray(np.asarray(xedges, dtype='f8') ** 2)
-        muedges_j = jnp.asarray(np.asarray(muedges, dtype='f8'))
-
-        value = y3d.value
-        is_cplx = jnp.iscomplexobj(value)
-
-        # slab-chunk the reduction over the leading axis so no full-mesh
-        # f64 temporary (x2 / mu / legendre / digitize) is ever live at
-        # once — at Nmesh >= 1024 the unchunked version needs several
-        # multi-GB buffers (round-1 VERDICT weak #6). With a device mesh
-        # the same chunking runs per-device inside shard_map (each device
-        # loops over its own rows and psums the small histograms) — the
-        # per-device memory hazard is worst exactly in the multi-chip
-        # configuration (round-2 VERDICT weak #4).
-        from ..parallel.runtime import mesh_size, vary_like, AXIS
-        S0, S1, S2 = (int(s) for s in value.shape)
-        try:
-            nproc = mesh_size(getattr(pm, 'comm', None))
-        except Exception:
-            nproc = 1
-        if nproc > 1 and S0 % nproc != 0:
-            nproc = 1  # unexpected layout: fused single-program path
-        S0_local = S0 // nproc
-        target_rows = max(1, _BIN_CHUNK_ELEMENTS // max(1, S1 * S2))
-        rows = min(S0_local, target_rows)
-        while S0_local % rows:
-            rows -= 1
-        nch = S0_local // rows
-        chunked = nch > 1
-        if not chunked:
-            rows = S0_local
-
-        def slice0(a, start):
-            """Slice the leading axis of a broadcastable factor at a global
-            row offset. Whether a factor varies along axis 0 depends on the
-            layout (transposed complex: ky leads; real: rx leads) — size-1
-            axes pass through."""
-            if a.shape[0] == 1:
-                return a
-            return jax.lax.dynamic_slice_in_dim(a, start, rows, 0)
-
-        from ..ops.histogram import (edge_count_index, hist2d_weighted,
-                                     mxu_split)
+        is_cplx = np.issubdtype(value.dtype, np.complexfloating)
         from ..utils import is_mxu_backend
-
-        def chunk_hists(v_c, start):
-            """All weighted histograms of one leading-axis slab whose
-            global row offset is ``start``."""
-            shape = v_c.shape
-            with scope('fftpower.binning.digitize'):
-                x2 = sum(slice0(f, start) for f in x2fac)
-                if exact_int:
-                    # x2 stays int32 for the (exact) digitize; float only
-                    # for the mean-|x| stream
-                    xnorm = unit * jnp.sqrt(x2.astype(jnp.float32))
-                else:
-                    xnorm = jnp.sqrt(x2)
-                mudot = sum(slice0(c, start) for c in coords)
-                mu = jnp.where(xnorm == 0, 0.0,
-                               mudot / jnp.where(xnorm == 0, 1.0, xnorm))
-                dig_x = edge_count_index(jnp.broadcast_to(x2, shape),
-                                         x2edges)
-                dig_mu = edge_count_index(mu, muedges_j)
-
-            # the chunk and its factors as they lie: what is constant along
-            # an axis stays size 1 there (hist2d_weighted broadcasts)
-            nonsing = (w_b == 2.0)
-            streams = [xnorm * w_b, mu * w_b,
-                       # 1.0 and 2.0, exact in bfloat16, and handed over as
-                       # such: one part of the MXU histogram's product
-                       w_b.astype(jnp.bfloat16)]
-            legs = _legendre_all(_poles, mu)
-            # accumulate the spectrum in the widest dtype the backend has
-            # (f8 under x64, f4 on TPU) — explicit, not silently demoted
-            vre = v_c.real.astype(working_dtype('f8'))
-            vim = (v_c.imag.astype(working_dtype('f8'))
-                   if is_cplx else None)
-            for iell, ell in enumerate(_poles):
-                leg = legs[iell]
-                yre = leg * vre
-                yim = leg * vim if is_cplx else None
-                if hermitian:
-                    if ell % 2:   # odd: real parts cancel between +k/-k
-                        yre = jnp.where(nonsing, 0.0, yre)
-                        yim = jnp.where(nonsing, 2.0 * yim, yim)
-                    else:         # even: imaginary parts cancel
-                        yre = jnp.where(nonsing, 2.0 * yre, yre)
-                        if is_cplx:
-                            yim = jnp.where(nonsing, 0.0, yim)
-                fac = (2.0 * ell + 1.0)
-                streams.append(fac * yre)
-                if is_cplx:
-                    streams.append(fac * yim)
-            with scope('fftpower.binning.hist'):
-                return hist2d_weighted(dig_x, dig_mu, streams,
-                                       Nx + 2, Nmu + 2)
-
-        nstreams = 3 + Nell * (2 if is_cplx else 1)
-        # what the MXU histogram's product looks like for these bins (None
-        # where hist2d_weighted sums by bincount): two bf16 parts a stream,
-        # one for the count
-        parts = 2 * nstreams - 1
-        split = (list(mxu_split(Nx + 2, Nmu + 2, parts))
-                 if is_mxu_backend() else None)
-
-        def _block_hists(v_loc, base):
-            """Histograms of one device's (S0_local, S1, S2) block starting
-            at global row ``base``, chunk-looped so only ``rows`` rows of
-            temporaries are live. Cross-chunk sums are Kahan-compensated:
-            in the no-x64 (TPU) regime the carry is f32 and a plain sum
-            over many chunks loses low bits of the per-bin totals."""
-            if split:       # traced once a program, by either ``binning``
-                counter('fftpower.binning.trace.split').add(1)
-            if not chunked:
-                return list(chunk_hists(v_loc, base))
-
-            def body(i, state):
-                acc, comp = state
-                hs_c = chunk_hists(
-                    jax.lax.dynamic_slice_in_dim(v_loc, i * rows, rows, 0),
-                    base + i * rows)
-                new_acc, new_comp = [], []
-                for a, c, h in zip(acc, comp, hs_c):
-                    y = h - c
-                    t = a + y
-                    new_comp.append((t - a) - y)
-                    new_acc.append(t)
-                return (new_acc, new_comp)
-            init_a = [jnp.zeros((Nx + 2, Nmu + 2), hist_dtype)
-                      for _ in range(nstreams)]
-            init_c = [jnp.zeros((Nx + 2, Nmu + 2), hist_dtype)
-                      for _ in range(nstreams)]
-            # inside shard_map the body folds device-local rows into the
-            # carry, so it must start with the block's varying type
-            init_a = [vary_like(a, v_loc) for a in init_a]
-            init_c = [vary_like(a, v_loc) for a in init_c]
-            acc, _ = jax.lax.fori_loop(0, nch, body, (init_a, init_c))
-            return acc
-
-        hist_dtype = jnp.float64 if jax.config.jax_enable_x64 \
-            else jnp.float32
-
-        # the program's name (``jit_binning``) is part of its key in jax's
-        # persistent cache and its scopes are not: under the name it had
-        # before it carried them, a cached executable would come back bare
-        if nproc > 1:
-            from jax.sharding import PartitionSpec as _P
-
-            def binning(v_loc):
-                with scope('fftpower.binning'):
-                    base = jax.lax.axis_index(AXIS) * S0_local
-                    hs = _block_hists(v_loc, base)
-                    return tuple(jax.lax.psum(h, AXIS) for h in hs)
-
-            _bin = instrumented_jit(jax.shard_map(
-                binning, mesh=pm.comm,
-                in_specs=(_P(AXIS, None, None),),
-                out_specs=(_P(),) * nstreams), label='fftpower.binning')
-        else:
-            def binning(v):
-                with scope('fftpower.binning'):
-                    return tuple(_block_hists(v, 0))
-
-            _bin = instrumented_jit(binning, label='fftpower.binning')
+        _bin, nstreams, split, parts = _binning_program(
+            kind, tuple(int(s) for s in value.shape),
+            np.dtype(value.dtype),
+            tuple(int(n) for n in pm.Nmesh),
+            tuple(float(b) for b in pm.BoxSize),
+            xedges.tobytes(), muedges.tobytes(),
+            tuple(float(x) for x in los), tuple(int(l) for l in _poles),
+            getattr(pm, 'comm', None), int(_BIN_CHUNK_ELEMENTS),
+            bool(is_mxu_backend()), bool(jax.config.jax_enable_x64))
 
     with scope('fftpower.binning', nstreams=nstreams,
                shape=[int(s) for s in value.shape],
                nx_edges=len(xedges), nmu_edges=len(muedges),
-               split=split, parts=parts if split else None) as sc:
+               split=split, parts=parts) as sc:
         hs = sc.done(_bin(value))
     # the fetch (it waits for the binning program) and the numpy
     # packaging of the small histograms
     with scope('fftpower.result'):
         hs = fetch(hs, 'fftpower.binning')
-        with scope('fftpower.binning.release'):
-            # the program built for this call dies here, under a name,
-            # and not on the way out of the function (its executable
-            # goes with it: the other half of the re-trace)
-            del _bin, binning
         xsum, musum, Nsum = hs[0], hs[1], hs[2]
         ys_re, ys_im = [], []
         k = 3
@@ -386,7 +421,7 @@ def project_to_basis(y3d, edges, los=[0, 0, 1], poles=[]):
         ysum = (np.asarray(ys_re, dtype='f8')
                 + 1j * np.asarray(ys_im, dtype='f8')
                 ).reshape(Nell, Nx + 2, Nmu + 2)
-        if not jnp.iscomplexobj(value):
+        if not is_cplx:
             ysum = ysum.real
 
         # fold the internal mu == 1 bin into the last visible bin

@@ -171,6 +171,49 @@ _route_jit = instrumented_jit(
     static_argnames=('nmesh', 'boxsize', 'nproc', 'compute_dtype'))
 
 
+def mode_k_list(nmesh, boxsize, dtype, circular=False, full=False):
+    """:meth:`ParticleMesh.k_list` of an ``nmesh`` / ``boxsize``
+    geometry, in the (resolved) ``dtype``: what a cached program
+    builder calls, which has the geometry and no mesh object."""
+    N0, N1, N2 = (int(n) for n in nmesh)
+    L = boxsize
+
+    def freq(n, L_i, r2c_axis=False):
+        if r2c_axis and not full:
+            j = jnp.arange(n // 2 + 1, dtype=dtype)
+        else:
+            j = jnp.fft.fftfreq(n, d=1.0 / n).astype(dtype)
+        if circular:
+            return j * jnp.asarray(2 * np.pi / n, dtype)
+        return j * jnp.asarray(2 * np.pi / L_i, dtype)
+
+    kx = freq(N0, L[0]).reshape(1, N0, 1)
+    ky = freq(N1, L[1]).reshape(N1, 1, 1)
+    nz = N2 if full else N2 // 2 + 1
+    kz = freq(N2, L[2], r2c_axis=True).reshape(1, 1, nz)
+    return [kx, ky, kz]
+
+
+def mode_i_list(nmesh):
+    """:meth:`ParticleMesh.i_list_complex` of an ``nmesh`` geometry."""
+    N0, N1, N2 = (int(n) for n in nmesh)
+    ix = jnp.fft.fftfreq(N0, d=1.0 / N0).astype(jnp.int32).reshape(1, N0, 1)
+    iy = jnp.fft.fftfreq(N1, d=1.0 / N1).astype(jnp.int32).reshape(N1, 1, 1)
+    iz = jnp.arange(N2 // 2 + 1, dtype=jnp.int32).reshape(1, 1, -1)
+    return [ix, iy, iz]
+
+
+def mode_hermitian_weights(n2, dtype=jnp.float32):
+    """:meth:`ParticleMesh.hermitian_weights` of a last axis of ``n2``
+    cells."""
+    from .utils import working_dtype
+    N2 = int(n2)
+    nz = N2 // 2 + 1
+    iz = jnp.arange(nz)
+    w = jnp.where((iz > 0) & ~((N2 % 2 == 0) & (iz == N2 // 2)), 2.0, 1.0)
+    return w.astype(working_dtype(dtype)).reshape(1, 1, nz)
+
+
 class ParticleMesh(object):
     """Geometry + parallel layout descriptor for 3-D particle-mesh fields.
 
@@ -306,43 +349,19 @@ class ParticleMesh(object):
         dtype = working_dtype(dtype) if dtype is not None else (
             jnp.float32 if self.dtype.itemsize <= 4
             else working_dtype('f8'))
-        N0, N1, N2 = (int(n) for n in self.Nmesh)
-        L = self.BoxSize
-
-        def freq(n, L_i, r2c_axis=False):
-            if r2c_axis and not full:
-                j = jnp.arange(n // 2 + 1, dtype=dtype)
-            else:
-                j = jnp.fft.fftfreq(n, d=1.0 / n).astype(dtype)
-            if circular:
-                return j * jnp.asarray(2 * np.pi / n, dtype)
-            return j * jnp.asarray(2 * np.pi / L_i, dtype)
-
-        kx = freq(N0, L[0]).reshape(1, N0, 1)
-        ky = freq(N1, L[1]).reshape(N1, 1, 1)
-        nz = N2 if full else N2 // 2 + 1
-        kz = freq(N2, L[2], r2c_axis=True).reshape(1, 1, nz)
-        return [kx, ky, kz]
+        return mode_k_list(self.Nmesh, self.BoxSize, dtype,
+                           circular=circular, full=full)
 
     def i_list_complex(self):
         """Broadcastable integer mode-index arrays [ix, iy, iz] (signed,
         fftfreq convention) for the transposed complex layout."""
-        N0, N1, N2 = (int(n) for n in self.Nmesh)
-        ix = jnp.fft.fftfreq(N0, d=1.0 / N0).astype(jnp.int32).reshape(1, N0, 1)
-        iy = jnp.fft.fftfreq(N1, d=1.0 / N1).astype(jnp.int32).reshape(N1, 1, 1)
-        iz = jnp.arange(N2 // 2 + 1, dtype=jnp.int32).reshape(1, 1, -1)
-        return [ix, iy, iz]
+        return mode_i_list(self.Nmesh)
 
     def hermitian_weights(self, dtype=jnp.float32):
         """Double-count weights for the compressed kz half-space: weight 2
         for 0 < kz < Nyquist, weight 1 on the kz=0 and Nyquist planes
         (reference: nbodykit/meshtools.py:188-215)."""
-        from .utils import working_dtype
-        N2 = int(self.Nmesh[2])
-        nz = N2 // 2 + 1
-        iz = jnp.arange(nz)
-        w = jnp.where((iz > 0) & ~((N2 % 2 == 0) & (iz == N2 // 2)), 2.0, 1.0)
-        return w.astype(working_dtype(dtype)).reshape(1, 1, nz)
+        return mode_hermitian_weights(self.Nmesh[2], dtype)
 
     # -- paint / readout --------------------------------------------------
 

@@ -164,6 +164,21 @@ def pytest_collection_modifyitems(config, items):
             item.add_marker(pytest.mark.slow)
 
 
+@pytest.fixture(autouse=True)
+def no_binning_program_kept():
+    """``fftpower._binning_program`` keeps its programs for the process,
+    and tests share one: every test starts and leaves with none kept, so
+    that one which counts hits, misses or traces, or patches what the
+    program's body reads and its key does not (``edge_count_index``,
+    ``instrumented_jit``, ``scope``), meets no other test's program and
+    leaves none of its own.  (A test that changes such a patch half way
+    clears the cache there itself.)"""
+    from nbodykit_tpu.algorithms.fftpower import _binning_program
+    _binning_program.cache_clear()
+    yield
+    _binning_program.cache_clear()
+
+
 @pytest.fixture(scope='session')
 def cpu8():
     """An 8-device CPU mesh."""

@@ -364,6 +364,8 @@ def test_fftpower_acceptance_trace(tmp_path, cpu8):
     from nbodykit_tpu.parallel.runtime import use_mesh
     from nbodykit_tpu.source.catalog.uniform import UniformCatalog
     from nbodykit_tpu.algorithms.fftpower import FFTPower
+    # a compile to attribute needs a cold cache: the binning program is
+    # kept per geometry, and conftest.py empties that cache for each test
     with nbodykit_tpu.set_options(diagnostics=str(tmp_path)):
         with use_mesh(cpu8):
             cat = UniformCatalog(nbar=3e-3, BoxSize=32.0, seed=42)

@@ -258,12 +258,14 @@ def test_project_to_basis_chunked_multidevice(monkeypatch):
 def test_project_to_basis_mxu_binning(monkeypatch):
     # the MXU one-hot-matmul histogram is the production binning on
     # TPU; force it on CPU and compare against the exact bincount path
-    import nbodykit_tpu.ops.histogram as hist
+    # (the switch is part of the binning program's key)
+    import nbodykit_tpu.utils
     rng = np.random.RandomState(21)
     field_np = rng.standard_normal((16, 16, 16))
     r_exact = FFTPower(ArrayMesh(field_np, BoxSize=16.0), mode='2d',
                        Nmu=5, poles=[0, 2, 4])
-    monkeypatch.setattr(hist, '_default_method', lambda: 'mxu')
+    monkeypatch.setattr(nbodykit_tpu.utils, 'is_mxu_backend',
+                        lambda: True)
     r_mxu = FFTPower(ArrayMesh(field_np, BoxSize=16.0), mode='2d',
                      Nmu=5, poles=[0, 2, 4])
     np.testing.assert_allclose(r_mxu.power['power'].real,
@@ -301,13 +303,18 @@ def test_fftpower_index_is_numpy_digitize_end_to_end(monkeypatch):
             lambda v, e: np.digitize(v, e).astype('i4'),
             jax.ShapeDtypeStruct(v.shape, jnp.int32), v, edges)
 
+    from nbodykit_tpu.algorithms.fftpower import _binning_program
     modes = {}
     for x64 in (True, False):
         with jax.enable_x64(x64):
             got = call()
+            # the index is not in the binning program's key: built anew
+            # under the patch, and not left for the next caller
+            _binning_program.cache_clear()
             with monkeypatch.context() as m:
                 m.setattr(hist, 'edge_count_index', host_digitize)
                 want = call()
+            _binning_program.cache_clear()
         for g, w in zip(got, want):
             assert g.dtype == w.dtype
             assert g.tobytes() == w.tobytes()
@@ -389,3 +396,240 @@ def test_cross_power_program_equals_its_ops_one_by_one(ndev, cross):
     assert got.dtype == want.dtype and got.sharding == a.sharding
     assert np.asarray(got).tobytes() == np.asarray(want).tobytes()
     assert got[0, 0, 0] == 0
+
+
+# ---------------------------------------------------------------------------
+# the binning as one cached program (``fftpower._binning_program``)
+
+def _binning_counts():
+    """``[hits, misses]`` of the builder's own cache and of the jit it
+    returns, and how many backend compiles the process has timed."""
+    from nbodykit_tpu.algorithms import fftpower as fp
+    from nbodykit_tpu.diagnostics.metrics import REGISTRY
+    snap = REGISTRY.snapshot()
+    info = fp._binning_program.cache_info()
+    return {'builder': [info.hits, info.misses],
+            'jit': [snap.get('compile.fftpower.binning.' + k,
+                             {}).get('value', 0)
+                    for k in ('hits', 'misses')],
+            'backend': snap.get('xla.compile.backend_s',
+                                {}).get('count', 0)}
+
+
+def _bits(result):
+    (r2d, poles) = result
+    return [np.asarray(a).tobytes()
+            for a in tuple(r2d) + tuple(poles or ())]
+
+
+def _binning_case(kind, comm=None, dtype='c8', nmesh=16, box=32.0):
+    """A field of one kind on ``comm`` with edges of its own: what
+    ``project_to_basis`` takes."""
+    import jax
+    nmesh = np.broadcast_to(nmesh, 3)
+    pm = ParticleMesh(Nmesh=nmesh, BoxSize=box, dtype='f4', comm=comm)
+    rng = np.random.RandomState(38)
+    shape = {'hermitian': pm.shape_complex,
+             'full': tuple(int(n) for n in nmesh[[1, 0, 2]]),
+             'real': pm.shape_real}[kind]
+    value = rng.standard_normal(shape)
+    if np.dtype(dtype).kind == 'c':
+        value = value + 1j * rng.standard_normal(shape)
+    value = jnp.asarray(value.astype(dtype))
+    if pm.comm is not None:
+        value = jax.device_put(value, pm.sharding())
+    if kind == 'real':
+        xedges = np.arange(0, box / 2, box / nmesh[0])
+    else:
+        dk = 2 * np.pi / box
+        xedges = np.arange(0, np.pi * nmesh[0] / box + dk / 2, dk)
+    field = Field(value, pm, 'real' if kind == 'real' else 'complex')
+    return field, [xedges, np.linspace(-1, 1, 6)]
+
+
+def _launches_by_mark(tmp_path, call):
+    """How many programs ``call`` launches under each ``nbk.`` host
+    annotation (the innermost), on the profiler's host lines."""
+    import glob
+    import os
+    import jax
+    from jax.profiler import ProfileData
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        out = call()
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(os.path.join(str(tmp_path), 'plugins', 'profile',
+                                   '*', '*.xplane.pb'))
+    host = ProfileData.from_file(path).find_plane_with_name('/host:CPU')
+    counts = {}
+    for line in host.lines:
+        events = list(line.events)
+        marks = [(ev.end_ns - ev.start_ns, ev.name, ev.start_ns,
+                  ev.end_ns) for ev in events
+                 if ev.name.startswith('nbk.')]
+        for ev in events:
+            if not ev.name.endswith('Executable::Execute'):
+                continue
+            inside = [m for m in marks
+                      if m[2] <= ev.start_ns and ev.end_ns <= m[3]]
+            name = min(inside)[1] if inside else None
+            counts[name] = counts.get(name, 0) + 1
+    return out, counts
+
+
+@pytest.mark.parametrize('chunked', [False, True],
+                         ids=['whole', 'chunked'])
+@pytest.mark.parametrize('ndev', [1, 4])
+@pytest.mark.parametrize('kind, poles', [
+    ('hermitian', []), ('hermitian', [0, 2, 4]), ('full', [1, 2]),
+    ('real', [0, 2])])
+def test_binning_second_call_builds_nothing(tmp_path, monkeypatch, kind,
+                                            poles, ndev, chunked):
+    """``project_to_basis`` keeps its program per geometry: a second
+    call with the same key is one hit of the builder and of its jit,
+    traces, lowers and compiles nothing, launches the one program and
+    no eager op before it, and gives the first call's bits."""
+    import nbodykit_tpu
+    from nbodykit_tpu.algorithms import fftpower as fp
+    from nbodykit_tpu.diagnostics import read_trace
+    if chunked:
+        monkeypatch.setattr(fp, '_BIN_CHUNK_ELEMENTS', 2 * 16 * 9)
+    field, edges = _binning_case(
+        kind, comm=cpu_mesh(ndev) if ndev > 1 else None,
+        dtype='f4' if kind == 'real' else 'c8')
+
+    def call():
+        return fp.project_to_basis(field, edges, poles=poles)
+    zero = _binning_counts()        # of an empty cache: conftest.py
+    first = call()
+    cold = _binning_counts()
+    assert cold['builder'] == [0, 1]
+    assert cold['jit'] == [zero['jit'][0], zero['jit'][1] + 1]
+    trace_dir = tmp_path / 'trace'
+    with nbodykit_tpu.set_options(diagnostics=str(trace_dir)):
+        second = call()
+    warm = _binning_counts()
+    assert warm['builder'] == [1, 1]
+    assert warm['jit'] == [cold['jit'][0] + 1, cold['jit'][1]]
+    assert warm['backend'] == cold['backend']
+    records, bad = read_trace(str(trace_dir))
+    assert bad == 0
+    names = [r['name'] for r in records if r.get('t') == 'span']
+    assert 'fftpower.binning' in names
+    assert not [n for n in names if n.startswith('compile.')]
+    third, launches = _launches_by_mark(tmp_path / 'profile', call)
+    assert _binning_counts()['builder'] == [2, 1]
+    assert launches == {'nbk.fftpower.binning': 1}
+    assert _bits(first) == _bits(second) == _bits(third)
+
+
+def _stale_cases():
+    """part of the key -> (base setting, changed setting): each a dict
+    of what ``_binning_case`` and ``project_to_basis`` take, and of
+    the ambient switches."""
+    return {
+        'xedges': ({}, {'xedges': np.arange(0.0, 2.4, 0.3)}),
+        'muedges': ({}, {'muedges': np.linspace(-1, 1, 4)}),
+        'poles': ({}, {'poles': [0, 2]}),
+        'los': ({}, {'los': [1, 0, 0]}),
+        # the hermitian shape (16, 16, 9) of both: an odd last axis has
+        # no Nyquist plane to count once
+        'nmesh': ({}, {'nmesh': (16, 16, 17)}),
+        'boxsize': ({}, {'box': 40.0}),
+        # one complex array of (16, 16, 16): a c2c spectrum, or a
+        # complex field of separations, over the same edges
+        'kind': ({'kind': 'full', 'xedges': np.arange(0.0, 4.4, 0.4)},
+                 {'kind': 'real', 'dtype': 'c8',
+                  'xedges': np.arange(0.0, 4.4, 0.4)}),
+        'dtype': ({'kind': 'real', 'dtype': 'f4'},
+                  {'kind': 'real', 'dtype': 'f8'}),
+        'chunk': ({}, {'chunk': 2 * 16 * 9}),
+        'mxu': ({}, {'mxu': True}),
+        'x64': ({}, {'x64': False}),
+        'mesh': ({}, {'ndev': 4}),
+    }
+
+
+def _stale_call(setting):
+    """``project_to_basis`` under one setting of ``_stale_cases``."""
+    import jax
+    import nbodykit_tpu.utils
+    from nbodykit_tpu.algorithms import fftpower as fp
+    s = dict(kind='hermitian', dtype='c8', nmesh=16, box=32.0, ndev=1,
+             poles=[], los=[0, 0, 1], chunk=fp._BIN_CHUNK_ELEMENTS,
+             mxu=False, x64=True, xedges=None, muedges=None)
+    s.update(setting)
+    field, edges = _binning_case(
+        s['kind'], comm=cpu_mesh(s['ndev']) if s['ndev'] > 1 else None,
+        dtype=s['dtype'], nmesh=s['nmesh'], box=s['box'])
+    # the base's edges for every box, so that one part changes at a time
+    edges = [np.arange(0.0, 2.4, 0.2) if s['xedges'] is None
+             else s['xedges'],
+             edges[1] if s['muedges'] is None else s['muedges']]
+    if s['kind'] == 'real' and s['xedges'] is None:
+        edges[0] = edges[0] * 10
+    with pytest.MonkeyPatch.context() as m:
+        m.setattr(fp, '_BIN_CHUNK_ELEMENTS', s['chunk'])
+        m.setattr(nbodykit_tpu.utils, 'is_mxu_backend',
+                  lambda: s['mxu'])
+        with jax.enable_x64(s['x64']):
+            return _bits(fp.project_to_basis(field, edges, los=s['los'],
+                                             poles=s['poles']))
+
+
+@pytest.mark.parametrize('part', sorted(_stale_cases()))
+def test_binning_program_key_holds_every_part(part):
+    """No stale program: with the base setting's program cached, a call
+    that differs in one part of the key is a miss, and gives what the
+    builder gives for that setting from an empty cache; the base
+    setting's entry is still there afterwards, and still its own."""
+    from nbodykit_tpu.algorithms import fftpower as fp
+    base, changed = _stale_cases()[part]
+    want_base = _stale_call(base)   # into an empty cache: conftest.py
+    assert _binning_counts()['builder'] == [0, 1]
+    before = _binning_counts()
+    got = _stale_call(changed)
+    after = _binning_counts()
+    assert after['builder'] == [0, 2], part
+    assert after['jit'] == [before['jit'][0], before['jit'][1] + 1]
+    assert _stale_call(base) == want_base
+    assert _binning_counts()['builder'] == [1, 2]
+    fp._binning_program.cache_clear()
+    want = _stale_call(changed)
+    assert _binning_counts()['builder'] == [0, 1]
+    assert got == want
+    # and the change is one the answer shows (or, for the chunking,
+    # one the program's loop shows: its sums agree to rounding)
+    if part != 'chunk':
+        assert got != want_base
+
+
+def test_convpower_three_binnings_share_one_program():
+    """The survey call bins three multipoles over one ``[kedges,
+    muedges]``, shape and ``poles=[]``: one entry serves all three, and
+    a second call (the next mock of a set) builds nothing."""
+    from nbodykit_tpu.algorithms import fftpower as fp
+    from nbodykit_tpu.lab import ConvolvedFFTPower, FKPCatalog
+    data = UniformCatalog(nbar=2e-3, BoxSize=64., seed=21)
+    ran = UniformCatalog(nbar=2e-2, BoxSize=64., seed=22)
+    nbar = data.csize / 64. ** 3
+    data['NZ'] = np.ones(data.csize) * nbar
+    ran['NZ'] = np.ones(ran.csize) * nbar
+    mesh = FKPCatalog(data, ran, BoxSize=70.0).to_mesh(
+        Nmesh=16, resampler='cic', compensated=True)
+    zero = _binning_counts()        # of an empty cache: conftest.py
+    first = ConvolvedFFTPower(mesh, poles=[0, 2, 4], dk=0.05)
+    cold = _binning_counts()
+    assert cold['builder'] == [2, 1]
+    assert cold['jit'] == [zero['jit'][0] + 2, zero['jit'][1] + 1]
+    second = ConvolvedFFTPower(mesh, poles=[0, 2, 4], dk=0.05)
+    warm = _binning_counts()
+    assert warm['builder'] == [5, 1]
+    assert warm['jit'] == [cold['jit'][0] + 3, cold['jit'][1]]
+    assert warm['backend'] == cold['backend']
+    for col in ('power_0', 'power_2', 'power_4', 'k', 'modes'):
+        assert np.asarray(first.poles[col]).tobytes() \
+            == np.asarray(second.poles[col]).tobytes()

@@ -317,7 +317,7 @@ def test_shell_sums_counts_past_the_f32_stall(weight, cols):
     assert float(naive[0]) < n
 
 
-def test_bench_pipeline_matches_fftpower():
+def test_bench_pipeline_matches_fftpower(request):
     """bench.py's fused paint->fft->bin program must agree with the
     production FFTPower(mode='2d') on the in-range bins."""
     import importlib.util
@@ -335,7 +335,10 @@ def test_bench_pipeline_matches_fftpower():
     rng = np.random.RandomState(5)
     pos = rng.uniform(0, L, (Npart, 3)).astype('f4')
 
-    nbodykit_tpu.set_options(paint_method='scatter')
+    # both sides with the scatter paint, and the option put back: the
+    # tests that follow in this process read the default
+    opts = nbodykit_tpu.set_options(paint_method='scatter')
+    request.addfinalizer(lambda: opts.__exit__())
     pm = ParticleMesh(Nmesh=Nmesh, BoxSize=L, dtype='f4')
     fused, _phases = bench._bench_fftpower_fn(pm, slab_chunks=8)
     fn = jax.jit(fused)

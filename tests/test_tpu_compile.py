@@ -212,8 +212,8 @@ BINNING_TEMP_PR35 = {(1, 'lab'): LAB_BINNING_TEMP_PR25,
 def test_fftpower_binning_program(one_chip, four_chips, monkeypatch,
                                   chips, cell):
     # the (k, mu) binning program FFTPower(mode='2d', ...) jits: taken
-    # from project_to_basis at the point where it would be called, with
-    # the MXU histogram it uses on a TPU. 'poles': Nmu=5, poles=[0, 2,
+    # from project_to_basis's builder at the point where it would be
+    # jitted and kept, with the MXU histogram it uses on a TPU. 'poles': Nmu=5, poles=[0, 2,
     # 4] at 512^3 on one chip, and at 1024^3 as the shard_map over four
     # (where the first four-chip run found a replicated loop carry).
     # 'lab': the edges of the benchmark's desi_like_n512.lab cell
@@ -247,11 +247,15 @@ def test_fftpower_binning_program(one_chip, four_chips, monkeypatch,
     dk = dk or 2 * np.pi / box
     edges = [np.arange(kmin, np.pi * nmesh / box + dk / 2, dk),
              np.linspace(-1, 1, nmu + 1)]
+    # the key reads the value's shape and dtype alone; a builder that
+    # raises keeps nothing
+    kept = fftpower._binning_program.cache_info().currsize
     with pytest.raises(Taken) as got:
         fftpower.project_to_basis(Field(value, pm, 'complex'), edges,
                                   poles=poles)
     fn, label = got.value.args
     assert label == 'fftpower.binning'
+    assert fftpower._binning_program.cache_info().currsize == kept
     compiled = _compile(fn, value)
     text = compiled.as_text()
     assert _total_bytes(compiled) < 0.25 * V5E_HBM
