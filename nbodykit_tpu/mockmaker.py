@@ -24,9 +24,9 @@ displacement readout (:246). TPU redesign:
   (``lognormal.overflow``), nothing is dropped.  Rows past the count
   carry ``Selection`` False and zeros;
 - cells become rows without a per-cell scatter (:func:`rows_to_cells`:
-  a scatter over the mesh's rows of cells, a cumsum, a binary search
-  inside the row), in raster order, so the selected rows are the
-  exact-count sample's, device-count invariant without the
+  a scatter over segments of 64 cells, a cumsum, one gather of the
+  segment's row a particle), in raster order, so the selected rows are
+  the exact-count sample's, device-count invariant without the
   reference's mpsort pass (mockmaker.py:344).
 """
 
@@ -220,19 +220,29 @@ def sample_rung(expected, drawn):
 
 
 def _select8(lanes, t):
-    """``lanes[:, t]`` of an (n, 8) array, by a chain of selects: the
-    value's own bits (a one-hot sum turns -0.0 into +0.0), and no
-    gather."""
-    out = lanes[:, 7]
-    for j in range(6, -1, -1):
-        out = jnp.where(t == j, lanes[:, j], out)
-    return out
+    """``lanes[:, t]`` of an (n, m) int array, by compares and an
+    integer sum of one non-zero term: the value's own bits (a float
+    one-hot sum turns -0.0 into +0.0), and no gather.  Named for the
+    8-lane blocks it first picked from; the cell's oracle test patches
+    it to read a segment's first cell."""
+    hit = jnp.arange(lanes.shape[1], dtype=t.dtype) == t[:, None]
+    return jnp.where(hit, lanes, 0).sum(axis=1, dtype=lanes.dtype)
 
 
-#: output rows a step of :func:`rows_to_cells`'s loop places: its row
-#: reads are (rows, N2 / 8) and (rows, 32) words; all 1e7 rows at once
+#: cells of the mesh's last axis in one segment of :func:`rows_to_cells`:
+#: a segment's row of its table is 4 x 64 words, two 128-lane tiles
+SEGMENT = 64
+
+#: output rows a step of :func:`rows_to_cells`'s loop places: its one
+#: row read is (rows, 4 SEGMENT) words, 0.5 GB; all 1e7 rows at once
 #: held 13.4 GB of temporaries at 512^3
-ROWS_PER_STEP = 1 << 20
+ROWS_PER_STEP = 1 << 19
+
+
+def _steps(nrows):
+    """``(rows, steps)``: :func:`rows_to_cells`'s loop over ``nrows``."""
+    step = min(ROWS_PER_STEP, nrows)
+    return step, -(-nrows // step)
 
 
 def rows_to_cells(counts, psi, nrows):
@@ -245,68 +255,60 @@ def rows_to_cells(counts, psi, nrows):
     (N^3 of them, mostly colliding empty cells: 1.75 s at 512^3 on a
     v5e, PERF.md), and a TPU gather costs 10-20 ns an index whatever
     the slice's width, so a binary search of 27 gathers is slower
-    still.  Here a row of output finds its cell in three steps, two
-    gathers of wide slices in all, against the cumulative counts along
-    each row of the mesh's last axis:
+    still.  Here the mesh's last axis is cut into segments of
+    :data:`SEGMENT` cells, and a row of output n finds its cell with
+    one gather:
 
-    1. its row of the mesh: a scatter of the N0 N1 row starts (and of
-       the start's increments, whose cumsum is the row's first output
-       row) and a cumsum over the output;
-    2. its block of 8 cells in that row: one gather of the row's
-       cumulative counts at the blocks' ends, compared with its offset
-       in the row;
-    3. its cell in the block, and psi there: one gather of a row of
-       128 words, four blocks' 8 cumulative counts and 3 x 8 psi values
-       each (as their bits: an int is never flushed as a denormal),
-       laid out side by side in one table; chains of selects.
+    1. its segment: a scatter of ones at the segments' first output
+       rows and a cumsum over the output (empty segments collide
+       harmlessly: the last segment starting at or before n holds n);
+    2. its cell in the segment, and psi there: one gather of the
+       segment's row of a table holding, side by side, the cells'
+       cumulative counts over the whole mesh (so n is compared as it
+       is) and the three psi values (as their bits: an int is never
+       flushed as a denormal); the cell is the number of counts at or
+       below n, and psi its lane's bits, picked by compares and an
+       integer sum of one non-zero term (-0.0 kept).
 
-    Steps 2 and 3 run :data:`ROWS_PER_STEP` rows at a time.  Both
-    gathers take whole rows of a table whose last axis is long: the
-    TPU tiles the last two axes (8, 128), an axis of 8 there is padded
-    16 times, and a gather of part of a row lowers to a loop of one
-    index an iteration (22 s a call at 512^3, PERF.md)."""
+    Step 2 runs :data:`ROWS_PER_STEP` rows at a time.  The gather takes
+    whole rows, 4 x 64 words: a gather of part of a row lowers to a
+    loop of one index an iteration (22 s a call at 512^3, PERF.md), and
+    the TPU tiles the last two axes (8, 128), so a last axis of 8 or 1
+    would be padded 16 or 128 times."""
+    counter('lognormal.sample.trace.segment').add(1)    # once a program
     N0, N1, N2 = counts.shape
-    R, S = N0 * N1, 4 * -(-N2 // 32)                 # blocks of 8, by 4
-    pad = ((0, 0), (0, 8 * S - N2))
-    cum = jnp.cumsum(jnp.pad(counts.reshape(R, N2).astype(jnp.int32), pad),
-                     axis=1, dtype=jnp.int32)
+    G = SEGMENT
+    R, S = N0 * N1, -(-N2 // G)
+    pad = ((0, 0), (0, G * S - N2))
+    n = jnp.pad(counts.reshape(R, N2).astype(jnp.int32), pad)
+    cum = jnp.cumsum(n, axis=1, dtype=jnp.int32)
     rowtot = cum[:, -1]
-    rowstart = jnp.cumsum(rowtot, dtype=jnp.int32) - rowtot
-    inc = jnp.concatenate([jnp.zeros(1, jnp.int32), rowtot[:-1]])
-    step = min(ROWS_PER_STEP, nrows)
-    nsteps = -(-nrows // step)
-    row, first = (jnp.cumsum(jnp.zeros(nsteps * step, jnp.int32).at[
-        rowstart].add(v, mode='drop'), dtype=jnp.int32)
-        for v in (jnp.ones_like(inc), inc))
-    off = jnp.arange(nsteps * step, dtype=jnp.int32) - first
-    ends = cum[:, 7::8]                                 # (R, S)
+    cum = cum + (jnp.cumsum(rowtot, dtype=jnp.int32) - rowtot)[:, None]
+    first = (cum - n)[:, ::G].reshape(R * S)
+    step, nsteps = _steps(nrows)
+    seg = jnp.cumsum(jnp.zeros(nsteps * step, jnp.int32).at[first].add(
+        1, mode='drop'), dtype=jnp.int32) - 1
     parts = [cum] + [jax.lax.bitcast_convert_type(
         jnp.pad(d.reshape(R, N2).astype(jnp.float32), pad), jnp.int32)
         for d in psi]
-    table = jnp.stack(parts, axis=1).reshape(R, 4, S, 8).transpose(
-        0, 2, 1, 3).reshape(R, S // 4, 128)
-
-    def window(r, b):
-        w = table[r, b // 4]                            # (step, 128)
-        q = (b % 4)[:, None]
-        out = w[:, 96:]
-        for j in (2, 1, 0):
-            out = jnp.where(q == j, w[:, 32 * j:32 * j + 32], out)
-        return out
+    # a part at a time: a (R S, G) part is padded to 128 lanes, and the
+    # four at once held 2.1 GB more at 512^3
+    table = jnp.zeros((R * S, 4 * G), jnp.int32)
+    for k, p in enumerate(parts):
+        table = table.at[:, k * G:(k + 1) * G].set(p.reshape(R * S, G))
 
     def place(args):
-        row, off = args
-        below = ends[row] <= off[:, None]               # (step, S)
-        s = jnp.minimum(below.sum(axis=1, dtype=jnp.int32), S - 1)
-        h = window(row, s)                              # (step, 32)
-        t = jnp.minimum((h[:, :8] <= off[:, None]).sum(
-            axis=1, dtype=jnp.int32), 7)
-        disp = jnp.stack([_select8(h[:, 8 * (k + 1):8 * (k + 2)], t)
-                          for k in range(3)], axis=-1)
-        return row * N2 + s * 8 + t, disp
+        seg, row = args
+        block = table[seg]                              # (step, 4 G)
+        t = jnp.minimum((block[:, :G] <= row[:, None]).sum(
+            axis=1, dtype=jnp.int32), G - 1)
+        disp = jnp.stack([_select8(block[:, G * k:G * (k + 1)], t)
+                          for k in (1, 2, 3)], axis=-1)
+        return (seg // S) * N2 + (seg % S) * G + t, disp
 
-    cell, disp = jax.lax.map(place, ((row - 1).reshape(nsteps, step),
-                                     off.reshape(nsteps, step)))
+    rows = jnp.arange(nsteps * step, dtype=jnp.int32)
+    cell, disp = jax.lax.map(place, (seg.reshape(nsteps, step),
+                                     rows.reshape(nsteps, step)))
     return (cell.reshape(-1)[:nrows],
             jax.lax.bitcast_convert_type(disp.reshape(-1, 3)[:nrows],
                                          jnp.float32))
@@ -435,10 +437,11 @@ def lognormal_sample(pm, linear_power, seed, nbar, bias=1.0, vfac=0.0,
         bool(inverted_phase))
     with scope('lognormal.fields'):
         counts, total, psi = jitted(key, amplitude)
-    with scope('lognormal.sample'):
+    with scope('lognormal.sample') as sc:
         # the rung is a static shape of what follows: one host sync
         N = int(fetch(total, 'lognormal.count'))
         rung, climbs = sample_rung(nbar * float(np.prod(pm.BoxSize)), N)
+        sc.set(segment=SEGMENT, steps=_steps(rung)[1])
         counter('lognormal.overflow').add(climbs)
         gauge('lognormal.capacity').set(rung)
         gauge('lognormal.fill').set(N / float(rung))

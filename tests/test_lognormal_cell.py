@@ -246,17 +246,26 @@ def test_poisson_counts_are_knuths_and_jaxs():
     assert got.sum() > 0.5 * lam.sum()
 
 
-@pytest.mark.parametrize('shape, step', [((4, 4, 16), None),
-                                         ((3, 5, 13), None),
-                                         ((8, 8, 32), 64)])
-def test_rows_to_cells_is_a_repeat(monkeypatch, shape, step):
-    # any last axis (13 is no multiple of a block), dense and sparse
-    # cells, counts over a block's worth, and rows in several steps
+@pytest.mark.parametrize('shape, step, case', [
+    ((4, 4, 16), None, None), ((3, 5, 13), None, None),
+    ((8, 8, 32), 64, None), ((4, 3, 100), None, None),
+    ((3, 4, 128), None, None), ((5, 4, 16), None, 'empty plane'),
+    ((4, 4, 64), None, 'full cell'), ((4, 4, 64), 16, 'full cell')])
+def test_rows_to_cells_is_a_repeat(monkeypatch, shape, step, case):
+    # any last axis (13 and 100 are no multiple of a segment, 128 is
+    # two), dense and sparse cells, a cell over a segment's worth of
+    # rows (200 of 64 cells), a plane of empty cells, and rows in
+    # several steps, down to steps shorter than a segment's rows
     if step:
         monkeypatch.setattr(mockmaker, 'ROWS_PER_STEP', step)
     rng = np.random.RandomState(sum(shape))
     counts = rng.poisson(rng.exponential(0.5, shape) ** 2).astype('i4')
     counts.reshape(-1)[5] = 40
+    if case == 'empty plane':
+        counts[2] = 0
+    elif case == 'full cell':
+        counts.reshape(-1)[70] = 200
+    counts.reshape(-1)[3] += 1
     psi = [rng.standard_normal(shape).astype('f4') for _ in range(3)]
     psi[0].reshape(-1)[3] = -0.0
     n = int(counts.sum())
@@ -267,6 +276,25 @@ def test_rows_to_cells_is_a_repeat(monkeypatch, shape, step):
     for k in range(3):
         assert np.asarray(disp)[:n, k].tobytes() \
             == psi[k].reshape(-1)[want].tobytes()
+
+
+def test_sample_span_and_counter_name_the_placement(plin, tmp_path,
+                                                    monkeypatch):
+    # each lognormal.sample span says how its rows were placed: segments
+    # of 64 cells, in so many steps of the loop; the trace-time counter
+    # lognormal.sample.trace.segment counts one a compiled program
+    from nbodykit_tpu import set_options
+    from nbodykit_tpu.diagnostics.trace import read_trace
+    monkeypatch.setattr(mockmaker, 'ROWS_PER_STEP', 1024)
+    before = counted('lognormal.sample.trace.segment')
+    with set_options(diagnostics=str(tmp_path)):
+        cats = [mock(plin, 32, seed) for seed in (1, 2)]
+    spans = [r for r in read_trace(str(tmp_path))[0]
+             if r.get('t') == 'span' and r['name'] == 'lognormal.sample']
+    assert cats[0].rung == cats[1].rung > 2048
+    assert [(s['attrs']['segment'], s['attrs']['steps']) for s in spans] \
+        == [(64, -(-cats[0].rung // 1024))] * 2
+    assert counted('lognormal.sample.trace.segment') - before == 1
 
 
 @pytest.mark.parametrize('ndev', [1, 4])

@@ -30,6 +30,9 @@ from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
 NMESH, NPART = 512, 10 ** 7
 #: what one v5e offers a program ("Used 16.50G of 15.75G hbm")
 V5E_HBM = 15.75 * 2 ** 30
+#: the lognormal sample program's temporaries at 512^3 when its rows
+#: were placed by two gathers a row and chains of selects
+SAMPLE_TEMP_BYTES = 4363914752
 
 
 @pytest.fixture(scope='module')
@@ -525,7 +528,7 @@ def test_lognormal_data_phase_programs_at_512(one_chip, program):
     # the fields program (white noise, delta_k and psi_k, four c2r, the
     # rate and its Poisson draw) and the sample program at the cell's
     # rung, with its 1.34e8 cells: no scatter of a cell's update, no
-    # gather of a row's index but the two row reads
+    # gather of a row's index but the one row read
     from nbodykit_tpu import mockmaker
     box, nbar = 5000.0, 8e-5
     key = jax.ShapeDtypeStruct((), jax.random.key(0).dtype,
@@ -562,16 +565,28 @@ def test_lognormal_data_phase_programs_at_512(one_chip, program):
             mockmaker._sample_program.cache_clear()
         names = ('nbk.lognormal.sample',)
         hlo = compiled.as_text()
+        looped = []
         for line in hlo.splitlines():
             kind = re.search(r'[\]})] (scatter|gather)\(',
                              line.split(' = ', 1)[-1])
             if kind and 'nbk.lognormal' in line:
-                # the N0 N1 row starts and the two row reads; nothing
-                # with an index a cell
+                # the segments' starts, the strided read of them and the
+                # row read; nothing with an index a cell
                 assert '[%d]' % NMESH ** 3 not in line.split(kind.group(1))[0], line
+                if '/while/' in line:
+                    looped.append(line)
+        # one read a row of output in the loop's body: a whole row of
+        # the segments' table, 4 x 64 words
+        assert len(looped) == 1 and ' gather(' in looped[0] \
+            and 'slice_sizes={1,256}' in looped[0], looped
     text = compiled.as_text()
     for name in names:
         assert name in text, name
     m = compiled.memory_analysis()
     print(program, m.temp_size_in_bytes, _total_bytes(compiled))
     assert _total_bytes(compiled) < 0.6 * V5E_HBM
+    if program == 'sample':
+        # no more than the two-gather placement held (3,759,289,856 B
+        # when this was written)
+        print('sample temp bound', SAMPLE_TEMP_BYTES)
+        assert m.temp_size_in_bytes <= SAMPLE_TEMP_BYTES
